@@ -2,7 +2,7 @@
 the zero-egress sandbox (1,797 genuine 8x8 grayscale digit scans bundled
 with scikit-learn). Used for recorded accuracy evidence: unlike the
 synthetic mnist/cifar fallbacks, convergence here demonstrates actual
-learning on actual data (VERDICT r1 #5 / BASELINE accuracy target).
+learning on actual data (BASELINE accuracy target).
 
 Images are upsampled 8x8 -> 32x32 so the conv stacks (two stride/pool
 halvings) still see a useful spatial extent. Split: 1,497 train / 300 val,

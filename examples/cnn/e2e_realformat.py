@@ -1,5 +1,5 @@
-"""End-to-end training through the REAL on-disk data formats (VERDICT r3
-#8): fabricate a valid CIFAR-10 pickle-batch directory and MNIST IDX
+"""End-to-end training through the REAL on-disk data formats:
+fabricate a valid CIFAR-10 pickle-batch directory and MNIST IDX
 files (the exact byte formats the reference downloads —
 reference examples/cnn/data/cifar10.py / mnist.py), then run
 examples/cnn/train_cnn.py for one epoch THROUGH ITS OWN argv entrypoint

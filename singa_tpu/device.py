@@ -93,12 +93,15 @@ class Device:
         pass
 
     def Sync(self):
-        """Fence: wait for all queued device work (Device::Sync)."""
-        try:
-            self.jax_device.client.synchronize_all_activity()  # type: ignore[attr-defined]
-        except Exception:
-            # Portable fallback: a tiny transfer forces a sync point.
-            jax.device_put(np.zeros(()), self.jax_device).block_until_ready()
+        """Fence: wait for all queued device work (Device::Sync). A TPU
+        runs its programs in order, so a tiny computation waited on
+        finishes only after everything queued before it. (A tiny
+        transfer does not: on the TPU it returned while queued matmuls
+        still ran — transfers ride their own stream. The CPU backend
+        may run independent programs side by side: there, block on the
+        arrays you need.)"""
+        (jax.device_put(np.zeros((), np.float32), self.jax_device)
+         + 0).block_until_ready()
 
     # ---- profiling (device.h:115-129) -----------------------------------
     def SetVerbosity(self, v: int):
@@ -196,8 +199,9 @@ class _Platform:
         self._cache = {}
 
     def _accel_devices(self):
-        devs = [d for d in jax.devices() if d.platform != "cpu"]
-        return devs if devs else jax.devices()
+        """The attached TPU chips — empty on a host-only machine, never
+        the CPU devices under another name."""
+        return [d for d in jax.devices() if d.platform == "tpu"]
 
     def GetNumGPUs(self) -> int:  # name kept for parity; counts accelerators
         return len(self._accel_devices())
@@ -212,7 +216,13 @@ class _Platform:
                 jd = jax.local_devices(backend="cpu")[idx]
                 self._cache[key] = Device(jd, id=idx, lang="kCpp")
             else:
-                jd = self._accel_devices()[idx]
+                devs = self._accel_devices()
+                if not devs:
+                    raise RuntimeError(
+                        "no TPU attached: jax.devices() reports "
+                        f"{[d.platform for d in jax.devices()]}; use "
+                        "get_default_device()/best_device() for the host")
+                jd = devs[idx]
                 self._cache[key] = Device(jd, id=idx, lang="kTpu")
         return self._cache[key]
 
@@ -233,7 +243,8 @@ def get_default_device() -> Device:
 
 
 def create_tpu_device(set_default: bool = False) -> Device:
-    """First attached TPU chip (reference: create_cuda_gpu)."""
+    """First attached TPU chip (reference: create_cuda_gpu). Raises
+    RuntimeError on a machine with no TPU."""
     d = platform.device("accel", 0)
     if set_default:
         global _default_device
@@ -256,9 +267,11 @@ def create_cpu_device() -> Device:
 
 
 def best_device() -> Device:
-    """The fastest attached device: TPU if present, else host CPU."""
-    accel = [d for d in jax.devices() if d.platform != "cpu"]
-    return platform.device("accel", 0) if accel else get_default_device()
+    """The fastest attached device: TPU if present, else host CPU. A
+    measurement or smoke path must assert `.platform` on the result, or
+    call create_tpu_device(), which raises with no TPU."""
+    return platform.device("accel", 0) if platform._accel_devices() \
+        else get_default_device()
 
 
 def enable_lazy_alloc(flag: bool):

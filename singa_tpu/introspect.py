@@ -104,7 +104,6 @@ PEAK_TFLOPS_BF16 = [
     ("v6", 918.0), ("trillium", 918.0),
     ("v5p", 459.0),
     ("v5 lite", 197.0), ("v5e", 197.0), ("v5litepod", 197.0),
-    ("v5", 459.0),
     ("v4", 275.0),
     ("v3", 123.0),
     ("v2", 45.0),
@@ -115,7 +114,6 @@ PEAK_HBM_GBS = [
     ("v6", 1638.0), ("trillium", 1638.0),
     ("v5p", 2765.0),
     ("v5 lite", 819.0), ("v5e", 819.0), ("v5litepod", 819.0),
-    ("v5", 2765.0),
     ("v4", 1228.0),
     ("v3", 900.0),
     ("v2", 700.0),
@@ -123,6 +121,8 @@ PEAK_HBM_GBS = [
 
 
 def chip_peak(device_kind: str, table):
+    """The table's peak for `device_kind`, or None for a kind no row
+    names — an unlisted chip gets no MFU, never a neighbour's peak."""
     kind = (device_kind or "").lower()
     for key, peak in table:
         if key in kind:
@@ -499,14 +499,9 @@ def _stage(fn, args):
     per-phase wall timing. Raises whatever the staging machinery
     raises; callers decide the fallback."""
     t0 = time.perf_counter()
-    if hasattr(fn, "trace"):
-        traced = fn.trace(*args)
-        t1 = time.perf_counter()
-        lowered = traced.lower()
-    else:
-        # pre-0.4.30 jax: no Traced stage; trace+lower in one call
-        t1 = t0
-        lowered = fn.lower(*args)
+    traced = fn.trace(*args)
+    t1 = time.perf_counter()
+    lowered = traced.lower()
     t2 = time.perf_counter()
     compiled = lowered.compile()
     t3 = time.perf_counter()
@@ -514,18 +509,124 @@ def _stage(fn, args):
                       "compile": t3 - t2}
 
 
+# Typed-key blob framing. jax.export serializes an extended PRNG-key
+# dtype (`key<fry>`), but the deserialized module does not stage with
+# device-committed arguments: `Exported.call` pins each argument with a
+# sharding constraint of the key's LOGICAL rank on its physical
+# `tensor<2xui32>` ("sharding doesn't match tensor rank: 0 != 1", jax
+# 0.9.0), and every training step threads a committed dev.rng_state — so
+# no train step would ever warm-hit. The bridge exports an adapter that
+# speaks raw uint32 key-data at the boundary (wrap_key_data on the way
+# in, key_data on the way out) and frames the blob with the key
+# positions so deserialization rebuilds a transparent wrapper: the
+# caller still passes/receives typed keys and never sees the framing.
+_KEY_BLOB_MAGIC = b"SGXK1"
+
+
+def _key_leaves(tree):
+    """[(flat_leaf_index, impl_name), ...] for every typed-PRNG-key
+    leaf of `tree` (works on concrete arrays and eval_shape structs)."""
+    import jax
+    out = []
+    for i, leaf in enumerate(jax.tree_util.tree_leaves(tree)):
+        dt = getattr(leaf, "dtype", None)
+        if dt is not None and jax.dtypes.issubdtype(dt, jax.dtypes.prng_key):
+            out.append((i, str(dt._impl.name)))
+    return out
+
+
+def _serialize_executable(fn, args) -> "bytes | None":
+    """`jax.export` blob (StableHLO) of jitted `fn` specialized to the
+    concrete `args` tuple, or None when the function resists exporting
+    (e.g. unserializable custom calls): the caller then builds fresh and
+    skips the store write. Typed PRNG keys in the signature are bridged
+    to raw key-data (see _KEY_BLOB_MAGIC above); note the adapter is a
+    plain jit, so buffer donation declared on `fn` does not survive into
+    the stored module."""
+    import jax
+    from jax import export as jexport
+    try:
+        keys_in = _key_leaves(args)
+        out_sds = jax.eval_shape(fn, *args)
+        keys_out = _key_leaves(out_sds)
+        if not keys_in and not keys_out:
+            return jexport.export(fn)(*args).serialize()
+        in_td = jax.tree_util.tree_structure(tuple(args))
+        out_td = jax.tree_util.tree_structure(out_sds)
+
+        def adapter(*raw):
+            ls = list(jax.tree_util.tree_leaves(raw))
+            for i, impl in keys_in:
+                ls[i] = jax.random.wrap_key_data(ls[i], impl=impl)
+            out = fn(*jax.tree_util.tree_unflatten(in_td, ls))
+            ols = list(jax.tree_util.tree_leaves(out))
+            for i, _impl in keys_out:
+                ols[i] = jax.random.key_data(ols[i])
+            return jax.tree_util.tree_unflatten(out_td, ols)
+
+        raw_leaves = list(jax.tree_util.tree_leaves(tuple(args)))
+        for i, _impl in keys_in:
+            raw_leaves[i] = jax.random.key_data(raw_leaves[i])
+        raw_args = jax.tree_util.tree_unflatten(in_td, raw_leaves)
+        fb = jexport.export(jax.jit(adapter))(*raw_args).serialize()
+        header = json.dumps(
+            {"keys_in": keys_in, "keys_out": keys_out}).encode("utf-8")
+        return (_KEY_BLOB_MAGIC + len(header).to_bytes(4, "big")
+                + header + fb)
+    except Exception:
+        return None
+
+
+def _deserialize_executable(blob: bytes):
+    """A fresh jit-wrapped callable over the deserialized exported
+    module, or None when the blob does not deserialize — the warm store
+    treats that as a corrupt entry. Staging the returned callable
+    re-traces only the exported module's call wrapper
+    (depth-independent), and its XLA cache key is stable across
+    processes — the property the warm-start layer's cold path relies on
+    by staging through this same round-trip. Key-framed blobs (see
+    _KEY_BLOB_MAGIC) come back wrapped so the caller passes and receives
+    typed PRNG keys exactly as it would with the original function."""
+    import jax
+    from jax import export as jexport
+    try:
+        if not blob.startswith(_KEY_BLOB_MAGIC):
+            return jax.jit(jexport.deserialize(blob).call)
+        off = len(_KEY_BLOB_MAGIC)
+        n = int.from_bytes(blob[off:off + 4], "big")
+        header = json.loads(blob[off + 4:off + 4 + n].decode("utf-8"))
+        keys_in = [(int(i), str(impl)) for i, impl in header["keys_in"]]
+        keys_out = [(int(i), str(impl)) for i, impl in header["keys_out"]]
+        exp = jexport.deserialize(blob[off + 4 + n:])
+    except Exception:
+        return None
+
+    def call(*a):
+        ls = list(jax.tree_util.tree_leaves(a))
+        td = jax.tree_util.tree_structure(tuple(a))
+        for i, _impl in keys_in:
+            ls[i] = jax.random.key_data(ls[i])
+        out = exp.call(*jax.tree_util.tree_unflatten(td, ls))
+        ols = list(jax.tree_util.tree_leaves(out))
+        otd = jax.tree_util.tree_structure(out)
+        for i, impl in keys_out:
+            ols[i] = jax.random.wrap_key_data(ols[i], impl=impl)
+        return jax.tree_util.tree_unflatten(otd, ols)
+
+    return jax.jit(call)
+
+
 def export_executable(fn, args, key, fingerprint) -> "bytes | None":
     """Serialize jitted `fn` specialized to the concrete `args` tuple
-    (jax.export, version-gated in _compat) and write it into the warm
-    store under (key, fingerprint). Returns the blob, or None when the
-    store is disabled, this jax cannot export, the function resists
+    and write it into the warm store under (key, fingerprint). Returns
+    the blob, or None when the store is disabled, the function resists
     exporting, or the store write fails — in every case the caller
     simply proceeds without persistence."""
-    from . import _compat, warmstart
+    from . import warmstart
     store = warmstart.get_store()
     if store is None:
         return None
-    blob = _compat.serialize_executable(fn, args)
+    blob = _serialize_executable(fn, args)
     if blob is None:
         return None
     if store.save(key, fingerprint, blob) is None:
@@ -545,7 +646,7 @@ def load_executable(key, fingerprint, *, count: bool = True):
     re-exports a clean replacement. With count=False the caller records
     the classification itself (`build_compiled` does, after staging
     confirms the artifact actually compiles)."""
-    from . import _compat, warmstart
+    from . import warmstart
     store = warmstart.get_store()
     if store is None:
         return None, None, 0.0
@@ -553,7 +654,7 @@ def load_executable(key, fingerprint, *, count: bool = True):
     blob, result = store.load(key, fingerprint)
     warm_fn = None
     if blob is not None:
-        warm_fn = _compat.deserialize_executable(blob)
+        warm_fn = _deserialize_executable(blob)
         if warm_fn is None:
             result = warmstart.RESULT_CORRUPT
             store.discard(key, fingerprint)
@@ -584,7 +685,7 @@ def build_compiled(fn, args, key, sig=None, device=None):
     fresh path and re-exports. The lookup classification lands on the
     build record (`warm`) and the EventLog compile record.
     """
-    from . import _compat, warmstart
+    from . import warmstart
     if sig is None:
         sig = signature(args)
     fingerprint = _sig_fingerprint(key, sig)
@@ -622,7 +723,7 @@ def build_compiled(fn, args, key, sig=None, device=None):
             # exported module's cache key is stable across processes;
             # the original python callable's is not)
             blob = export_executable(fn, args, key, fingerprint)
-            rt = _compat.deserialize_executable(blob) if blob else None
+            rt = _deserialize_executable(blob) if blob else None
             if rt is not None:
                 try:
                     compiled, phases = _stage(rt, args)

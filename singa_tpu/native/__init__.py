@@ -3,7 +3,8 @@
 Each component is one .cc compiled into a cached shared object and loaded
 via ctypes (this environment has no pybind11; ctypes IS the binding
 layer). Loaders return None when no compiler is available — callers then
-use their pure-Python fallback paths.
+use their pure-Python fallback paths; `lib() is not None` says which of
+the two a machine got.
 
 Components:
 - recordio.cc  -> lib():          threaded-prefetch record IO (data plane)
@@ -15,6 +16,7 @@ Components:
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -26,20 +28,26 @@ _libs: dict = {}
 
 
 def _compile(name: str) -> str | None:
+    """Path of the shared object built from the committed `name`.cc, or
+    None with no compiler. The file name carries a hash of the source,
+    so a `lib*.so` that came along with a copied tree (whose mtimes say
+    nothing) is loaded only if it was built from exactly this source."""
     src = os.path.join(_DIR, name + ".cc")
-    so = os.path.join(_DIR, f"lib{name}.so")
-    if os.path.exists(so) and \
-            os.path.getmtime(so) >= os.path.getmtime(src):
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:12]
+    so = os.path.join(_DIR, f"lib{name}-{digest}.so")
+    if os.path.exists(so):
         return so
+    tmp = f"{so}.{os.getpid()}.tmp"
     try:
         subprocess.run(
             ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
-             src, "-o", so + ".tmp"],
+             src, "-o", tmp],
             check=True, capture_output=True, timeout=120)
-        os.replace(so + ".tmp", so)
-        return so
+        os.replace(tmp, so)
     except (OSError, subprocess.SubprocessError):
         return None
+    return so
 
 
 def _load(name: str, annotate) -> "ctypes.CDLL | None":

@@ -46,6 +46,13 @@ COMM_OPS = ("all_reduce", "all_reduce_half", "all_gather", "broadcast",
             "sparse_all_reduce_topk", "sparse_all_reduce_threshold",
             "other")
 
+#: the attention call sites that choose between a Pallas kernel and a jnp
+#: reference (ops.attention), and the paths they can take: the compiled
+#: kernel, the same kernel in interpret mode (how CPU tests run it), or
+#: the reference math.
+ATTN_SITES = ("flash_fwd", "flash_bwd", "paged", "flash_decode")
+ATTN_PATHS = ("kernel", "interpret", "reference")
+
 # Log-scale bucket boundaries (seconds): 1e-6 .. 1e3, ratio sqrt(10).
 # Wide enough for a 2us collective and a 15-minute XLA compile alike.
 DEFAULT_BUCKETS = tuple(10.0 ** (e / 2.0) for e in range(-12, 7))
@@ -836,6 +843,20 @@ def record_comm(op: str, nbytes: int, world_size: int = 1):
         counter("singa_comm_bytes_total",
                 "payload bytes per traced collective"
                 ).inc(float(nbytes), op=op)
+
+
+def record_attention_dispatch(site: str, path: str):
+    """The implementation one attention call site chose. Called at trace
+    time (once per call site per compilation, like record_comm), so the
+    counter describes the compiled programs: on a TPU backend any
+    `path="reference"` or `"interpret"` count means a kernel was
+    silently not used — the smoke run fails on it."""
+    assert site in ATTN_SITES and path in ATTN_PATHS, (site, path)
+    if not _enabled:
+        return
+    counter("singa_attention_dispatch_total",
+            "attention call sites traced, by site and the path taken "
+            "(kernel|interpret|reference)").inc(site=site, path=path)
 
 
 def record_comm_host(op: str, start: float, seconds: float):

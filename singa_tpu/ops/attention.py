@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .. import _compat  # noqa: F401  (installs jax.shard_map on old jax)
+from ..observe import record_attention_dispatch
 
 _NEG_INF = -1e30
 
@@ -601,6 +601,10 @@ def _resolve(scale, d, interpret):
     return scale, interpret
 
 
+def _kernel_path(interpret):
+    return "interpret" if interpret else "kernel"
+
+
 def _resolve_blocks(sq, sk, block_q, block_k):
     """(bq, bk, ok): pick tiles that divide the sequence on 8-sublane
     alignment (TPU lowering constraint). None selects the largest evenly-
@@ -628,7 +632,9 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
     sq, sk = q.shape[2], k.shape[2]
     bq, bk, ok = _resolve_blocks(sq, sk, block_q, block_k)
     if not _HAS_PALLAS or not ok:
+        record_attention_dispatch("flash_fwd", "reference")
         return attention_reference(q, k, v, causal, scale), None
+    record_attention_dispatch("flash_fwd", _kernel_path(interpret))
     out, lse = _flash_fwd_pallas(q, k, v, causal, scale, bq, bk, interpret)
     return out, lse
 
@@ -649,6 +655,7 @@ def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
 def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, res, g):
     saved, ref_vjp = res
     if saved is None:
+        record_attention_dispatch("flash_bwd", "reference")
         return ref_vjp(g)
     q, k, v, out, lse = saved
     d = q.shape[-1]
@@ -662,8 +669,10 @@ def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, res, g):
     bq = _fit_block(sq, min(block_q or _default_block(sq), 512))
     bk = _fit_block(sk, min(block_k or _default_block(sk), 512))
     if _HAS_PALLAS and bq and bk:
+        record_attention_dispatch("flash_bwd", _kernel_path(interp))
         return _flash_bwd_pallas(q, k, v, out, lse, g, causal, s, bq, bk,
                                  interp)
+    record_attention_dispatch("flash_bwd", "reference")
     return _flash_bwd_blockwise(q, k, v, out, lse, g, causal, s,
                                 _fit_block(sk, 512) or sk)
 
@@ -1166,7 +1175,10 @@ def paged_attention(q, k_pool, v_pool, page_table, lengths, page_size,
     interpret mode, unrolling the whole (N, Hp, pages) grid into every
     traced decode step; `use_kernel=True` forces it (interpret off-TPU,
     how the agreement test exercises the kernel path), False forces the
-    reference."""
+    reference. On a TPU an unaligned shape takes the reference whatever
+    `use_kernel` says — always so for int4 KV at PD=128, whose packed
+    rows are 64 lanes wide. The path taken is counted per call site
+    (observe.record_attention_dispatch)."""
     N, Hp, Q, PD = q.shape
     ps = int(page_size)
     on_tpu = jax.default_backend() == "tpu"
@@ -1178,10 +1190,12 @@ def paged_attention(q, k_pool, v_pool, page_table, lengths, page_size,
     if use_kernel is None:
         use_kernel = on_tpu and aligned
     if not use_kernel or not _HAS_PALLAS or (on_tpu and not aligned):
+        record_attention_dispatch("paged", "reference")
         return paged_attention_reference(
             q, k_pool, v_pool, page_table, lengths, ps, scale,
             k_scales, v_scales, groups, q_tokens)
     interpret = not on_tpu
+    record_attention_dispatch("paged", _kernel_path(interpret))
     return _paged_fwd_pallas(q, k_pool, v_pool, page_table, lengths, ps,
                              scale, k_scales, v_scales, groups, interpret,
                              q_tokens)
@@ -1376,8 +1390,10 @@ def flash_decode(q, K, V, lengths, scale=1.0, k_scales=None,
         use_kernel = on_tpu and aligned
     if not use_kernel or not _HAS_PALLAS or bt is None \
             or (on_tpu and not aligned):
+        record_attention_dispatch("flash_decode", "reference")
         return flash_decode_reference(q, K, V, lengths, scale, k_scales,
                                       v_scales, groups, q_tokens)
+    record_attention_dispatch("flash_decode", _kernel_path(not on_tpu))
     return _flash_decode_pallas(q, K, V, lengths, scale, k_scales,
                                 v_scales, groups, not on_tpu, q_tokens,
                                 bt)

@@ -19,10 +19,9 @@ module *reclaims* them, standard TPU-systems practice:
     out — no double counting).
 
   - Async checkpointing: `start_async_save` routes an orbax tree through
-    `AsyncCheckpointer` (version-gated in `_compat.make_async_
-    checkpointer`; callers fall back to the sync write when this orbax
-    cannot). The save call returns after the device→host snapshot; the
-    serialize/write overlaps training in orbax's background thread.
+    `AsyncCheckpointer`. The save call returns after the device→host
+    snapshot; the serialize/write overlaps training in orbax's
+    background thread.
     `wait_for_checkpoints()` is the barrier: it blocks until every
     in-flight save is durable and RE-RAISES the first deferred write
     failure instead of swallowing it. The barrier is auto-invoked by the
@@ -277,8 +276,8 @@ _pending: "list[_PendingSave]" = []
 # paths whose deferred write failed at a barrier — outlives the barrier
 # that drained them (see write_failed); a fresh save to the path clears it
 _failed_paths: "set[str]" = set()
-_async_ck = None       # cached orbax AsyncCheckpointer (or False: probed,
-_atexit_installed = False  # unavailable on this orbax)
+_async_ck = None       # cached orbax AsyncCheckpointer (built on first save)
+_atexit_installed = False
 
 
 class _PendingSave:
@@ -293,27 +292,17 @@ class _PendingSave:
         self.checkpointer.wait_until_finished()
 
 
-def async_available() -> bool:
-    """True when this orbax can async-save. A pure probe: consults the
-    construction cache when a save already built (or failed to build)
-    the checkpointer, otherwise answers from `_compat.has_async_
-    checkpointer`'s attribute check — never constructing one itself,
-    so a diagnostics scrape on a process that never checkpoints does
-    not spin up orbax's resident worker threads."""
-    with _ckpt_lock:
-        if _async_ck is not None:
-            return bool(_async_ck)
-    from . import _compat
-    return _compat.has_async_checkpointer()
-
-
 def _get_async_checkpointer():
+    """The process's orbax AsyncCheckpointer, built on first save only:
+    constructing one spins up orbax's resident worker threads, which a
+    process that never checkpoints must not pay for."""
     global _async_ck
     with _ckpt_lock:
         if _async_ck is None:
-            from . import _compat
-            _async_ck = _compat.make_async_checkpointer() or False
-        return _async_ck or None
+            import orbax.checkpoint as ocp
+            _async_ck = ocp.AsyncCheckpointer(
+                ocp.StandardCheckpointHandler())
+        return _async_ck
 
 
 def _atexit_barrier():
@@ -407,7 +396,7 @@ def wait_for_checkpoints():
         with _ckpt_lock:
             _failed_paths.update(os.path.abspath(e.path)
                                  for e, _ in errors)
-            if _async_ck and any(e.checkpointer is _async_ck
+            if _async_ck is not None and any(e.checkpointer is _async_ck
                                  for e, _ in errors):
                 try:
                     _async_ck.close()
@@ -421,21 +410,15 @@ def wait_for_checkpoints():
         ) from err
 
 
-def start_async_save(path: str, tree, force: bool = False) -> bool:
-    """Begin an async orbax save of `tree` under `path`. Returns False
-    when this orbax has no AsyncCheckpointer (caller writes sync).
-    Blocks only for the device→host snapshot (booked under the
-    `checkpoint.save` span); the serialize/write runs in orbax's
-    background thread until `wait_for_checkpoints`. Synchronous
-    failures (existing directory without `force`) raise immediately,
-    exactly like the sync path."""
+def start_async_save(path: str, tree, force: bool = False):
+    """Begin an async orbax save of `tree` under `path`. Blocks only
+    for the device→host snapshot (booked under the `checkpoint.save`
+    span); the serialize/write runs in orbax's background thread until
+    `wait_for_checkpoints`. Synchronous failures (existing directory
+    without `force`) raise immediately, exactly like the sync path."""
+    import orbax.checkpoint as ocp
     ck = _get_async_checkpointer()
-    if ck is None:
-        return False
-    from . import _compat
-    save_args = _compat.standard_save_args(tree)
-    if save_args is None:
-        return False
+    save_args = ocp.args.StandardSave(tree)
     t0 = time.perf_counter()
     # a fresh write supersedes any recorded failure for this path
     clear_write_failed(path)
@@ -446,7 +429,6 @@ def start_async_save(path: str, tree, force: bool = False) -> bool:
         ck.save(path, args=save_args, force=force)
     _register_pending(_PendingSave(ck, path),
                       blocking_s=time.perf_counter() - t0)
-    return True
 
 
 # ---- /statusz section ------------------------------------------------------
@@ -471,14 +453,13 @@ def overlap_report() -> str:
     lines.append(
         f"async-ckpt: pending={pending_checkpoints()} "
         f"started={int(started.value()) if started else 0} "
-        f"blocking_s_sum={blk.sum() if blk else 0.0:.3f} "
-        f"(available={async_available()})")
+        f"blocking_s_sum={blk.sum() if blk else 0.0:.3f}")
     return "\n".join(lines)
 
 
 __all__ = [
     "DevicePrefetcher", "prefetch_to_device",
     "start_async_save", "wait_for_checkpoints", "pending_checkpoints",
-    "write_failed", "clear_write_failed", "async_available",
+    "write_failed", "clear_write_failed",
     "overlap_report",
 ]

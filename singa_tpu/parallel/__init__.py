@@ -10,7 +10,6 @@ carries tensor/sequence/pipeline sharding helpers used by the transformer
 stack — long-context and multi-chip are first-class here.
 """
 
-from .. import _compat  # noqa: F401  (jax.shard_map/lax.axis_size shims)
 from .mesh import (  # noqa: F401
     make_mesh, data_parallel_mesh, factor_mesh, local_device_count,
 )

@@ -567,9 +567,11 @@ class _DecodeCore:
                 else jax.default_backend() == "tpu"
             if use_k:
                 # TPU: the Pallas flash-decode kernel streams the cache
-                # blockwise — quantized caches stream their BYTES and
-                # dequantize in-kernel (the whole point of int8/int4);
-                # the XLA einsum below would materialize the dequant
+                # blockwise — an int8 cache streams its BYTES and
+                # dequantizes in-kernel; the XLA einsum below would
+                # materialize the dequant. (int4 at PD=128 packs to 64
+                # lanes, fails flash_decode's alignment gate and takes
+                # its reference path on TPU — counted, not hidden.)
                 from .ops.attention import flash_decode
                 lens_att = jnp.broadcast_to(pos_idx + 1, (n,)) \
                     .astype(jnp.int32)
@@ -583,6 +585,8 @@ class _DecodeCore:
                         Q2, Kc, Vc, lens_att, scale=self.scale,
                         groups=G, use_kernel=use_k).astype(x.dtype)
             else:
+                from .observe import record_attention_dispatch
+                record_attention_dispatch("flash_decode", "reference")
                 s = jnp.einsum("nhqj,nhtj->nhqt", Q2, Kmat) * self.scale
                 if self.kvq:
                     # K-scales: one factor per (source position, own

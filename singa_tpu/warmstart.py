@@ -10,7 +10,8 @@ moves the bet across process lifetimes with two stacked persistence
 layers, both rooted under ONE directory (`SINGA_TPU_COMPILE_CACHE` or
 `enable(root)`):
 
-1. **XLA persistent compilation cache** (`<root>/xla`): the stock
+1. **XLA persistent compilation cache** (`<root>/xla`, or wherever
+   `JAX_COMPILATION_CACHE_DIR` says when it is set): the stock
    `jax_compilation_cache_dir` machinery, configured with the
    `persistent_cache_min_*` knobs opened wide so every executable —
    CPU-test-sized ones included — is written and re-read. This layer
@@ -84,6 +85,10 @@ RESULT_STALE = "stale"
 RESULT_CORRUPT = "corrupt"
 
 ENV_CACHE_DIR = "SINGA_TPU_COMPILE_CACHE"
+#: jax's own variable for the XLA persistent cache: where it is set, the
+#: XLA layer lives there and only the serialized executables stay under
+#: the warm store's root.
+ENV_XLA_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
 ENV_KEEP = "SINGA_TPU_COMPILE_CACHE_KEEP"
 DEFAULT_KEEP = 8
 
@@ -342,36 +347,37 @@ class WarmStore:
 
 # ---- lifecycle --------------------------------------------------------------
 
-def _configure_xla_cache(dir_path: str) -> "str | None":
-    """Point jax's persistent compilation cache at `dir_path` with the
-    min-entry-size / min-compile-time gates opened wide (CPU-test-sized
-    executables must persist too). Returns the dir, or None when this
-    jax lacks the knobs — the serialized-executable layer still works
-    without it, warm compiles just re-run the XLA backend."""
+def configure_xla_cache(default_dir: str) -> str:
+    """Turn on jax's persistent compilation cache and return the
+    directory in effect: the one `JAX_COMPILATION_CACHE_DIR` names when
+    it is set — jax reads that variable itself, and nothing here
+    overrides it — else `default_dir`. The min-entry-size /
+    min-compile-time gates are opened wide so every executable,
+    CPU-test-sized ones included, is written and re-read. A later run
+    finds the entries only in the same directory, so entry points pass
+    a fixed one, never one made from a pid, the time or `tempfile`."""
     import jax
-    try:
-        jax.config.update("jax_compilation_cache_dir", dir_path)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except Exception:
-        return None
-    return dir_path
+    env_dir = os.environ.get(ENV_XLA_CACHE_DIR)
+    os.makedirs(env_dir or default_dir, exist_ok=True)
+    if not env_dir:
+        jax.config.update("jax_compilation_cache_dir", default_dir)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return env_dir or default_dir
 
 
 def _unconfigure_xla_cache():
+    """Put back what the environment asked for: with
+    `JAX_COMPILATION_CACHE_DIR` set the directory was never touched;
+    without it the cache goes back to off."""
     import jax
-    try:
+    if not os.environ.get(ENV_XLA_CACHE_DIR):
         jax.config.update("jax_compilation_cache_dir", None)
-    except Exception:
-        pass
-    try:
-        # drop the process-wide cache handle so a later enable() with a
-        # NEW root actually re-initializes against it (tests enable a
-        # fresh tmp dir per test)
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:
-        pass
+    # drop the process-wide cache handle so a later enable() with a
+    # NEW root actually re-initializes against it (tests enable a
+    # fresh tmp dir per test)
+    from jax.experimental.compilation_cache import compilation_cache
+    compilation_cache.reset_cache()
 
 
 def enable(root: "str | None" = None, *,
@@ -387,9 +393,7 @@ def enable(root: "str | None" = None, *,
     root = os.path.abspath(root)
     if _store is not None and _store.root == root:
         return _store
-    xla = os.path.join(root, "xla")
-    os.makedirs(xla, exist_ok=True)
-    _xla_dir = _configure_xla_cache(xla)
+    _xla_dir = configure_xla_cache(os.path.join(root, "xla"))
     _store = WarmStore(root, keep=keep)
     _set_store_gauges()
     return _store
@@ -474,7 +478,7 @@ def warm_report() -> str:
         f"store: {snap['root']}  entries {snap.get('entries', 0)}  "
         f"{(snap.get('store_bytes') or 0) / 1e6:.2f} MB  "
         f"keep-last-{snap.get('keep')}",
-        f"xla persistent cache: {snap['xla_cache_dir'] or 'unavailable'}",
+        f"xla persistent cache: {snap['xla_cache_dir']}",
         "lookups: " + "  ".join(f"{r} {c[r]}" for r in CACHE_RESULTS)
         + (f"  (hit rate {hr * 100.0:.1f}%)" if hr is not None else ""),
         f"exports: {snap['exports']}",
@@ -488,6 +492,7 @@ def warm_report() -> str:
 __all__ = [
     "CACHE_RESULTS", "RESULT_HIT", "RESULT_MISS", "RESULT_STALE",
     "RESULT_CORRUPT", "ENV_CACHE_DIR", "ENV_KEEP",
+    "ENV_XLA_CACHE_DIR", "configure_xla_cache",
     "WarmStore", "enable", "maybe_enable_from_env", "get_store",
     "is_enabled", "reset",
     "note_lookup", "note_export", "lookup_history", "snapshot",

@@ -1,4 +1,4 @@
-"""Mixed-precision policy tests (VERDICT r1 #14): bf16 compute + fp32
+"""Mixed-precision policy tests: bf16 compute + fp32
 master weights via Model.compile(amp=...)."""
 
 import numpy as np

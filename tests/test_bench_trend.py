@@ -127,7 +127,7 @@ def test_smoke_on_repo_artifacts():
     raising (exit code not pinned: future rounds may legitimately
     regress and that is the tool's job to report)."""
     rounds = bench_trend.collect([bench_trend.ROOT])
-    assert rounds  # BENCH_r01..: the repo always carries artifacts
+    assert rounds  # BENCH_r06, MULTICHIP_r*..: the repo carries artifacts
     table = bench_trend.trend_table(rounds)
     assert "multichip_ok" in table
     assert bench_trend.format_table(table)

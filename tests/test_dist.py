@@ -183,8 +183,8 @@ from singa_tpu.utils import dense_allreduce_types as _dense_allreduce_types
 
 
 def test_sparse_step_hlo_is_packed(dev, mesh, data):
-    """Wire-level guarantee for strategy 4 THROUGH the compiled Model step
-    (VERDICT r2 #8): the executable's gradient collectives are capacity-
+    """Wire-level guarantee for strategy 4 THROUGH the compiled Model step:
+    the executable's gradient collectives are capacity-
     sized all-gathers of (index, value) pairs — k = n*spars elements per
     shard — and NO param-shaped dense all-reduce exists. Fails if anyone
     regresses the sparse path to dense (ref communicator.cc:619-719)."""
@@ -219,7 +219,7 @@ def test_partial_update_compiles_per_partition(dev, mesh, data):
 
 
 def test_sparse_with_sharded_params(dev, rng):
-    """Strategy 4 on a TP model (VERDICT r2 weak #7): replicated params
+    """Strategy 4 on a TP model: replicated params
     keep the packed sparse allreduce (residuals pre-created at setup so
     the per-leaf spec'd state thread stays pytree-stable), sharded params
     take the dense reduction — instead of the old hard raise."""
@@ -263,7 +263,7 @@ def test_sparse_with_sharded_params(dev, rng):
 
 
 def test_broadcast_tree(dev, rng, mesh):
-    """Tree broadcast (VERDICT r2 #10): every device ends with ROOT's
+    """Tree broadcast: every device ends with ROOT's
     value for any root, and the executable uses collective-permute rounds
     (ceil(log2 n) of them) — no allreduce-of-masked-zeros."""
     import jax

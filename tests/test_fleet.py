@@ -835,11 +835,11 @@ def test_merged_trace_links_router_and_replicas_via_trace_ctx(
         q2 = next(t for e, t, _i in h2.events if e == "dispatch")
         w2 = ((q2 + off) + (h2.finished_ts + off)) / 2.0
 
-        def _tl(rid, trace, terminal=True):
-            evs = [["submit", 100.0, None], ["admit", 100.0001, None],
-                   ["first_token", 100.0003, None]]
+        def _tl(rid, trace, terminal=True, at=100.0):
+            evs = [["submit", at, None], ["admit", at + 0.0001, None],
+                   ["first_token", at + 0.0003, None]]
             if terminal:
-                evs.append(["terminal", 100.0004,
+                evs.append(["terminal", at + 0.0004,
                             {"outcome": "completed"}])
             return {"id": rid, "trace": trace, "slot": 0,
                     "outcome": "completed" if terminal else None,
@@ -855,8 +855,12 @@ def test_merged_trace_links_router_and_replicas_via_trace_ctx(
         victim["active"] = [_tl(1, h1.trace, terminal=False)]
         _write_fake_shard(d, "hostA", 100, ts=w1 - 100.0, perf=0.0,
                           serve=victim)
+        # (each request's steps sit inside ITS router window: the shard
+        # clock maps 100.0 to w2, so request 1 is shifted by w1 - w2 —
+        # under load h1 may finish before h2 is even dispatched)
         winner = _fake_serve(
-            timelines=[_tl(1, h1.trace), _tl(2, h2.trace)], syncs=[])
+            timelines=[_tl(1, h1.trace, at=100.0 + (w1 - w2)),
+                       _tl(2, h2.trace)], syncs=[])
         _write_fake_shard(d, "hostB", 101, ts=w2 - 100.0, perf=0.0,
                           serve=winner)
         agg = fleet.FleetAggregator(d)

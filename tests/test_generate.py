@@ -359,7 +359,7 @@ def test_kv8_decode_tracks_bf16():
 
 
 def test_kv8_decode_agrees_on_trained_model():
-    """On a TRAINED model (VERDICT r4 #6) the int8-KV greedy decode must
+    """On a TRAINED model the int8-KV greedy decode must
     near-completely agree with the bf16 cache: training gives the logits
     real margins, so per-(head,position) int8 quantization noise (~0.4%
     relative) should almost never flip an argmax. (The untrained-model

@@ -1,4 +1,4 @@
-"""Real-file parse paths of the example data loaders (VERDICT r2 #3).
+"""Real-file parse paths of the example data loaders.
 
 The zero-egress sandbox means the synthetic fallback branch is the only one
 normally executed; these tests fabricate VALID on-disk datasets — CIFAR-10

@@ -211,7 +211,7 @@ def test_sequential_serial_mode(dev):
 
 def test_eval_shape_bucketing(dev):
     """Varying eval batch sizes reuse power-of-two compiled variants and
-    return correctly-sized outputs (VERDICT r1 weak #8)."""
+    return correctly-sized outputs."""
     import numpy as np
     from singa_tpu import layer, tensor
 
@@ -392,7 +392,7 @@ def test_checkpoint_sharded_params(tmp_path, dev):
 
 
 def test_eval_bucketing_auto_default(dev):
-    """Default "auto" bucketing (VERDICT r2 #10): per-sample outputs are
+    """Default "auto" bucketing: per-sample outputs are
     detected on the first eval, and the last partial batch then runs
     WITHOUT a retrace (padded into the already-compiled bucket)."""
     import numpy as np

@@ -34,7 +34,7 @@ def test_top1_gating_capacity():
 
 
 def test_top2_gating():
-    """Top-2 routing (VERDICT r2 #7): each token occupies at most 2 slots,
+    """Top-2 routing: each token occupies at most 2 slots,
     gates renormalize over the chosen pair, capacity still binds, and the
     z-loss / overflow stats are surfaced."""
     rng = np.random.default_rng(2)
@@ -83,8 +83,8 @@ def test_ep_matches_dense_top2():
 
 
 def test_moe_gpt_model_api():
-    """MoE-GPT through Model/DistOpt on a {data, ep} mesh (VERDICT r2 #7:
-    EP training through the framework, not the functional path). DistOpt
+    """MoE-GPT through Model/DistOpt on a {data, ep} mesh (EP
+    training through the framework, not the functional path). DistOpt
     reduces over BOTH axes (tuple axis) so replicated params stay in sync
     and grad-scaled expert slices recover the dense-equivalent update;
     losses match the same model run serially (generous capacity)."""
@@ -226,7 +226,7 @@ def test_moe_aux_loss_grads_reach_gate(dev, train_mode):
 
 
 def test_moe_gpt_ep_x_tp():
-    """EP x TP composition (VERDICT r4 #7): attention/LN run Megatron
+    """EP x TP composition: attention/LN run Megatron
     tensor-parallel over `tp` while the MoE FFN dispatches experts over
     `ep` (expert compute replicates across tp ranks — the MoE has no tp
     sharding, so each tp rank runs the same dispatch; correct because

@@ -1,4 +1,4 @@
-"""ONNX export inventory (VERDICT r3 #7): every Operator class is either
+"""ONNX export inventory: every Operator class is either
 exportable (with a round-trip parity test for the families the reference
 exports — RNNs, ConvTranspose/superres, Pad/UpSample) or DELIBERATELY
 unexportable with a documented reason (frontend.UNEXPORTABLE). An op in

@@ -1,7 +1,7 @@
 """Real-world ONNX interop: import models exported by torch (an independent
 producer) and match its outputs.
 
-VERDICT r1 item #4 asked for a real .onnx file imported end-to-end; the
+An earlier review asked for a real .onnx file imported end-to-end; the
 sandbox has no model zoo on disk (zero egress), so we generate genuine
 third-party files at test time with torch's TorchScript ONNX exporter.
 The exporter's last step needs the `onnx` pip package only to inline

@@ -3,7 +3,7 @@
 Device prefetch ring (ordering, sharded/teardown/error semantics, the
 fit acceptance A/B: >=50% data_wait cut with bitwise-identical losses
 and compile_count==1), async checkpointing (returns-before-durable,
-barrier + deferred-error re-raise, load round-trip, sync fallback), and
+barrier + deferred-error re-raise, load round-trip), and
 the step-dispatch fast path (per-variant cache, static-arg guard).
 """
 
@@ -199,21 +199,25 @@ def test_fit_prefetch_cuts_data_wait_bitwise_identical(dev):
     off on the same workload, with bitwise-identical losses and
     compile_count == 1 on the cached path."""
     tracker = goodput.install()
+    # fit dispatches steps without waiting for them, so by itself the
+    # loop is as fast as its iterator and nothing is there to overlap.
+    # Per-step profiling fences every step (the loop of a device-bound
+    # job); the model is sized so that fenced step (~50 ms) stays well
+    # above the fixed stall, which in turn is large beside the
+    # prefetcher's own per-batch cost (~1 ms).
+    sleep_s = 0.02
+    prev_profiling = (dev.verbosity, dev.skip_iteration)
+    dev.SetVerbosity(1)
+    dev.SetSkipIteration(0)
     try:
-        # hidden=512/batch=256 puts the fenced step well above the
-        # injected sleep, so the producer genuinely overlaps execution
-        m_off, tx, ty = _build(dev, batch=256, feat=512, hidden=512)
-        m_on, _, _ = _build(dev, batch=256, feat=512, hidden=512)
+        m_off, tx, ty = _build(dev, batch=512, feat=2048, hidden=2048)
+        m_on, _, _ = _build(dev, batch=512, feat=2048, hidden=2048)
         # compile + warm both with the SAME number of steps (the models
         # must enter the measured fits in identical states)
-        step_s = 1.0
         for mm in (m_off, m_on):
             dev.rng_state = jax.random.PRNGKey(1)
             mm(tx, ty)
-            t0 = time.perf_counter()
-            jax.block_until_ready(mm(tx, ty)[1].data)
-            step_s = time.perf_counter() - t0
-        sleep_s = min(max(step_s / 3.0, 0.005), 0.08)
+            mm(tx, ty)
 
         class Slow:
             def __iter__(self):
@@ -223,7 +227,7 @@ def test_fit_prefetch_cuts_data_wait_bitwise_identical(dev):
 
         reg = observe.get_registry()
         compiles0 = reg.get("singa_model_compile_total").value(
-            batch_class="256")
+            batch_class="512")
         dev.rng_state = jax.random.PRNGKey(7)
         b0 = tracker.snapshot()["buckets"]["data_wait"]
         hist_off = m_off.fit(Slow(), epochs=1)
@@ -238,10 +242,13 @@ def test_fit_prefetch_cuts_data_wait_bitwise_identical(dev):
         assert hist_on == hist_off
         # cached path: the fits added no compile and no recompile
         assert reg.get("singa_model_compile_total").value(
-            batch_class="256") == compiles0
+            batch_class="512") == compiles0
         assert reg.get("singa_model_recompile_total") is None
         assert _no_prefetch_threads()
     finally:
+        dev.SetVerbosity(prev_profiling[0])
+        dev.SetSkipIteration(prev_profiling[1])
+        del dev.step_times[:]
         goodput.uninstall()
 
 
@@ -299,8 +306,6 @@ def test_fit_prefetch_skip_step_semantics_unchanged(dev, tmp_path):
 def test_async_save_returns_before_durable_then_roundtrips(dev, tmp_path):
     """The save returns with the write still pending; the barrier makes
     it durable; load_checkpoint restores bit-identical state."""
-    if not overlap.async_available():
-        pytest.skip("orbax too old for AsyncCheckpointer")
     m, tx, ty = _build(dev)
     m(tx, ty)
     path = m.save_checkpoint(str(tmp_path / "ck"), step=0)
@@ -322,8 +327,6 @@ def test_async_save_returns_before_durable_then_roundtrips(dev, tmp_path):
 
 
 def test_next_save_barriers_previous(dev, tmp_path):
-    if not overlap.async_available():
-        pytest.skip("orbax too old for AsyncCheckpointer")
     m, tx, ty = _build(dev)
     m(tx, ty)
     p0 = m.save_checkpoint(str(tmp_path / "ck"), step=0)
@@ -373,43 +376,13 @@ def test_wait_for_checkpoints_reraises_deferred_failure():
     overlap.wait_for_checkpoints()  # drained: the barrier is clean again
 
 
-def test_sync_fallback_on_old_orbax(dev, tmp_path, monkeypatch):
-    """With no AsyncCheckpointer (old orbax), async_save=True silently
-    takes the blocking path: nothing pending, checkpoint still loads."""
-    from singa_tpu import _compat
-    monkeypatch.setattr(_compat, "make_async_checkpointer", lambda: None)
-    monkeypatch.setattr(_compat, "has_async_checkpointer", lambda: False)
+def test_overlap_report_constructs_no_checkpointer(monkeypatch):
+    """A /statusz scrape of a process that never checkpoints must not
+    spin up orbax's resident worker pools: the report reads counters
+    only, and the AsyncCheckpointer is built by the first save."""
     monkeypatch.setattr(overlap, "_async_ck", None)
-    m, tx, ty = _build(dev)
-    m(tx, ty)
-    assert not overlap.async_available()
-    path = m.save_checkpoint(str(tmp_path / "ck"), step=0)
-    assert overlap.pending_checkpoints() == 0  # wrote synchronously
-    m2, _, _ = _build(dev, seed=9)
-    m2.load_checkpoint(path)
-    for k, v in m.get_params().items():
-        np.testing.assert_array_equal(
-            np.asarray(jax.device_get(v.data)),
-            np.asarray(jax.device_get(m2.get_params()[k].data)), err_msg=k)
-    monkeypatch.setattr(overlap, "_async_ck", None)  # drop the False probe
-
-
-def test_async_available_probe_has_no_side_effects(monkeypatch):
-    """async_available answers from an attribute probe (or the save
-    path's construction cache), never by constructing an
-    AsyncCheckpointer — a /statusz scrape of a process that never
-    checkpoints must not spin up orbax's resident worker pools."""
-    from singa_tpu import _compat
-    monkeypatch.setattr(overlap, "_async_ck", None)
-    calls = []
-    monkeypatch.setattr(_compat, "make_async_checkpointer",
-                        lambda: calls.append(1))
-    assert overlap.async_available() == _compat.has_async_checkpointer()
-    assert not calls                   # nothing constructed
-    assert overlap._async_ck is None   # construction cache untouched
-    # a probed-unavailable cache (False) wins over the attribute check
-    monkeypatch.setattr(overlap, "_async_ck", False)
-    assert overlap.async_available() is False
+    assert "async-ckpt: pending=0" in overlap.overlap_report()
+    assert overlap._async_ck is None   # nothing constructed
 
 
 def test_async_save_books_only_blocking_portion(dev, tmp_path):

@@ -39,8 +39,8 @@ def test_tp_mlp_matches_dense():
 
 def test_tp_through_model_api_matches_serial():
     """Linear(tp_axis=...) + DistOpt on a {data:2, tp:4} mesh must train to
-    the same losses/params as a serial single-device model (VERDICT r1 #7:
-    TP as a framework feature, not a library function)."""
+    the same losses/params as a serial single-device model (TP
+    as a framework feature, not a library function)."""
     from singa_tpu import layer, model, opt, tensor
     from singa_tpu.device import get_default_device
 
@@ -130,7 +130,7 @@ def test_tp_gpt_through_model_api():
 
 def test_tp_gpt_vocab_parallel():
     """GPT(vocab_tp=True): the (V, E) embedding is row-sharded over tp and
-    the head is tied to it (Megatron vocab parallelism, VERDICT r2 #5).
+    the head is tied to it (Megatron vocab parallelism).
     Vocab 50 is NOT divisible by tp=4 — internal padding to a multiple of 8
     (->56) must be invisible: losses match the same model run serially, and
     the per-device embedding shard is V_pad/tp rows (param bytes drop)."""
@@ -290,7 +290,7 @@ def test_pp_gpt_1f1b_matches_serial():
     """pipeline_schedule="1f1b": the fused fwd+bwd interleaved schedule
     (loss inside the pipeline, remat per stage, in-flight activations
     bounded by ~2*stages) trains to the same losses/params as the serial
-    model — and therefore as GPipe (VERDICT r2 #6)."""
+    model — and therefore as GPipe."""
     from singa_tpu import models, opt, tensor
     from singa_tpu.device import get_default_device
 
@@ -334,7 +334,7 @@ def test_pp_gpt_1f1b_matches_serial():
 
 
 def test_pp_non_uniform_stages():
-    """num_layers % stages != 0 (VERDICT r2 #6): 5 layers over 4 stages —
+    """num_layers % stages != 0: 5 layers over 4 stages —
     stacks padded to 8 rows, masked to identity past row 5; numerics match
     the serial model for BOTH schedules."""
     from singa_tpu import models, opt, tensor
@@ -476,7 +476,7 @@ def test_pp_interleaved_rejects_1f1b():
 
 
 def test_pp_ep_moe_gpt_matches_serial():
-    """PP x EP (VERDICT r3 #6): PipelinedGPT(moe_experts=4, ep_axis="ep")
+    """PP x EP: PipelinedGPT(moe_experts=4, ep_axis="ep")
     on a {data:1, pp:2, ep:2} mesh — MoE FFN inside the pipeline stage
     scan, expert dispatch via all_to_all over ep within each slot. In
     the no-drop regime (capacity_factor=num_experts) with router-loss

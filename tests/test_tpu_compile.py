@@ -1,0 +1,123 @@
+"""The main path's Pallas kernels, compiled for a described TPU v5e.
+
+Interpret mode (how every other CPU test runs these kernels) cannot see a
+tile that is not aligned, a kernel that wants more fast memory than it may
+use, or a Mosaic lowering that jax dropped. The chip's compiler is
+installed here and compiles for a chip that is described, not attached, so
+these cases hand it the kernels at the shapes `chip_smoke.py` runs —
+GPT-2-small training (flash forward/backward) and serving (paged and
+flash-decode, head-packed: 12 heads x 64 -> 6 x 128 lanes) — and look for
+the Mosaic custom call in what comes back. Nothing runs: a compile that
+passes is not a chip run.
+
+All in this one file, topology described inside a fixture (never at import
+or collection time) and compiled in the test's own process: only one
+process at a time may load the TPU's library.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from singa_tpu.ops import attention as A
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip would be written to a persistent
+    # cache but can never be read back without the chip: keep it off
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _has_mosaic_call(fn, *args):
+    return "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()
+
+
+# GPT-2-small (H12, D64) and a D128 head at the smoke's batch and sequence
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("shape", [(8, 12, 1024, 64), (8, 16, 1024, 128)],
+                         ids=["h12d64", "h16d128"])
+def test_flash_attention_compiles_for_v5e(one_chip, shape, direction):
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def fwd(q, k, v):  # causal, default scale and blocks, compiled
+        return A.flash_attention(q, k, v, True, None, None, None, False)
+
+    def bwd(q, k, v):
+        return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    assert _has_mosaic_call(fwd if direction == "fwd" else bwd, q, q, q)
+
+
+# what ServingEngine builds for GPT-2-small in chip_smoke.py: 8 slots,
+# P=2 heads packed per 128-lane row -> Hp=6, Q=P*G=2 query rows per token,
+# pages of 16 tokens, 64 pages per sequence (max_ctx 1024), 512 in the pool
+_N, _HP, _PD, _P, _PS, _M, _PAGES, _T = 8, 6, 128, 2, 16, 64, 512, 1024
+
+
+def _decode_operands(sh, kv_rows, kv, q_tokens):
+    """(q, K, V, scales-or-None) ShapeDtypeStructs: K/V are
+    (`kv_rows`..., PD) pools or caches, bf16 or int8 with fp32
+    per-(position, packed head) scales."""
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+    q = sds((_N, _HP, q_tokens * _P, _PD), jnp.bfloat16)
+    if kv == "int8":
+        return (q, sds(kv_rows + (_PD,), jnp.int8),
+                sds(kv_rows + (_P,), jnp.float32))
+    return q, sds(kv_rows + (_PD,), jnp.bfloat16), None
+
+
+@pytest.mark.parametrize("kv,q_tokens", [("bf16", 1), ("int8", 1),
+                                         ("bf16", 4)],
+                         ids=["bf16", "int8kv", "verify4"])
+def test_paged_attention_compiles_for_v5e(one_chip, kv, q_tokens):
+    q, pool, scales = _decode_operands(one_chip, (_PAGES, _HP, _PS), kv,
+                                       q_tokens)
+    table = jax.ShapeDtypeStruct((_N, _M), jnp.int32, sharding=one_chip)
+    lens = jax.ShapeDtypeStruct((_N,), jnp.int32, sharding=one_chip)
+
+    def fn(q, k, v, table, lens, *sc):
+        ks, vs = sc if sc else (None, None)
+        return A._paged_fwd_pallas(q, k, v, table, lens, _PS, 0.125, ks, vs,
+                                   1, False, q_tokens)
+
+    sc = (scales, scales) if scales is not None else ()
+    assert _has_mosaic_call(fn, q, pool, pool, table, lens, *sc)
+
+
+@pytest.mark.parametrize("kv,q_tokens", [("bf16", 1), ("int8", 1),
+                                         ("int8", 4)],
+                         ids=["bf16", "int8kv", "verify4_int8kv"])
+def test_flash_decode_compiles_for_v5e(one_chip, kv, q_tokens):
+    q, cache, scales = _decode_operands(one_chip, (_N, _HP, _T), kv,
+                                        q_tokens)
+    lens = jax.ShapeDtypeStruct((_N,), jnp.int32, sharding=one_chip)
+    block_t = A._fit_block(_T, 256, floor=8)  # flash_decode's own choice
+
+    def fn(q, k, v, lens, *sc):
+        ks, vs = sc if sc else (None, None)
+        return A._flash_decode_pallas(q, k, v, lens, 0.125, ks, vs, 1,
+                                      False, q_tokens, block_t)
+
+    sc = (scales, scales) if scales is not None else ()
+    assert _has_mosaic_call(fn, q, cache, cache, lens, *sc)

@@ -17,13 +17,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from singa_tpu import _compat, introspect, observe, warmstart
+from singa_tpu import introspect, observe, warmstart
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-pytestmark = pytest.mark.skipif(
-    not _compat.has_jax_export(),
-    reason="this jax cannot serialize executables (no jax.export)")
 
 
 def _fn():
@@ -231,6 +227,58 @@ def test_conftest_isolation_resets_warm_state(tmp_path):
         "hit": 0, "miss": 0, "stale": 0, "corrupt": 0}
 
 
+_ENV_CACHE_CHILD = textwrap.dedent("""
+    import json, os, sys
+    sys.path.insert(0, {root!r})
+    import jax, jax.numpy as jnp
+    from singa_tpu import warmstart
+    seen = [jax.config.jax_compilation_cache_dir]
+    warmstart.enable({store!r})
+    seen.append(jax.config.jax_compilation_cache_dir)
+    jax.jit(lambda x: jnp.cumsum(x) * 5)(jnp.arange(16.0)).block_until_ready()
+    snap = warmstart.snapshot()
+    warmstart.reset()
+    seen.append(jax.config.jax_compilation_cache_dir)
+    print(json.dumps({{"seen": seen, "xla_cache_dir": snap["xla_cache_dir"]}}))
+""")
+
+
+def test_jax_compilation_cache_dir_is_never_overridden(tmp_path):
+    """Where JAX_COMPILATION_CACHE_DIR is set, the XLA cache lives there
+    and nowhere else: enable(root) keeps only its serialized executables
+    under root, assigns no other cache directory, and reset() puts back
+    what the environment asked for, not None. (A subprocess: jax reads
+    the variable at import.)"""
+    env_dir, store = tmp_path / "from_env", tmp_path / "warm"
+    out = subprocess.run(
+        [sys.executable, "-c",
+         _ENV_CACHE_CHILD.format(root=_ROOT, store=str(store))],
+        env=dict(os.environ, JAX_PLATFORMS="cpu",
+                 JAX_COMPILATION_CACHE_DIR=str(env_dir)),
+        capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    got = json.loads(out.stdout.strip().splitlines()[-1])
+    assert got["seen"] == [str(env_dir)] * 3
+    assert got["xla_cache_dir"] == str(env_dir)
+    assert os.listdir(env_dir)                    # entries appear under D
+    assert not os.path.exists(store / "xla")      # and nowhere else
+
+
+def test_configure_xla_cache_uses_the_given_dir_without_the_env(tmp_path):
+    """Unset, the entry points' fixed directory is the cache; reset()
+    (via the store) or a later configure may move it, nothing else."""
+    assert "JAX_COMPILATION_CACHE_DIR" not in os.environ  # conftest pops it
+    d = str(tmp_path / "fixed")
+    try:
+        assert warmstart.configure_xla_cache(d) == d
+        assert jax.config.jax_compilation_cache_dir == d
+        jax.jit(lambda x: x * 7 + 2)(jnp.arange(8.0)).block_until_ready()
+        assert os.listdir(d)
+    finally:
+        warmstart._unconfigure_xla_cache()
+    assert jax.config.jax_compilation_cache_dir is None
+
+
 def test_env_var_enables_store(tmp_path, monkeypatch):
     monkeypatch.setenv(warmstart.ENV_CACHE_DIR, str(tmp_path / "envw"))
     warmstart.reset()  # clear the one-shot env probe
@@ -353,17 +401,8 @@ def _key_args():
     return (jax.random.key(7), jnp.arange(4, dtype=jnp.float32))
 
 
-def test_typed_key_blob_round_trips_and_is_framed():
-    fn, args = _key_fn(), _key_args()
-    blob = _compat.serialize_executable(fn, args)
-    # the flatbuffer serializer cannot encode key<fry>: a working blob
-    # proves the key-data bridge engaged (and says so in the framing)
-    assert blob is not None
-    assert blob.startswith(_compat._KEY_BLOB_MAGIC)
-    rt = _compat.deserialize_executable(blob)
-    assert rt is not None
-    want_key, want_val = fn(*args)
-    got_key, got_val = rt(*args)
+def _assert_same_key_outputs(got, want):
+    (got_key, got_val), (want_key, want_val) = got, want
     # outputs are typed keys again, not raw uint32 leaking out
     assert jax.dtypes.issubdtype(got_key.dtype, jax.dtypes.prng_key)
     np.testing.assert_array_equal(
@@ -372,10 +411,37 @@ def test_typed_key_blob_round_trips_and_is_framed():
     np.testing.assert_allclose(np.asarray(got_val), np.asarray(want_val))
 
 
-def test_keyless_blob_stays_unframed():
-    blob = _compat.serialize_executable(_fn(), _args())
+def test_typed_key_blob_round_trips_and_is_framed():
+    fn, args = _key_fn(), _key_args()
+    blob = introspect._serialize_executable(fn, args)
+    # a framed blob proves the key-data bridge engaged
     assert blob is not None
-    assert not blob.startswith(_compat._KEY_BLOB_MAGIC)
+    assert blob.startswith(introspect._KEY_BLOB_MAGIC)
+    rt = introspect._deserialize_executable(blob)
+    assert rt is not None
+    _assert_same_key_outputs(rt(*args), fn(*args))
+
+
+def test_keyless_blob_stays_unframed():
+    blob = introspect._serialize_executable(_fn(), _args())
+    assert blob is not None
+    assert not blob.startswith(introspect._KEY_BLOB_MAGIC)
+
+
+def test_typed_key_blob_stages_with_device_committed_args():
+    """What the bridge is for on the installed jax: a train step's
+    dev.rng_state is committed to its device, and an exported module
+    that takes the typed key itself refuses to stage with such an
+    argument (sharding constraint of rank 0 on the key's tensor<2xui32>).
+    Caught here in-process so the fast tier sees it, not only the slow
+    cross-process restart below."""
+    fn = _key_fn()
+    dev = jax.devices()[0]
+    args = tuple(jax.device_put(a, dev) for a in _key_args())
+    rt = introspect._deserialize_executable(
+        introspect._serialize_executable(fn, args))
+    compiled, _ = introspect._stage(rt, args)
+    _assert_same_key_outputs(compiled(*args), fn(*args))
 
 
 def test_typed_key_fn_warm_hit_through_build_compiled(tmp_path):
@@ -384,15 +450,11 @@ def test_typed_key_fn_warm_hit_through_build_compiled(tmp_path):
     compiled, rec = introspect.build_compiled(fn, args, "t.keyed")
     assert compiled is not None and rec["warm"] == warmstart.RESULT_MISS
     assert warmstart.snapshot()["exports"] == 1
-    want_key, want_val = fn(*args)
+    want = fn(*args)
     introspect.reset()
     compiled2, rec2 = introspect.build_compiled(fn, args, "t.keyed")
     assert rec2["warm"] == warmstart.RESULT_HIT
-    got_key, got_val = compiled2(*args)
-    np.testing.assert_array_equal(
-        np.asarray(jax.random.key_data(got_key)),
-        np.asarray(jax.random.key_data(want_key)))
-    np.testing.assert_allclose(np.asarray(got_val), np.asarray(want_val))
+    _assert_same_key_outputs(compiled2(*args), want)
 
 
 @pytest.mark.slow

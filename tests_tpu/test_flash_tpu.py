@@ -1,7 +1,6 @@
 """Compiled (interpret=False) Pallas flash attention on real TPU.
 
-Round-1 verdict: the kernel had only ever run in interpret mode on CPU —
-a TPU-lowering bug would be invisible. These tests compile and execute the
+In interpret mode on CPU a TPU-lowering bug is invisible. These tests compile and execute the
 forward and backward kernels on the actual chip and check numerics against
 the O(S^2) reference math.
 """

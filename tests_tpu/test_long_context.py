@@ -1,6 +1,6 @@
 """Long-context proof on the real chip: flash attention runs fwd+bwd at
 S=32k, where the O(S^2) reference path cannot exist — the fp32 score matrix
-alone would be H*S*S*4B = ~34 GB against 16 GB of HBM. VERDICT r1 #3."""
+alone would be H*S*S*4B = ~34 GB against 16 GB of HBM."""
 
 import numpy as np
 

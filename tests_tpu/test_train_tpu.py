@@ -4,9 +4,7 @@ and numerically sane on actual TPU hardware, not just the CPU mesh."""
 import numpy as np
 import pytest
 
-from singa_tpu import device, layer, model, models, opt, tensor
-
-DEV = device.best_device()
+from singa_tpu import layer, model, models, opt, tensor
 
 
 class SmallConv(model.Model):
@@ -36,13 +34,13 @@ def _data(n=32):
 
 
 @pytest.mark.parametrize("amp", [None, "bfloat16"])
-def test_conv_training_on_tpu(amp):
+def test_conv_training_on_tpu(dev, amp):
     # pin the device RNG stream: earlier tests in the session consume it,
     # and an unlucky init draw diverges at this lr
-    DEV.SetRandSeed(0)
+    dev.SetRandSeed(0)
     x_np, y_np = _data()
-    x = tensor.from_numpy(x_np, device=DEV)
-    y = tensor.from_numpy(y_np, device=DEV)
+    x = tensor.from_numpy(x_np, device=dev)
+    y = tensor.from_numpy(y_np, device=dev)
     m = SmallConv()
     m.set_optimizer(opt.SGD(lr=0.05, momentum=0.9))
     m.compile([x], is_train=True, use_graph=True, amp=amp)
@@ -56,11 +54,11 @@ def test_conv_training_on_tpu(amp):
     assert out.shape == (32, 10)
 
 
-def test_resnet18_amp_step_on_tpu():
+def test_resnet18_amp_step_on_tpu(dev):
     """One amp train step of the bench model family on the real chip."""
     rng = np.random.RandomState(0)
-    x = tensor.from_numpy(rng.rand(8, 3, 64, 64).astype(np.float32), device=DEV)
-    y = tensor.from_numpy(rng.randint(0, 10, 8).astype(np.int32), device=DEV)
+    x = tensor.from_numpy(rng.rand(8, 3, 64, 64).astype(np.float32), device=dev)
+    y = tensor.from_numpy(rng.randint(0, 10, 8).astype(np.int32), device=dev)
     m = models.create_model("resnet18", num_channels=3, num_classes=10)
     m.set_optimizer(opt.SGD(lr=0.05, momentum=0.9))
     m.compile([x], is_train=True, use_graph=True, amp="bfloat16")
@@ -68,7 +66,7 @@ def test_resnet18_amp_step_on_tpu():
     assert np.isfinite(losses).all(), losses
 
 
-def test_gpt_flash_train_step_on_tpu():
+def test_gpt_flash_train_step_on_tpu(dev):
     """GPT + compiled Pallas flash attention: train step on the chip."""
     rng = np.random.RandomState(0)
     ids = rng.randint(0, 512, (2, 256)).astype(np.int32)
@@ -76,8 +74,8 @@ def test_gpt_flash_train_step_on_tpu():
     m = models.create_model("gpt", vocab_size=512, max_seq=256, dim=128,
                             num_heads=4, num_layers=2)
     m.set_optimizer(opt.SGD(lr=0.01))
-    tx = tensor.from_numpy(ids, device=DEV)
-    ty = tensor.from_numpy(tgt, device=DEV)
+    tx = tensor.from_numpy(ids, device=dev)
+    ty = tensor.from_numpy(tgt, device=dev)
     m.compile([tx], is_train=True, use_graph=True)
     losses = [float(m(tx, ty)[1].numpy()) for _ in range(4)]
     assert np.isfinite(losses).all() and losses[-1] < losses[0], losses
