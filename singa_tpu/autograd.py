@@ -16,12 +16,14 @@ tracing unchanged — Model's graph mode simply traces one step (model.py).
 
 from __future__ import annotations
 
+import contextlib
 from collections import deque
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax._src import source_info_util   # the name stack has no public reader
 
 from .tensor import Tensor
 from . import tensor as tensor_module
@@ -36,6 +38,19 @@ def _raw(x):
 
 def _is_float0(a):
     return getattr(a, "dtype", None) == jax.dtypes.float0
+
+
+# ---- program scopes -------------------------------------------------------
+# Every instruction of a traced program carries, in its `op_name`, the path
+# of the layers it was recorded under: `Layer.__call__` opens
+# `jax.named_scope(<the attribute name get_params() keys the layer by>)`.
+# An operator remembers jax's own name stack as it is recorded, so that
+# `backward()` below can run its backward rule under the same path again,
+# behind a leading `bwd`.
+
+def _current_scope():
+    """jax's name stack here, as the `/`-joined path an `op_name` shows."""
+    return str(source_info_util.current_name_stack())
 
 
 class Operator:
@@ -69,18 +84,26 @@ class Operator:
         else:
             self.requires_grad = False
 
-        if self.requires_grad:
-            for x in xs:
-                if x.creator is None:
-                    x.creator = Dummy(x)
-                self.src.append((x.creator, id(x), x, x.stores_grad))
-            raw = [x.data for x in xs]
-            if type(self).backward is Operator.backward:
-                ys, self._vjp = jax.vjp(self.forward, *raw)
+        # the scope this operator is recorded under, for backward(): the
+        # layer's, or outside any layer the operator's own name
+        self._scope = _current_scope()
+        outside = not self._scope
+        if outside:
+            self._scope = self.name
+        raw = [x.data for x in xs]
+        with jax.named_scope(self.name) if outside \
+                else contextlib.nullcontext():
+            if self.requires_grad:
+                for x in xs:
+                    if x.creator is None:
+                        x.creator = Dummy(x)
+                    self.src.append((x.creator, id(x), x, x.stores_grad))
+                if type(self).backward is Operator.backward:
+                    ys, self._vjp = jax.vjp(self.forward, *raw)
+                else:
+                    ys = self.forward(*raw)
             else:
                 ys = self.forward(*raw)
-        else:
-            ys = self.forward(*[x.data for x in xs])
 
         single = not isinstance(ys, tuple)
         if single:
@@ -159,11 +182,17 @@ def backward(y: Tensor, dy=None):
         op, dys = ready.popleft()
         if isinstance(op, Dummy):
             continue
-        # zero-fill output cotangents that never received a gradient
-        full = [dys[i] if i < len(dys) else None for i in range(op._n_out)]
-        filled = [g if g is not None else jnp.zeros(s, d)
-                  for g, (s, d) in zip(full, op._out_shapes)]
-        dxs = op.backward(*filled)
+        # the operator's backward goes under the scope it was recorded in,
+        # behind `bwd`: a rule called from this loop would otherwise read
+        # like whatever drives the generator (the optimizer's update), and
+        # the vjp-derived one names itself `transpose(jvp())` and no more
+        bwd_scope = "bwd/" + op._scope
+        with jax.named_scope(bwd_scope):
+            # zero-fill output cotangents that never received a gradient
+            filled = [dys[i] if i < len(dys) and dys[i] is not None
+                      else jnp.zeros(s, d)
+                      for i, (s, d) in enumerate(op._out_shapes)]
+            dxs = op.backward(*filled)
         if not isinstance(dxs, (tuple, list)):
             dxs = (dxs,)
         assert len(dxs) == len(op.src), \
@@ -175,8 +204,11 @@ def backward(y: Tensor, dy=None):
             if dx is not None and not _is_float0(dx):
                 y_idx = src_op.y_id2idx[x_id]
                 slots = not_ready.setdefault(src_op, [None] * src_op._n_out)
-                slots[y_idx] = dx if slots[y_idx] is None \
-                    else slots[y_idx] + dx
+                if slots[y_idx] is None:
+                    slots[y_idx] = dx
+                else:
+                    with jax.named_scope(bwd_scope):
+                        slots[y_idx] = slots[y_idx] + dx
             dependency[src_op] -= 1
             if dependency[src_op] == 0:
                 # Completion is uniform regardless of whether the LAST edge
@@ -2001,12 +2033,15 @@ class ComputeCast(Operator):
         super().__init__()
         self.to = to
 
+    # both ways under the device scope `amp_cast`, a leaf of the layer's
     def forward(self, x):
         self._orig = x.dtype
-        return x.astype(self.to)
+        with jax.named_scope("amp_cast"):
+            return x.astype(self.to)
 
     def backward(self, dy):
-        return dy.astype(self._orig)
+        with jax.named_scope("amp_cast"):
+            return dy.astype(self._orig)
 
 
 def compute_cast(*xs):

@@ -53,6 +53,7 @@ class Device:
         self._rng_key = jax.device_put(
             jax.random.key(int(seed), impl="threefry2x32"), self.jax_device)
 
+    @jax.named_scope("rng")   # in a traced step: the key plumbing's scope
     def rand_key(self):
         """Split off a fresh PRNG key (functional curandGenerate analog)."""
         self._rng_key, sub = jax.random.split(self._rng_key)

@@ -465,37 +465,44 @@ class ServingEngine:
             h, kvs = core.prefill_parts(p, prompt, 1)
             hl = lax.dynamic_slice_in_dim(h, true_len - 1, 1,
                                           axis=1)[:, 0]
-            logits = serving._mm(core.ln(hl, p["gf"], p["bf"]),
-                                 p["head"])                   # (1, V)
+            logits = core.head(p, hl)                         # (1, V)
             tok0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)[0]
             nf = jnp.sum((~jnp.isfinite(logits)).astype(jnp.int32))
             return kvs, tok0, nf
 
+        # Device scopes: every instruction of a prefill program sits under
+        # `prefill.b<bucket>`, of the decode program under `decode`, and
+        # inside them under the layers' own names (serving._DecodeCore).
         def prefill_fn(p, pools, prompt, true_len, pages):
             Sb = prompt.shape[1]
-            kvs, tok0, nf = prefill_head(p, prompt, true_len)
-            t = jnp.arange(Sb)
-            # positions past the true prompt scatter out of bounds and
-            # are DROPPED — the padded bucket tail never reaches a page
-            pvec = jnp.where(t < true_len, pages[t // ps], n_pages)
-            off = t % ps
-            return scatter_prefill(core, kvs, pools, pvec, off), \
-                tok0, nf
+            with jax.named_scope(f"prefill.b{Sb}"):
+                kvs, tok0, nf = prefill_head(p, prompt, true_len)
+                t = jnp.arange(Sb)
+                # positions past the true prompt scatter out of bounds
+                # and are DROPPED — the padded bucket tail never reaches
+                # a page
+                pvec = jnp.where(t < true_len, pages[t // ps], n_pages)
+                off = t % ps
+                return scatter_prefill(core, kvs, pools, pvec, off), \
+                    tok0, nf
 
         def spec_prefill_fn(p, pd, pools, dpools, prompt, true_len,
                             pages):
             Sb = prompt.shape[1]
-            kvs, tok0, nf = prefill_head(p, prompt, true_len)
-            # the draft prefills the SAME prompt into its own pools
-            # (its logits are unused — the first token is the target's)
-            _hd, dkvs = dcore.prefill_parts(pd, prompt, 1)
-            t = jnp.arange(Sb)
-            pvec = jnp.where(t < true_len, pages[t // ps], n_pages)
-            off = t % ps
-            return (scatter_prefill(core, kvs, pools, pvec, off),
-                    scatter_prefill(dcore, dkvs, dpools, pvec, off),
-                    tok0, nf)
+            with jax.named_scope(f"prefill.b{Sb}"):
+                kvs, tok0, nf = prefill_head(p, prompt, true_len)
+                # the draft prefills the SAME prompt into its own pools
+                # (its logits are unused — the first token is the
+                # target's)
+                _hd, dkvs = dcore.prefill_parts(pd, prompt, 1)
+                t = jnp.arange(Sb)
+                pvec = jnp.where(t < true_len, pages[t // ps], n_pages)
+                off = t % ps
+                return (scatter_prefill(core, kvs, pools, pvec, off),
+                        scatter_prefill(dcore, dkvs, dpools, pvec, off),
+                        tok0, nf)
 
+        @jax.named_scope("decode")
         def decode_fn(p, pools, tok, page_table, lens, limits, active):
             def step(carry, _):
                 pools, tok, lens, active, nf = carry
@@ -519,6 +526,7 @@ class ServingEngine:
                 step, carry, None, length=H)
             return pools, tok, lens, active, toks, masks, nf
 
+        @jax.named_scope("decode")
         def spec_decode_fn(p, pd, pools, dpools, tok, page_table, lens,
                            limits, active):
             """H speculative verify rounds per sync: draft K proposals

@@ -52,6 +52,8 @@ import os
 import time
 from collections import deque
 
+import jax
+
 from . import observe
 
 POLICIES = ("warn", "skip_step", "halt")
@@ -137,7 +139,10 @@ class StepStatsCollector:
         self._nonfinite = None   # count of non-finite grad entries (int32)
         self._groups = {}        # group -> [param_sq, update_sq]
 
-    # -- feeding (called at trace time from the optimizer loops) -----------
+    # -- feeding (called at trace time from the optimizer loops; what the
+    # collector adds to the step program goes under the device scope
+    # `health`) ------------------------------------------------------------
+    @jax.named_scope("health")
     def observe_loss(self, loss_arr):
         import jax.numpy as jnp
         self.loss = jnp.asarray(loss_arr).astype(jnp.float32)
@@ -176,6 +181,7 @@ class StepStatsCollector:
                             acc[2] + v[2], acc[3] + v[3]),
             tuple(range(g.ndim)))
 
+    @jax.named_scope("health")
     def observe(self, param, grad_arr, old_arr, new_arr):
         """One (param, post-reduction grad, pre/post-update value)."""
         import jax.numpy as jnp
@@ -199,6 +205,7 @@ class StepStatsCollector:
         slot[1] = usq if slot[1] is None else slot[1] + usq
 
     # -- finalize (still at trace time) ------------------------------------
+    @jax.named_scope("health")
     def finalize(self, comm=None):
         """Reduce the accumulators into the step_stats pytree of scalars.
 
@@ -259,6 +266,7 @@ class StepStatsCollector:
         return stats
 
 
+@jax.named_scope("health")
 def apply_skip(stats, old_arrays, new_arrays):
     """In-graph conditional commit: when the agreed anomaly flag is set,
     keep every pre-step array (params, opt slots — the step-counter

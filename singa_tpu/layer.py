@@ -15,6 +15,7 @@ from __future__ import annotations
 
 from collections import OrderedDict
 
+import jax
 import numpy as np
 
 from . import autograd
@@ -48,6 +49,9 @@ class Layer(metaclass=LayerMeta):
         # use object.__setattr__ to avoid registry recursion
         object.__setattr__(self, "_layers", OrderedDict())
         object.__setattr__(self, "_initialized", False)
+        # program scope of what forward records: the attribute name the
+        # parent registered this layer under (set there), else `name`
+        object.__setattr__(self, "_scope", None)
         self.name = name or self.__class__.__name__
         self._param_names = []   # attribute names holding trainable Tensors
         self._state_names = []   # attribute names holding non-trainable state
@@ -56,6 +60,7 @@ class Layer(metaclass=LayerMeta):
     def __setattr__(self, key, value):
         if isinstance(value, Layer):
             self._layers[key] = value
+            object.__setattr__(value, "_scope", key)
         object.__setattr__(self, key, value)
 
     def _register_param(self, attr: str, t: Tensor):
@@ -82,7 +87,10 @@ class Layer(metaclass=LayerMeta):
         raise NotImplementedError
 
     def __call__(self, *args, **kwargs):
-        return self.forward(*args, **kwargs)
+        # the scope is the key get_params() gives this layer's parameters,
+        # so an instruction's scope and the parameter it reads agree
+        with jax.named_scope(self._scope or self.name):
+            return self.forward(*args, **kwargs)
 
     # ---- params / states (ref layer.py:140-220) --------------------------
     # Names are scoped by *attribute path* (e.g. "conv1.W"), which is what
@@ -148,6 +156,7 @@ class Layer(metaclass=LayerMeta):
                     name += "_"
                 self._layers[name] = value
                 value.name = name
+                object.__setattr__(value, "_scope", name)
 
     def sublayers(self):
         return dict(self._layers)

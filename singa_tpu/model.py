@@ -339,11 +339,15 @@ class Model(Layer, metaclass=ModelMeta):
             def step(state_arrs, opt_arrs, rng, input_arrs):
                 if opt is not None:
                     opt._partial_static_idx = tag
+                # Device scopes of the step outside its layers: `rng`
+                # here, `opt` (opt.py), `health` (health.py), `amp_cast`
+                # (autograd.ComputeCast)
                 if dist:
                     # flattened rank (communicator handles tuple axes for
                     # multi-axis reductions like DP+EP)
-                    dev.rng_state = jax.random.fold_in(
-                        rng, opt.communicator.rank())
+                    with jax.named_scope("rng"):
+                        dev.rng_state = jax.random.fold_in(
+                            rng, opt.communicator.rank())
                 else:
                     dev.rng_state = rng
                 for t, a in zip(state_tensors, state_arrs):
@@ -409,8 +413,9 @@ class Model(Layer, metaclass=ModelMeta):
                             hstats, state_arrs, new_states)
                         new_opt = health.apply_skip(
                             hstats, opt_arrs, new_opt)
-                new_rng = jax.random.split(rng, 1)[0] if dist \
-                    else dev.rng_state
+                with jax.named_scope("rng"):
+                    new_rng = jax.random.split(rng, 1)[0] if dist \
+                        else dev.rng_state
                 return new_states, new_opt, new_rng, outs, hstats
 
             if dist:

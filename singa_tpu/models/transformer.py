@@ -9,6 +9,8 @@ length scales with the number of chips.
 
 from __future__ import annotations
 
+import jax
+
 from .. import autograd, layer, model
 from ..tensor import Tensor, float32
 # serving engine lives in singa_tpu/serving.py; re-exports kept so
@@ -178,8 +180,10 @@ class GPT(_VocabTPMixin, model.Model):
             # (_pos_init still gates the decode-params contract)
             self._pos_init = True
         else:
-            pos = self._pos_embedding(h)
-            h = autograd.add(h, autograd.expand(pos, h.shape))
+            # the table's key in get_params(): the scope of what reads it
+            with jax.named_scope("pos_embed"):
+                pos = self._pos_embedding(h)
+                h = autograd.add(h, autograd.expand(pos, h.shape))
         for b in self.blocks:
             h = b(h)
         return self.ln_f(h)
@@ -212,8 +216,9 @@ class GPT(_VocabTPMixin, model.Model):
     def train_one_batch(self, ids, targets):
         if not self.vocab_tp:
             logits = self.forward(ids)
-            flat = autograd.reshape(logits, (-1, self.vocab_size))
-            tflat = autograd.reshape(targets, (-1,))
+            with jax.named_scope("sce"):    # the loss's own reshapes
+                flat = autograd.reshape(logits, (-1, self.vocab_size))
+                tflat = autograd.reshape(targets, (-1,))
             loss = self._moe_losses(self.sce(flat, tflat), ids.device)
             self.optimizer(loss)
             return logits, loss

@@ -240,6 +240,7 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k, interpret):
     kvmap = _causal_kv_map(causal, block_q, block_k, nk)
     out, lse = pl.pallas_call(
         kernel,
+        name="singa_flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j, kb: (i, j, 0)),
@@ -432,6 +433,7 @@ def _flash_bwd_fused(qf, kf, vf, dof, lsef, delta, causal, scale,
         functools.partial(_flash_bwd_fused_kernel, nq=nq, nk=nk,
                           block_q=block_q, block_k=block_k,
                           causal=causal, scale=scale),
+        name="singa_flash_bwd",
         grid=(bh, nk, nq),
         in_specs=[
             pl.BlockSpec((1, block_q, d), qmap),
@@ -502,6 +504,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, block_q, block_k,
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, nk=nk, block_q=block_q,
                           block_k=block_k, causal=causal, scale=scale),
+        name="singa_flash_bwd_dq",
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j, kb: (i, j, 0)),
@@ -520,6 +523,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, block_q, block_k,
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, nq=nq, block_q=block_q,
                           block_k=block_k, causal=causal),
+        name="singa_flash_bwd_dkv",
         grid=(bh, nk, nq),
         in_specs=[
             pl.BlockSpec((1, block_q, d), qmap),
@@ -1156,6 +1160,7 @@ def _paged_fwd_pallas(q, k_pool, v_pool, page_table, lengths, page_size,
         functools.partial(_paged_fwd_kernel, nM=M, page_size=ps,
                           groups=groups, kvq=kvq, q_tokens=q_tokens,
                           rows_per_token=Q // max(q_tokens, 1)),
+        name="singa_paged_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N, Hp, Qp, PD), q.dtype),
         interpret=interpret,
@@ -1363,6 +1368,7 @@ def _flash_decode_pallas(q, K, V, lengths, scale, k_scales, v_scales,
         functools.partial(_flash_decode_kernel, nT=nT, block_t=bt,
                           groups=groups, kvq=kvq, q_tokens=q_tokens,
                           rows_per_token=Q // max(q_tokens, 1)),
+        name="singa_flash_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N, Hp, Qp, PD), q.dtype),
         interpret=interpret,

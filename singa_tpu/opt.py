@@ -21,6 +21,7 @@ from __future__ import annotations
 import time
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from . import autograd
@@ -181,10 +182,15 @@ class Optimizer:
         self.step()
         observe.record_opt_update(n, time.perf_counter() - t0, "local")
 
+    @jax.named_scope("opt")
     def step(self):
         self.step_counter = self.step_counter + 1.0
 
     def apply(self, param: Tensor, grad: Tensor):
+        """One parameter's update. Subclasses run it under the device scope
+        `opt` (`@jax.named_scope("opt")` on `apply` itself, not on the loops
+        that call it: `autograd.backward` is a generator those loops drive,
+        so a scope around a loop would name the whole backward pass)."""
         raise NotImplementedError
 
     def device_check(self, *args):
@@ -209,6 +215,7 @@ class SGD(Optimizer):
             return {"momentum_buf": jnp.zeros(param.shape, dtype=param.dtype)}
         return {}
 
+    @jax.named_scope("opt")
     def apply(self, param: Tensor, grad: Tensor):
         g = grad.data
         lr = self.lr(self.step_counter).astype(param.dtype)
@@ -234,6 +241,7 @@ class RMSProp(Optimizer):
     def _init_state(self, param):
         return {"running_average": jnp.zeros(param.shape, dtype=param.dtype)}
 
+    @jax.named_scope("opt")
     def apply(self, param: Tensor, grad: Tensor):
         g = grad.data
         lr = self.lr(self.step_counter).astype(param.dtype)
@@ -256,6 +264,7 @@ class AdaGrad(Optimizer):
     def _init_state(self, param):
         return {"history": jnp.zeros(param.shape, dtype=param.dtype)}
 
+    @jax.named_scope("opt")
     def apply(self, param: Tensor, grad: Tensor):
         g = grad.data
         lr = self.lr(self.step_counter).astype(param.dtype)
@@ -282,6 +291,7 @@ class Adam(Optimizer):
         return {"m": jnp.zeros(param.shape, dtype=param.dtype),
                 "v": jnp.zeros(param.shape, dtype=param.dtype)}
 
+    @jax.named_scope("opt")
     def apply(self, param: Tensor, grad: Tensor):
         g = grad.data
         lr = self.lr(self.step_counter).astype(param.dtype)

@@ -30,6 +30,8 @@ from __future__ import annotations
 
 import weakref
 
+import jax
+
 #: every KV-cache storage mode the serving stack supports (the
 #: `kv_dtype=` label on singa_serve_* metrics is proven against this
 #: tuple by tools/check_metrics_names.py rule 5). "fp" is the
@@ -79,6 +81,12 @@ def _mm(x, W):
 
 
 _Q8_KEYS = ("Wqkv", "Wo", "W1", "W2", "head")
+
+
+def _blk(li, name):
+    """Device scope of sublayer `name` of block `li`, as the training model
+    names it: the decode programs read by the same names as the step."""
+    return f"TransformerBlock_{li}/{name}"
 
 
 def _cast_params(p, dtype):
@@ -160,17 +168,30 @@ class _DecodeCore:
     def cast(self, p, dtype):
         return _cast_params(p, dtype)
 
-    def ln(self, x, g, b, eps=1e-5):
+    def ln(self, x, g, b, scope, eps=1e-5):
         # fp32 island like autograd.LayerNorm: variance in bf16 is
-        # catastrophically lossy
+        # catastrophically lossy. `scope`: the device scope, the layer's
+        # name in the training model (_blk(li, "ln1"), "ln_f")
         import jax.numpy as jnp
         from jax import lax
-        x32 = x.astype(jnp.float32)
-        m = jnp.mean(x32, axis=-1, keepdims=True)
-        v = jnp.var(x32, axis=-1, keepdims=True)
-        y = (x32 - m) * lax.rsqrt(v + eps) * g.astype(jnp.float32) \
-            + b.astype(jnp.float32)
-        return y.astype(x.dtype)
+        with jax.named_scope(scope):
+            x32 = x.astype(jnp.float32)
+            m = jnp.mean(x32, axis=-1, keepdims=True)
+            v = jnp.var(x32, axis=-1, keepdims=True)
+            y = (x32 - m) * lax.rsqrt(v + eps) * g.astype(jnp.float32) \
+                + b.astype(jnp.float32)
+            return y.astype(x.dtype)
+
+    def embed(self, p, tok, pos):
+        """Token rows plus, unless rotary, the rows of positions `pos`."""
+        with jax.named_scope("tok_embed"):
+            return p["emb"][tok] + (0 if self.rope else p["pos"][pos])
+
+    def head(self, p, h):
+        """Final norm and output head: (..., E) -> logits (..., V)."""
+        x = self.ln(h, p["gf"], p["bf"], "ln_f")
+        with jax.named_scope("head"):
+            return _mm(x, p["head"])
 
     def mlp(self, bp, x, li):
         """Block MLP on (..., E): dense two-layer, or the MoE FFN when
@@ -189,12 +210,15 @@ class _DecodeCore:
             from .parallel.moe import moe_ffn
             lead = x.shape[:-1]
             flat = x.reshape(-1, x.shape[-1])
-            y, _, _ = moe_ffn(flat, bp["moeWg"], bp["moeW1"], bp["moeb1"],
-                              bp["moeW2"], bp["moeb2"],
-                              capacity_factor=cf, k=k)
+            with jax.named_scope(_blk(li, "moe")):
+                y, _, _ = moe_ffn(flat, bp["moeWg"], bp["moeW1"],
+                                  bp["moeb1"], bp["moeW2"], bp["moeb2"],
+                                  capacity_factor=cf, k=k)
             return y.reshape(*lead, x.shape[-1]).astype(x.dtype)
-        return _mm(jax.nn.gelu(_mm(x, bp["W1"]) + bp["bb1"]),
-                   bp["W2"]) + bp["bb2"]
+        with jax.named_scope(_blk(li, "fc1")):
+            h = jax.nn.gelu(_mm(x, bp["W1"]) + bp["bb1"])
+        with jax.named_scope(_blk(li, "fc2")):
+            return _mm(h, bp["W2"]) + bp["bb2"]
 
     def qkv(self, bp, x, n, S=None):
         """Fused QKV projection: one (E, E + 2*Hkv*D) matmul, split into
@@ -274,7 +298,7 @@ class _DecodeCore:
         D = self.E // self.H
         S = prompt.shape[1]
         ln = self.ln
-        h = p["emb"][prompt] + (0 if self.rope else p["pos"][:S])
+        h = self.embed(p, prompt, slice(S))
 
         kvs = []
         G = self.G
@@ -282,19 +306,20 @@ class _DecodeCore:
             from .autograd import rope_tables, apply_rope
             rcos, rsin = rope_tables(jnp.arange(S), D, self.rope_theta)
         for li, bp in enumerate(p["blocks"]):
-            x = ln(h, bp["g1"], bp["b1"])
-            q, k, v = self.qkv(bp, x, n, S)     # q (n,H,·); kv (n,Hkv,·)
-            if self.rope:
-                # rotate q/k; the cache stores ROTATED keys (standard),
-                # so decode steps only rotate their own position
-                q = apply_rope(q, rcos, rsin)
-                k = apply_rope(k, rcos, rsin)
-            kr = jnp.repeat(k, G, axis=1) if G > 1 else k
-            vr = jnp.repeat(v, G, axis=1) if G > 1 else v
-            o = flash_attention(q, kr, vr, True, self.scale)
-            h = h + _mm(o.swapaxes(1, 2).reshape(n, S, self.E),
-                        bp["Wo"]) + bp["bo"]
-            x = ln(h, bp["g2"], bp["b2"])
+            x = ln(h, bp["g1"], bp["b1"], _blk(li, "ln1"))
+            with jax.named_scope(_blk(li, "attn")):
+                q, k, v = self.qkv(bp, x, n, S)     # q (n,H,·); kv (n,Hkv,·)
+                if self.rope:
+                    # rotate q/k; the cache stores ROTATED keys (standard),
+                    # so decode steps only rotate their own position
+                    q = apply_rope(q, rcos, rsin)
+                    k = apply_rope(k, rcos, rsin)
+                kr = jnp.repeat(k, G, axis=1) if G > 1 else k
+                vr = jnp.repeat(v, G, axis=1) if G > 1 else v
+                o = flash_attention(q, kr, vr, True, self.scale)
+                h = h + _mm(o.swapaxes(1, 2).reshape(n, S, self.E),
+                            bp["Wo"]) + bp["bo"]
+            x = ln(h, bp["g2"], bp["b2"], _blk(li, "ln2"))
             h = h + self.mlp(bp, x, li)
             kvs.append((k, v))
         return h, kvs
@@ -328,7 +353,7 @@ class _DecodeCore:
                 Vc = jnp.zeros((n, Hkv // P, T, P * D), v.dtype) \
                     .at[:, :, :S0].set(self._pack(v, n, S0))
             caches.append((Kc, Vc))
-        logits0 = _mm(self.ln(h[:, -1], p["gf"], p["bf"]), p["head"])
+        logits0 = self.head(p, h[:, -1])
         return logits0, caches
 
     def _pack_q(self, q, n):
@@ -382,7 +407,7 @@ class _DecodeCore:
         # clamp positions so an inactive slot's stale length can never
         # index outside the table/pos-embedding (its output is masked)
         pos = jnp.minimum(lens, self.T - 1)
-        h = p["emb"][tok] + (0 if self.rope else p["pos"][pos])
+        h = self.embed(p, tok, pos)
         if self.rope:
             from .autograd import rope_tables, apply_rope
             rcos, rsin = rope_tables(pos, D, self.rope_theta)  # (n, D)
@@ -395,42 +420,43 @@ class _DecodeCore:
         ln_att = jnp.where(active, pos + 1, 1)
         new_pools = []
         for li, (bp, pool) in enumerate(zip(p["blocks"], pools)):
-            x = ln(h, bp["g1"], bp["b1"])
-            q, kn, vn = self.qkv(bp, x, n)   # q (n,H,D); kv (n,Hkv,D)
-            if self.rope:
-                q = apply_rope(q, rcos, rsin)
-                kn = apply_rope(kn, rcos, rsin)
-            if self.kvq:
-                (K8, Ks), (V8, Vs) = pool
-                k8, ks = self._quant_kv(kn[:, :, None], n, 1)
-                v8, vs = self._quant_kv(vn[:, :, None], n, 1)
-                K8 = K8.at[pvec, :, off, :].set(k8[:, :, 0], mode="drop")
-                Ks = Ks.at[pvec, :, off, :].set(ks[:, :, 0], mode="drop")
-                V8 = V8.at[pvec, :, off, :].set(v8[:, :, 0], mode="drop")
-                Vs = Vs.at[pvec, :, off, :].set(vs[:, :, 0], mode="drop")
-                pool = ((K8, Ks), (V8, Vs))
-                Kmat, Vmat, Ksc, Vsc = K8, V8, Ks, Vs
-            else:
-                K, V = pool
-                K = K.at[pvec, :, off, :].set(
-                    self._pack(kn[:, :, None], n, 1)[:, :, 0],
-                    mode="drop")
-                V = V.at[pvec, :, off, :].set(
-                    self._pack(vn[:, :, None], n, 1)[:, :, 0],
-                    mode="drop")
-                pool = (K, V)
-                Kmat, Vmat, Ksc, Vsc = K, V, None, None
-            Q2 = self._pack_q(q, n)
-            O2 = paged_attention(
-                Q2, Kmat, Vmat, page_table, ln_att, ps,
-                scale=self.scale, k_scales=Ksc, v_scales=Vsc,
-                groups=G, use_kernel=use_kernel)
-            o = self._unpack_o(O2.astype(x.dtype), n)
-            h = h + _mm(o, bp["Wo"]) + bp["bo"]
-            x = ln(h, bp["g2"], bp["b2"])
+            x = ln(h, bp["g1"], bp["b1"], _blk(li, "ln1"))
+            with jax.named_scope(_blk(li, "attn")):
+                q, kn, vn = self.qkv(bp, x, n)   # q (n,H,D); kv (n,Hkv,D)
+                if self.rope:
+                    q = apply_rope(q, rcos, rsin)
+                    kn = apply_rope(kn, rcos, rsin)
+                if self.kvq:
+                    (K8, Ks), (V8, Vs) = pool
+                    k8, ks = self._quant_kv(kn[:, :, None], n, 1)
+                    v8, vs = self._quant_kv(vn[:, :, None], n, 1)
+                    K8 = K8.at[pvec, :, off, :].set(k8[:, :, 0], mode="drop")
+                    Ks = Ks.at[pvec, :, off, :].set(ks[:, :, 0], mode="drop")
+                    V8 = V8.at[pvec, :, off, :].set(v8[:, :, 0], mode="drop")
+                    Vs = Vs.at[pvec, :, off, :].set(vs[:, :, 0], mode="drop")
+                    pool = ((K8, Ks), (V8, Vs))
+                    Kmat, Vmat, Ksc, Vsc = K8, V8, Ks, Vs
+                else:
+                    K, V = pool
+                    K = K.at[pvec, :, off, :].set(
+                        self._pack(kn[:, :, None], n, 1)[:, :, 0],
+                        mode="drop")
+                    V = V.at[pvec, :, off, :].set(
+                        self._pack(vn[:, :, None], n, 1)[:, :, 0],
+                        mode="drop")
+                    pool = (K, V)
+                    Kmat, Vmat, Ksc, Vsc = K, V, None, None
+                Q2 = self._pack_q(q, n)
+                O2 = paged_attention(
+                    Q2, Kmat, Vmat, page_table, ln_att, ps,
+                    scale=self.scale, k_scales=Ksc, v_scales=Vsc,
+                    groups=G, use_kernel=use_kernel)
+                o = self._unpack_o(O2.astype(x.dtype), n)
+                h = h + _mm(o, bp["Wo"]) + bp["bo"]
+            x = ln(h, bp["g2"], bp["b2"], _blk(li, "ln2"))
             h = h + self.mlp(bp, x, li)
             new_pools.append(pool)
-        logits = _mm(ln(h, p["gf"], p["bf"]), p["head"])
+        logits = self.head(p, h)
         return logits, new_pools
 
     def paged_verify_step(self, p, toks, pools, page_table, lens,
@@ -456,7 +482,7 @@ class _DecodeCore:
         nidx = jnp.arange(n)
         posk = lens[:, None] + jnp.arange(k)[None, :]      # (n, k)
         pos_emb = jnp.minimum(posk, self.T - 1)
-        h = p["emb"][toks] + (0 if self.rope else p["pos"][pos_emb])
+        h = self.embed(p, toks, pos_emb)
         if self.rope:
             from .autograd import rope_tables, apply_rope
             rcos, rsin = rope_tables(pos_emb.reshape(-1), D,
@@ -472,46 +498,47 @@ class _DecodeCore:
         ln_att = jnp.where(active, lens + k, 1)
         new_pools = []
         for li, (bp, pool) in enumerate(zip(p["blocks"], pools)):
-            x = ln(h, bp["g1"], bp["b1"])
-            q, kn, vn = self.qkv(bp, x, n, S=k)  # q (n,H,k,D)
-            if self.rope:
-                q = apply_rope(q, rcos, rsin)
-                kn = apply_rope(kn, rcos, rsin)
-            if self.kvq:
-                (K8, Ks), (V8, Vs) = pool
-                k8, ks = self._quant_kv(kn, n, k)
-                v8, vs = self._quant_kv(vn, n, k)
-                K8 = K8.at[pvec, :, off, :].set(
-                    k8.swapaxes(1, 2), mode="drop")
-                Ks = Ks.at[pvec, :, off, :].set(
-                    ks.swapaxes(1, 2), mode="drop")
-                V8 = V8.at[pvec, :, off, :].set(
-                    v8.swapaxes(1, 2), mode="drop")
-                Vs = Vs.at[pvec, :, off, :].set(
-                    vs.swapaxes(1, 2), mode="drop")
-                pool = ((K8, Ks), (V8, Vs))
-                Kmat, Vmat, Ksc, Vsc = K8, V8, Ks, Vs
-            else:
-                K, V = pool
-                kp = self._pack(kn, n, k)
-                vp = self._pack(vn, n, k)
-                K = K.at[pvec, :, off, :].set(
-                    kp.swapaxes(1, 2), mode="drop")
-                V = V.at[pvec, :, off, :].set(
-                    vp.swapaxes(1, 2), mode="drop")
-                pool = (K, V)
-                Kmat, Vmat, Ksc, Vsc = K, V, None, None
-            Q2 = self._pack_q_multi(q, n, k)
-            O2 = paged_attention(
-                Q2, Kmat, Vmat, page_table, ln_att, ps,
-                scale=self.scale, k_scales=Ksc, v_scales=Vsc,
-                groups=G, use_kernel=use_kernel, q_tokens=k)
-            o = self._unpack_o_multi(O2.astype(x.dtype), n, k)
-            h = h + _mm(o, bp["Wo"]) + bp["bo"]
-            x = ln(h, bp["g2"], bp["b2"])
+            x = ln(h, bp["g1"], bp["b1"], _blk(li, "ln1"))
+            with jax.named_scope(_blk(li, "attn")):
+                q, kn, vn = self.qkv(bp, x, n, S=k)  # q (n,H,k,D)
+                if self.rope:
+                    q = apply_rope(q, rcos, rsin)
+                    kn = apply_rope(kn, rcos, rsin)
+                if self.kvq:
+                    (K8, Ks), (V8, Vs) = pool
+                    k8, ks = self._quant_kv(kn, n, k)
+                    v8, vs = self._quant_kv(vn, n, k)
+                    K8 = K8.at[pvec, :, off, :].set(
+                        k8.swapaxes(1, 2), mode="drop")
+                    Ks = Ks.at[pvec, :, off, :].set(
+                        ks.swapaxes(1, 2), mode="drop")
+                    V8 = V8.at[pvec, :, off, :].set(
+                        v8.swapaxes(1, 2), mode="drop")
+                    Vs = Vs.at[pvec, :, off, :].set(
+                        vs.swapaxes(1, 2), mode="drop")
+                    pool = ((K8, Ks), (V8, Vs))
+                    Kmat, Vmat, Ksc, Vsc = K8, V8, Ks, Vs
+                else:
+                    K, V = pool
+                    kp = self._pack(kn, n, k)
+                    vp = self._pack(vn, n, k)
+                    K = K.at[pvec, :, off, :].set(
+                        kp.swapaxes(1, 2), mode="drop")
+                    V = V.at[pvec, :, off, :].set(
+                        vp.swapaxes(1, 2), mode="drop")
+                    pool = (K, V)
+                    Kmat, Vmat, Ksc, Vsc = K, V, None, None
+                Q2 = self._pack_q_multi(q, n, k)
+                O2 = paged_attention(
+                    Q2, Kmat, Vmat, page_table, ln_att, ps,
+                    scale=self.scale, k_scales=Ksc, v_scales=Vsc,
+                    groups=G, use_kernel=use_kernel, q_tokens=k)
+                o = self._unpack_o_multi(O2.astype(x.dtype), n, k)
+                h = h + _mm(o, bp["Wo"]) + bp["bo"]
+            x = ln(h, bp["g2"], bp["b2"], _blk(li, "ln2"))
             h = h + self.mlp(bp, x, li)
             new_pools.append(pool)
-        logits = _mm(ln(h, p["gf"], p["bf"]), p["head"])
+        logits = self.head(p, h)
         return logits, new_pools
 
     def token_step(self, p, tok, caches, i, n, use_kernel=None):
@@ -528,7 +555,7 @@ class _DecodeCore:
         Hp = Hkv // P
         ln = self.ln
         pos_idx = self.S0 + i
-        h = p["emb"][tok] + (0 if self.rope else p["pos"][pos_idx])
+        h = self.embed(p, tok, pos_idx)
         kmask = (jnp.arange(self.T) <= pos_idx)
         if self.rope:
             from .autograd import rope_tables, apply_rope
@@ -536,76 +563,77 @@ class _DecodeCore:
             rcos, rsin = rcos[0], rsin[0]          # (D,) broadcast
         new_caches = []
         for li, ((Kc, Vc), bp) in enumerate(zip(caches, p["blocks"])):
-            x = ln(h, bp["g1"], bp["b1"])
-            q, kn, vn = self.qkv(bp, x, n)   # q (n,H,D); kv (n,Hkv,D)
-            if self.rope:
-                q = apply_rope(q, rcos, rsin)
-                kn = apply_rope(kn, rcos, rsin)
-            # packed caches: one contiguous (P*D)-lane row per token
-            if self.kvq:
-                (K8, Ks), (V8, Vs) = Kc, Vc
-                k8, ks = self._quant_kv(kn[:, :, None], n, 1)
-                v8, vs = self._quant_kv(vn[:, :, None], n, 1)
-                K8 = lax.dynamic_update_slice(K8, k8, (0, 0, pos_idx, 0))
-                Ks = lax.dynamic_update_slice(Ks, ks, (0, 0, pos_idx, 0))
-                V8 = lax.dynamic_update_slice(V8, v8, (0, 0, pos_idx, 0))
-                Vs = lax.dynamic_update_slice(Vs, vs, (0, 0, pos_idx, 0))
-                Kc, Vc = (K8, Ks), (V8, Vs)
-                Kmat = self._dequant_cache(K8, x.dtype)
-                Vmat = self._dequant_cache(V8, x.dtype)
-            else:
-                Kc = lax.dynamic_update_slice(
-                    Kc, kn.reshape(n, Hp, 1, P * D), (0, 0, pos_idx, 0))
-                Vc = lax.dynamic_update_slice(
-                    Vc, vn.reshape(n, Hp, 1, P * D), (0, 0, pos_idx, 0))
-                Kmat, Vmat = Kc, Vc
-            # block-diagonal queries (see _pack_q): the full-width
-            # contraction with the packed K yields exactly the per-head
-            # scores (GQA: G rows per block; MHA is the G=1 case)
-            Q2 = self._pack_q(q, n)
-            use_k = use_kernel if use_kernel is not None \
-                else jax.default_backend() == "tpu"
-            if use_k:
-                # TPU: the Pallas flash-decode kernel streams the cache
-                # blockwise — an int8 cache streams its BYTES and
-                # dequantizes in-kernel; the XLA einsum below would
-                # materialize the dequant. (int4 at PD=128 packs to 64
-                # lanes, fails flash_decode's alignment gate and takes
-                # its reference path on TPU — counted, not hidden.)
-                from .ops.attention import flash_decode
-                lens_att = jnp.broadcast_to(pos_idx + 1, (n,)) \
-                    .astype(jnp.int32)
+            x = ln(h, bp["g1"], bp["b1"], _blk(li, "ln1"))
+            with jax.named_scope(_blk(li, "attn")):
+                q, kn, vn = self.qkv(bp, x, n)   # q (n,H,D); kv (n,Hkv,D)
+                if self.rope:
+                    q = apply_rope(q, rcos, rsin)
+                    kn = apply_rope(kn, rcos, rsin)
+                # packed caches: one contiguous (P*D)-lane row per token
                 if self.kvq:
-                    O2 = flash_decode(
-                        Q2, K8, V8, lens_att, scale=self.scale,
-                        k_scales=Ks, v_scales=Vs, groups=G,
-                        use_kernel=use_k).astype(x.dtype)
+                    (K8, Ks), (V8, Vs) = Kc, Vc
+                    k8, ks = self._quant_kv(kn[:, :, None], n, 1)
+                    v8, vs = self._quant_kv(vn[:, :, None], n, 1)
+                    K8 = lax.dynamic_update_slice(K8, k8, (0, 0, pos_idx, 0))
+                    Ks = lax.dynamic_update_slice(Ks, ks, (0, 0, pos_idx, 0))
+                    V8 = lax.dynamic_update_slice(V8, v8, (0, 0, pos_idx, 0))
+                    Vs = lax.dynamic_update_slice(Vs, vs, (0, 0, pos_idx, 0))
+                    Kc, Vc = (K8, Ks), (V8, Vs)
+                    Kmat = self._dequant_cache(K8, x.dtype)
+                    Vmat = self._dequant_cache(V8, x.dtype)
                 else:
-                    O2 = flash_decode(
-                        Q2, Kc, Vc, lens_att, scale=self.scale,
-                        groups=G, use_kernel=use_k).astype(x.dtype)
-            else:
-                from .observe import record_attention_dispatch
-                record_attention_dispatch("flash_decode", "reference")
-                s = jnp.einsum("nhqj,nhtj->nhqt", Q2, Kmat) * self.scale
-                if self.kvq:
-                    # K-scales: one factor per (source position, own
-                    # block)
-                    s = s * self._scale_rows(Ks, G)
-                a = jax.nn.softmax(jnp.where(kmask, s, -jnp.inf),
-                                   axis=-1)
-                if self.kvq:
-                    # V-scales fold into the weights for the own-head
-                    # block (the only one extracted below)
-                    a = (a * self._scale_rows(Vs, G)).astype(x.dtype)
-                O2 = jnp.einsum("nhqt,nhtj->nhqj", a,
-                                Vmat)           # (n,Hp,P*G,P*D)
-            o = self._unpack_o(O2, n)
-            h = h + _mm(o, bp["Wo"]) + bp["bo"]
-            x = ln(h, bp["g2"], bp["b2"])
+                    Kc = lax.dynamic_update_slice(
+                        Kc, kn.reshape(n, Hp, 1, P * D), (0, 0, pos_idx, 0))
+                    Vc = lax.dynamic_update_slice(
+                        Vc, vn.reshape(n, Hp, 1, P * D), (0, 0, pos_idx, 0))
+                    Kmat, Vmat = Kc, Vc
+                # block-diagonal queries (see _pack_q): the full-width
+                # contraction with the packed K yields exactly the per-head
+                # scores (GQA: G rows per block; MHA is the G=1 case)
+                Q2 = self._pack_q(q, n)
+                use_k = use_kernel if use_kernel is not None \
+                    else jax.default_backend() == "tpu"
+                if use_k:
+                    # TPU: the Pallas flash-decode kernel streams the cache
+                    # blockwise — an int8 cache streams its BYTES and
+                    # dequantizes in-kernel; the XLA einsum below would
+                    # materialize the dequant. (int4 at PD=128 packs to 64
+                    # lanes, fails flash_decode's alignment gate and takes
+                    # its reference path on TPU — counted, not hidden.)
+                    from .ops.attention import flash_decode
+                    lens_att = jnp.broadcast_to(pos_idx + 1, (n,)) \
+                        .astype(jnp.int32)
+                    if self.kvq:
+                        O2 = flash_decode(
+                            Q2, K8, V8, lens_att, scale=self.scale,
+                            k_scales=Ks, v_scales=Vs, groups=G,
+                            use_kernel=use_k).astype(x.dtype)
+                    else:
+                        O2 = flash_decode(
+                            Q2, Kc, Vc, lens_att, scale=self.scale,
+                            groups=G, use_kernel=use_k).astype(x.dtype)
+                else:
+                    from .observe import record_attention_dispatch
+                    record_attention_dispatch("flash_decode", "reference")
+                    s = jnp.einsum("nhqj,nhtj->nhqt", Q2, Kmat) * self.scale
+                    if self.kvq:
+                        # K-scales: one factor per (source position, own
+                        # block)
+                        s = s * self._scale_rows(Ks, G)
+                    a = jax.nn.softmax(jnp.where(kmask, s, -jnp.inf),
+                                       axis=-1)
+                    if self.kvq:
+                        # V-scales fold into the weights for the own-head
+                        # block (the only one extracted below)
+                        a = (a * self._scale_rows(Vs, G)).astype(x.dtype)
+                    O2 = jnp.einsum("nhqt,nhtj->nhqj", a,
+                                    Vmat)           # (n,Hp,P*G,P*D)
+                o = self._unpack_o(O2, n)
+                h = h + _mm(o, bp["Wo"]) + bp["bo"]
+            x = ln(h, bp["g2"], bp["b2"], _blk(li, "ln2"))
             h = h + self.mlp(bp, x, li)
             new_caches.append((Kc, Vc))
-        logits = _mm(ln(h, p["gf"], p["bf"]), p["head"])
+        logits = self.head(p, h)
         return logits, new_caches
 
     def _pack_q_multi(self, q, n, k):
@@ -657,8 +685,7 @@ class _DecodeCore:
         nidx = jnp.arange(n)
         posk = pos[:, None] + jnp.arange(k)[None, :]       # (n, k)
         pos_emb = jnp.minimum(posk, self.T - 1)
-        h = p["emb"][toks] + (0 if self.rope
-                              else p["pos"][pos_emb])      # (n, k, E)
+        h = self.embed(p, toks, pos_emb)                   # (n, k, E)
         if self.rope:
             from .autograd import rope_tables, apply_rope
             rcos, rsin = rope_tables(pos_emb.reshape(-1), D,
@@ -678,43 +705,44 @@ class _DecodeCore:
         lens_att = pos + k                                 # (n,)
         new_caches = []
         for li, ((Kc, Vc), bp) in enumerate(zip(caches, p["blocks"])):
-            x = ln(h, bp["g1"], bp["b1"])
-            q, kn, vn = self.qkv(bp, x, n, S=k)  # q (n,H,k,D)
-            if self.rope:
-                q = apply_rope(q, rcos, rsin)
-                kn = apply_rope(kn, rcos, rsin)
-            if self.kvq:
-                (K8, Ks), (V8, Vs) = Kc, Vc
-                k8, ks = self._quant_kv(kn, n, k)   # (n,Hp,k,·)
-                v8, vs = self._quant_kv(vn, n, k)
-                K8 = K8.at[nidx[:, None], :, posw, :].set(
-                    k8.swapaxes(1, 2), mode="drop")
-                Ks = Ks.at[nidx[:, None], :, posw, :].set(
-                    ks.swapaxes(1, 2), mode="drop")
-                V8 = V8.at[nidx[:, None], :, posw, :].set(
-                    v8.swapaxes(1, 2), mode="drop")
-                Vs = Vs.at[nidx[:, None], :, posw, :].set(
-                    vs.swapaxes(1, 2), mode="drop")
-                Kc, Vc = (K8, Ks), (V8, Vs)
-                Kq, Vq, Ksc, Vsc = K8, V8, Ks, Vs
-            else:
-                kp = self._pack(kn, n, k)           # (n,Hp,k,P*D)
-                vp = self._pack(vn, n, k)
-                Kc = Kc.at[nidx[:, None], :, posw, :].set(
-                    kp.swapaxes(1, 2), mode="drop")
-                Vc = Vc.at[nidx[:, None], :, posw, :].set(
-                    vp.swapaxes(1, 2), mode="drop")
-                Kq, Vq, Ksc, Vsc = Kc, Vc, None, None
-            Q2 = self._pack_q_multi(q, n, k)
-            O2 = flash_decode(Q2, Kq, Vq, lens_att, scale=self.scale,
-                              k_scales=Ksc, v_scales=Vsc, groups=G,
-                              q_tokens=k, use_kernel=use_kernel)
-            o = self._unpack_o_multi(O2.astype(x.dtype), n, k)
-            h = h + _mm(o, bp["Wo"]) + bp["bo"]
-            x = ln(h, bp["g2"], bp["b2"])
+            x = ln(h, bp["g1"], bp["b1"], _blk(li, "ln1"))
+            with jax.named_scope(_blk(li, "attn")):
+                q, kn, vn = self.qkv(bp, x, n, S=k)  # q (n,H,k,D)
+                if self.rope:
+                    q = apply_rope(q, rcos, rsin)
+                    kn = apply_rope(kn, rcos, rsin)
+                if self.kvq:
+                    (K8, Ks), (V8, Vs) = Kc, Vc
+                    k8, ks = self._quant_kv(kn, n, k)   # (n,Hp,k,·)
+                    v8, vs = self._quant_kv(vn, n, k)
+                    K8 = K8.at[nidx[:, None], :, posw, :].set(
+                        k8.swapaxes(1, 2), mode="drop")
+                    Ks = Ks.at[nidx[:, None], :, posw, :].set(
+                        ks.swapaxes(1, 2), mode="drop")
+                    V8 = V8.at[nidx[:, None], :, posw, :].set(
+                        v8.swapaxes(1, 2), mode="drop")
+                    Vs = Vs.at[nidx[:, None], :, posw, :].set(
+                        vs.swapaxes(1, 2), mode="drop")
+                    Kc, Vc = (K8, Ks), (V8, Vs)
+                    Kq, Vq, Ksc, Vsc = K8, V8, Ks, Vs
+                else:
+                    kp = self._pack(kn, n, k)           # (n,Hp,k,P*D)
+                    vp = self._pack(vn, n, k)
+                    Kc = Kc.at[nidx[:, None], :, posw, :].set(
+                        kp.swapaxes(1, 2), mode="drop")
+                    Vc = Vc.at[nidx[:, None], :, posw, :].set(
+                        vp.swapaxes(1, 2), mode="drop")
+                    Kq, Vq, Ksc, Vsc = Kc, Vc, None, None
+                Q2 = self._pack_q_multi(q, n, k)
+                O2 = flash_decode(Q2, Kq, Vq, lens_att, scale=self.scale,
+                                  k_scales=Ksc, v_scales=Vsc, groups=G,
+                                  q_tokens=k, use_kernel=use_kernel)
+                o = self._unpack_o_multi(O2.astype(x.dtype), n, k)
+                h = h + _mm(o, bp["Wo"]) + bp["bo"]
+            x = ln(h, bp["g2"], bp["b2"], _blk(li, "ln2"))
             h = h + self.mlp(bp, x, li)
             new_caches.append((Kc, Vc))
-        logits = _mm(ln(h, p["gf"], p["bf"]), p["head"])
+        logits = self.head(p, h)
         return logits, new_caches
 
 
