@@ -859,6 +859,24 @@ def record_attention_dispatch(site: str, path: str):
             "(kernel|interpret|reference)").inc(site=site, path=path)
 
 
+def record_flash_tiles(site: str, visited: int, masked: int, square: int):
+    """The tile schedule one flash call site was traced with
+    (ops.attention.flash_plan), in sub-tiles a (batch x head) row: how
+    many the kernel computes, how many of those add a mask, and the whole
+    score square. Causal calls visit little over half and mask only the
+    sub-tiles the diagonal crosses; others visit all and mask none. A
+    gauge: it holds the site's latest trace."""
+    assert site in ATTN_SITES, site
+    if not _enabled:
+        return
+    g = gauge("singa_flash_tiles",
+              "sub-tiles a (batch x head) row in the latest traced flash "
+              "call, by site and kind (visited|masked|square)")
+    for kind, n in (("visited", visited), ("masked", masked),
+                    ("square", square)):
+        g.set(n, site=site, kind=kind)
+
+
 def record_comm_host(op: str, start: float, seconds: float):
     """Host-side entry/exit stamp of one collective CALL SITE
     (parallel.communicator wraps every collective body in one). Under

@@ -20,19 +20,24 @@ Three tiers, same math:
 from __future__ import annotations
 
 import functools
+import operator
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..observe import record_attention_dispatch
+from ..observe import record_attention_dispatch, record_flash_tiles
 
 _NEG_INF = -1e30
 
 
-def _causal_mask(sq, sk, q_off=0, k_off=0, dtype=jnp.float32):
-    q_pos = q_off + lax.broadcasted_iota(jnp.int32, (sq, sk), 0)
-    k_pos = k_off + lax.broadcasted_iota(jnp.int32, (sq, sk), 1)
+def _causal_mask(sq, sk, q_off=0, k_off=0, dtype=jnp.float32,
+                 transposed=False):
+    """Additive mask, (sq, sk), or (sk, sq) for scores held keys x queries."""
+    shape, q_ax = ((sk, sq), 1) if transposed else ((sq, sk), 0)
+    q_pos = q_off + lax.broadcasted_iota(jnp.int32, shape, q_ax)
+    k_pos = k_off + lax.broadcasted_iota(jnp.int32, shape, 1 - q_ax)
     return jnp.where(k_pos > q_pos, _NEG_INF, 0.0).astype(dtype)
 
 
@@ -50,25 +55,52 @@ def attention_reference(q, k, v, causal=False, scale=None):
 
 
 # ======================= 2. flash attention ==============================
-# Online-softmax over K blocks; the kernel keeps one (Bq, D) accumulator,
-# running row-max m and row-sum l in VMEM scratch. Backward recomputes
-# blockwise (no S matrix ever materialized).
-
-# Measured on v5e (fp32, differential timing): at S=4096, 128x128 tiles
-# run 30.6 ms vs 4.3 ms at 1024x1024 — per-grid-step overhead dominates
-# small tiles, and a (1024,64) tile is still only 256 KB of VMEM. At
-# S<=512 inside a full model, 256 beats 512 (~8%) — VMEM pressure against
-# the surrounding fused ops. None = pick by sequence length.
+# Online softmax over K blocks, blockwise-recompute backward (no S matrix
+# ever materialized). A grid step holds a LARGE (block_q, block_k) tile —
+# per-grid-step overhead is real — and works through it in BANDS: the
+# forward takes `band` query rows at a time against the key columns the
+# band may see, the backward `band` key rows against the query columns
+# that may see them. On the tile the causal diagonal crosses, a band's
+# columns stop at the diagonal: sub-tiles above it are never multiplied,
+# exponentiated or masked, sub-tiles under it take no mask, and only the
+# band x band sub-tile on the diagonal adds one. `flash_plan` is the one
+# place that picks blocks and band from (sq, sk, d, causal, dtype).
+#
+# Measured on one TPU v5e (PR 26; `chiprun -- python3 tools/flash_bench.py
+# [--shape ... --causal 0|1 --bands ...]`: device time of the Mosaic call,
+# median of 30 in a profiler trace; "before" is the parent commit's kernel
+# under the same script; tables in PERF.md section 6), bf16, ms a call:
+#   (4, 16, 1024, 64) causal, the GPT-2-medium training shape:
+#     forward   one 1024 x 1024 tile, mask hoisted (before)  0.371
+#               bands of 128 / 256 / 512 / one band   0.182 / 0.159 / 0.163 / 0.217
+#     backward  512-blocks, 3 of 4 tiles (before)            0.476
+#               bands of 128 / 256 / 512              0.354 / 0.364 / 0.396
+#   the same, not causal: forward 0.365 -> 0.208 (256), backward
+#     0.622 -> 0.525 (128) / 0.500 (256); D = 128 (8, 16, 1024, 128) causal:
+#     forward 0.714 -> 0.320 (256), backward 0.973 -> 0.619 (128) / 0.640.
+#   K streamed, (2, 8, 2048, 64) / (1, 8, 4096, 64) causal forward: 0.215 /
+#     0.340 before; bands of 128 on the diagonal tile and tiles under it
+#     whole 0.218 / 0.339, bands of 256 0.238 / 0.360, every tile in bands
+#     0.240 / 0.406: the running (m, l, acc) round trip through VMEM, once
+#     a band a step, costs what the skipped sub-tiles save.
+# A band of 256 rows keeps each key tile in the MXU for twice the rows of
+# one of 128, which is worth more to the forward than the sub-tiles 128
+# would skip (36 of 64 against 10 of 16). The backward's matmuls are deeper
+# and 128 is 3 % faster a call under a causal mask, but its kernel is twice
+# the instructions to trace and lower, once a layer: at 24 layers about a
+# second of set-up against 0.24 ms a step, so the backward takes 256 too.
+# None = blocks from flash_plan; an explicit block is honoured if it tiles.
 DEFAULT_BLOCK_Q = None
 DEFAULT_BLOCK_K = None
 
-
-def _default_block(s):
-    import os
-    env = os.environ.get("SINGA_FLASH_BLOCK")
-    if env:
-        return int(env)
-    return 1024 if s >= 1024 else 256
+# Largest tile a grid step holds. Bands are lane multiples (the backward
+# slices its per-row statistics along lanes); a block that no band divides
+# runs as one band.
+_BLOCK_TARGET = 1024
+# Without bands a backward step holds ~3x the forward's tiles (q/k/v/do and
+# four score-sized temporaries): 1024-blocks can overflow the 16 MB of
+# scoped VMEM (D = 128, fp32), so such a block is refit at or below 512.
+_UNBANDED_BWD_CAP = 512
 
 
 def _fit_block(s, target, floor=128):
@@ -86,6 +118,92 @@ def _fit_block(s, target, floor=128):
     return None
 
 
+class FlashTiles(NamedTuple):
+    """One direction's schedule. `band` 0: a block is worked whole.
+    visited / masked / square count sub-tiles a (batch x head) row, in
+    units of band x band (block_q x block_k when band is 0): how many the
+    kernel computes, how many of those add a mask, and the whole score
+    square."""
+    block_q: int
+    block_k: int
+    band: int
+    visited: int
+    masked: int
+    square: int
+
+
+class FlashPlan(NamedTuple):
+    """`fwd` None: no block tiles the call, it takes the reference path
+    (`ok` False). `bwd` None: the backward takes the blockwise XLA path.
+    `fused`: one backward kernel (dq accumulated in VMEM) instead of the
+    dq / dkv pair."""
+    fwd: FlashTiles | None
+    bwd: FlashTiles | None
+    fused: bool
+
+    @property
+    def ok(self):
+        return self.fwd is not None
+
+
+def _tile_counts(sq, sk, uq, uk, causal):
+    """(visited, masked, square) over uq x uk sub-tiles: a sub-tile exists
+    when its first column is at or under its last row, and takes a mask
+    unless its last column is at or under its first row."""
+    rows, cols = sq // uq, sk // uk
+    if not causal:
+        return rows * cols, 0, rows * cols
+    visited = masked = 0
+    for i in range(rows):
+        seen = min(cols, (i * uq + uq - 1) // uk + 1)
+        visited += seen
+        masked += seen - min(cols, (i * uq + 1) // uk)
+    return visited, masked, rows * cols
+
+
+def _tiles(sq, sk, bq, bk, causal, bands):
+    """The schedule at blocks (bq, bk), in the first of `bands` that
+    divides both."""
+    band = next((t for t in bands if bq % t == 0 and bk % t == 0), 0)
+    return FlashTiles(bq, bk, band, *_tile_counts(
+        sq, sk, band or bq, band or bk, causal))
+
+
+def flash_plan(sq, sk, d, causal, dtype, block_q=None, block_k=None):
+    """The tile schedule of one flash_attention call, forward and backward,
+    from what the call can see. Blocks: an explicit block is honoured when
+    it tiles its sequence on 8-sublane alignment (else ok=False -> the
+    reference path); None takes the largest evenly-tiling block at or
+    under 1024, so S <= 1024 is one grid step a (batch x head) row and
+    S = 384 or 896 still run the kernel."""
+    def pick(s, explicit):
+        if explicit is None:
+            return _fit_block(s, _BLOCK_TARGET)
+        b = min(explicit, s)
+        return b if s % b == 0 and b % 8 == 0 else None
+
+    bq, bk = pick(sq, block_q), pick(sk, block_k)
+    if bq is None or bk is None:
+        return FlashPlan(None, None, False)
+    # band heights as measured (comment above): 256, but 128 on the
+    # forward's diagonal tile once K streams over the grid
+    bwd_bands = (256, 128)
+    fwd = _tiles(sq, sk, bq, bk, causal, bwd_bands if bk == sk else (128,))
+    bwd = _tiles(sq, sk, bq, bk, causal, bwd_bands)
+    if not bwd.band and max(bq, bk) > _UNBANDED_BWD_CAP:
+        # a capped block may stop tiling evenly (1016 -> 512 at S=1016), so
+        # refit rather than crash the blockwise fallback on a non-divisor
+        bq = _fit_block(sq, min(bq, _UNBANDED_BWD_CAP))
+        bk = _fit_block(sk, min(bk, _UNBANDED_BWD_CAP))
+        bwd = _tiles(sq, sk, bq, bk, causal, bwd_bands) if bq and bk \
+            else None
+    # the fused backward keeps dq for a whole (batch x head) row in VMEM:
+    # an f32 accumulator and the double-buffered output row
+    itemsize = jnp.dtype(dtype).itemsize
+    fused = sq * d * (4 + 2 * itemsize) <= _FUSED_DQ_BYTES_CAP
+    return FlashPlan(fwd, bwd, fused)
+
+
 try:  # import here so CPU-only environments still import the module
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -94,97 +212,136 @@ except ImportError:  # pragma: no cover
     _HAS_PALLAS = False
 
 
-# TPU Pallas needs the last two block dims (sublane, lane) aligned; scalar
-# per-row stats (lse, delta, running m/l) are carried as (rows, _STAT_LANES)
-# with the value replicated across lanes — rows on sublanes means reading
-# [:, :1] yields the column vector with no relayout.
+# TPU Pallas needs the last two block dims (sublane, lane) aligned; the
+# forward's per-row stats (lse, running m/l) are carried as (rows,
+# _STAT_LANES) with the value replicated across lanes — rows on sublanes
+# means reading [:, :1] yields the column vector with no relayout.
 _STAT_LANES = 8
 
 
-def _maybe_when(cond, fn):
-    """pl.when for traced predicates; plain call for static True."""
-    if cond is True:
-        fn()
+def _total(parts):
+    """Sum of a non-empty iterable of arrays, in the order given."""
+    return functools.reduce(operator.add, parts)
+
+
+def _dot_nt(a, b):
+    """a @ b.T on the MXU as one contraction of the last dimensions, f32
+    accumulation."""
+    return lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+def _visit_by_diagonal(causal, square, single, j, kb, block_q, block_k,
+                       visit):
+    """Run `visit(kind)` for this grid step's (block_q, block_k) tile:
+    "full" (no mask), "diag" (square blocks, the tile on the diagonal:
+    bands stop at it), "crossed" (blocks that are not square: one mask
+    over the tile, built from the step's offsets), or nothing for a tile
+    above the diagonal. The DMA for skipped tiles is elided too:
+    _causal_kv_map / _causal_q_map re-address the last needed block."""
+    if not causal:
+        visit("full")
+    elif square and single:
+        visit("diag")
+    elif square:
+        pl.when(kb < j)(lambda: visit("full"))
+        pl.when(kb == j)(lambda: visit("diag"))
     else:
-        pl.when(cond)(fn)
+        needed = kb * block_k <= j * block_q + block_q - 1
+        under = (kb + 1) * block_k - 1 <= j * block_q
+        pl.when(needed & under)(lambda: visit("full"))
+        pl.when(needed & jnp.logical_not(under))(lambda: visit("crossed"))
 
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                      acc_ref, m_ref, l_ref, *scratch,
-                      nk, block_q, block_k, causal, hoist_mask=False):
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
+                      nq, nk, block_q, block_k, band, causal):
     """Grid: (batch*heads, q_blocks, k_blocks) — K/V blocks STREAM through
     VMEM one (block_k, D) tile at a time (no whole-row residency, so
-    sequence length is bounded by HBM, not VMEM). The online-softmax state
-    (acc, m, l) lives in VMEM scratch, which persists across the k grid
-    dimension. CONTRACT: the grid must stay FULLY sequential (no
-    dimension_semantics 'parallel' on any dim) — hoist_mask initializes
-    its scratch at program_id(0) == 0 and every later bh step reads it,
-    so a parallelized bh dimension would read uninitialized VMEM."""
-    qi = pl.program_id(1)
+    sequence length is bounded by HBM, not VMEM). Inside a step the query
+    rows go `band` at a time: one softmax over every column the band may
+    see in this tile (scores of the unmasked columns and of the diagonal
+    sub-tile share one row maximum, so a band pays the statistics once,
+    not once a sub-tile). With nk > 1 the online-softmax state (acc, m, l)
+    lives in VMEM scratch, which persists across the k grid dimension."""
+    j = pl.program_id(1)
     kb = pl.program_id(2)
+    square = block_q == block_k
+    f32 = jnp.float32
+    if nk > 1:
+        acc_ref, m_ref, l_ref = scratch
 
-    # hoist_mask (static; only when nq == nk == 1, e.g. S <= 1024 at the
-    # default block): the causal mask is identical for every grid step,
-    # so it is built ONCE into a persistent VMEM scratch instead of
-    # paying iota+compare+select on the full score tile per step
-    if hoist_mask:
-        mask_ref = scratch[0]          # bf16: -1e30 is representable
-        # (8-bit exponent), and halves the persistent VMEM cost
+        @pl.when(kb == 0)
+        def _init():
+            m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        @pl.when(pl.program_id(0) == 0)
-        def _mask_init():
-            mask_ref[...] = _causal_mask(block_q, block_k,
-                                         dtype=mask_ref.dtype)
+    # one mask serves every sub-tile on the diagonal: square, aligned
+    diag_mask = _causal_mask(band or block_q, band or block_q) \
+        if causal and square else None
 
-    @pl.when(kb == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def finish(rows, m, l, acc):
+        l = jnp.maximum(l, 1e-20)
+        o_ref[0, rows, :] = (acc / l).astype(o_ref.dtype)
+        lse_ref[0, rows, :] = jnp.broadcast_to(
+            m + jnp.log(l), (acc.shape[0], _STAT_LANES))
 
-    # causal: skip K blocks strictly above the diagonal of this Q block.
-    # COMPUTE is gated here; the DMA for those blocks is skipped too —
-    # _causal_clamp maps their BlockSpec index to the diagonal block, and
-    # Pallas TPU elides the copy when the block index doesn't change
-    # between grid steps.
-    needed = (kb * block_k <= qi * block_q + block_q - 1) if causal else True
+    def visit(kind):
+        # with K streamed a tile under the diagonal goes whole: its bands
+        # would each reload the key tile into the MXU for no column saved
+        rows_per = block_q if kind == "full" and nk > 1 else band or block_q
+        for r in range(block_q // rows_per):
+            lo, hi = r * rows_per, (r + 1) * rows_per
+            rows = slice(lo, hi)
+            if kind == "diag":
+                cols = ([(0, lo, None)] if lo else []) \
+                    + [(lo, hi, diag_mask)]
+            elif kind == "full":
+                cols = [(0, block_k, None)]
+            else:
+                cols = [(0, block_k, _causal_mask(
+                    rows_per, block_k, q_off=j * block_q + lo,
+                    k_off=kb * block_k))]
+            # dots run in the INPUT dtype (bf16 inputs → native MXU rate;
+            # upcasting to f32 first would run the matmul at the ~4x-slower
+            # fp32 rate) and accumulate f32 via preferred_element_type; the
+            # softmax/stats stay in f32. q arrives PRE-SCALED (the wrapper
+            # folds the softmax scale into q, where XLA fuses it for free —
+            # an in-kernel multiply would cost a VPU pass over the scores).
+            q = q_ref[0, rows, :]                      # (band, D), scaled
+            ss = []
+            for a, b, mask in cols:
+                s = _dot_nt(q, k_ref[0, a:b, :])
+                ss.append(s if mask is None else s + mask)
+            m_new = functools.reduce(
+                jnp.maximum, (jnp.max(s, axis=-1, keepdims=True) for s in ss))
+            if nk > 1:
+                m_prev = m_ref[rows, :][:, :1]
+                m_new = jnp.maximum(m_prev, m_new)
+            ps = [jnp.exp(s - m_new) for s in ss]
+            l_new = _total(jnp.sum(p, axis=-1, keepdims=True) for p in ps)
+            pv = _total(
+                jnp.dot(p.astype(v_ref.dtype), v_ref[0, a:b, :],
+                        preferred_element_type=f32)
+                for p, (a, b, _) in zip(ps, cols))
+            if nk == 1:         # the band has seen every column it may
+                finish(rows, m_new, l_new, pv)
+                continue
+            corr = jnp.exp(m_prev - m_new)
+            acc_ref[rows, :] = acc_ref[rows, :] * corr + pv
+            l_ref[rows, :] = jnp.broadcast_to(
+                l_ref[rows, :][:, :1] * corr + l_new,
+                (rows_per, _STAT_LANES))
+            m_ref[rows, :] = jnp.broadcast_to(m_new, (rows_per, _STAT_LANES))
 
-    def _update():
-        # dots run in the INPUT dtype (bf16 inputs → native MXU rate;
-        # upcasting to f32 first would run the matmul at the ~4x-slower
-        # fp32 rate) and accumulate f32 via preferred_element_type; the
-        # softmax/stats stay in f32. q arrives PRE-SCALED (the wrapper
-        # folds the softmax scale into q, where XLA fuses it for free —
-        # an in-kernel multiply would cost a VPU pass over the full
-        # score tile every grid step).
-        q = q_ref[0]                                   # (Bq, D), scaled
-        k_blk = k_ref[0]                               # (Bk, D)
-        v_blk = v_ref[0]
-        s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32)
-        if hoist_mask:
-            s = s + mask_ref[...]
-        elif causal:
-            s = s + _causal_mask(block_q, block_k, q_off=qi * block_q,
-                                 k_off=kb * block_k)
-        m_prev = m_ref[...][:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_ref[...][:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-            p.astype(v_blk.dtype), v_blk,
-            preferred_element_type=jnp.float32)
-        m_ref[...] = jnp.broadcast_to(m_new, (block_q, _STAT_LANES))
-        l_ref[...] = jnp.broadcast_to(l_new, (block_q, _STAT_LANES))
+    _visit_by_diagonal(causal, square, nq == 1 and nk == 1, j, kb, block_q,
+                       block_k, visit)
 
-    _maybe_when(needed, _update)
-
-    @pl.when(kb == nk - 1)
-    def _finish():
-        l = jnp.maximum(l_ref[...][:, :1], 1e-20)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
-        lse_ref[0] = jnp.broadcast_to(m_ref[...][:, :1] + jnp.log(l),
-                                      (block_q, _STAT_LANES))
+    if nk > 1:
+        @pl.when(kb == nk - 1)
+        def _finish():
+            finish(slice(None), m_ref[...][:, :1], l_ref[...][:, :1],
+                   acc_ref[...])
 
 
 def _causal_kv_map(causal, block_q, block_k, nk):
@@ -217,10 +374,11 @@ def _causal_q_map(causal, block_q, block_k):
     return qmap
 
 
-def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k, interpret):
+def _flash_fwd_pallas(q, k, v, causal, scale, tiles, interpret):
     b, h, sq, d = q.shape
     sk = k.shape[2]
     bh = b * h
+    block_q, block_k, band = tiles[:3]
     # fold the softmax scale into q here: XLA fuses the multiply into
     # whatever produced q, so the kernel never spends a VPU pass on it
     qf = (q * scale).astype(q.dtype).reshape(bh, sq, d)
@@ -228,20 +386,14 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k, interpret):
     vf = v.reshape(bh, sk, d)
     nk = sk // block_k
     nq = sq // block_q
-    grid = (bh, nq, nk)
-    # single-tile causal grids reuse one mask every step; cap the
-    # persistent scratch at 2MB so an env-forced giant block can't eat
-    # the VMEM budget the streamed tiles need
-    hoist = (causal and nq == 1 and nk == 1
-             and block_q * block_k * 2 <= 2 * 1024 * 1024)
     kernel = functools.partial(
-        _flash_fwd_kernel, nk=nk, block_q=block_q, block_k=block_k,
-        causal=causal, hoist_mask=hoist)
+        _flash_fwd_kernel, nq=nq, nk=nk, block_q=block_q, block_k=block_k,
+        band=band, causal=causal)
     kvmap = _causal_kv_map(causal, block_q, block_k, nk)
     out, lse = pl.pallas_call(
         kernel,
         name="singa_flash_fwd",
-        grid=grid,
+        grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j, kb: (i, j, 0)),
             pl.BlockSpec((1, block_k, d), kvmap),
@@ -260,291 +412,203 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k, interpret):
             pltpu.VMEM((block_q, d), jnp.float32),
             pltpu.VMEM((block_q, _STAT_LANES), jnp.float32),
             pltpu.VMEM((block_q, _STAT_LANES), jnp.float32),
-        ] + ([pltpu.VMEM((block_q, block_k), jnp.bfloat16)]
-             if hoist else []),
+        ] if nk > 1 else [],
         interpret=interpret,
     )(qf, kf, vf)
     return out.reshape(b, h, sq, d), lse[:, :, 0].reshape(b, h, sq)
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, dq_acc, *, nk, block_q, block_k, causal,
-                         scale):
-    """Grid (bh, q_blocks, k_blocks): accumulate dQ over streamed K/V."""
-    qi = pl.program_id(1)
-    kb = pl.program_id(2)
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      *refs, nq, nk, block_q, block_k, band, causal, scale,
+                      outs):
+    """The backward of one (block_q, block_k) tile, TRANSPOSED: scores are
+    held keys x queries, so p.T and ds.T — what dv = p.T @ do and
+    dk = ds.T @ q consume — come out of the matmuls as they are, the
+    per-row statistics lie along lanes, and only dq = ds @ k contracts the
+    leading dimension (one transpose a sub-tile, not two). Key rows go
+    `band` at a time against the query columns that may see them.
 
-    @pl.when(kb == 0)
-    def _init():
-        dq_acc[...] = jnp.zeros_like(dq_acc)
-
-    needed = (kb * block_k <= qi * block_q + block_q - 1) if causal else True
-
-    def _update():
-        # native-dtype MXU dots (see fwd kernel); ds is rounded to the
-        # input dtype for its matmul, standard flash-2 practice. q
-        # arrives PRE-SCALED, so s matches the forward's lse directly;
-        # the true dL/dq = scale * ds @ k is applied at _finish.
-        q = q_ref[0]
-        k_blk = k_ref[0]
-        v_blk = v_ref[0]
-        do = do_ref[0]
-        s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32)
-        if causal:
-            s = s + _causal_mask(block_q, block_k, q_off=qi * block_q,
-                                 k_off=kb * block_k)
-        p = jnp.exp(s - lse_ref[0][:, :1])
-        dp = jnp.dot(do, v_blk.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, :1])
-        dq_acc[...] += jnp.dot(ds.astype(k_blk.dtype), k_blk,
-                               preferred_element_type=jnp.float32)
-
-    _maybe_when(needed, _update)
-
-    @pl.when(kb == nk - 1)
-    def _finish():
-        dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
-
-
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, dk_acc, dv_acc, *, nq, block_q,
-                          block_k, causal):
-    """Grid (bh, k_blocks, q_blocks): accumulate dK/dV over streamed Q."""
-    kb = pl.program_id(1)
-    qi = pl.program_id(2)
-
-    @pl.when(qi == 0)
-    def _init():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
-
-    needed = (qi * block_q + block_q - 1 >= kb * block_k) if causal else True
-
-    def _update():
-        # native-dtype MXU dots; p/ds rounded to the input dtype for
-        # their matmuls (flash-2 practice). q arrives PRE-SCALED, so
-        # dk = ds.T @ q_scaled IS the true scale * ds.T @ q — no extra
-        # multiply anywhere.
-        q = q_ref[0]
-        k_blk = k_ref[0]
-        v_blk = v_ref[0]
-        do = do_ref[0]
-        s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32)
-        if causal:
-            s = s + _causal_mask(block_q, block_k, q_off=qi * block_q,
-                                 k_off=kb * block_k)
-        p = jnp.exp(s - lse_ref[0][:, :1])                 # (Bq, Bk)
-        dv_acc[...] += jnp.dot(p.astype(do.dtype).T, do,
-                               preferred_element_type=jnp.float32)
-        dp = jnp.dot(do, v_blk.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, :1])
-        dk_acc[...] += jnp.dot(ds.astype(q.dtype).T, q,
-                               preferred_element_type=jnp.float32)
-
-    _maybe_when(needed, _update)
-
-    @pl.when(qi == nq - 1)
-    def _finish():
-        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
-
-
-def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                            delta_ref, dq_ref, dk_ref, dv_ref,
-                            dq_acc, dk_acc, dv_acc, *, nq, nk, block_q,
-                            block_k, causal, scale):
-    """Single-pass backward: grid (bh, k_blocks, q_blocks) computes
-    s/p/ds ONCE per tile pair and emits all three gradients — the split
-    dq/dkv pair recomputes the two largest matmuls (s and dp) and the
-    exp, and streams every q/k/v/do tile twice. dQ accumulates in a
-    persistent (Sq, D) VMEM scratch (TPU grid iteration is sequential,
-    so the scratch survives the whole (nk, nq) sweep of one bh row);
-    callers gate this kernel on that scratch fitting VMEM."""
-    kb = pl.program_id(1)
-    j = pl.program_id(2)
-
-    @pl.when((kb == 0) & (j == 0))
-    def _init_dq():
-        dq_acc[...] = jnp.zeros_like(dq_acc)
-
-    @pl.when(j == 0)
-    def _init_dkv():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
-
-    needed = (j * block_q + block_q - 1 >= kb * block_k) if causal \
-        else True
-    # the q-side window this step addresses (mirrors _causal_q_map's
-    # clamp) — masked steps re-address the first needed block so their
-    # unconditional dq store writes that block's current partial
-    if causal:
-        eff_j = jnp.maximum(j, (kb * block_k) // block_q)
+    `outs` picks the gradients, the grid and the accumulators:
+      "all"  grid (bh, k_blocks, q_blocks): s/p/ds computed ONCE a tile
+             pair for all three gradients. dq accumulates in a (Sq, D) VMEM
+             scratch over the whole sweep of one bh row (TPU grid iteration
+             is sequential, so the scratch survives it) and is written once,
+             when the row's last tile is in; callers gate this on the
+             scratch fitting VMEM (FlashPlan.fused).
+      "dkv"  the same grid, dk/dv only.
+      "dq"   grid (bh, q_blocks, k_blocks), dq only, one q block of scratch.
+    Together "dq" + "dkv" recompute the two largest matmuls and the exp,
+    and stream every tile twice: the long-context path."""
+    refs = list(refs)
+    want_dq, want_dkv = outs != "dkv", outs != "dq"
+    dq_ref = refs.pop(0) if want_dq else None
+    dk_ref, dv_ref = (refs.pop(0), refs.pop(0)) if want_dkv else (None, None)
+    dq_acc = refs.pop(0) if want_dq else None
+    dk_acc, dv_acc = refs if want_dkv else (None, None)
+    if outs == "dq":
+        j, kb = pl.program_id(1), pl.program_id(2)
+        dq_first, dq_last = kb == 0, kb == nk - 1
+        dq_base = 0
     else:
-        eff_j = j
-    rows = pl.dslice(eff_j * block_q, block_q)
+        kb, j = pl.program_id(1), pl.program_id(2)
+        dq_first = (kb == 0) & (j == 0)
+        dq_last = (kb == nk - 1) & (j == nq - 1)
+        dq_base = j * block_q if nq > 1 else 0
+    rows_per = band or block_k
+    square = block_q == block_k
+    f32 = jnp.float32
 
-    def _update():
-        q = q_ref[0]                  # pre-scaled (see fwd kernel)
-        k_blk = k_ref[0]
-        v_blk = v_ref[0]
-        do = do_ref[0]
-        s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32)
-        if causal:
-            s = s + _causal_mask(block_q, block_k, q_off=j * block_q,
-                                 k_off=kb * block_k)
-        p = jnp.exp(s - lse_ref[0][:, :1])                 # (Bq, Bk)
-        dv_acc[...] += jnp.dot(p.astype(do.dtype).T, do,
-                               preferred_element_type=jnp.float32)
-        dp = jnp.dot(do, v_blk.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, :1])
-        dk_acc[...] += jnp.dot(ds.astype(q.dtype).T, q,
-                               preferred_element_type=jnp.float32)
-        dq_acc[rows, :] += jnp.dot(ds.astype(k_blk.dtype), k_blk,
-                                   preferred_element_type=jnp.float32)
+    if want_dq:
+        @pl.when(dq_first)
+        def _init_dq():
+            dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    _maybe_when(needed, _update)
+    if want_dkv:
+        @pl.when(j == 0)
+        def _init_dkv():
+            dk_acc[...] = jnp.zeros_like(dk_acc)
+            dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    # dq: store the addressed window's partial every step — its LAST
-    # flush for window j happens at this row's diagonal block (causal;
-    # kb = nk-1 otherwise), where the accumulation is complete
-    dq_ref[0] = (dq_acc[rows, :] * scale).astype(dq_ref.dtype)
+    diag_mask = _causal_mask(rows_per, rows_per, transposed=True) \
+        if causal and square else None
 
-    @pl.when(j == nq - 1)
-    def _finish_dkv():
-        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+    def visit(kind):
+        for c in range(block_k // rows_per):
+            lo, hi = c * rows_per, (c + 1) * rows_per
+            if kind == "diag":
+                cols = [(lo, hi, diag_mask)] \
+                    + ([(hi, block_q, None)] if hi < block_q else [])
+            elif kind == "full":
+                cols = [(0, block_q, None)]
+            else:
+                cols = [(0, block_q, _causal_mask(
+                    block_q, rows_per, q_off=j * block_q,
+                    k_off=kb * block_k + lo, transposed=True))]
+            # native-dtype MXU dots (see fwd kernel); p and ds are rounded
+            # to the input dtype for their matmuls, standard flash-2
+            # practice. q arrives PRE-SCALED, so s matches the forward's
+            # lse directly and dk = ds.T @ q_scaled IS the true
+            # scale * ds.T @ q; dq gets its factor when it is written.
+            k_blk = k_ref[0, lo:hi, :]                     # (band, D)
+            v_blk = v_ref[0, lo:hi, :]
+            dk = dv = None
+            for a, b, mask in cols:
+                q = q_ref[0, a:b, :]
+                do = do_ref[0, a:b, :]
+                s = _dot_nt(k_blk, q)                      # (band, b - a)
+                if mask is not None:
+                    s = s + mask
+                p = jnp.exp(s - lse_ref[0, 0, :, a:b])
+                dp = _dot_nt(v_blk, do)
+                ds = (p * (dp - delta_ref[0, 0, :, a:b])).astype(q.dtype)
+                if want_dkv:
+                    # summed as they come: collecting a band's products
+                    # and adding them after the loop read 3 to 8 % slower
+                    # on the chip (the compiler follows the order given)
+                    part = jnp.dot(p.astype(do.dtype), do,
+                                   preferred_element_type=f32)
+                    dv = part if dv is None else dv + part
+                    part = jnp.dot(ds, q, preferred_element_type=f32)
+                    dk = part if dk is None else dk + part
+                if want_dq:
+                    dq_acc[pl.ds(dq_base + a, b - a), :] += lax.dot_general(
+                        ds, k_blk, (((0,), (0,)), ((), ())),
+                        preferred_element_type=f32)
+            if want_dkv:
+                dk_acc[lo:hi, :] += dk
+                dv_acc[lo:hi, :] += dv
 
+    _visit_by_diagonal(causal, square, nq == 1 and nk == 1, j, kb, block_q,
+                       block_k, visit)
 
-# dq scratch cap for the fused backward: (Sq, D) f32 must fit scoped
-# VMEM alongside the streamed tiles (~16 MB total) — 4 MB covers
-# S=8192 at D=128; longer sequences fall back to the split kernels.
-_FUSED_DQ_BYTES_CAP = 4 * 1024 * 1024
+    if want_dq:
+        @pl.when(dq_last)
+        def _finish_dq():
+            dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
-
-def _flash_bwd_fused(qf, kf, vf, dof, lsef, delta, causal, scale,
-                     block_q, block_k, interpret, shapes):
-    b, h, sq, sk, d = shapes
-    bh = b * h
-    nq, nk = sq // block_q, sk // block_k
-    kvmap_kq = lambda i, kb, j: (i, kb, 0)
-    qmap = _causal_q_map(causal, block_q, block_k)
-    stat_spec = pl.BlockSpec((1, block_q, _STAT_LANES), qmap)
-    dq, dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_fused_kernel, nq=nq, nk=nk,
-                          block_q=block_q, block_k=block_k,
-                          causal=causal, scale=scale),
-        name="singa_flash_bwd",
-        grid=(bh, nk, nq),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), qmap),
-            pl.BlockSpec((1, block_k, d), kvmap_kq),
-            pl.BlockSpec((1, block_k, d), kvmap_kq),
-            pl.BlockSpec((1, block_q, d), qmap),
-            stat_spec,
-            stat_spec,
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), qmap),
-            pl.BlockSpec((1, block_k, d), kvmap_kq),
-            pl.BlockSpec((1, block_k, d), kvmap_kq),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, sq, d), qf.dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), kf.dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), vf.dtype),
-        ],
-        scratch_shapes=[pltpu.VMEM((sq, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
-        interpret=interpret,
-    )(qf, kf, vf, dof, lsef, delta)
-    return dq, dk, dv
+    if want_dkv:
+        @pl.when(j == nq - 1)
+        def _finish_dkv():
+            dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+            dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _flash_bwd_stats(o, lse, do):
-    """(lsef, delta) lane-broadcast stat tensors for the backward kernels;
-    loop-invariant across ring hops, so callers may precompute once."""
+# VMEM the fused backward may spend on dq for one (batch x head) row: the
+# (Sq, D) f32 accumulator and the double-buffered output row beside the
+# streamed tiles (~16 MB scoped in all) — 6 MB covers S=8192 at D=64 in
+# bf16; longer rows take the split kernels.
+_FUSED_DQ_BYTES_CAP = 6 * 1024 * 1024
+
+
+def _flash_bwd_stats(o, lse, do, block_q):
+    """(lse, delta) for the backward kernels as (bh, q_blocks, 1, block_q):
+    a q block's statistics lie along lanes, one contiguous row a block.
+    Loop-invariant across ring hops, so callers may precompute once."""
     b, h, sq, _ = o.shape
-    bh = b * h
-    stat = (bh, sq, _STAT_LANES)
-    lsef = jnp.broadcast_to(lse.reshape(bh, sq)[:, :, None], stat)
+    stat = (b * h, sq // block_q, 1, block_q)
     # delta = rowsum(do * o): cheap elementwise, leave to XLA fusion
-    delta = jnp.broadcast_to(
-        jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                axis=-1).reshape(bh, sq)[:, :, None], stat)
-    return lsef, delta
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    return lse.reshape(stat), delta.reshape(stat)
 
 
-def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, block_q, block_k,
+def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, tiles, fused,
                       interpret, stats=None):
-    """Pallas flash backward: dQ and dK/dV kernels with streamed tiles."""
+    """Pallas flash backward: the fused kernel, or the dq + dkv pair."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
     bh = b * h
-    # q pre-scaled, as in the forward (kernels consume scaled q; dq gets
-    # its own scale factor at _finish, dk inherits it from q itself)
-    qf = (q * scale).astype(q.dtype).reshape(bh, sq, d)
-    kf, vf = (a.reshape(bh, -1, d) for a in (k, v))
-    dof = do.reshape(bh, sq, d)
-    lsef, delta = stats if stats is not None else _flash_bwd_stats(o, lse,
-                                                                   do)
-    if sq * d * 4 <= _FUSED_DQ_BYTES_CAP:
-        dq, dk, dv = _flash_bwd_fused(
-            qf, kf, vf, dof, lsef, delta, causal, scale, block_q,
-            block_k, interpret, (b, h, sq, sk, d))
-        return (dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),
-                dv.reshape(b, h, sk, d))
+    block_q, block_k, band = tiles[:3]
     nq, nk = sq // block_q, sk // block_k
-    kvmap = _causal_kv_map(causal, block_q, block_k, nk)
+    # q pre-scaled, as in the forward (kernels consume scaled q; dq gets
+    # its own scale factor as it is written, dk inherits it from q itself)
+    qf = (q * scale).astype(q.dtype).reshape(bh, sq, d)
+    kf, vf = (a.reshape(bh, sk, d) for a in (k, v))
+    dof = do.reshape(bh, sq, d)
+    lsef, delta = stats if stats is not None else _flash_bwd_stats(
+        o, lse, do, block_q)
+    shape_q = jax.ShapeDtypeStruct((bh, sq, d), q.dtype)
+    shape_k = jax.ShapeDtypeStruct((bh, sk, d), k.dtype)
+    shape_v = jax.ShapeDtypeStruct((bh, sk, d), v.dtype)
+    acc_k = pltpu.VMEM((block_k, d), jnp.float32)
+
+    def call(outs, name, grid, qmap, kvmap, out_specs, out_shape, scratch):
+        q_spec = pl.BlockSpec((1, block_q, d), qmap)
+        kv_spec = pl.BlockSpec((1, block_k, d), kvmap)
+        stat_spec = pl.BlockSpec(
+            (1, 1, 1, block_q), lambda *g: qmap(*g)[:2] + (0, 0))
+        return pl.pallas_call(
+            functools.partial(
+                _flash_bwd_kernel, nq=nq, nk=nk, block_q=block_q,
+                block_k=block_k, band=band, causal=causal, scale=scale,
+                outs=outs),
+            name=name, grid=grid,
+            in_specs=[q_spec, kv_spec, kv_spec, q_spec, stat_spec,
+                      stat_spec],
+            out_specs=out_specs, out_shape=out_shape,
+            scratch_shapes=scratch, interpret=interpret,
+        )(qf, kf, vf, dof, lsef, delta)
+
+    # grid (bh, k blocks, q blocks): q-side tiles stream, clamped to the
+    # first block that sees this k block
     qmap = _causal_q_map(causal, block_q, block_k)
-    stat_spec_q = pl.BlockSpec((1, block_q, _STAT_LANES),
-                               lambda i, j, kb: (i, j, 0))
-    stat_spec_kq = pl.BlockSpec((1, block_q, _STAT_LANES), qmap)
-
-    dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, nk=nk, block_q=block_q,
-                          block_k=block_k, causal=causal, scale=scale),
-        name="singa_flash_bwd_dq",
-        grid=(bh, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j, kb: (i, j, 0)),
-            pl.BlockSpec((1, block_k, d), kvmap),
-            pl.BlockSpec((1, block_k, d), kvmap),
-            pl.BlockSpec((1, block_q, d), lambda i, j, kb: (i, j, 0)),
-            stat_spec_q,
-            stat_spec_q,
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda i, j, kb: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
-    )(qf, kf, vf, dof, lsef, delta)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, nq=nq, block_q=block_q,
-                          block_k=block_k, causal=causal),
-        name="singa_flash_bwd_dkv",
-        grid=(bh, nk, nq),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), qmap),
-            pl.BlockSpec((1, block_k, d), lambda i, kb, j: (i, kb, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, kb, j: (i, kb, 0)),
-            pl.BlockSpec((1, block_q, d), qmap),
-            stat_spec_kq,
-            stat_spec_kq,
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda i, kb, j: (i, kb, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, kb, j: (i, kb, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
-        ],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
-        interpret=interpret,
-    )(qf, kf, vf, dof, lsef, delta)
+    kvmap_kq = lambda i, kb, j: (i, kb, 0)
+    dkv_specs = [pl.BlockSpec((1, block_k, d), kvmap_kq)] * 2
+    if fused:
+        dq, dk, dv = call(
+            "all", "singa_flash_bwd", (bh, nk, nq), qmap, kvmap_kq,
+            [pl.BlockSpec((1, sq, d), lambda i, kb, j: (i, 0, 0))]
+            + dkv_specs, [shape_q, shape_k, shape_v],
+            [pltpu.VMEM((sq, d), jnp.float32), acc_k, acc_k])
+    else:
+        qmap_qk = lambda i, j, kb: (i, j, 0)
+        dq = call(
+            "dq", "singa_flash_bwd_dq", (bh, nq, nk), qmap_qk,
+            _causal_kv_map(causal, block_q, block_k, nk),
+            pl.BlockSpec((1, block_q, d), qmap_qk), shape_q,
+            [pltpu.VMEM((block_q, d), jnp.float32)])
+        dk, dv = call(
+            "dkv", "singa_flash_bwd_dkv", (bh, nk, nq), qmap, kvmap_kq,
+            dkv_specs, [shape_k, shape_v], [acc_k, acc_k])
     return (dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),
             dv.reshape(b, h, sk, d))
 
@@ -609,38 +673,17 @@ def _kernel_path(interpret):
     return "interpret" if interpret else "kernel"
 
 
-def _resolve_blocks(sq, sk, block_q, block_k):
-    """(bq, bk, ok): pick tiles that divide the sequence on 8-sublane
-    alignment (TPU lowering constraint). None selects the largest evenly-
-    tiling block at or below the measured per-sequence-length default
-    (so S=384 runs the kernel at 192 instead of falling back); an EXPLICIT
-    block that doesn't tile keeps the old contract: ok=False -> reference
-    path."""
-    if block_q is None:
-        bq = _fit_block(sq, _default_block(sq))
-    else:
-        bq = min(block_q, sq)
-        bq = bq if (sq % bq == 0 and bq % 8 == 0) else None
-    if block_k is None:
-        bk = _fit_block(sk, _default_block(sk))
-    else:
-        bk = min(block_k, sk)
-        bk = bk if (sk % bk == 0 and bk % 8 == 0) else None
-    ok = bq is not None and bk is not None
-    return (bq or 0), (bk or 0), ok
-
-
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
     d = q.shape[-1]
     scale, interpret = _resolve(scale, d, interpret)
-    sq, sk = q.shape[2], k.shape[2]
-    bq, bk, ok = _resolve_blocks(sq, sk, block_q, block_k)
-    if not _HAS_PALLAS or not ok:
+    plan = flash_plan(q.shape[2], k.shape[2], d, causal, q.dtype, block_q,
+                      block_k)
+    if not _HAS_PALLAS or not plan.ok:
         record_attention_dispatch("flash_fwd", "reference")
         return attention_reference(q, k, v, causal, scale), None
     record_attention_dispatch("flash_fwd", _kernel_path(interpret))
-    out, lse = _flash_fwd_pallas(q, k, v, causal, scale, bq, bk, interpret)
-    return out, lse
+    record_flash_tiles("flash_fwd", *plan.fwd[3:])
+    return _flash_fwd_pallas(q, k, v, causal, scale, plan.fwd, interpret)
 
 
 def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
@@ -664,18 +707,13 @@ def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, res, g):
     q, k, v, out, lse = saved
     d = q.shape[-1]
     s, interp = _resolve(scale, d, interpret)
-    sq, sk = q.shape[2], k.shape[2]
-    # backward kernels hold ~3x the tiles of forward (q/k/v/do + two
-    # accumulators); 1024-blocks overflow the 16MB scoped VMEM, so cap the
-    # target at 512 and fit to a dividing block (a capped explicit block
-    # may stop tiling evenly — e.g. 768 -> 512 with S=768 — so refit
-    # rather than crash the blockwise fallback on a non-divisor)
-    bq = _fit_block(sq, min(block_q or _default_block(sq), 512))
-    bk = _fit_block(sk, min(block_k or _default_block(sk), 512))
-    if _HAS_PALLAS and bq and bk:
+    sk = k.shape[2]
+    plan = flash_plan(q.shape[2], sk, d, causal, q.dtype, block_q, block_k)
+    if _HAS_PALLAS and plan.bwd:
         record_attention_dispatch("flash_bwd", _kernel_path(interp))
-        return _flash_bwd_pallas(q, k, v, out, lse, g, causal, s, bq, bk,
-                                 interp)
+        record_flash_tiles("flash_bwd", *plan.bwd[3:])
+        return _flash_bwd_pallas(q, k, v, out, lse, g, causal, s, plan.bwd,
+                                 plan.fused, interp)
     record_attention_dispatch("flash_bwd", "reference")
     return _flash_bwd_blockwise(q, k, v, out, lse, g, causal, s,
                                 _fit_block(sk, 512) or sk)
@@ -698,7 +736,7 @@ flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 # `ring_attention` dispatches between them.
 
 
-def _ring_flash_fwd_impl(q, k, v, axis_name, causal, scale, bq, bk, interp):
+def _ring_flash_fwd_impl(q, k, v, axis_name, causal, scale, plan, interp):
     n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
@@ -707,13 +745,13 @@ def _ring_flash_fwd_impl(q, k, v, axis_name, causal, scale, bq, bk, interp):
 
     def hop(k_cur, v_cur, src):
         def full(_):
-            o, l = _flash_fwd_pallas(q, k_cur, v_cur, False, scale, bq, bk,
-                                     interp)
+            o, l = _flash_fwd_pallas(q, k_cur, v_cur, False, scale,
+                                     plan.fwd, interp)
             return o.astype(f32), l
 
         def diag(_):
-            o, l = _flash_fwd_pallas(q, k_cur, v_cur, True, scale, bq, bk,
-                                     interp)
+            o, l = _flash_fwd_pallas(q, k_cur, v_cur, True, scale,
+                                     plan.fwd, interp)
             return o.astype(f32), l
 
         def skip(_):
@@ -748,38 +786,34 @@ def _ring_flash_fwd_impl(q, k, v, axis_name, causal, scale, bq, bk, interp):
     return out, lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _ring_flash(q, k, v, axis_name, causal, scale, bq, bk, interp):
-    out, _ = _ring_flash_fwd_impl(q, k, v, axis_name, causal, scale, bq,
-                                  bk, interp)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _ring_flash(q, k, v, axis_name, causal, scale, plan, interp):
+    out, _ = _ring_flash_fwd_impl(q, k, v, axis_name, causal, scale, plan,
+                                  interp)
     return out
 
 
-def _ring_flash_vjp_fwd(q, k, v, axis_name, causal, scale, bq, bk, interp):
-    out, lse = _ring_flash_fwd_impl(q, k, v, axis_name, causal, scale, bq,
-                                    bk, interp)
+def _ring_flash_vjp_fwd(q, k, v, axis_name, causal, scale, plan, interp):
+    out, lse = _ring_flash_fwd_impl(q, k, v, axis_name, causal, scale, plan,
+                                    interp)
     return out, (q, k, v, out, lse)
 
 
-def _ring_flash_vjp_bwd(axis_name, causal, scale, bq, bk, interp, res, g):
+def _ring_flash_vjp_bwd(axis_name, causal, scale, plan, interp, res, g):
     q, k, v, out, lse = res
     n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     f32 = jnp.float32
-    # backward tiles capped at 512 for VMEM, same as single-shard flash
-    sq, sk = q.shape[2], k.shape[2]
-    bqb = _fit_block(sq, min(bq, 512))
-    bkb = _fit_block(sk, min(bk, 512))
-
-    stats = _flash_bwd_stats(out, lse, g)  # loop-invariant across hops
+    # loop-invariant across hops
+    stats = _flash_bwd_stats(out, lse, g, plan.bwd.block_q)
 
     def hop(k_cur, v_cur, src):
         def run(causal_flag):
             def f(_):
-                dq, dk, dv = _flash_bwd_pallas(q, k_cur, v_cur, out, lse,
-                                               g, causal_flag, scale, bqb,
-                                               bkb, interp, stats=stats)
+                dq, dk, dv = _flash_bwd_pallas(
+                    q, k_cur, v_cur, out, lse, g, causal_flag, scale,
+                    plan.bwd, plan.fused, interp, stats=stats)
                 return dq.astype(f32), dk.astype(f32), dv.astype(f32)
             return f
 
@@ -829,19 +863,17 @@ def ring_attention(q, k, v, axis_name: str, causal=False, scale=None):
     jnp einsum path below is the fallback.
     """
     d = q.shape[-1]
-    sq, sk = q.shape[2], k.shape[2]
     resolved_scale = scale if scale is not None else d ** -0.5
-    bq = _fit_block(sq, _default_block(sq))
-    bk = _fit_block(sk, _default_block(sk))
-    # the backward ring has no blockwise fallback, so its capped tiles
-    # must fit as well (e.g. S_local=2032: fwd fits 1016 but nothing in
-    # [128,512] divides it)
-    bwd_ok = _fit_block(sq, min(bq or 0, 512)) and \
-        _fit_block(sk, min(bk or 0, 512))
-    if _HAS_PALLAS and bq and bk and bwd_ok:
+    # one plan serves every hop: the blocks do not depend on the mask, and
+    # only the diagonal hop is causal. The backward ring has no blockwise
+    # fallback, so its tiles must fit as well (e.g. S_local=2032: the
+    # forward fits 1016, which takes no band, and nothing in [128,512]
+    # divides 2032)
+    plan = flash_plan(q.shape[2], k.shape[2], d, causal, q.dtype)
+    if _HAS_PALLAS and plan.ok and plan.bwd:
         _, interp = _resolve(resolved_scale, d, None)
         return _ring_flash(q, k, v, axis_name, causal, resolved_scale,
-                           bq, bk, interp)
+                           plan, interp)
     return _ring_jnp(q, k, v, axis_name, causal, scale)
 
 

@@ -187,14 +187,14 @@ def test_gpt_seq_parallel_dryrun(dev):
 
 
 def test_block_autofit_nonpow2_seq():
-    """None-default blocks fit a divisor (S=384 -> 192) so the kernel
-    path keeps working off power-of-two lengths; explicit non-tiling
-    blocks keep the documented reference fallback."""
+    """None-default blocks fit a divisor (S=384: one block, in bands of
+    128) so the kernel path keeps working off power-of-two lengths;
+    explicit non-tiling blocks keep the documented reference fallback."""
     from singa_tpu.ops import attention as A
-    bq, bk, ok = A._resolve_blocks(384, 384, None, None)
-    assert ok and bq == 192 and bk == 192
-    _, _, ok = A._resolve_blocks(384, 384, 256, 256)
-    assert not ok
+    plan = A.flash_plan(384, 384, 32, True, jnp.float32)
+    assert plan.ok and plan.fwd[:3] == (384, 384, 128)
+    assert plan.bwd[:3] == (384, 384, 128) and plan.fused
+    assert not A.flash_plan(384, 384, 32, True, jnp.float32, 256, 256).ok
     rng = np.random.RandomState(0)
     q = jnp.asarray(rng.rand(1, 2, 384, 32), jnp.float32)
     out = A.flash_attention(q, q, q, causal=True)
@@ -204,9 +204,16 @@ def test_block_autofit_nonpow2_seq():
 
 
 def test_backward_block_cap_refits():
-    """Explicit blocks above the backward VMEM cap refit to a divisor
-    instead of crashing the blockwise fallback (bq=768 at S=768)."""
+    """An explicit block above the unbanded backward's VMEM cap runs in
+    bands where a band divides it (768 = 3 x 256) and refits to a divisor
+    where none does (1016 -> nothing at or under 512 -> the blockwise
+    path), instead of crashing the blockwise fallback on a non-divisor."""
     from singa_tpu.ops import attention as A
+    assert A.flash_plan(768, 768, 32, True, jnp.float32, 768, 768).bwd[:3] \
+        == (768, 768, 256)
+    assert A.flash_plan(1016, 1016, 32, True, jnp.float32).bwd is None
+    assert A.flash_plan(1000, 1000, 32, True, jnp.float32).bwd[:3] \
+        == (200, 200, 0)     # 500 is not on 8 sublanes
     rng = np.random.RandomState(0)
     q = jnp.asarray(rng.rand(1, 2, 768, 32), jnp.float32)
     g = jax.grad(lambda q: A.flash_attention(
@@ -349,3 +356,140 @@ def test_flash_bwd_fused_matches_split():
                                    rtol=2e-4, atol=2e-4, err_msg=name)
         np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
                                    rtol=2e-3, atol=2e-3, err_msg=name)
+
+
+# ---- the causal tile schedule (flash_plan) ---------------------------------
+
+def _flash_vs_reference(shape, dtype, causal, seed):
+    """Forward and all three gradients of the default-block kernel
+    (interpret mode) against attention_reference in fp32 on the same
+    inputs; returns the largest absolute differences."""
+    rng = np.random.default_rng(seed)
+    q, k, v, w = (jnp.asarray(rng.standard_normal(shape), dtype)
+                  for _ in range(4))
+
+    def run(fn, *a):
+        out, vjp = jax.vjp(lambda *x: fn(*x, causal), *a)
+        return (out,) + vjp(w.astype(out.dtype))
+
+    got = run(att.flash_attention, q, k, v)
+    want = run(att.attention_reference,
+               *(a.astype(jnp.float32) for a in (q, k, v)))
+    return [float(jnp.max(jnp.abs(g.astype(jnp.float32) - r)))
+            for g, r in zip(got, want)]
+
+
+# the training cell's tile geometry with few heads (one 1024 x 1024 grid
+# step a head, worked in bands of 256 rows), the
+# serving buckets 384 / 512 / 896 (896 = 7 bands of 128), a D = 128 head,
+# and K streamed over two blocks (S = 2048)
+@pytest.mark.parametrize("shape,dtype,causal", [
+    ((1, 2, 1024, 64), "bfloat16", True),
+    ((1, 2, 1024, 64), "bfloat16", False),
+    ((1, 2, 1024, 64), "float32", True),
+    ((1, 2, 1024, 64), "float32", False),
+    ((1, 2, 384, 64), "bfloat16", True),
+    ((1, 2, 512, 64), "bfloat16", True),
+    ((1, 2, 896, 64), "bfloat16", True),
+    ((1, 2, 512, 128), "bfloat16", True),
+    ((1, 2, 512, 128), "float32", False),
+    ((1, 1, 2048, 64), "float32", True),
+], ids=lambda p: "x".join(map(str, p)) if isinstance(p, tuple) else str(p))
+def test_flash_default_plan_matches_reference(shape, dtype, causal):
+    errs = _flash_vs_reference(shape, jnp.dtype(dtype), causal, seed=7)
+    # bf16: p and ds are rounded to 8 bits of mantissa for their matmuls;
+    # a dropped or doubly-counted sub-tile moves an output by O(0.1-1)
+    tol = 2e-4 if dtype == "float32" else 6e-2
+    assert max(errs) < tol, dict(zip(("out", "dq", "dk", "dv"), errs))
+
+
+def test_flash_split_backward_matches_reference():
+    """The dq + dkv pair (long rows) over a 2 x 2 grid of banded blocks."""
+    cap = att._FUSED_DQ_BYTES_CAP
+    try:
+        att._FUSED_DQ_BYTES_CAP = 0
+        errs = _flash_vs_reference((1, 1, 2048, 64), jnp.float32, True, 8)
+    finally:
+        att._FUSED_DQ_BYTES_CAP = cap
+    assert max(errs) < 2e-4, errs
+
+
+@pytest.mark.parametrize("args,fwd,bwd", [
+    # (sq, sk, d, causal, dtype[, block_q, block_k]) ->
+    # (block_q, block_k, band, visited, masked, square) each way
+    ((1024, 1024, 64, True, "bfloat16"),
+     (1024, 1024, 256, 10, 4, 16), (1024, 1024, 256, 10, 4, 16)),
+    ((1024, 1024, 64, False, "bfloat16"),
+     (1024, 1024, 256, 16, 0, 16), (1024, 1024, 256, 16, 0, 16)),
+    ((896, 896, 64, True, "bfloat16"),
+     (896, 896, 128, 28, 7, 49), (896, 896, 128, 28, 7, 49)),
+    ((512, 512, 64, True, "bfloat16"),
+     (512, 512, 256, 3, 2, 4), (512, 512, 256, 3, 2, 4)),
+    ((128, 128, 64, True, "bfloat16"),
+     (128, 128, 128, 1, 1, 1), (128, 128, 128, 1, 1, 1)),
+    ((2048, 2048, 64, True, "bfloat16"),
+     (1024, 1024, 128, 136, 16, 256), (1024, 1024, 256, 36, 8, 64)),
+    # explicit blocks are honoured; no band divides 64, so tiles go whole
+    ((128, 128, 32, True, "float32", 64, 64),
+     (64, 64, 0, 3, 2, 4), (64, 64, 0, 3, 2, 4)),
+    ((512, 512, 128, True, "float32", 128, 256),
+     (128, 256, 128, 10, 4, 16), (128, 256, 128, 10, 4, 16)),
+    ((256, 512, 64, False, "bfloat16", 128, 128),
+     (128, 128, 128, 8, 0, 8), (128, 128, 128, 8, 0, 8)),
+], ids=lambda p: "-".join(map(str, p)) if len(p) in (5, 7) else None)
+def test_flash_plan_table(args, fwd, bwd):
+    plan = att.flash_plan(*args[:4], jnp.dtype(args[4]), *args[5:])
+    assert plan.ok and tuple(plan.fwd) == fwd and tuple(plan.bwd) == bwd
+    for t in (plan.fwd, plan.bwd):
+        if args[3]:     # causal: little over half, masks on the diagonal
+            assert t.masked <= t.visited < t.square or t.square == 1
+        else:
+            assert t.visited == t.square and t.masked == 0
+
+
+def test_flash_plan_causal_1024_visits_under_two_thirds():
+    plan = att.flash_plan(1024, 1024, 64, True, jnp.bfloat16)
+    for t in (plan.fwd, plan.bwd):
+        assert t.visited <= 0.65 * t.square
+        # only sub-tiles the diagonal crosses take a mask: one a band
+        assert t.masked == 1024 // t.band
+
+
+@pytest.mark.parametrize("args", [
+    (384, 384, 32, True, "float32", 256, 256),   # 256 does not divide 384
+    (128, 128, 32, True, "float32", 60, 60),     # not on 8 sublanes
+    (100, 100, 32, False, "float32"),            # nothing >= 100 tiles 100
+    (1024, 1023, 64, True, "bfloat16"),          # the engine's last bucket
+], ids=lambda a: "-".join(map(str, a)))
+def test_flash_plan_refuses_blocks_that_do_not_tile(args):
+    plan = att.flash_plan(*args[:4], jnp.dtype(args[4]), *args[5:])
+    assert not plan.ok and plan.fwd is None and plan.bwd is None
+
+
+def test_flash_plan_fused_backward_budget():
+    """dq for a whole row in VMEM (f32 accumulator + double-buffered
+    output) within 6 MB: S = 8192 at D = 64 fuses in bf16 (4 MB) and fp32
+    (6 MB); D = 128, or S = 16384, takes the dq + dkv pair."""
+    assert att.flash_plan(8192, 8192, 64, True, jnp.bfloat16).fused
+    assert att.flash_plan(8192, 8192, 64, True, jnp.float32).fused
+    assert not att.flash_plan(8192, 8192, 128, True, jnp.bfloat16).fused
+    assert not att.flash_plan(16384, 16384, 64, True, jnp.bfloat16).fused
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_tiles_gauge_reads_the_plan(causal):
+    """One traced call leaves the schedule it was traced with in
+    singa_flash_tiles{site, kind}: readable with no chip."""
+    from singa_tpu import observe
+    q = jax.ShapeDtypeStruct((1, 2, 1024, 64), jnp.bfloat16)
+    jax.eval_shape(jax.grad(lambda q, k, v: att.flash_attention(
+        q, k, v, causal).astype(jnp.float32).sum(), (0, 1, 2)), q, q, q)
+    g = observe.get_registry().get("singa_flash_tiles")
+    plan = att.flash_plan(1024, 1024, 64, causal, jnp.bfloat16)
+    for site, t in (("flash_fwd", plan.fwd), ("flash_bwd", plan.bwd)):
+        got = tuple(int(g.value(site=site, kind=kind))
+                    for kind in ("visited", "masked", "square"))
+        assert got == tuple(t[3:]), (site, got, t)
+    if causal:
+        assert int(g.value(site="flash_fwd", kind="visited")) == 10
+        assert int(g.value(site="flash_fwd", kind="masked")) == 4

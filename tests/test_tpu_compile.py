@@ -47,25 +47,60 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _mosaic_calls(fn, *args):
+    return jax.jit(fn).lower(*args).compile().as_text().count(
+        'custom_call_target="tpu_custom_call"')
+
+
 def _has_mosaic_call(fn, *args):
-    return "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()
+    return _mosaic_calls(fn, *args) > 0
 
 
-# GPT-2-small (H12, D64) and a D128 head at the smoke's batch and sequence
-@pytest.mark.parametrize("direction", ["fwd", "bwd"])
-@pytest.mark.parametrize("shape", [(8, 12, 1024, 64), (8, 16, 1024, 128)],
-                         ids=["h12d64", "h16d128"])
-def test_flash_attention_compiles_for_v5e(one_chip, shape, direction):
-    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+def _flash_fwd_and_grad(causal=True):
+    def fwd(q, k, v):  # default scale and blocks, compiled
+        return A.flash_attention(q, k, v, causal, None, None, None, False)
 
-    def fwd(q, k, v):  # causal, default scale and blocks, compiled
-        return A.flash_attention(q, k, v, True, None, None, None, False)
-
-    def bwd(q, k, v):
+    def grad(q, k, v):  # holds the forward's call beside the backward's
         return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
                         argnums=(0, 1, 2))(q, k, v)
 
-    assert _has_mosaic_call(fwd if direction == "fwd" else bwd, q, q, q)
+    return fwd, grad
+
+
+# GPT-2-small (H12, D64) and a D128 head at the smoke's batch and sequence,
+# and the benchmark's training cell (GPT-2-medium: 4 x 16 heads of 64).
+# EXACTLY one Mosaic call a forward and one a backward: the benchmark's
+# flash_roofline.train multiplies the calls it counts by a whole pass.
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("shape", [(8, 12, 1024, 64), (8, 16, 1024, 128),
+                                   (4, 16, 1024, 64)],
+                         ids=["h12d64", "h16d128", "train_gpt2m"])
+def test_flash_attention_compiles_for_v5e(one_chip, shape, direction):
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    fwd, grad = _flash_fwd_and_grad()
+    if direction == "fwd":
+        assert _mosaic_calls(fwd, q, q, q) == 1
+    else:
+        assert _mosaic_calls(grad, q, q, q) == 2
+
+
+# the other plans flash_plan hands out: the engine's prefill buckets (one
+# block, 896 = 7 bands of 128), an encoder (no mask), K streamed over the
+# grid with dq for a whole row in VMEM (fused, at its 6 MB budget), and
+# the dq + dkv pair of long rows. VMEM overflow shows here, not on the chip.
+@pytest.mark.parametrize("shape,causal,calls", [
+    ((1, 20, 128, 64), True, (1, 2)),
+    ((1, 20, 384, 64), True, (1, 2)),
+    ((1, 20, 896, 64), True, (1, 2)),
+    ((4, 16, 1024, 64), False, (1, 2)),
+    ((1, 2, 12288, 64), True, (1, 2)),
+    ((1, 2, 16384, 128), True, (1, 3)),
+], ids=["b128", "b384", "b896", "encoder", "s12k_fused", "s16k_split"])
+def test_flash_plans_compile_for_v5e(one_chip, shape, causal, calls):
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    fwd, grad = _flash_fwd_and_grad(causal)
+    assert (_mosaic_calls(fwd, q, q, q), _mosaic_calls(grad, q, q, q)) \
+        == calls
 
 
 # what ServingEngine builds for GPT-2-small in chip_smoke.py: 8 slots,

@@ -64,6 +64,27 @@ def test_flash_backward_compiled(causal):
         _assert_close_quantile(a, b, tol=2e-2, max_tol=1e-1)
 
 
+def test_flash_train_cell_shape_default_plan():
+    """The benchmark's training cell: (4, 16, 1024, 64) bf16 causal at the
+    default plan (one grid step a head, in bands of 256 rows; the backward
+    holds scores keys x queries), forward and the three gradients against
+    the reference on the same bf16 inputs in fp32."""
+    rng = np.random.RandomState(4)
+    q, k, v = _rand_qkv(rng, 4, 16, 1024, 64, jnp.bfloat16)
+    w = jnp.asarray(rng.standard_normal(q.shape), jnp.bfloat16)
+
+    def run(fn, *a):
+        out, vjp = jax.vjp(lambda *x: fn(*x, True), *a)
+        return (out,) + vjp(w.astype(out.dtype))
+
+    got = jax.jit(lambda *a: run(flash_attention, *a))(q, k, v)
+    want = jax.jit(lambda *a: run(attention_reference, *a))(
+        *(a.astype(jnp.float32) for a in (q, k, v)))
+    _assert_close_quantile(got[0], want[0], tol=8e-3, max_tol=5e-2)
+    for a, b in zip(got[1:], want[1:]):
+        _assert_close_quantile(a, b, tol=2e-2, max_tol=1e-1)
+
+
 def test_flash_long_sequence_compiled():
     """S=16k head: whole-row VMEM residency would blow VMEM (16k*128*4B*2
     = 16 MB just for K/V of one head); streamed blocks must handle it."""
