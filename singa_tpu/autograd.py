@@ -48,9 +48,18 @@ def _is_float0(a):
 # `backward()` below can run its backward rule under the same path again,
 # behind a leading `bwd`.
 
+#: leading scope of a recomputed `Region`'s second forward
+RECOMPUTE_SCOPE = "recompute"
+
+
 def _current_scope():
-    """jax's name stack here, as the `/`-joined path an `op_name` shows."""
-    return str(source_info_util.current_name_stack())
+    """jax's name stack here, as the `/`-joined path an `op_name` shows,
+    less the leading `recompute` of a replayed region: an operator recorded
+    in a replay runs its backward under the scope of the first forward."""
+    path = str(source_info_util.current_name_stack())
+    if path == RECOMPUTE_SCOPE:
+        return ""
+    return path.removeprefix(RECOMPUTE_SCOPE + "/")
 
 
 class Operator:
@@ -62,6 +71,8 @@ class Operator:
 
     #: class-level: op can never produce gradients (comparisons, casts, ...)
     never_requires_grad = False
+    #: class-level: recorded outside any scope, the operator's name is one
+    scope_by_name = True
 
     def __init__(self, name: str | None = None):
         self.name = name or self.__class__.__name__
@@ -87,7 +98,7 @@ class Operator:
         # the scope this operator is recorded under, for backward(): the
         # layer's, or outside any layer the operator's own name
         self._scope = _current_scope()
-        outside = not self._scope
+        outside = not self._scope and self.scope_by_name
         if outside:
             self._scope = self.name
         raw = [x.data for x in xs]
@@ -186,7 +197,7 @@ def backward(y: Tensor, dy=None):
         # behind `bwd`: a rule called from this loop would otherwise read
         # like whatever drives the generator (the optimizer's update), and
         # the vjp-derived one names itself `transpose(jvp())` and no more
-        bwd_scope = "bwd/" + op._scope
+        bwd_scope = "bwd/" + op._scope if op._scope else "bwd"
         with jax.named_scope(bwd_scope):
             # zero-fill output cotangents that never received a gradient
             filled = [dys[i] if i < len(dys) and dys[i] is not None
@@ -1075,6 +1086,26 @@ class Gelu(Operator):
         return jax.nn.gelu(x)
 
 
+class RMSNorm(Operator):
+    """x / rms(x) * gamma over the last axis, no mean and no shift."""
+
+    def __init__(self, eps=1e-6):
+        super().__init__()
+        self.eps = eps
+
+    def forward(self, x, gamma):
+        xf = x.astype(jnp.float32)     # fp32 island, as LayerNorm
+        ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
+        return (xf * lax.rsqrt(ms + self.eps) * gamma).astype(x.dtype)
+
+
+class SwiGLU(Operator):
+    """silu(gate) * up: the product inside a gated feed-forward."""
+
+    def forward(self, gate, up):
+        return jax.nn.silu(gate) * up
+
+
 def axis_bound(name: str) -> bool:
     """True iff mesh axis `name` is bound in the current trace (i.e. we
     are inside a shard_map over it)."""
@@ -1541,6 +1572,14 @@ def layernorm(x, gamma, beta, eps=1e-5):
 
 def gelu(x):
     return Gelu()(x)
+
+
+def rmsnorm(x, gamma, eps=1e-6):
+    return RMSNorm(eps)(x, gamma)
+
+
+def swiglu(gate, up):
+    return SwiGLU()(gate, up)
 
 
 def attention(q, k, v, causal=False, seq_axis=None):
@@ -2044,6 +2083,50 @@ class ComputeCast(Operator):
             return dy.astype(self._orig)
 
 
+class _CastUse(Operator):
+    """One use of a parameter's `cast_once` copy: the copy itself on the
+    way in; on the way back this use's gradient in the parameter's own
+    dtype, so that the uses are summed there and not in the compute
+    dtype."""
+
+    def __init__(self, orig):
+        super().__init__()
+        self._orig = orig
+
+    def forward(self, x):
+        return x
+
+    def backward(self, dy):
+        with jax.named_scope("amp_cast"):
+            return dy.astype(self._orig)
+
+
+#: {id(parameter): (the parameter, its cast)} inside a `cast_once` block
+_casts = {}
+
+
+@contextlib.contextmanager
+def cast_once(params):
+    """Cast each of `params` to the compute dtype here, once, and within
+    the block hand that copy to every `compute_cast` of it: a weight that a
+    model applies T times (and T times more in rebuilt regions) is read and
+    rounded once a step. Each use's gradient goes back to the parameter's
+    dtype before the uses are summed. Put the backward pass inside the
+    block too: a `Region` rebuilt there has to find the same copies."""
+    global _casts
+    prev, _casts = _casts, {}
+    try:
+        for p in params:
+            cast = compute_cast(p)
+            if cast is not p:
+                # the entry holds the parameter too: an id is only unique
+                # while its object lives
+                _casts[id(p)] = (p, cast)
+        yield
+    finally:
+        _casts = prev
+
+
 def compute_cast(*xs):
     """Cast float Tensors to the active compute dtype (no-op when the
     policy is off or dtypes already match)."""
@@ -2054,9 +2137,100 @@ def compute_cast(*xs):
     for x in xs:
         if x is not None and jnp.issubdtype(x.data.dtype, jnp.floating) \
                 and x.data.dtype != tgt:
-            x = ComputeCast(tgt)(x)
+            kept = _casts.get(id(x))
+            x = ComputeCast(tgt)(x) if kept is None \
+                else _CastUse(x.data.dtype)(kept[1])
         out.append(x)
     return tuple(out) if len(out) > 1 else out[0]
+
+
+class Region(Operator):
+    """A part of the tape that keeps its inputs and nothing else, and is
+    rebuilt in the backward pass: `fn(*xs[:n_args])`, a function of Tensors
+    that may also read the Tensors `xs[n_args:]` (the parameters of the
+    layers it calls, and their `cast_once` copies). The forward runs `fn`
+    off the tape, so none of its residuals outlive it. The backward puts
+    the saved inputs and the cotangents behind one
+    `lax.optimization_barrier` (what `jax.checkpoint` lowers to: the
+    compiler may neither merge the second forward with the first nor start
+    it early), runs `fn` again on a tape of its own under the leading scope
+    `recompute`, and walks that tape back: every inner operator runs its
+    own backward rule under its own `bwd/<scope>`, as on the ordinary
+    tape. (`jax.checkpoint` itself would hand the region to
+    jax's differentiation: no tape rule, no `bwd` scope.)
+
+    The inner tape's leaves have to be the very Tensors `fn` reads (the
+    layers hold them), so a run lends them its arrays and takes them back."""
+
+    scope_by_name = False    # its operators name themselves
+
+    def __init__(self, fn, n_args):
+        super().__init__("Region")
+        self.fn, self.n_args = fn, n_args
+
+    def __call__(self, *xs):
+        self._leaves = xs
+        return self._do_forward(*xs)
+
+    def _run(self, arrays, record):
+        """`fn` on the leaves holding `arrays`: off the tape, or (`record`)
+        on a tape of its own whose leaves they are. A tuple of Tensors."""
+        global training
+        leaves = self._leaves
+        held = [(t.data, t.creator, t.stores_grad) for t in leaves]
+        prev, training = training, record
+        try:
+            for t, a in zip(leaves, arrays):
+                t.data, t.creator, t.stores_grad = a, None, t.requires_grad
+            outs = self.fn(*leaves[:self.n_args])
+        finally:
+            for t, (a, creator, stores) in zip(leaves, held):
+                t.data, t.creator, t.stores_grad = a, creator, stores
+            training = prev
+        return outs if isinstance(outs, tuple) else (outs,)
+
+    def forward(self, *raw):
+        # what the second forward has to find again
+        self._saved = raw
+        self._rng = self._leaves[0].device.rng_state
+        outs = tuple(y.data for y in self._run(raw, False))
+        return outs if len(outs) > 1 else outs[0]
+
+    def backward(self, *dys):
+        saved, dys = lax.optimization_barrier((self._saved, tuple(dys)))
+        dev = self._leaves[0].device
+        now, dev.rng_state = dev.rng_state, self._rng
+        # out of the outer `bwd/<scope>`: the replay is named like the
+        # first forward behind `recompute`, the backward like any other
+        with source_info_util.reset_name_stack():
+            try:
+                with jax.named_scope("/".join(filter(
+                        None, (RECOMPUTE_SCOPE, self._scope)))):
+                    outs = self._run(saved, True)
+            finally:
+                dev.rng_state = now
+            # an output off the tape (a sample, an index) has no cotangent
+            # to take; two outputs that share inner work walk it once each
+            grads = {}
+            for y, dy in zip(outs, dys):
+                if y.creator is None:
+                    continue
+                for p, g in backward(y, dy):
+                    grads[id(p)] = g.data if id(p) not in grads \
+                        else grads[id(p)] + g.data
+        return tuple(grads.get(id(t)) for t in self._leaves)
+
+
+def region(fn, *xs, reads=()):
+    """`fn(*xs)` as a `Region`: kept as its inputs, rebuilt in the backward
+    pass. `reads`: the parameter Tensors `fn` reads besides its arguments
+    (`layer.get_params().values()`); where `cast_once` holds a parameter's
+    copy, the region reads that as well."""
+    seen = {id(x) for x in xs}
+    reads = [t for p in reads
+             for t in (p, *(_casts[id(p)][1:] if id(p) in _casts else ()))]
+    extra = [t for t in reads if not (id(t) in seen or seen.add(id(t)))]
+    return Region(fn, len(xs))(*xs, *extra)
 
 
 # ---- reference-name functional parity (python/singa/autograd.py) --------

@@ -271,15 +271,20 @@ class Embedding(Layer):
     (Megatron vocab-parallel embedding): each device gathers only ids in
     its vocab range and one psum assembles the activations — the model's
     largest tensor stops being replicated. V must divide by the axis size
-    (pad the vocab, e.g. to a multiple of 128, as GPT(vocab_tp=) does)."""
+    (pad the vocab, e.g. to a multiple of 128, as GPT(vocab_tp=) does).
+    `out_dtype="float32"` hands the looked-up rows on as they are under
+    `amp` too (a residual stream kept in fp32)."""
 
     def __init__(self, input_dim, output_dim, initializer_fn=None, name=None,
-                 tp_axis: "str | None" = None):
+                 tp_axis: "str | None" = None,
+                 out_dtype: "str | None" = None):
         super().__init__(name)
+        assert out_dtype in (None, "float32"), out_dtype
         self.input_dim = input_dim
         self.output_dim = output_dim
         self.initializer_fn = initializer_fn
         self.tp_axis = tp_axis
+        self.out_dtype = out_dtype
 
     def initialize(self, x):
         W = Tensor((self.input_dim, self.output_dim), device=x.device,
@@ -293,9 +298,10 @@ class Embedding(Layer):
     def forward(self, x):
         # cast AFTER the lookup: (B,S,D) activations, not the (V,D) table
         if self.tp_axis is not None and autograd.axis_bound(self.tp_axis):
-            return autograd.compute_cast(
-                autograd.vocab_parallel_embedding(x, self.W, self.tp_axis))
-        return autograd.compute_cast(autograd.embedding(x, self.W))
+            y = autograd.vocab_parallel_embedding(x, self.W, self.tp_axis)
+        else:
+            y = autograd.embedding(x, self.W)
+        return y if self.out_dtype else autograd.compute_cast(y)
 
 
 class _ConvGeometry:
@@ -635,6 +641,22 @@ class LayerNorm(Layer):
         return autograd.layernorm(x, self.gamma, self.beta, self.eps)
 
 
+class RMSNorm(Layer):
+    """x / rms(x) * gamma (no mean, no shift)."""
+
+    def __init__(self, eps=1e-6, name=None):
+        super().__init__(name)
+        self.eps = eps
+
+    def initialize(self, x):
+        g = Tensor((x.shape[-1],), device=x.device, dtype=x.dtype)
+        g.set_value(1.0)
+        self._register_param("gamma", g)
+
+    def forward(self, x):
+        return autograd.rmsnorm(x, self.gamma, self.eps)
+
+
 class MultiHeadAttention(Layer):
     """Self-attention over (B, S, E); the core runs as ONE fused tape op
     (flash attention / ring attention when seq_axis is a mesh axis).
@@ -750,25 +772,45 @@ class MultiHeadAttention(Layer):
 
 
 class TransformerBlock(Layer):
-    """Pre-LN block: x + MHA(LN(x)); x + MLP(LN(x)). `tp_axis` makes the
+    """Pre-norm block: x + MHA(N(x)); x + MLP(N(x)). `tp_axis` makes the
     attention head-parallel and the MLP column→row parallel (two psums per
     block total, the Megatron layout). `moe_experts > 0` replaces the dense
     MLP with a top-`moe_k` MoE FFN (expert-parallel over `ep_axis`); the
-    router losses surface on `self.moe.{aux_loss,z_loss}` after forward."""
+    router losses surface on `self.moe.{aux_loss,z_loss}` after forward.
+
+    The defaults are the GPT-2 block (LayerNorm, biased GELU MLP of
+    `mlp_ratio` x the width). `norm="rms"` takes RMS norms with `norm_eps`;
+    `ffn="swiglu"` the gated feed-forward (silu(x W_gate) * (x W_up)) W_down
+    (`fc_gate`, `fc1`, `fc2`); `ffn_dim` sets the feed-forward's width where
+    it is no multiple of the block's; `ffn_bias=False` drops its biases;
+    `post_norm=True` adds a norm on each branch's output before it joins
+    the residual (`ln1_post`, `ln2_post`: "sandwich" norms)."""
 
     def __init__(self, num_heads, mlp_ratio=4, causal=True, seq_axis=None,
                  tp_axis=None, attn_bias=False, moe_experts=0, moe_k=1,
                  ep_axis=None, moe_capacity_factor=1.25, num_kv_heads=None,
-                 rope=False, rope_theta=10000.0, name=None):
+                 rope=False, rope_theta=10000.0, norm="layer", norm_eps=None,
+                 ffn="gelu", ffn_dim=None, ffn_bias=True, post_norm=False,
+                 name=None):
         super().__init__(name)
-        self.ln1 = LayerNorm()
+        assert norm in ("layer", "rms") and ffn in ("gelu", "swiglu"), \
+            (norm, ffn)
+        norm_cls = LayerNorm if norm == "layer" else RMSNorm
+
+        def make_norm():    # each class has its own default eps
+            return norm_cls() if norm_eps is None else norm_cls(norm_eps)
+        self.ln1 = make_norm()
         self.attn = MultiHeadAttention(num_heads, causal=causal,
                                        seq_axis=seq_axis, tp_axis=tp_axis,
                                        bias=attn_bias,
                                        num_kv_heads=num_kv_heads,
                                        rope=rope, rope_theta=rope_theta)
-        self.ln2 = LayerNorm()
+        self.ln2 = make_norm()
+        self.post_norm = post_norm
+        if post_norm:
+            self.ln1_post, self.ln2_post = make_norm(), make_norm()
         self.mlp_ratio = mlp_ratio
+        self.ffn, self.ffn_dim, self.ffn_bias = ffn, ffn_dim, ffn_bias
         self.tp_axis = tp_axis
         self.moe_experts = moe_experts
         if moe_experts:
@@ -777,19 +819,29 @@ class TransformerBlock(Layer):
 
     def initialize(self, x):
         e = x.shape[-1]
+        width = self.ffn_dim or e * self.mlp_ratio
         if self.moe_experts:
-            self.moe.hidden = e * self.mlp_ratio
+            self.moe.hidden = width
             return
-        self.fc1 = Linear(e * self.mlp_ratio, tp_axis=self.tp_axis,
-                          tp_mode="column")
-        self.fc2 = Linear(e, tp_axis=self.tp_axis, tp_mode="row")
+        wide = dict(bias=self.ffn_bias, tp_axis=self.tp_axis,
+                    tp_mode="column")
+        if self.ffn == "swiglu":
+            self.fc_gate = Linear(width, **wide)
+        self.fc1 = Linear(width, **wide)
+        self.fc2 = Linear(e, bias=self.ffn_bias, tp_axis=self.tp_axis,
+                          tp_mode="row")
 
     def forward(self, x):
-        x = autograd.add(x, self.attn(self.ln1(x)))
+        a = self.attn(self.ln1(x))
+        x = autograd.add(x, self.ln1_post(a) if self.post_norm else a)
+        h = self.ln2(x)
         if self.moe_experts:
-            return autograd.add(x, self.moe(self.ln2(x)))
-        h = autograd.gelu(self.fc1(self.ln2(x)))
-        return autograd.add(x, self.fc2(h))
+            m = self.moe(h)
+        elif self.ffn == "swiglu":
+            m = self.fc2(autograd.swiglu(self.fc_gate(h), self.fc1(h)))
+        else:
+            m = self.fc2(autograd.gelu(self.fc1(h)))
+        return autograd.add(x, self.ln2_post(m) if self.post_norm else m)
 
 
 class MoE(Layer):

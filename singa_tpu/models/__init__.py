@@ -8,7 +8,8 @@ dispatch in every model file; here it lives once in `base.Classifier`).
 """
 
 from .base import Classifier  # noqa: F401
-from . import mlp, cnn, alexnet, resnet, xceptionnet, transformer  # noqa: F401
+from . import (mlp, cnn, alexnet, resnet, xceptionnet, transformer,  # noqa: F401
+               looplm)
 
 _REGISTRY = {
     "mlp": mlp.create_model,
@@ -23,6 +24,7 @@ _REGISTRY = {
     "xceptionnet": xceptionnet.create_model,
     "gpt": transformer.create_model,
     "gpt_pipe": transformer.create_pipelined,
+    "looplm": looplm.create_model,
 }
 
 
