@@ -27,7 +27,7 @@ jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_num_cpu_devices", 2)
 
 import numpy as np
-from singa_tpu import distributed, layer, model, opt, tensor
+from singa_tpu import distributed, layer, model, opt, overlap, tensor
 from singa_tpu.device import get_default_device
 
 distributed.init()
@@ -66,12 +66,22 @@ phase = os.environ["CKPT_PHASE"]
 ckpt = os.environ["CKPT_DIR"]
 losses = []
 if phase == "save":
-    for _ in range(2):
-        _, l = m(tx, ty)
+    want = np.maximum(X @ m.fc1.W.numpy() + m.fc1.b.numpy(), 0) \
+        @ m.fc2.W.numpy() + m.fc2.b.numpy()
+    for step in range(2):
+        out, l = m(tx, ty)
+        if step == 0:
+            # the step left its batch output sharded over both processes'
+            # devices; the read gathers the global batch on each
+            assert not out.data.is_fully_addressable
+            np.testing.assert_allclose(out.numpy(), want, rtol=1e-5,
+                                       atol=1e-5)
     path = m.save_checkpoint(ckpt, step=2)
     for _ in range(3):
         _, l = m(tx, ty)
         losses.append(float(l.numpy()))
+    # the write is asynchronous: make it durable before this process exits
+    overlap.wait_for_checkpoints()
 else:
     m.load_checkpoint(os.path.join(ckpt, "step_2"))
     for _ in range(3):

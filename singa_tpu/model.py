@@ -12,7 +12,9 @@ buffers donated so params update in place), compiles it with XLA, and every
 later call replays the executable with zero Python op dispatch. Distributed
 training shard_maps the same step over a mesh so DistOpt's `lax.psum` calls
 bind to the data axis — the XLA analog of submitting NCCL ops as graph
-nodes (communicator.cc:175-186).
+nodes (communicator.cc:175-186). A batch output of that step comes back as
+one global array sharded over the data axis, each device holding the rows it
+computed; the step gathers nothing, a read (`Tensor.numpy`) does.
 """
 
 from __future__ import annotations
@@ -385,13 +387,22 @@ class Model(Layer, metaclass=ModelMeta):
                 out_template_box["t"] = template
                 outs = [o.data for o in out_leaves]
                 if dist:
-                    # scalars (loss): average across shards; batched
-                    # outputs: gather to global batch so callers see one
-                    # coherent result
-                    outs = [lax.pmean(o, opt.axis) if o.ndim == 0
-                            else lax.all_gather(o, opt.axis, axis=0,
-                                                tiled=True)
-                            for o in outs]
+                    # scalars (loss): averaged across shards, replicated.
+                    # Batched outputs stay where they were made: each
+                    # leaves as this shard's rows of one global array
+                    # (out_specs P(opt.axis)), so the step runs no gather;
+                    # a read (Tensor.numpy) assembles the global batch.
+                    # out_specs is fixed before the trace, so the two
+                    # kinds go back as two lists and the mask restores
+                    # the leaves' order (_invoke_step).
+                    sharded = [o.ndim > 0 for o in outs]
+                    out_template_box["sharded"] = sharded
+                    outs = ([lax.pmean(o, opt.axis)
+                             for o, s in zip(outs, sharded) if not s],
+                            [o for o, s in zip(outs, sharded) if s])
+                    observe.record_step_outputs(
+                        batch_sharded=len(outs[1]),
+                        mean_reduced=len(outs[0]))
                 new_states = [t.data for t in state_tensors]
                 if dist:
                     # non-param states (BN running stats) differ per shard:
@@ -424,7 +435,8 @@ class Model(Layer, metaclass=ModelMeta):
                 wrapped = jax.shard_map(
                     step, mesh=mesh,
                     in_specs=(state_in, opt_in, P(), P(opt.axis)),
-                    out_specs=(state_in, opt_in, P(), P(), P()),
+                    out_specs=(state_in, opt_in, P(),
+                               (P(), P(opt.axis)), P()),
                     check_vma=False)
             else:
                 wrapped = step
@@ -724,6 +736,11 @@ class Model(Layer, metaclass=ModelMeta):
                 # the update was discarded in-graph: this step's wall
                 # time produced nothing — move it out of `step`
                 goodput.mark_step_skipped()
+        sharded = self._out_template_box.get("sharded")
+        if sharded is not None:
+            # the data-parallel step's two lists, back in the leaves' order
+            reduced, batch = map(iter, outs)
+            outs = [next(batch if s else reduced) for s in sharded]
         tensors = [Tensor(data=a, device=dev, requires_grad=False)
                    for a in outs]
         return _rebuild_out(self._out_template_box["t"], tensors)

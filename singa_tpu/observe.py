@@ -877,6 +877,22 @@ def record_flash_tiles(site: str, visited: int, masked: int, square: int):
         g.set(n, site=site, kind=kind)
 
 
+def record_step_outputs(batch_sharded: int, mean_reduced: int):
+    """How the latest traced data-parallel step (Model under DistOpt on a
+    mesh) hands back the outputs of train_one_batch: `batch_sharded`
+    leave as one global array sharded over the data axis, each device
+    keeping the rows it computed (no gather in the step); `mean_reduced`
+    are scalars averaged over the shards and replicated. A gauge: it
+    holds the latest trace."""
+    if not _enabled:
+        return
+    g = gauge("singa_step_outputs",
+              "outputs of the latest traced data-parallel step, by how "
+              "they left it (batch_sharded|mean_reduced)")
+    g.set(batch_sharded, kind="batch_sharded")
+    g.set(mean_reduced, kind="mean_reduced")
+
+
 def record_comm_host(op: str, start: float, seconds: float):
     """Host-side entry/exit stamp of one collective CALL SITE
     (parallel.communicator wraps every collective body in one). Under

@@ -124,7 +124,15 @@ class Tensor:
 
     # ---- conversions ----------------------------------------------------
     def numpy(self) -> np.ndarray:
-        return np.asarray(self.data)
+        a = self.data
+        if not getattr(a, "is_fully_addressable", True):
+            # a global array whose rows live on other processes' devices
+            # (a data-parallel step's batch output under jax.distributed):
+            # the read gathers, every process gets the whole value
+            from jax.experimental import multihost_utils
+            return np.asarray(
+                multihost_utils.process_allgather(a, tiled=True))
+        return np.asarray(a)
 
     def item(self):
         return self.numpy().item()

@@ -90,23 +90,216 @@ def test_strategies_converge(cls, dev, mesh, data):
     X, Y = data
     m, losses, out = _run(cls, dev, mesh, X, Y)
     assert losses[-1] < 0.4 * losses[0], losses
-    assert out.shape == (32, 4)  # global batch gathered back
+    assert out.shape == (32, 4)  # the global batch's shape, left sharded
+
+
+# ---- how the data-parallel step hands its outputs back ----------------------
+
+def _dp_twin(cls, dev, mesh, X, lr=0.1, **kw):
+    """A single-device model and `cls` under DistOpt on the same initial
+    weights."""
+    tx = tensor.from_numpy(X, dev)
+    m1 = MLP(**kw)
+    m1.set_optimizer(opt.SGD(lr=lr))
+    m1.compile([tx], is_train=True, use_graph=True)
+    w0 = {k: v.numpy().copy() for k, v in m1.get_params().items()}
+    m2 = cls(**kw)
+    m2.set_optimizer(opt.DistOpt(opt.SGD(lr=lr), mesh=mesh))
+    m2.compile([tx], is_train=True, use_graph=True)
+    m2.set_params(w0)
+    return m1, m2
+
+
+def test_dp_step_runs_no_gather_and_leaves_the_batch_sharded(dev, mesh, data):
+    """The step's text holds no all-gather; its batch output is one global
+    array, each device holding the rows it computed; the loss replicated."""
+    from jax.sharding import PartitionSpec as P
+    X, Y = data
+    m, _, out = _run(MLP, dev, mesh, X, Y, steps=1)
+    hlo = m.lower_step().as_text()
+    assert "all_reduce" in hlo or "all-reduce" in hlo  # the dialect's names
+    assert "all_gather" not in hlo and "all-gather" not in hlo
+    assert out.shape == (32, 4)
+    assert out.data.sharding.spec == P("data")
+    assert out.data.sharding.mesh.shape == mesh.shape
+    shards = out.data.addressable_shards
+    assert len(shards) == 8
+    whole = out.numpy()
+    for s in shards:
+        assert s.data.shape == (4, 4)
+        np.testing.assert_array_equal(np.asarray(s.data), whole[s.index])
+    assert sorted(s.index[0].start or 0 for s in shards) \
+        == list(range(0, 32, 4))
+    _, loss = m(tensor.from_numpy(X, dev), tensor.from_numpy(Y, dev))
+    assert loss.data.sharding.spec == P()
+
+
+@pytest.mark.parametrize("cls", [MLP, MLPHalf, MLPSparse, MLPPartial],
+                         ids=["plain", "half", "sparse_topk", "partial"])
+def test_dp_first_output_equals_single_device(cls, dev, mesh, data):
+    """A read of the first step's batch output gives the global batch, row
+    for row what one device computes on the same weights (the forward
+    comes before any strategy acts)."""
+    X, Y = data
+    m1, m2 = _dp_twin(cls, dev, mesh, X)
+    tx, ty = tensor.from_numpy(X, dev), tensor.from_numpy(Y, dev)
+    out1, loss1 = m1(tx, ty)
+    out2, loss2 = m2(tx, ty)
+    assert out2.shape == out1.shape == (32, 4)
+    np.testing.assert_allclose(out2.numpy(), out1.numpy(),
+                               rtol=1e-5, atol=1e-6)
+    assert abs(float(loss1.numpy()) - float(loss2.numpy())) < 1e-5
+
+
+class MLPTwoOutputs(MLP):
+    """Two batch outputs around the scalar: the order must come back."""
+
+    def train_one_batch(self, x, y):
+        hidden = self.relu(self.l1(x))
+        out = self.l2(hidden)
+        loss = self.loss_fn(out, y)
+        self._optimizer(loss)
+        return hidden, loss, out
+
+
+def _step_outputs_gauge():
+    from singa_tpu import observe
+    g = observe.get_registry().get("singa_step_outputs")
+    return {k: int(g.value(kind=k)) for k in ("batch_sharded",
+                                              "mean_reduced")}
+
+
+def test_step_outputs_gauge_follows_the_traced_step(dev, mesh, data):
+    X, Y = data
+    _run(MLP, dev, mesh, X, Y, steps=1)
+    assert _step_outputs_gauge() == {"batch_sharded": 1, "mean_reduced": 1}
+    m1, m2 = _dp_twin(MLPTwoOutputs, dev, mesh, X)
+    tx, ty = tensor.from_numpy(X, dev), tensor.from_numpy(Y, dev)
+    hidden, loss, out = m2(tx, ty)
+    assert _step_outputs_gauge() == {"batch_sharded": 2, "mean_reduced": 1}
+    assert (hidden.shape, loss.shape, out.shape) == ((32, 16), (), (32, 4))
+    out1, loss1 = m1(tx, ty)
+    np.testing.assert_allclose(out.numpy(), out1.numpy(),
+                               rtol=1e-5, atol=1e-6)
+    assert abs(float(loss.numpy()) - float(loss1.numpy())) < 1e-5
+
+
+@pytest.mark.parametrize("is_train", [True, False], ids=["train", "eval"])
+def test_dp_output_feeds_a_later_call(is_train, dev, mesh, data):
+    """A step's sharded output as the next step's input, or an
+    is_train=False call's: the same result as its host copy gives."""
+    X, Y = data
+    tx, ty = tensor.from_numpy(X, dev), tensor.from_numpy(Y, dev)
+    # 10 classes, so that an output has an input's shape; the single-device
+    # twin takes the same step and then the host copy
+    m1, m2 = _dp_twin(MLP, dev, mesh, X, classes=10)
+    out1, _ = m1(tx, ty)
+    out2, _ = m2(tx, ty)
+    assert out2.data.sharding.spec[0] == "data"
+    host = tensor.from_numpy(out1.numpy(), dev)
+    if is_train:
+        got, got_loss = m2(out2, ty)
+        want, want_loss = m1(host, ty)
+        assert abs(float(got_loss.numpy()) - float(want_loss.numpy())) < 1e-5
+    else:
+        m1.eval()
+        m2.eval()
+        got, want = m2(out2), m1(host)
+    assert got.shape == (32, 10)
+    np.testing.assert_allclose(got.numpy(), want.numpy(),
+                               rtol=1e-4, atol=1e-5)
+
+
+def test_dp_output_on_a_data_tp_mesh(dev, rng):
+    """With a tp axis in the mesh the batch output is sharded over `data`
+    and replicated over `tp`."""
+    from jax.sharding import PartitionSpec as P
+
+    class TPMLP(model.Model):
+        def __init__(self):
+            super().__init__()
+            self.l1 = layer.Linear(16, tp_axis="tp", tp_mode="column")
+            self.relu = layer.ReLU()
+            self.l2 = layer.Linear(4, tp_axis="tp", tp_mode="row")
+            self.loss_fn = layer.SoftMaxCrossEntropy()
+
+        def forward(self, x):
+            return self.l2(self.relu(self.l1(x)))
+
+        def train_one_batch(self, x, y):
+            out = self.forward(x)
+            loss = self.loss_fn(out, y)
+            self._optimizer(loss)
+            return out, loss
+
+    mesh = make_mesh({"data": 2, "tp": 4})
+    X = rng.randn(16, 10).astype(np.float32)
+    Y = np.argmax(X @ rng.randn(10, 4).astype(np.float32), 1) \
+        .astype(np.int32)
+    m = TPMLP()
+    m.set_optimizer(opt.DistOpt(opt.SGD(lr=0.1), axis="data", mesh=mesh))
+    tx, ty = tensor.from_numpy(X, dev), tensor.from_numpy(Y, dev)
+    m.compile([tx], is_train=True, use_graph=True)
+    w = {k: v.numpy().copy() for k, v in m.get_params().items()}
+    out, _ = m(tx, ty)
+    assert out.shape == (16, 4)
+    assert out.data.sharding.spec == P("data")
+    rows = {}
+    for s in out.data.addressable_shards:
+        assert s.data.shape == (8, 4)
+        rows.setdefault(s.index[0].start or 0, []).append(
+            np.asarray(s.data))
+    assert sorted(rows) == [0, 8]
+    for copies in rows.values():       # one copy on each of the 4 tp ranks
+        assert len(copies) == 4
+        for c in copies[1:]:
+            np.testing.assert_array_equal(c, copies[0])
+    want = np.maximum(X @ w["l1.W"] + w["l1.b"], 0) @ w["l2.W"] + w["l2.b"]
+    np.testing.assert_allclose(out.numpy(), want, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("addressable", [True, False],
+                         ids=["addressable", "spans_processes"])
+def test_numpy_gathers_only_what_is_not_fully_addressable(
+        addressable, dev, monkeypatch):
+    """Tensor.numpy() picks its branch by `is_fully_addressable` and by
+    nothing else: an array whose rows live on other processes' devices
+    (a data-parallel step's batch output under jax.distributed) is gathered
+    at the read, tiled; any other is converted in place. One process
+    cannot hold such an array, so a stand-in carries the flag;
+    examples/multihost/ckpt_2proc.py is the two-process run."""
+    from jax.experimental import multihost_utils
+
+    class StandIn:
+        is_fully_addressable = addressable
+        converted = 0
+
+        def __array__(self, dtype=None, copy=None):
+            self.converted += 1
+            return np.arange(6, dtype=np.float32).reshape(3, 2)
+
+    calls = []
+
+    def gather(a, tiled=False):
+        calls.append((a, tiled))
+        return np.ones((12, 2), np.float32)
+
+    monkeypatch.setattr(multihost_utils, "process_allgather", gather)
+    a = StandIn()
+    got = tensor.Tensor(data=a, device=dev, requires_grad=False).numpy()
+    if addressable:
+        assert calls == [] and a.converted == 1
+        assert got.shape == (3, 2)
+    else:
+        assert calls == [(a, True)] and a.converted == 0
+        assert got.shape == (12, 2) and isinstance(got, np.ndarray)
 
 
 def test_dp_matches_single_device(dev, mesh, data):
     """psum-mean grads over 8 shards == full-batch single device."""
     X, Y = data
-    m1 = MLP()
-    m1.set_optimizer(opt.SGD(lr=0.1))
     tx, ty = tensor.from_numpy(X, dev), tensor.from_numpy(Y, dev)
-    m1.compile([tx], is_train=True, use_graph=True)
-    w0 = {k: v.numpy().copy() for k, v in m1.get_params().items()}
-
-    m2 = MLP()
-    m2.set_optimizer(opt.DistOpt(opt.SGD(lr=0.1), mesh=mesh))
-    m2.compile([tx], is_train=True, use_graph=True)
-    m2.set_params(w0)
-
+    m1, m2 = _dp_twin(MLP, dev, mesh, X)
     for _ in range(3):
         _, l1 = m1(tx, ty)
         _, l2 = m2(tx, ty)
