@@ -12,7 +12,7 @@ first half of that loop, in three cooperating pieces:
      slot occupancy, page-pool utilization, queue depth, TTFT
      percentiles against the declared SLO, and decode tokens/s against
      the bytes-per-token bandwidth floor the roofline harvests
-     (bench_decode registers it via `note_decode_floor`). Each signal
+     (a caller registers it via `note_decode_floor`). Each signal
      becomes a utilization fraction in [0, 1]; the BINDING WALL is the
      max — no opaque score, the report names which wall binds each
      replica — and measured RPS extrapolates linearly through it into
@@ -147,11 +147,11 @@ def _metrics():
 
 
 # ---- the measured bandwidth floor ------------------------------------------
-# bench_decode's weight-streaming roofline computes the bytes-per-token
-# floor (per-step HBM traffic / peak bandwidth, introspect's per-
-# generation table); it registers the implied decode token-rate ceiling
-# here so the capacity model can hold measured decode tokens/s against
-# it without re-deriving the model geometry.
+# A weight-streaming roofline gives the bytes-per-token floor (per-step
+# HBM traffic / peak bandwidth, introspect's per-generation table); who
+# computes it registers the implied decode token-rate ceiling here so the
+# capacity model can hold measured decode tokens/s against it without
+# re-deriving the model geometry.
 
 _decode_floor_tok_s: "float | None" = None
 

@@ -139,9 +139,8 @@ def _metrics():
 
 def pctile(xs, p):
     """Nearest-rank percentile over an unsorted sequence (None when
-    empty) — shared by serving_report and bench_decode's --serve arm so
-    the /statusz TTFT numbers and the BENCHDEC records can never use
-    diverging rank conventions."""
+    empty) — one rank convention for serving_report's /statusz TTFT
+    numbers and every harness that reports the same requests."""
     xs = sorted(xs)
     return xs[min(len(xs) - 1, int(p * len(xs)))] if xs else None
 
@@ -223,8 +222,7 @@ class ServingEngine:
     `max_slots` bounds in-flight sequences (the fixed decode-batch
     shape); `page_size` tokens per KV page; `num_pages` the pool size
     (default: enough for every slot at `max_ctx` — pass less to trade
-    occupancy for HBM, the equal-budget comparison `bench_decode.py
-    --serve` runs); `steps_per_sync` decode steps per host sync (the
+    occupancy for HBM); `steps_per_sync` decode steps per host sync (the
     admission/eviction cadence); `eos_id` stops a sequence early;
     `ttft_deadline_s` bounds admission-to-first-token (queued requests
     past it finish "timeout"). Decode is greedy.

@@ -29,10 +29,10 @@ the build-time half of observability, in three parts:
    the cached step path dispatches the same executable bytes `jax.jit`
    would have cached, with zero added per-step work.
 
-3. **`explain` report.** `python -m singa_tpu.introspect` (reusing
-   bench.py's model builders) prints params, GFLOPs/step, the HBM
-   breakdown, compile-phase times, recompile history, and — given an
-   xplane dir — the top-K ops by device time (`xprof.top_ops`).
+3. **`explain` report.** `python -m singa_tpu.introspect` (a few preset
+   models) prints params, GFLOPs/step, the HBM breakdown, compile-phase
+   times, recompile history, and — given an xplane dir — the top-K ops
+   by device time (`xprof.top_ops`).
    `capture_hlo(dir)` additionally dumps each executable's HLO text
    (manifest + fingerprint); FlightRecorder bundles reference the
    manifest so an anomaly dump pins the exact executable.
@@ -97,7 +97,7 @@ PHASE_COMPILE = "compile"
 EXEC_KEYS = ("step", "eval", "serving.prefill", "serving.decode_scan",
              "serving.beam")
 
-# ---- per-platform peaks (public spec sheets; shared with bench.py) --------
+# ---- per-platform peaks (public spec sheets) -------------------------------
 
 #: Dense bf16 peak TFLOP/s by TPU generation.
 PEAK_TFLOPS_BF16 = [
@@ -365,7 +365,7 @@ def _mfu_callback(seconds):
     steady state it converges to the true step time (the loop is
     device-throughput-bound), but a sample implying more than the
     hardware peak is physically impossible and is DROPPED rather than
-    poisoning the gauge — the same mfu_suspect guard bench.py applies."""
+    poisoning the gauge."""
     peak = peak_tflops(_step_device_kind)
     if not peak or not _step_flops or seconds <= 0:
         return
@@ -468,7 +468,7 @@ def latest_fingerprint(key: str) -> "str | None":
 
 def last_build(key: str) -> "dict | None":
     """The most recent build record for `key` (phases, cost, memory,
-    blame) — bench.py --explain reads this."""
+    blame) — benchmark/run.py reads its `hlo_path`."""
     recs = _builds.get(key)
     return dict(recs[-1]) if recs else None
 
@@ -806,20 +806,61 @@ def _register_build(key, sig, rec, device=None):
             observe.set_step_callback(_mfu_callback)
 
 
-_AOT_MISS = object()  # "no cache entry" (a stored None = negative-cached)
+def _leaf_avals(args):
+    """The default cache key: every array leaf's (shape, dtype)."""
+    import jax
+    return tuple(_aval(a) for a in jax.tree_util.tree_leaves(args))
+
+
+class AotVariant:
+    """What one abstract signature of an `AotExecutor` resolved to.
+
+    run     the compiled executable, or None when plain jit owns the
+            signature (staging failed, or the executable rejected a call)
+    record  `build_compiled`'s record of it (phases, cost, memory, warm)
+    flops   the record's cost-analysis flops; 0.0 once jit owns it
+    fresh   True from the `prepare` that built it until its first dispatch
+    cold    the next jit call of this signature traces and compiles
+    """
+
+    __slots__ = ("run", "record", "flops", "fresh", "cold")
+
+    def __init__(self, run, record):
+        self.run = run
+        self.record = record
+        self.flops = float(
+            ((record or {}).get("cost") or {}).get("flops", 0) or 0)
+        self.fresh = True
+        self.cold = run is None
 
 
 class AotExecutor:
-    """Wrap a jitted callable so every distinct abstract signature is
+    """The one place a compiled program is staged, cached and fallen back
+    from. Wraps a jitted callable so every distinct abstract signature is
     built through `build_compiled` (phase timing, cost/memory harvest,
-    recompile blame) and later calls dispatch the cached executable.
-    Falls back to the plain jit call when staging or dispatch fails —
-    jit then (re)traces exactly as it always did; a failed signature is
-    negative-cached so the fallback never re-pays staging per call."""
+    recompile blame, the warm store) and later calls dispatch the cached
+    executable. Falls back to the plain jit call when staging or dispatch
+    fails — jit then (re)traces exactly as it always did; a failed
+    signature is negative-cached so the fallback never re-pays staging a
+    call — but never on an out-of-memory error: that dumps the forensics
+    bundle and propagates.
 
-    __slots__ = ("fn", "key", "names", "donated", "_execs")
+    `ex(*args)` is `ex.dispatch(ex.prepare(*args), args)`; a caller that
+    times the dispatch apart from the build, or needs what the dispatch
+    resolved to (Model), makes the two calls itself.
 
-    def __init__(self, fn, key, names=None, donated=()):
+    names / donated / tag / static / device go into every signature and
+    build this executor registers, as `signature` and `build_compiled`
+    take them. cache_key(args) is the per-call cache key: by default every
+    leaf's aval; a caller whose signature can only change through a few of
+    its arguments (the training step: inputs, and how many optimizer
+    arrays) keys on those, so a cached call does O(inputs) host work."""
+
+    __slots__ = ("fn", "key", "names", "donated", "tag", "static",
+                 "device", "cache_key", "_execs")
+
+    def __init__(self, fn, key, names=None, donated=(), tag=None,
+                 static=None, device=None, cache_key=_leaf_avals):
         self.fn = fn
         self.key = key
         self.names = names
@@ -828,42 +869,77 @@ class AotExecutor:
         # identity (input-output aliasing), so the warm store must not
         # key a donated variant and an undonated one identically
         self.donated = tuple(donated)
+        self.tag = tag
+        self.static = static
+        self.device = device
+        self.cache_key = cache_key
         self._execs = {}
 
-    def _sig_key(self, args):
-        import jax
-        flat, _ = jax.tree_util.tree_flatten(args)
-        return tuple(_aval(a) for a in flat)
+    def __len__(self):
+        """How many abstract signatures this executor has resolved."""
+        return len(self._execs)
 
-    def __call__(self, *args):
-        k = self._sig_key(args)
-        ex = self._execs.get(k, _AOT_MISS)
-        if ex is _AOT_MISS:
-            sig = signature(args, names=self.names,
-                            donated=self.donated)
-            ex, _rec = build_compiled(self.fn, args, self.key, sig)
-            self._execs[k] = ex  # None negative-caches failed staging
-            if ex is None:
-                # fresh staging failure: this jit call compiles cold —
-                # the mapped span books it to the goodput `compile`
-                # bucket instead of the enclosing serving/step span
+    def prepare(self, *args, batch_hint=None):
+        """The variant `args` dispatch to, staged on first sight of their
+        signature. One key and one dict lookup when it is cached.
+        batch_hint: the true batch size when the traced leading dim is a
+        padded bucket (recompile blame names the real sizes)."""
+        k = self.cache_key(args)
+        v = self._execs.get(k)
+        if v is None:
+            sig = signature(args, names=self.names, tag=self.tag,
+                            static=self.static, donated=self.donated,
+                            batch_hint=batch_hint)
+            v = self._execs[k] = AotVariant(*build_compiled(
+                self.fn, args, self.key, sig, device=self.device))
+        return v
+
+    def give_to_jit(self, variant):
+        """Negative-cache a variant: plain jit owns its signature from now
+        on (correctness over telemetry, and no rebuild-a-call churn); its
+        next call compiles cold."""
+        variant.run = None
+        variant.flops = 0.0
+        variant.cold = True
+
+    def _oom(self, exc) -> bool:
+        """Device allocator exhausted? Then a jit fallback would re-pay
+        the same allocation and die the same way: dump the OOM forensics
+        bundle (timeline, region breakdown, top-K arrays, executable
+        manifest) and tell the caller to let it propagate."""
+        from . import memory
+        if not memory.is_resource_exhausted(exc):
+            return False
+        memory.handle_oom(exc, key=self.key)
+        return True
+
+    def dispatch(self, variant, args):
+        """Run `args` through the variant `prepare` resolved them to."""
+        variant.fresh = False
+        if variant.run is not None:
+            try:
+                return variant.run(*args)
+            except Exception as exec_exc:
+                if self._oom(exec_exc):
+                    raise
+                # the executable rejected the call (e.g. an argument
+                # changed shape in a way the cache key cannot see)
+                self.give_to_jit(variant)
+        try:
+            if variant.cold:
+                # this jit call traces and compiles: the mapped span
+                # books it to the goodput `compile` bucket instead of the
+                # enclosing serving/step span
+                variant.cold = False
                 with observe.span("model.jit_fallback"):
                     return self.fn(*args)
-        if ex is None:
             return self.fn(*args)
-        try:
-            return ex(*args)
-        except Exception as exec_exc:
-            from . import memory
-            if memory.is_resource_exhausted(exec_exc):
-                # device allocator exhausted: the jit fallback would
-                # re-pay the same allocation and die the same way —
-                # dump the OOM forensics bundle and let it propagate
-                memory.handle_oom(exec_exc, key=self.key)
-                raise
-            self._execs[k] = None
-            with observe.span("model.jit_fallback"):
-                return self.fn(*args)
+        except Exception as jit_exc:
+            self._oom(jit_exc)
+            raise
+
+    def __call__(self, *args):
+        return self.dispatch(self.prepare(*args), args)
 
 
 # ---- the explain report ----------------------------------------------------
@@ -1006,33 +1082,40 @@ def format_explain(rep: dict) -> str:
 # ---- CLI: python -m singa_tpu.introspect ----------------------------------
 
 _CLI_PRESETS = {
-    # reuse bench.py's builders (build_bench_model) so the explain report
-    # describes the exact executables the bench times
-    "tiny": dict(model="mlp", batch=8, size=16),
-    "mlp": dict(model="mlp", batch=32, size=64),
-    "cnn": dict(model="cnn", batch=4, size=28),
-    "resnet18": dict(model="resnet18", batch=4, size=32),
-    "gpt": dict(model="gpt", batch=2, size=64,
-                gpt_dim=128, gpt_layers=2, gpt_heads=4),
+    # name -> (create_model name, its arguments, batch, a sample's shape)
+    "tiny": ("mlp", dict(data_size=16, num_classes=10), 8, (16,)),
+    "mlp": ("mlp", dict(data_size=64, num_classes=10), 32, (64,)),
+    "cnn": ("cnn", dict(num_channels=3), 4, (3, 28, 28)),
+    "resnet18": ("resnet18", dict(num_channels=3), 4, (3, 32, 32)),
+    "gpt": ("gpt", dict(vocab_size=8192, max_seq=64, dim=128, num_heads=4,
+                        num_layers=2), 2, (64,)),
 }
 
 
 def _build_cli_model(cfg: str):
-    import sys
-    try:
-        import bench
-    except ImportError:
-        sys.path.insert(0, os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))))
-        import bench
-    return bench.build_bench_model(**_CLI_PRESETS[cfg])
+    """(model, inputs, targets) of one preset, on seeded random data."""
+    import numpy as np
+    from . import device, models, tensor
+    name, kwargs, batch, shape = _CLI_PRESETS[cfg]
+    dev = device.best_device()
+    rng = np.random.RandomState(0)
+    m = models.create_model(name, **kwargs)
+    if name == "gpt":
+        ids = rng.randint(0, kwargs["vocab_size"],
+                          (batch,) + shape).astype(np.int32)
+        return (m, tensor.from_numpy(ids, device=dev),
+                tensor.from_numpy(np.roll(ids, -1, axis=1), device=dev))
+    x = rng.standard_normal((batch,) + shape).astype(np.float32)
+    y = rng.randint(0, 10, batch).astype(np.int32)
+    return (m, tensor.Tensor(data=x, device=dev),
+            tensor.from_numpy(y, device=dev))
 
 
 def main(argv=None) -> int:
     import argparse
     ap = argparse.ArgumentParser(
         prog="python -m singa_tpu.introspect",
-        description="Compile & memory explain report: build a bench "
+        description="Compile & memory explain report: build a preset "
                     "model, run a few steps through the AOT-staged path, "
                     "and print GFLOPs/step, the HBM breakdown, "
                     "compile-phase times and the recompile history.")
@@ -1061,7 +1144,7 @@ def main(argv=None) -> int:
         set_peak_tflops(args.peak_tflops)
     if args.hlo_dir:
         capture_hlo(args.hlo_dir)
-    m, tx, ty, _items, _unit, _factory = _build_cli_model(args.config)
+    m, tx, ty = _build_cli_model(args.config)
     dev = tx.device
     m.set_optimizer(opt_mod.SGD(lr=0.1, momentum=0.9))
     m.compile([tx], is_train=True, use_graph=True)
@@ -1096,7 +1179,6 @@ __all__ = [
     "compile_phase_totals",
     "explain", "format_explain", "reset", "main",
 ]
-
 
 if __name__ == "__main__":
     import sys as _sys

@@ -669,7 +669,7 @@ class MultiHeadAttention(Layer):
     = 1 is MQA): Wk/Wv project to num_kv_heads*D and each KV head
     serves num_heads/num_kv_heads query heads. This shrinks the KV
     params AND — the real point — the serving KV cache, which is the
-    binding term of the decode roofline (PROFILE.md)."""
+    binding term of the decode roofline."""
 
     def __init__(self, num_heads, causal=False, seq_axis=None, tp_axis=None,
                  bias=False, num_kv_heads=None, rope=False,

@@ -31,9 +31,9 @@ This module is the dynamic half of the memory model:
     under the monitor's (or an explicit) warn/halt policy; the region
     with the largest positive delta over the window names the suspect.
 
-  - **OOM forensics**: step dispatch (`model._invoke_step`) and the
-    serving AOT executors (`introspect.AotExecutor`) call
-    `handle_oom()` on a resource-exhausted `XlaRuntimeError` before
+  - **OOM forensics**: every compiled program's dispatch
+    (`introspect.AotExecutor.dispatch`: the model step, the eval
+    forward, the serving programs) calls `handle_oom()` on a resource-exhausted `XlaRuntimeError` before
     re-raising — a FlightRecorder-style JSONL bundle (timeline, region
     breakdown, top-K largest live arrays with shapes/dtypes, the
     executable manifest) lands on disk, round-tripped by
@@ -44,11 +44,11 @@ This module is the dynamic half of the memory model:
     introspect's arguments/temps/outputs analysis with the ledger's
     param+opt bytes against the device limit (memory_stats
     `bytes_limit`, or `SINGA_TPU_HBM_LIMIT_BYTES`), surfaced in the
-    explain report and the `bench.py --mem` arm.
+    explain report.
 
 Overhead contract: every snapshot is host-side bookkeeping over object
 identities — nothing traces, so `compile_count` stays 1 with the ledger
-installed (test-enforced; the bound is measured by `bench.py --mem`).
+installed (test-enforced).
 """
 
 from __future__ import annotations
@@ -734,7 +734,7 @@ def dump_oom_bundle(exc=None, key=None, out_dir=None,
 
 
 def handle_oom(exc, key=None, out_dir=None) -> "str | None":
-    """The dispatch-site hook (model step, serving AOT executors):
+    """The dispatch-site hook (`introspect.AotExecutor.dispatch`):
     dump the forensics bundle for a resource-exhausted error and
     return its path. Never raises — the original OOM must propagate,
     not a forensics failure."""

@@ -19,6 +19,7 @@ computed; the step gathers nothing, a read (`Tensor.numpy`) does.
 
 from __future__ import annotations
 
+import contextlib
 import io
 import json
 import os
@@ -41,7 +42,8 @@ from .layer import Layer, LayerMeta
 from .tensor import Tensor
 
 
-_AOT_MISS = introspect._AOT_MISS  # shared "no cache entry yet" sentinel
+def _input_avals(arrs):
+    return tuple((tuple(a.shape), str(a.dtype)) for a in arrs)
 
 
 def _flatten_out(out):
@@ -116,9 +118,8 @@ class Model(Layer, metaclass=ModelMeta):
         self.sequential = False
         self._optimizer = None
         self._device = None
-        self._compiled_step = None
-        self._step_execs = {}   # AOT executables per abstract signature
-        self._eval_execs = {}
+        self._compiled_step = None   # step tag -> introspect.AotExecutor
+        self._compiled_eval = None   # one AotExecutor around the forward
         self._step_stats = {"compile_s": 0.0, "steps": 0}
         self._health_monitor = None
         self._health_steps = 0
@@ -157,10 +158,7 @@ class Model(Layer, metaclass=ModelMeta):
         self.sequential = sequential
         if isinstance(self._compiled_step, dict):
             self._compiled_step = {}   # drop stale-flag executables
-            self._step_execs = {}
-            self._dispatch_cache = {}
         self._compiled_eval = None
-        self._eval_execs = {}
 
     def compile(self, inputs, is_train=True, use_graph=False,
                 sequential=False, pipeline_axis=None, n_micro=1,
@@ -329,6 +327,8 @@ class Model(Layer, metaclass=ModelMeta):
         # values against these without rebuilding/comparing dicts
         self._n_call_args = len(example_args)
         self._static_items = tuple(sorted(static_args.items()))
+        static_repr = repr(sorted(
+            (i, repr(v)) for i, v in static_args.items()))
         out_template_box = {}
 
         def make_step(tag):
@@ -440,16 +440,15 @@ class Model(Layer, metaclass=ModelMeta):
                     check_vma=False)
             else:
                 wrapped = step
-            if self.sequential:
-                # RunGraph(sequential=true) parity (ref device.cc / SURVEY
-                # §2.1): execute ops one-by-one eagerly for debugging —
-                # op-level python breakpoints and immediate error locations
-                # instead of one fused XLA program
-                def serial(*a):
-                    with jax.disable_jit():
-                        return wrapped(*a)
-                return serial
-            return jax.jit(wrapped, donate_argnums=(0, 1))
+            # The cache key is O(#inputs): a step's signature changes only
+            # with its inputs, or with len(opt_arrs) — the sparse DistOpt
+            # strategies GROW their optimizer state (new residual slots)
+            # between steps.
+            return introspect.AotExecutor(
+                jax.jit(wrapped, donate_argnums=(0, 1)), "step",
+                names=("state", "opt", "rng", "arg"), donated=(0, 1),
+                tag=tag, static=static_repr, device=dev,
+                cache_key=lambda a: (_input_avals(a[3]), len(a[1])))
 
         self._dist_shardings = None
         state_in = opt_in = None
@@ -507,14 +506,9 @@ class Model(Layer, metaclass=ModelMeta):
         self._state_tensors = state_tensors
         self._out_template_box = out_template_box
         self._step_builder = make_step
-        self._compiled_step = {}   # step-tag -> jitted executable
-        self._step_execs = {}      # (tag, abstract sig) -> AOT executable
-        self._step_sigs = set()    # (tag, input shapes) variants seen
-        # (tag, abstract sig) -> [step_fn, flops, sig, recorded]:
-        # everything the cached dispatch needs, resolved once per variant
-        # so the hot path does O(#inputs) work (the key) instead of
-        # rebuilding signatures/cache lookups every step
-        self._dispatch_cache = {}
+        # step tag -> the executor that stages, caches and dispatches
+        # that tag's jitted step, one variant an abstract signature
+        self._compiled_step = {}
         self._step_stats["compile_s"] = time.perf_counter() - t0
         observe.record_step_build(self._step_stats["compile_s"])
 
@@ -596,57 +590,34 @@ class Model(Layer, metaclass=ModelMeta):
             rng = put(rng, rep)
             input_arrs = [put(a, shard) for a in input_arrs]
         tag = opt.step_tag() if opt is not None else 0
-        fn = self._compiled_step.get(tag)
-        if fn is None:
-            fn = self._compiled_step[tag] = self._step_builder(tag)
+        ex = self._compiled_step.get(tag)
+        if ex is None:
+            ex = self._compiled_step[tag] = self._step_builder(tag)
         obs = observe.is_enabled()
         bs = None
         if input_arrs and getattr(input_arrs[0], "ndim", 0):
             bs = input_arrs[0].shape[0]
-        step_fn = fn
-        exec_key = None
-        variant = None
-        cold_jit = False  # this dispatch pays a fresh jit trace+compile
-        if not self.sequential:
-            # dispatch fast path: one O(#inputs) key resolves everything
-            # a repeat step needs — the AOT executable (or jit fallback),
-            # its harvested flops, and the already-recorded observe
-            # signature — so the cached path rebuilds no signatures and
-            # touches no introspection. len(opt_arrs) is in the key
-            # because the sparse strategies GROW their optimizer state
-            # (new residual slots) between steps.
-            exec_key = (tag,
-                        tuple((tuple(a.shape), str(a.dtype))
-                              for a in input_arrs),
-                        len(opt_arrs))
-            variant = self._dispatch_cache.get(exec_key)
-            if variant is None:
-                variant, cold_jit = self._dispatch_slow_path(
-                    exec_key, tag, fn, state_arrs, opt_arrs, rng,
-                    input_arrs, bs)
-            step_fn = variant[0]
-            # the MFU gauge must use the DISPATCHED variant's flops, not
-            # the most recently built one (a partial-batch build would
-            # otherwise skew later full-batch readings); 0 for a
-            # negative-cached variant disables the gauge instead
-            introspect.note_step_flops(variant[1])
-        else:
-            introspect.note_step_flops(0)  # sequential: no AOT variant
+        call = (state_arrs, opt_arrs, rng, input_arrs)
+        # The explicit trace -> lower -> compile staging of a new (tag,
+        # signature) happens here, before the step span opens, so compile
+        # time lands at build/retrace time and never in a step's. A repeat
+        # step pays one O(#inputs) key and one dict lookup. sequential is
+        # RunGraph(sequential=true) parity (ref device.cc / SURVEY §2.1):
+        # ops run one by one, eagerly, for op-level python breakpoints and
+        # immediate error locations — nothing is staged or cached.
+        variant = None if self.sequential else self._staged(
+            ex, call, bs, self._state_tensors, self._out_template_box)
         if obs:
-            # (tag, input-shape) signature: jit retraces exactly when it
-            # changes, so first-seen == a compile (first ever) or a
-            # recompile (new batch-size class / step tag). A variant
-            # records at most once (its flag), so the cached path skips
-            # the signature rebuild + set lookup entirely.
-            if variant is not None:
-                if not variant[3]:
-                    variant[3] = True
-                    self._record_step_sig(variant[2], bs,
-                                          state_arrs, opt_arrs)
-            else:  # sequential debug path: no variant cache
-                sig = (tag,
-                       tuple(getattr(a, "shape", ()) for a in input_arrs))
-                self._record_step_sig(sig, bs, state_arrs, opt_arrs)
+            if variant is not None and variant.fresh:
+                # jit retraces exactly when the executor builds: a compile
+                # (the first ever) or a recompile (new batch-size class /
+                # step tag)
+                observe.record_compile(
+                    bs, recompile=sum(
+                        map(len, self._compiled_step.values())) > 1,
+                    donated_bytes=sum(
+                        int(getattr(a, "nbytes", 0))
+                        for a in (*state_arrs, *opt_arrs)))
             t_obs = time.perf_counter()
         profiling = (dev.verbosity > 0 and
                      self._step_stats["steps"] >= dev.skip_iteration)
@@ -660,44 +631,24 @@ class Model(Layer, metaclass=ModelMeta):
         # `health_skip`); covers dispatch and, when profiling, the fence.
         # The watchdog guard arms the `step` deadline over the same
         # region (nested no-op when a TrainController's outer guard is
-        # already armed); a cold jit fallback's build span taints the
-        # entry, so first-compile time neither breaches nor calibrates
-        # tag attr: the regress detector baselines each optimizer-tag
-        # variant separately (different tags dispatch different
-        # executables with different per-step costs)
+        # already armed); a cold jit fallback's span (the executor's)
+        # taints the entry, so first-compile time neither breaches nor
+        # calibrates. tag attr: the regress detector baselines each
+        # optimizer-tag variant separately (different tags dispatch
+        # different executables with different per-step costs)
         with watchdog.guard("step"), observe.span("model.step", tag=tag):
-            try:
-                if cold_jit:
-                    # nested mapped span: the fresh trace+compile nets
-                    # out of `step` and lands in the `compile` bucket
-                    with observe.span("model.jit_fallback"):
-                        new_states, new_opt, new_rng, outs, hstats = \
-                            step_fn(state_arrs, opt_arrs, rng, input_arrs)
-                else:
-                    new_states, new_opt, new_rng, outs, hstats = step_fn(
-                        state_arrs, opt_arrs, rng, input_arrs)
-            except Exception as step_exc:
-                if memory.is_resource_exhausted(step_exc):
-                    # the device allocator ran out: re-dispatching via
-                    # the jit fallback would just OOM again — dump the
-                    # forensics bundle (timeline, region breakdown,
-                    # top-K arrays, executable manifest) and re-raise
-                    memory.handle_oom(step_exc, key="step")
-                    raise
-                if step_fn is fn:
-                    raise
-                # the AOT executable rejected the call (e.g. an optimizer
-                # slot changed shape in place, invisible to exec_key):
-                # negative-cache the signature so jit owns it from now on —
-                # correctness over telemetry, and no rebuild-per-step churn
-                self._step_execs[exec_key] = None
-                if variant is not None:
-                    variant[0] = fn     # later fast-path hits go straight
-                    variant[1] = 0.0    # to jit, with the MFU gauge off
-                introspect.note_step_flops(0)  # this step: jit-dispatched
-                with observe.span("model.jit_fallback"):
-                    new_states, new_opt, new_rng, outs, hstats = fn(
-                        state_arrs, opt_arrs, rng, input_arrs)
+            if variant is None:
+                with jax.disable_jit():
+                    new_states, new_opt, new_rng, outs, hstats = \
+                        ex.fn(*call)
+            else:
+                new_states, new_opt, new_rng, outs, hstats = \
+                    ex.dispatch(variant, call)
+            # the MFU gauge must use the DISPATCHED variant's flops, not
+            # the most recently built one (a partial-batch build would
+            # otherwise skew later full-batch readings); 0 when jit owns
+            # the signature (or nothing is compiled) disables the gauge
+            introspect.note_step_flops(variant.flops if variant else 0)
             if profiling:
                 jax.block_until_ready(new_states)
                 fenced = time.perf_counter() - t0
@@ -745,88 +696,45 @@ class Model(Layer, metaclass=ModelMeta):
                    for a in outs]
         return _rebuild_out(self._out_template_box["t"], tensors)
 
-    def _dispatch_slow_path(self, exec_key, tag, fn, state_arrs, opt_arrs,
-                            rng, input_arrs, bs):
-        """First dispatch of a (tag, abstract-signature) variant: the
-        explicit trace -> lower -> compile staging happens here ONLY, so
-        compile-phase timing, cost/memory harvesting and recompile blame
-        all land at build/retrace time; the resolved executable (the
-        same bytes jit would have cached), its flops, and the observe
-        signature are cached in a slim per-variant record for every
-        later step. Returns (variant_record, cold_jit)."""
-        entry = self._step_execs.get(exec_key, _AOT_MISS)
-        cold_jit = False
-        if entry is _AOT_MISS:
-            asig = introspect.signature(
-                (state_arrs, opt_arrs, rng, input_arrs),
-                names=("state", "opt", "rng", "arg"), tag=tag,
-                static=repr(sorted(
-                    (i, repr(v))
-                    for i, v in self._static_args.items())),
-                donated=(0, 1), batch_hint=bs)
-            aot, rec = introspect.build_compiled(
-                fn, (state_arrs, opt_arrs, rng, input_arrs),
-                "step", asig, device=self._device)
-            # a failed build negative-caches as None so the cached path
-            # never re-pays a staging attempt per step
-            entry = self._step_execs[exec_key] = None if aot is None \
-                else (aot, float((rec or {}).get("cost", {})
-                                 .get("flops", 0) or 0))
-            # staging just failed: the jit dispatch below compiles
-            # cold — goodput must book that as compile, not step
-            cold_jit = aot is None
-            if entry is not None and "t" not in self._out_template_box:
-                # warm-store hit: the executable came back deserialized,
-                # so the original step fn was never traced and the
-                # out-template side channel is empty. One abstract trace
-                # (no lower/compile) recovers it; snapshot + restore the
-                # state the trace mutates (lower_step's contract) so no
-                # tracer escapes into eager work.
-                dev = self._device
-                opt_obj = self._optimizer
-                snap_state = [t.data for t in self._state_tensors]
-                snap_opt = list(opt_obj.state_arrays()) \
-                    if opt_obj is not None else []
-                snap_rng = dev.rng_state
-                snap_training = autograd.training
-                try:
-                    jax.eval_shape(fn, state_arrs, opt_arrs, rng,
-                                   input_arrs)
-                except Exception:
-                    # template unrecoverable: drop the warm variant and
-                    # let plain jit own the signature — its first
-                    # dispatch traces the fn and fills the box
-                    entry = self._step_execs[exec_key] = None
-                    cold_jit = True
-                finally:
-                    autograd.training = snap_training
-                    dev.rng_state = snap_rng
-                    for t, a in zip(self._state_tensors, snap_state):
-                        t.data = a
-                    if opt_obj is not None and snap_opt:
-                        opt_obj.load_state_arrays(snap_opt)
-        if entry is not None:
-            step_fn, flops = entry
-        else:
-            step_fn, flops = fn, 0.0  # negative-cached: plain jit owns it
-        sig = (tag, tuple(getattr(a, "shape", ()) for a in input_arrs))
-        variant = self._dispatch_cache[exec_key] = \
-            [step_fn, flops, sig, False]
-        return variant, cold_jit
+    @contextlib.contextmanager
+    def _tracers_kept_out(self, tensors):
+        """Tracing the step or the eval forward assigns tracers into the
+        state Tensors, the optimizer's arrays and dev.rng_state, and sets
+        autograd.training: snapshot them, yield the snapshots (state
+        arrays, optimizer arrays, rng key), and put them back so no tracer
+        escapes into later eager work."""
+        opt = self._optimizer
+        dev = self._device
+        state = [t.data for t in tensors]
+        opt_arrs = list(opt.state_arrays()) if opt is not None else []
+        rng = dev.rng_state
+        training = autograd.training
+        try:
+            yield state, opt_arrs, rng
+        finally:
+            autograd.training = training
+            dev.rng_state = rng
+            for t, a in zip(tensors, state):
+                t.data = a
+            if opt is not None and opt_arrs:
+                opt.load_state_arrays(opt_arrs)
 
-    def _record_step_sig(self, sig, bs, state_arrs, opt_arrs):
-        """First sighting of a (tag, input-shape) signature == a jit
-        trace: record the compile (or recompile, when other signatures
-        exist) with the donated-buffer bytes. Shared by the variant
-        fast path and the sequential debug path."""
-        if sig in self._step_sigs:
-            return
-        observe.record_compile(
-            bs, recompile=bool(self._step_sigs),
-            donated_bytes=sum(
-                int(getattr(a, "nbytes", 0))
-                for a in (*state_arrs, *opt_arrs)))
-        self._step_sigs.add(sig)
+    def _staged(self, ex, call, batch_hint, tensors, template_box):
+        """The executor's variant for `call`, built on first sight. After
+        a warm-store hit the executable came back deserialized, so the
+        python function was never traced and the out-template side
+        channel is empty: one abstract trace (no lower/compile) recovers
+        it. If even that fails, plain jit owns the signature — its first
+        dispatch traces the function and fills the template."""
+        variant = ex.prepare(*call, batch_hint=batch_hint)
+        if variant.fresh and variant.run is not None \
+                and "t" not in template_box:
+            try:
+                with self._tracers_kept_out(tensors):
+                    jax.eval_shape(ex.fn, *call)
+            except Exception:
+                ex.give_to_jit(variant)
+        return variant
 
     # ---- training health (singa_tpu.health) ------------------------------
     def _health_groups(self):
@@ -972,38 +880,25 @@ class Model(Layer, metaclass=ModelMeta):
         if not self._compiled_step or \
                 getattr(self, "_last_input_arrs", None) is None:
             return None
-        fn = self._compiled_step.get(tag)
-        if fn is None:
+        ex = self._compiled_step.get(tag)
+        if ex is None:
             return None
-        opt = self._optimizer
-        dev = self._device
-        snap_state = [t.data for t in self._state_tensors]
-        snap_opt = list(opt.state_arrays()) if opt is not None else []
-        snap_rng = dev.rng_state
-        state_arrs, opt_arrs, rng = snap_state, snap_opt, snap_rng
-        if self._dist_shardings is not None:
-            rep, _, state_sh, opt_sh = self._dist_shardings
-            state_arrs = [jax.device_put(a, s) for a, s in
-                          zip(state_arrs, state_sh)] if state_sh else \
-                [jax.device_put(a, rep) for a in state_arrs]
-            opt_arrs = [jax.device_put(a, s) for a, s in
-                        zip(opt_arrs, opt_sh)] if opt_sh else \
-                [jax.device_put(a, rep) for a in opt_arrs]
-            rng = jax.device_put(rng, rep)
-        snap_training = autograd.training
-        try:
-            return fn.lower(state_arrs, opt_arrs, rng,
-                            self._last_input_arrs)
-        finally:
-            # restore the PRE-replication snapshots: leaving mesh-committed
-            # arrays in globally shared state would poison later
-            # single-device work
-            autograd.training = snap_training
-            dev.rng_state = snap_rng
-            for t, a in zip(self._state_tensors, snap_state):
-                t.data = a
-            if opt is not None and snap_opt:
-                opt.load_state_arrays(snap_opt)
+        # lower from the PRE-replication snapshots and restore those:
+        # leaving mesh-committed arrays in globally shared state would
+        # poison later single-device work
+        with self._tracers_kept_out(self._state_tensors) as (
+                state_arrs, opt_arrs, rng):
+            if self._dist_shardings is not None:
+                rep, _, state_sh, opt_sh = self._dist_shardings
+                state_arrs = [jax.device_put(a, s) for a, s in
+                              zip(state_arrs, state_sh)] if state_sh else \
+                    [jax.device_put(a, rep) for a in state_arrs]
+                opt_arrs = [jax.device_put(a, s) for a, s in
+                            zip(opt_arrs, opt_sh)] if opt_sh else \
+                    [jax.device_put(a, rep) for a in opt_arrs]
+                rng = jax.device_put(rng, rep)
+            return ex.fn.lower(state_arrs, opt_arrs, rng,
+                               self._last_input_arrs)
 
     def step_cost_analysis(self):
         """XLA cost analysis of the compiled training step (flops, bytes
@@ -1021,55 +916,11 @@ class Model(Layer, metaclass=ModelMeta):
 
     # ---- jitted inference (graph mode for eval; the reference replays its
     # buffered graph for eval too, model.py:94-100) ------------------------
-    def _eval_invoke(self, concrete, arrs, nb=None):
-        """Eval forward through the AOT-staged executable cache: one
-        executable per abstract input signature, built via
-        introspect.build_compiled (compile-phase timing + recompile
-        blame; `nb` is the PRE-padding batch so a bucket crossing blames
-        the true sizes). Falls back to the plain jit call when staging
-        or dispatch fails."""
-        key = tuple((tuple(a.shape), str(a.dtype)) for a in arrs)
-        aot = self._eval_execs.get(key, _AOT_MISS)
-        if aot is _AOT_MISS:
-            asig = introspect.signature(
-                (concrete, arrs), names=("state", "arg"), batch_hint=nb)
-            aot, _rec = introspect.build_compiled(
-                self._compiled_eval, (concrete, arrs), "eval", asig)
-            if aot is not None and \
-                    not hasattr(self, "_eval_template"):
-                # warm-store hit: efwd was never traced, so the eval
-                # out-template side channel is empty — one abstract
-                # trace recovers it (same contract as the step path;
-                # efwd's only other side effects are the trace counter
-                # and state-tensor assignments restored below)
-                snap_state = [t.data for t in self._eval_tensors]
-                try:
-                    jax.eval_shape(self._compiled_eval, concrete, arrs)
-                except Exception:
-                    aot = None  # jit owns it: first dispatch traces
-                finally:
-                    for t, a in zip(self._eval_tensors, snap_state):
-                        t.data = a
-            # None negative-caches a failed build: jit owns this shape
-            self._eval_execs[key] = aot
-            if aot is None:
-                # fresh staging failure: the jit call compiles cold —
-                # goodput books it as compile, not eval
-                with observe.span("model.jit_fallback"):
-                    return self._compiled_eval(concrete, arrs)
-        if aot is None:
-            return self._compiled_eval(concrete, arrs)
-        try:
-            return aot(concrete, arrs)
-        except Exception:
-            self._eval_execs[key] = None
-            with observe.span("model.jit_fallback"):
-                return self._compiled_eval(concrete, arrs)
-
     def _eval_step(self, args):
-        if getattr(self, "_compiled_eval", None) is None:
+        if self._compiled_eval is None:
             states = self.get_states()
             eval_tensors = list(states.values())
+            template_box = {}
 
             def efwd(state_arrs, input_arrs):
                 # host-side trace counter: jit re-runs this body only on a
@@ -1090,13 +941,30 @@ class Model(Layer, metaclass=ModelMeta):
                     autograd.training = prev
                     autograd.compute_dtype = prev_cd
                 leaves, template = _flatten_out(out)
-                self._eval_template = template
+                template_box["t"] = template
                 return [o.data for o in leaves]
 
             self._eval_tensors = eval_tensors
-            self._compiled_eval = jax.jit(efwd)
-            self._eval_execs = {}
-        concrete = [t.data for t in self._eval_tensors]
+            self._eval_template_box = template_box
+            # one executable an abstract input signature (compile-phase
+            # timing + recompile blame), keyed on the inputs alone
+            self._compiled_eval = introspect.AotExecutor(
+                jax.jit(efwd), "eval", names=("state", "arg"),
+                cache_key=lambda a: _input_avals(a[1]))
+        ex = self._compiled_eval
+
+        def run(concrete, arrs, nb):
+            """`nb` is the PRE-padding batch, so a bucket crossing blames
+            the true sizes."""
+            if self.sequential:
+                # serial debug mode applies to inference too (RunInSerial)
+                with jax.disable_jit():
+                    return ex.fn(concrete, arrs)
+            call = (concrete, arrs)
+            return ex.dispatch(
+                self._staged(ex, call, nb, self._eval_tensors,
+                             self._eval_template_box), call)
+
         # batch-shape bucketing: pad the batch dim up to the next power of
         # two so varying eval sizes (e.g. the last partial batch) reuse
         # O(log B) compiled variants instead of retracing per size. Only
@@ -1124,66 +992,56 @@ class Model(Layer, metaclass=ModelMeta):
                     for a in arrs]
             else:
                 bucket = None
-        try:
-            if self.sequential:
-                # serial debug mode applies to inference too (RunInSerial)
-                with jax.disable_jit():
-                    outs = self._compiled_eval(concrete, arrs)
-            else:
-                outs = self._eval_invoke(concrete, arrs, nb)
-        finally:
-            # tracing assigns tracers into the state Tensors; put the real
-            # arrays back so later eager/train calls see concrete buffers
-            for t, a in zip(self._eval_tensors, concrete):
-                t.data = a
-        if bucket is not None:
-            # the eval_buckets contract is "every output is per-sample";
-            # enforce it loudly (ValueError, not assert: -O must not turn
-            # this back into silent truncation of a fixed-size output that
-            # merely matches the bucket)
-            for o in outs:
-                if o.ndim == 0 or o.shape[0] != bucket:
-                    raise ValueError(
-                        f"eval_buckets requires per-sample outputs; "
-                        f"got shape {o.shape} with batch bucket {bucket} "
-                        f"(compile with eval_buckets=False to retrace "
-                        f"per shape instead)")
-            outs = [o[:nb] for o in outs]
-        elif mode == "auto" and nb is not None and \
-                getattr(self, "_eval_per_sample", None) is not False and \
-                nb not in getattr(self, "_eval_probed_nbs", ()):
-            # auto-detect on unbucketed calls. Shape alone is not proof —
-            # a batch-coupled output (softmax over axis 0) is batch-shaped
-            # too — so PROBE semantics: re-run on the first half of the
-            # batch and require out(x[:h]) == out(x)[:h]. The probe
-            # re-runs once per NEW batch-size class (a coupling that was
-            # numerically invisible at one size may not be at another),
-            # and a failed re-probe permanently disables bucketing rather
-            # than silently zero-padding a coupled model.
-            shaped = all(o.ndim > 0 and o.shape[0] == nb for o in outs)
-            ok = False
-            if shaped and nb > 1:
-                h = nb // 2
-                try:
-                    houts = self._eval_invoke(
-                        concrete, [a[:h] for a in arrs], h)
-                    ok = all(
-                        np.allclose(np.asarray(jax.device_get(ho)),
-                                    np.asarray(jax.device_get(o))[:h],
-                                    rtol=1e-5, atol=1e-6)
-                        for ho, o in zip(houts, outs))
-                except Exception:
-                    ok = False
-                finally:
-                    for t, a in zip(self._eval_tensors, concrete):
-                        t.data = a
-            if not hasattr(self, "_eval_probed_nbs"):
-                self._eval_probed_nbs = set()
-            self._eval_probed_nbs.add(nb)
-            self._eval_per_sample = shaped and ok
+        # tracing assigns tracers into the state Tensors; the guard puts
+        # the real arrays back so later eager/train calls see concrete
+        # buffers
+        with self._tracers_kept_out(self._eval_tensors) as (concrete, _, _):
+            outs = run(concrete, arrs, nb)
+            if bucket is not None:
+                # the eval_buckets contract is "every output is
+                # per-sample"; enforce it loudly (ValueError, not assert:
+                # -O must not turn this back into silent truncation of a
+                # fixed-size output that merely matches the bucket)
+                for o in outs:
+                    if o.ndim == 0 or o.shape[0] != bucket:
+                        raise ValueError(
+                            f"eval_buckets requires per-sample outputs; "
+                            f"got shape {o.shape} with batch bucket "
+                            f"{bucket} (compile with eval_buckets=False "
+                            f"to retrace per shape instead)")
+                outs = [o[:nb] for o in outs]
+            elif mode == "auto" and nb is not None and \
+                    getattr(self, "_eval_per_sample", None) is not False \
+                    and nb not in getattr(self, "_eval_probed_nbs", ()):
+                # auto-detect on unbucketed calls. Shape alone is not
+                # proof — a batch-coupled output (softmax over axis 0) is
+                # batch-shaped too — so PROBE semantics: re-run on the
+                # first half of the batch and require out(x[:h]) ==
+                # out(x)[:h]. The probe re-runs once per NEW batch-size
+                # class (a coupling that was numerically invisible at one
+                # size may not be at another), and a failed re-probe
+                # permanently disables bucketing rather than silently
+                # zero-padding a coupled model.
+                shaped = all(o.ndim > 0 and o.shape[0] == nb for o in outs)
+                ok = False
+                if shaped and nb > 1:
+                    h = nb // 2
+                    try:
+                        houts = run(concrete, [a[:h] for a in arrs], h)
+                        ok = all(
+                            np.allclose(np.asarray(jax.device_get(ho)),
+                                        np.asarray(jax.device_get(o))[:h],
+                                        rtol=1e-5, atol=1e-6)
+                            for ho, o in zip(houts, outs))
+                    except Exception:
+                        ok = False
+                if not hasattr(self, "_eval_probed_nbs"):
+                    self._eval_probed_nbs = set()
+                self._eval_probed_nbs.add(nb)
+                self._eval_per_sample = shaped and ok
         tensors = [Tensor(data=a, device=self._device, requires_grad=False)
                    for a in outs]
-        return _rebuild_out(self._eval_template, tensors)
+        return _rebuild_out(self._eval_template_box["t"], tensors)
 
     # ---- checkpointing (ref model.py:244-354) ----------------------------
     def save_states(self, fpath: str, aux_states: dict | None = None):

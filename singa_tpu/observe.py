@@ -1011,21 +1011,6 @@ def record_regress_verdict(rec: dict):
     _default.emit({**rec, "kind": "regress_verdict"})
 
 
-def record_bench(rec: dict):
-    """Mirror a bench.py result record into the registry (gauges named
-    singa_bench_<field>) and the EventLog, so BENCH_*.json artifacts and
-    runtime telemetry share one schema."""
-    if not _enabled:
-        return
-    for k, v in rec.items():
-        if isinstance(v, bool) or not isinstance(v, (int, float)):
-            continue
-        name = "singa_bench_" + re.sub(r"[^a-z0-9_]", "_", str(k).lower())
-        gauge(name, "bench.py result field"
-              ).set(float(v), metric=str(rec.get("metric", "")))
-    _default.emit({"kind": "bench", **rec})
-
-
 __all__ = [
     "MetricsRegistry", "Counter", "Gauge", "Histogram", "EventLog",
     "span", "suppress_spans", "spans_suppressed", "current_span",
@@ -1040,7 +1025,7 @@ __all__ = [
     "record_step", "record_step_build", "record_step_fenced",
     "record_compile", "record_hbm", "record_opt_update", "record_comm",
     "record_comm_host",
-    "record_decode", "record_bench", "record_scaler_decision",
+    "record_decode", "record_scaler_decision",
     "record_regress_verdict", "record_checkpoint_bytes",
     "record_prefetch", "record_ckpt_async",
 ]

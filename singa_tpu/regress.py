@@ -513,7 +513,7 @@ class RegressionDetector:
 
     def feed(self, signal: str, seconds: float):
         """One raw latency sample for `signal` — the listener entry
-        point, also driven directly by tests and bench.py --regress."""
+        point, also driven directly by tests."""
         with self._lock:
             sig = self._signals.get(signal)
             if sig is None:

@@ -42,8 +42,7 @@ label values against.
 CLI: `python -m singa_tpu.router --replica` runs one replica process
 (engine + diag + shard writer + the HTTP control surface the router
 drives); `--ab` is the kill-and-replace harness: 3 replicas under the
-seeded Poisson workload from `bench_decode --serve`
-(`serving.poisson_workload`), SIGKILL one mid-traffic, a standby
+seeded Poisson workload (`serving.poisson_workload`), SIGKILL one mid-traffic, a standby
 replica joins, and the run asserts ZERO lost requests (every submit
 terminal, failover outputs token-identical to a clean arm) plus the
 p99 TTFT delta through the event -> SERVE_rNN.json.

@@ -13,7 +13,7 @@ prefill + lax.scan over decode steps with a preallocated (T-length) KV
 cache updated via dynamic_update_slice — O(T) per token instead of
 O(T^2), no retrace per step, static shapes throughout.
 
-Serving-roofline design notes (PROFILE.md "KV-cached decode"):
+Serving-roofline design notes:
 - HEAD-PACKED KV caches, (B, H/P, T, P*D) with P = 128//D: TPU bf16
   tiles are (16 sublanes, 128 lanes), so a (B,H,T,D) cache with D=64
   pads every row to 128 lanes — the cache physically occupies and
@@ -1449,8 +1449,8 @@ def build_beam_decode(m, B, S0, max_new, num_beams, length_penalty,
 
 def poisson_workload(seed, n_req, rps, vocab, prompt_lens, new_lens,
                      new_dist="bimodal"):
-    """The seeded Poisson serving workload shared by `bench_decode
-    --serve`, `slo --ab`, and the router's kill-and-replace harness
+    """The seeded Poisson serving workload shared by `slo --ab`,
+    `capacity --ab`, `audit --ab` and the router's kill-and-replace harness
     (all three of its arms — clean, kill, and the FaultPlan-delayed
     tail-attribution arm replay the same schedule, which is what makes
     the /tailz and cold-vs-warm comparisons apples-to-apples):

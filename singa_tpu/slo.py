@@ -244,8 +244,8 @@ class SLOConfig:
 
 
 # ---- the pure math ---------------------------------------------------------
-# Free functions over plain record dicts, so bench_decode's static arm
-# (which has no engine, only measured latencies) and the tests' synthetic
+# Free functions over plain record dicts, so a caller with no engine
+# (only measured latencies) and the tests' synthetic
 # violation sequences evaluate with EXACTLY the tracker's arithmetic.
 
 def objective_good(objective, rec, cfg) -> "bool | None":

@@ -64,7 +64,6 @@ sleeping-and-hoping tests.
 CLI: `python -m singa_tpu.watchdog --ab --out HANG_r01.json` runs the
 3-worker hang A/B (one FaultPlan-wedged collective; detection +
 coordinated restore asserted from the coordinator's HTTP surface).
-`bench.py --watchdog` measures the guard's per-step overhead.
 """
 
 from __future__ import annotations

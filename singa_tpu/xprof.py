@@ -469,17 +469,6 @@ def hlo_category_table(logdir: str, steps: int = 1):
     return rows
 
 
-def format_hlo_categories(rows) -> str:
-    lines = [f"{'category':<26} {'ms/step':>8} {'pct':>6} {'GB/step':>8} "
-             f"{'GB/s':>7} {'TF/step':>8} {'TF/s':>7}"]
-    for r in rows:
-        lines.append(
-            f"{r['category']:<26} {r['ms']:>8.3f} {r['pct']:>5.1f}% "
-            f"{r['gbytes']:>8.3f} {r['achieved_gbs']:>7.0f} "
-            f"{r['tflops']:>8.4f} {r['tflops_s']:>7.1f}")
-    return "\n".join(lines)
-
-
 def category_table(rows):
     """Collapse an op_table into per-category totals. Span rows are
     dropped: a span is a host-side envelope AROUND the device ops

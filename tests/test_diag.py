@@ -254,7 +254,7 @@ def test_profilez_capture(served):
 
 def test_profilez_flags_truncation(served):
     """The seconds cap expiring before N steps pass must be visible in
-    the response (PROFILE.md tells operators to check it): the trace
+    the response (operators are told to check it): the trace
     covers a shorter window than requested."""
     srv = served[0]
     # nobody is stepping: 5 requested steps can never arrive in 0.2s

@@ -404,6 +404,9 @@ def test_partial_update_compiles_per_partition(dev, mesh, data):
     m, losses, _ = _run(MLPPartial, dev, mesh, X, Y, steps=5)
     tags = sorted(m._compiled_step)
     assert tags == [0, 1], tags
+    # one executor a tag, each built once for the one input signature
+    assert [(m._compiled_step[t].tag, len(m._compiled_step[t]))
+            for t in tags] == [(0, 1), (1, 1)]
     texts = {tag: m.lower_step(tag).as_text() for tag in tags}
     for tag in tags:
         assert "all_reduce" in texts[tag] or "all-reduce" in texts[tag]

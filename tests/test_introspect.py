@@ -214,8 +214,8 @@ def test_cached_path_no_new_records(dev, rng, tmp_path):
     reg = observe.get_registry()
     assert reg.get("singa_model_compile_total").value(batch_class="16") == 1
     assert reg.get("singa_recompile_total") is None
-    # the AOT executable cache holds exactly one variant
-    assert len(m._step_execs) <= 1
+    # one executor for the one step tag, holding exactly one variant
+    assert [len(ex) for ex in m._compiled_step.values()] == [1]
 
 
 # ---- HLO capture + flight-recorder integration -----------------------------

@@ -271,9 +271,10 @@ def test_oom_forensics_bundle_roundtrip(dev, rng, tmp_path):
     def boom(*_a, **_k):
         raise err
 
-    assert m._dispatch_cache, "expected a cached step variant"
-    for variant in m._dispatch_cache.values():
-        variant[0] = boom
+    (ex,) = m._compiled_step.values()
+    assert len(ex) == 1, "expected a cached step variant"
+    for variant in ex._execs.values():
+        variant.run = boom
     with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
         m(tx, ty)
     bundles = [f for f in os.listdir(tmp_path)
@@ -315,8 +316,8 @@ def test_oom_from_aot_executor_dumps_and_reraises(tmp_path):
     ex = introspect.AotExecutor(jax.jit(fn), "serving.prefill")
     ex(jnp.ones((2,)))  # builds + caches
     # poison the cached executable
-    k = next(iter(ex._execs))
-    ex._execs[k] = lambda *a: (_ for _ in ()).throw(err)
+    (variant,) = ex._execs.values()
+    variant.run = lambda *a: (_ for _ in ()).throw(err)
     with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
         ex(jnp.ones((2,)))
     assert any(f.startswith("flight_oom_") for f in os.listdir(tmp_path))
@@ -365,8 +366,9 @@ err = type("XlaRuntimeError", (RuntimeError,), {{}})(
     "RESOURCE_EXHAUSTED: Out of memory allocating 9999999999 bytes")
 def boom(*_a, **_k):
     raise err
-for variant in m._dispatch_cache.values():
-    variant[0] = boom
+for ex in m._compiled_step.values():
+    for variant in ex._execs.values():
+        variant.run = boom
 m(tx, ty)  # dies here; the bundle must already be on disk
 ''')
     proc = subprocess.run([sys.executable, str(script)],

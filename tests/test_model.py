@@ -75,7 +75,8 @@ def test_graph_step_is_compiled_once(dev, data):
     X, Y = data
     m = MLP()
     losses, _ = _train(m, dev, X, Y, 5, True)
-    assert m._compiled_step is not None
+    (ex,) = m._compiled_step.values()   # one step tag, one executor
+    assert len(ex) == 1                 # five steps, one staged build
     assert m._step_stats["steps"] == 5
     assert m._step_stats["compile_s"] > 0
 

@@ -15,8 +15,8 @@ import pytest
 
 import jax
 
-from singa_tpu import (goodput, layer, model, observe, opt, overlap,
-                       tensor)
+from singa_tpu import (goodput, introspect, layer, model, observe, opt,
+                       overlap, tensor)
 from singa_tpu.device import get_default_device
 from singa_tpu.health import HealthError, HealthMonitor
 
@@ -404,18 +404,30 @@ def test_async_save_books_only_blocking_portion(dev, tmp_path):
 
 # ---- step-dispatch fast path -----------------------------------------------
 
-def test_dispatch_cache_one_variant_per_signature(dev):
+def test_dispatch_cache_one_variant_per_signature(dev, monkeypatch):
     m, tx, ty = _build(dev)
-    for _ in range(3):
+    m(tx, ty)
+    # a repeat step does O(inputs) host work: one key, one dict lookup,
+    # and neither of the two functions that walk every state leaf
+    calls = []
+    for name in ("signature", "build_compiled"):
+        real = getattr(introspect, name)
+        monkeypatch.setattr(
+            introspect, name,
+            lambda *a, _n=name, _r=real, **k: calls.append(_n) or _r(*a, **k))
+    for _ in range(2):
         m(tx, ty)
-    assert len(m._dispatch_cache) == 1  # one (tag, sig) variant
-    ((key, rec),) = m._dispatch_cache.items()
-    assert rec[0] is not None and rec[3] is True  # resolved + recorded
+    assert calls == []
+    (ex,) = m._compiled_step.values()   # one executor a step tag
+    assert len(ex) == 1                 # one variant a (tag, signature)
+    (variant,) = ex._execs.values()
+    assert variant.run is not None and variant.fresh is False
     # a second batch-size class adds exactly one more variant
     X2 = np.zeros((16, 16), np.float32)
     Y2 = np.zeros(16, np.int32)
     m(tensor.from_numpy(X2, dev), tensor.from_numpy(Y2, dev))
-    assert len(m._dispatch_cache) == 2
+    assert len(ex) == 2 and list(m._compiled_step) == [0]
+    assert calls == ["signature", "build_compiled"]
     reg = observe.get_registry()
     assert reg.get("singa_model_compile_total").value(batch_class="32") == 1
     assert reg.get("singa_model_compile_total").value(batch_class="16") == 1

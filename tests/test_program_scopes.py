@@ -14,6 +14,7 @@ import glob
 import importlib.util
 import os
 
+import jax
 import numpy as np
 import pytest
 
@@ -168,19 +169,12 @@ def test_groups_cover_the_step(step):
 
 def test_pallas_calls_of_the_traced_step_carry_the_kernels_names(step):
     m, _table, (tx, ty) = step
-    dev = tx.device
-    fn = m._step_builder(0)
-    snap_state = [t.data for t in m._state_tensors]
-    snap_opt = list(m.optimizer.state_arrays())
-    snap_rng = dev.rng_state
-    try:
-        jaxpr = fn.trace(snap_state, snap_opt, snap_rng,
-                         [tx.data, ty.data]).jaxpr
-    finally:     # tracing parks tracers in the model: put the arrays back
-        dev.rng_state = snap_rng
-        for t, a in zip(m._state_tensors, snap_state):
-            t.data = a
-        m.optimizer.load_state_arrays(snap_opt)
+    fn = m._step_builder(0).fn      # tag 0's jitted step, traced afresh
+    # tracing parks tracers in the model: the guard puts the arrays back
+    with m._tracers_kept_out(m._state_tensors) as (state, opt_arrs, rng):
+        jaxpr = fn.trace(state, opt_arrs, rng, [tx.data, ty.data]).jaxpr
+    assert not any(isinstance(t.data, jax.core.Tracer)
+                   for t in m._state_tensors)
 
     names = []
 

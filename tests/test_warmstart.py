@@ -459,8 +459,7 @@ def test_typed_key_fn_warm_hit_through_build_compiled(tmp_path):
 
 @pytest.mark.slow
 def test_train_step_warm_restart_matches_cold_losses(tmp_path):
-    # the end-to-end claim behind `bench.py --goodput --compile-cache`:
-    # a warm process's training losses are bit-identical to cold ones
+    # the end-to-end claim of the warm store: a warm process's training losses are bit-identical to cold ones
     # (same exported module), with the step executable served from the
     # store — exercised across a REAL process boundary
     script = textwrap.dedent("""

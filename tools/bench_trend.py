@@ -101,7 +101,7 @@ LOWER_BETTER_SUBSTRINGS = ("ttft", "dropped", "lost", "failover",
 #: lower-better suffix would otherwise match — SLO attainment records
 #: end in `_pct` (and the percentile suffixes), but a DROP in
 #: attainment is the regression; speculative-decoding `accept`/
-#: `acceptance` rates (BENCHDEC_r07's spec records) likewise regress
+#: `acceptance` rates (a spec-decoding A/B's records) likewise regress
 #: DOWN even when written unit-less or percentile-suffixed; capacity
 #: `headroom` fractions (CAPACITY_rNN) regress DOWN too — shrinking
 #: headroom at the same load is the capacity regression; `hit_rate` is

@@ -60,8 +60,8 @@ literal, then fails if
      `distributed.topology()` or `distributed.host_label()` (the only
      minters of host identities — same enclosing-guard style as rule 5).
 
-Dynamic names (f-strings, e.g. bench.py's singa_bench_* gauges) cannot be
-checked statically; the runtime ValueError in observe._Metric covers
+Dynamic names (f-strings, e.g. observe.record_bench's singa_bench_* gauges)
+cannot be checked statically; the runtime ValueError in observe._Metric covers
 those. Run as a script (exit 1 on violations) or via
 tests/test_metrics_lint.py in the tier-1 pass.
 """
@@ -78,12 +78,7 @@ METRIC_FUNCS = {"counter", "gauge", "histogram"}
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(_HERE)
-DEFAULT_PATHS = [
-    os.path.join(ROOT, "singa_tpu"),
-    os.path.join(ROOT, "bench.py"),
-    os.path.join(ROOT, "bench_decode.py"),
-    os.path.join(ROOT, "bench_ops.py"),
-]
+DEFAULT_PATHS = [os.path.join(ROOT, "singa_tpu")]
 
 
 def iter_py_files(paths):
