@@ -26,6 +26,7 @@ from jax import lax
 from jax._src import source_info_util   # the name stack has no public reader
 
 from .tensor import Tensor
+from . import observe
 from . import tensor as tensor_module
 
 #: global train/eval switch (ref autograd.py `training`)
@@ -60,6 +61,12 @@ def _current_scope():
     if path == RECOMPUTE_SCOPE:
         return ""
     return path.removeprefix(RECOMPUTE_SCOPE + "/")
+
+
+def _in_replay():
+    """Whether this is traced in a `Region`'s second forward."""
+    path = str(source_info_util.current_name_stack())
+    return (path + "/").startswith(RECOMPUTE_SCOPE + "/")
 
 
 class Operator:
@@ -907,9 +914,22 @@ class RankingLoss(Operator):
         return jnp.mean(jnp.maximum(self.M - (pos - neg), 0.0))
 
 
+def cross_entropy_path(logits, targets):
+    """(the targets' kind, where the backward's log-sum-exp comes from) of a
+    softmax cross-entropy recorded here, as `singa_cross_entropy` names
+    them: with class indices the backward reads the forward's, which inside
+    a `Region` is the second forward's; a distribution's takes the softmax
+    anew."""
+    if not tensor_module.targets_are_indices(logits, targets):
+        return "dense", "rebuilt"
+    return "integer", "rebuilt" if _in_replay() else "kept"
+
+
 class SoftMaxCrossEntropy(Operator):
     """Fused stable softmax-CE with a HAND backward (ref: C++ fused
-    CrossEntropyFwd/Bwd tensor.h:625-637 for exactly this reason)."""
+    CrossEntropyFwd/Bwd tensor.h:625-637 for exactly this reason). Logits of
+    any rank; the mean is over all leading axes. With class-index targets
+    the backward reads the forward's log-sum-exp."""
 
     def __init__(self):
         super().__init__()
@@ -918,15 +938,18 @@ class SoftMaxCrossEntropy(Operator):
     def forward(self, x, t):
         self._in_dtype = x.dtype
         x = x.astype(jnp.float32)  # fp32 island under bf16 compute policy
-        self._cache = (x, t)
-        return jnp.mean(tensor_module.softmax_cross_entropy_fwd(x, t))
+        lse = tensor_module.softmax_lse(x)
+        self._cache = (x, t, lse)
+        self._path = cross_entropy_path(x, t)
+        return jnp.mean(tensor_module.softmax_cross_entropy_fwd(x, t, lse))
 
     def backward(self, dy):
-        x, t = self._cache
+        x, t, lse = self._cache
+        observe.record_cross_entropy(*self._path)
         # mean is over ALL leading dims (per-token for 3D logits), so the
         # scale is prod(x.shape[:-1]), not just the batch dim
         n = int(np.prod(x.shape[:-1])) if x.ndim > 1 else 1
-        dx = tensor_module.softmax_cross_entropy_bwd(x, t) * (dy / n)
+        dx = tensor_module.softmax_cross_entropy_bwd(x, t, lse) * (dy / n)
         return dx.astype(self._in_dtype), None  # no grad for targets
 
 

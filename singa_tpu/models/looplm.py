@@ -40,12 +40,15 @@ class _TokenCrossEntropy(autograd.Operator):
     are the functions `SoftMaxCrossEntropy` uses."""
 
     def forward(self, z, t):
-        self._cache = (z, t)
-        return tensor_module.softmax_cross_entropy_fwd(z, t)
+        lse = tensor_module.softmax_lse(z)
+        self._cache = (z, t, lse)
+        self._path = autograd.cross_entropy_path(z, t)
+        return tensor_module.softmax_cross_entropy_fwd(z, t, lse)
 
     def backward(self, dy):
-        z, t = self._cache
-        return tensor_module.softmax_cross_entropy_bwd(z, t) \
+        z, t, lse = self._cache
+        observe.record_cross_entropy(*self._path)
+        return tensor_module.softmax_cross_entropy_bwd(z, t, lse) \
             * dy[..., None], None
 
 

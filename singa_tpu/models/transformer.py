@@ -216,10 +216,9 @@ class GPT(_VocabTPMixin, model.Model):
     def train_one_batch(self, ids, targets):
         if not self.vocab_tp:
             logits = self.forward(ids)
-            with jax.named_scope("sce"):    # the loss's own reshapes
-                flat = autograd.reshape(logits, (-1, self.vocab_size))
-                tflat = autograd.reshape(targets, (-1,))
-            loss = self._moe_losses(self.sce(flat, tflat), ids.device)
+            # (B, S, V) as the head wrote them: a 2-D view would be a
+            # relayout where the compiler laid the vocabulary out second
+            loss = self._moe_losses(self.sce(logits, targets), ids.device)
             self.optimizer(loss)
             return logits, loss
         # vocab-parallel path: the loss consumes the SHARDED logits (full

@@ -53,6 +53,11 @@ COMM_OPS = ("all_reduce", "all_reduce_half", "all_gather", "broadcast",
 ATTN_SITES = ("flash_fwd", "flash_bwd", "paged", "flash_decode")
 ATTN_PATHS = ("kernel", "interpret", "reference")
 
+#: what a softmax cross-entropy's targets are, and where its backward's
+#: log-sum-exp comes from (record_cross_entropy)
+CE_TARGETS = ("integer", "dense")
+CE_LSE = ("kept", "rebuilt")
+
 # Log-scale bucket boundaries (seconds): 1e-6 .. 1e3, ratio sqrt(10).
 # Wide enough for a 2us collective and a 15-minute XLA compile alike.
 DEFAULT_BUCKETS = tuple(10.0 ** (e / 2.0) for e in range(-12, 7))
@@ -891,6 +896,26 @@ def record_step_outputs(batch_sharded: int, mean_reduced: int):
               "they left it (batch_sharded|mean_reduced)")
     g.set(batch_sharded, kind="batch_sharded")
     g.set(mean_reduced, kind="mean_reduced")
+
+
+def record_cross_entropy(targets: str, lse: str):
+    """The form of the latest traced softmax cross-entropy backward:
+    `targets` "integer" (class indices: the loss is lse - logits[target],
+    nothing of the logits' size is formed) or "dense" (a distribution);
+    `lse` "kept" (the backward reads the forward's log-sum-exp) or
+    "rebuilt" (it takes the sums again: dense targets, or an operator
+    replayed in a recomputed region, whose second forward makes them).
+    A gauge: 1 on the latest trace's pair, 0 on the others."""
+    assert targets in CE_TARGETS and lse in CE_LSE, (targets, lse)
+    if not _enabled:
+        return
+    g = gauge("singa_cross_entropy",
+              "the latest traced softmax cross-entropy backward, by the "
+              "targets' kind (integer|dense) and where its log-sum-exp "
+              "comes from (kept|rebuilt): 1 on the pair it took")
+    for t in CE_TARGETS:
+        for l in CE_LSE:
+            g.set(int((t, l) == (targets, lse)), targets=t, lse=l)
 
 
 def record_comm_host(op: str, start: float, seconds: float):

@@ -588,30 +588,58 @@ def bernoulli(p, shape, device=None, dtype=float32) -> Tensor:
 
 # ---- fused softmax cross-entropy (tensor.h:625-637) ----------------------
 
-def softmax_cross_entropy_fwd(logits, targets):
-    """Fused stable log-softmax CE; targets may be class indices or one-hot.
+def targets_are_indices(logits, targets):
+    """Whether `targets` name one class a row (integers, a rank below the
+    logits) and not a distribution over the classes (one-hot or soft)."""
+    return targets.ndim == logits.ndim - 1 \
+        or targets.dtype in (jnp.int32, jnp.int64)
+
+
+def softmax_lse(logits):
+    """log(sum(exp(logits))) over the last axis, a number a row."""
+    return jax.scipy.special.logsumexp(logits, axis=-1)
+
+
+def _at_target(logits, targets):
+    """True where the last axis' index is the row's target. A compare of an
+    iota, so that a consumer folds it into its own pass over the logits in
+    whatever layout they lie: no gather, no one-hot array."""
+    classes = jax.lax.broadcasted_iota(jnp.int32, logits.shape,
+                                       logits.ndim - 1)
+    return classes == targets.astype(jnp.int32)[..., None]
+
+
+def softmax_cross_entropy_fwd(logits, targets, lse=None):
+    """Fused stable softmax CE of every row; targets may be class indices
+    or a distribution (one-hot, soft). `lse`: `softmax_lse(logits)` where
+    the caller has it.
 
     Reference: CrossEntropyFwd (tensor.h:636) fuses softmax+CE on device; on
-    TPU the fusion is done by XLA from this logsumexp formulation.
+    TPU the fusion is done by XLA from this logsumexp formulation. With
+    class indices the loss is `lse - logits[target]`, the picked logit a
+    masked sum over the classes beside the sum of exponentials: nothing of
+    the logits' size is formed, and the logits are read as they lie.
     """
-    lse = jax.scipy.special.logsumexp(logits, axis=-1, keepdims=True)
-    logp = logits - lse
-    if targets.ndim == logits.ndim - 1 or targets.dtype in (jnp.int32, jnp.int64):
-        picked = jnp.take_along_axis(
-            logp, targets.astype(jnp.int32)[..., None], axis=-1)[..., 0]
-        return -picked
+    if lse is None:
+        lse = softmax_lse(logits)
+    if targets_are_indices(logits, targets):
+        picked = jnp.sum(jnp.where(_at_target(logits, targets), logits, 0),
+                         axis=-1)
+        return lse - picked
+    logp = logits - lse[..., None]
     return -jnp.sum(targets * logp, axis=-1)
 
 
-def softmax_cross_entropy_bwd(logits, targets):
-    """d(CE)/d(logits) = softmax(logits) - onehot(targets)."""
-    p = jax.nn.softmax(logits, axis=-1)
-    if targets.ndim == logits.ndim - 1 or targets.dtype in (jnp.int32, jnp.int64):
-        onehot = jax.nn.one_hot(targets.astype(jnp.int32), logits.shape[-1],
-                                dtype=logits.dtype)
-    else:
-        onehot = targets
-    return p - onehot
+def softmax_cross_entropy_bwd(logits, targets, lse=None):
+    """d(CE)/d(logits) = softmax(logits) - onehot(targets). With class
+    indices the softmax is `exp(logits - lse)`, from the `lse` the forward
+    made where the caller kept it."""
+    if not targets_are_indices(logits, targets):
+        return jax.nn.softmax(logits, axis=-1) - targets
+    if lse is None:
+        lse = softmax_lse(logits)
+    return jnp.exp(logits - lse[..., None]) \
+        - _at_target(logits, targets).astype(logits.dtype)
 
 
 # ---- reference-name module-fn parity (python/singa/tensor.py) -----------

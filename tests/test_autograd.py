@@ -166,6 +166,74 @@ class TestBackward:
                 logits.data)
         assert np.allclose(g[id(logits)], np.asarray(ref), atol=1e-5)
 
+    @pytest.mark.parametrize("region", [False, True],
+                             ids=["tape", "region"])
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("shape", [(6, 11), (2, 5, 11)],
+                             ids=["rank2", "rank3"])
+    def test_softmax_cross_entropy_integer_targets(
+            self, dev, rng, train_mode, shape, dtype, region):
+        """Loss and gradient against jax.grad of a plain fp32
+        -log_softmax(z)[t], on the ordinary tape and replayed in a
+        `Region`; the gradient in the logits' dtype; the gauge says where
+        the backward's log-sum-exp came from."""
+        from singa_tpu import observe
+        z = jnp.asarray(rng.randn(*shape).astype(np.float32) * 3).astype(dtype)
+        logits = tensor.Tensor(data=z, device=dev, requires_grad=True,
+                               stores_grad=True)
+        labels = tensor.from_numpy(
+            rng.randint(0, shape[-1], shape[:-1]).astype(np.int32), dev)
+        plain = lambda z: jnp.mean(-jnp.take_along_axis(
+            jax.nn.log_softmax(z.astype(jnp.float32)),
+            labels.data[..., None], axis=-1))
+        if region:
+            loss = autograd.region(
+                lambda x: autograd.softmax_cross_entropy(x, labels), logits)
+        else:
+            loss = autograd.softmax_cross_entropy(logits, labels)
+        g = autograd.gradients(loss)[logits].data
+        want, want_g = plain(z), jax.grad(plain)(z.astype(jnp.float32))
+        assert loss.data.dtype == jnp.float32 and g.dtype == z.dtype
+        assert abs(float(loss.data) - float(want)) <= 1e-6 * float(want)
+        # bf16: the fp32 gradient rounded once, entries of at most 1 / rows
+        tol = 1e-7 if dtype == "float32" else 2 ** -8 / want_g.shape[0]
+        assert np.allclose(np.asarray(g, np.float32), want_g, atol=tol)
+        took = observe.get_registry().get("singa_cross_entropy")
+        pairs = {(t, l): took.value(targets=t, lse=l)
+                 for t in observe.CE_TARGETS for l in observe.CE_LSE}
+        mine = ("integer", "rebuilt" if region else "kept")
+        assert pairs == {k: float(k == mine) for k in pairs}
+
+    def test_softmax_cross_entropy_region_equals_the_tape(
+            self, dev, rng, train_mode):
+        """The looped model's use: the same operator replayed in a region
+        gives the ordinary tape's loss and gradient bit for bit."""
+        z = rng.randn(2, 5, 11).astype(np.float32)
+        labels = tensor.from_numpy(
+            rng.randint(0, 11, (2, 5)).astype(np.int32), dev)
+        got = []
+        for region in (False, True):
+            x = _param(z, dev)
+            f = lambda x: autograd.softmax_cross_entropy(x, labels)
+            loss = autograd.region(f, x) if region else f(x)
+            got.append((loss.numpy(), _grads(loss)[id(x)]))
+        assert np.array_equal(got[0][0], got[1][0])
+        assert np.array_equal(got[0][1], got[1][1])
+
+    def test_softmax_cross_entropy_dense_targets_gauge(
+            self, dev, rng, train_mode):
+        from singa_tpu import observe
+        logits = _param(rng.randn(4, 3).astype(np.float32), dev)
+        onehot = tensor.from_numpy(np.eye(3, dtype=np.float32)[[0, 2, 1, 1]],
+                                   dev)
+        g = _grads(autograd.softmax_cross_entropy(logits, onehot))
+        ref = jax.grad(lambda z: jnp.mean(-jnp.sum(
+            onehot.data * jax.nn.log_softmax(z), -1)))(logits.data)
+        assert np.allclose(g[id(logits)], np.asarray(ref), atol=1e-6)
+        took = observe.get_registry().get("singa_cross_entropy")
+        assert took.value(targets="dense", lse="rebuilt") == 1
+        assert took.value(targets="integer", lse="kept") == 0
+
     def test_param_grad_survives_none_edge(self, dev, rng, train_mode):
         """A param consumed by both a None-grad slot (CE targets) and a real
         consumer must still yield its accumulated grad."""

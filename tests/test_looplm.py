@@ -251,6 +251,13 @@ def test_loop_plan_gauge(recompute):
     assert plan == {"passes": 4, "blocks": 6, "applications": 24,
                     "recomputed_regions": 28 if recompute else 0,
                     "weight_casts": 1}
+    # a pass's cross-entropy reads the log-sum-exp its forward made: the
+    # first forward's on the ordinary tape, the second's in a region
+    ce = observe.get_registry().get("singa_cross_entropy")
+    assert ce.value(targets="integer",
+                    lse="rebuilt" if recompute else "kept") == 1
+    assert ce.value(targets="integer",
+                    lse="kept" if recompute else "rebuilt") == 0
 
 
 # ---- the step program: scopes, casts, kernels --------------------------------
@@ -269,12 +276,12 @@ def test_scopes_name_each_pass_and_the_recomputed_work():
         assert has(f"bwd/ut{t}/TransformerBlock_2/fc2/")
         assert has(f"recompute/ut{t}/TransformerBlock_1/fc_gate/")
     assert not has("ut4/")
-    # (the replayed cross-entropy's forward feeds nothing: its hand-written
-    # backward reads the logits, so jax drops it and only the head's matmul
-    # is done again)
+    # (of the replayed cross-entropy only the log-sum-exp feeds anything:
+    # its hand-written backward reads that and the logits, so jax drops the
+    # rest and the second forward is the head's matmul and those two sums)
     for scope in ("head/", "exit_gate/", "loop_loss/", "bwd/head/",
                   "bwd/exit_gate/", "bwd/loop_loss/", "recompute/head/",
-                  "opt/", "tok_embed/"):
+                  "recompute/loop_loss/", "opt/", "tok_embed/"):
         assert has(scope), scope
     # nothing of a replay is named like the first forward, or nested
     assert not any("recompute" in n.split("/", 1)[1] for n in names

@@ -110,6 +110,23 @@ def test_hand_written_backward_runs_under_its_forward_scope(step):
     assert ("pos_embed",) in _paths(table, "bwd")
 
 
+def test_the_loss_takes_the_logits_as_the_head_wrote_them(step):
+    """The integer-target cross-entropy of the GPT step asks for no other
+    view of the (B, S, V) logits and gathers nothing from an array of their
+    size: on the chip a 2-D view is a relayout of them, and the gather read
+    a log-probability tensor written out for it (ISSUE 31). From the
+    lowered text; what the chip's compiler then forms of the logits' size
+    is `test_tpu_compile.py`'s to say."""
+    import re
+    m, _table, _ = step
+    text = m.lower_step().as_text()
+    logits = re.compile(r"tensor<(2x32x211|64x211)xf32>")
+    assert logits.search(text)              # the head's output is there
+    for line in text.splitlines():
+        if logits.search(line):
+            assert not re.search(r"gather|dynamic_slice|reshape", line), line
+
+
 def test_operator_outside_any_scope_reads_as_its_own_name():
     import jax
     from singa_tpu import autograd

@@ -143,6 +143,59 @@ def test_softmax_ce_fused_pair(dev, rng):
     assert np.allclose(np.asarray(ce), want, rtol=1e-4)
 
 
+def _ce_case(rng, shape, dtype):
+    import jax.numpy as jnp
+    z = jnp.asarray(rng.randn(*shape).astype(np.float32) * 3).astype(dtype)
+    t = jnp.asarray(rng.randint(0, shape[-1], shape[:-1]).astype(np.int32))
+    return z, t
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("shape", [(6, 11), (2, 5, 11)],
+                         ids=["rank2", "rank3"])
+def test_softmax_ce_integer_targets_against_log_softmax(rng, shape, dtype):
+    """lse - logits[target] and exp(logits - lse) - onehot against jax's
+    own -log_softmax(z)[t] and its gradient, taken in fp32 on the same
+    inputs; with the forward's lse handed on and without."""
+    import jax
+    import jax.numpy as jnp
+    z, t = _ce_case(rng, shape, dtype)
+    plain = lambda z: -jnp.take_along_axis(
+        jax.nn.log_softmax(z.astype(jnp.float32)), t[..., None], -1)[..., 0]
+    want = plain(z)
+    want_g = jax.grad(lambda z: plain(z).sum())(z.astype(jnp.float32))
+    tol = 1e-6 if dtype == "float32" else 4e-2     # bf16: 8 bits of an lse
+    lse = tensor.softmax_lse(z)
+    assert lse.shape == shape[:-1] and lse.dtype == z.dtype
+    for kept in (None, lse):
+        ce = tensor.softmax_cross_entropy_fwd(z, t, kept)
+        g = tensor.softmax_cross_entropy_bwd(z, t, kept)
+        assert ce.shape == shape[:-1] and ce.dtype == z.dtype
+        assert g.shape == shape and g.dtype == z.dtype
+        assert np.allclose(np.asarray(ce, np.float32), want, rtol=tol,
+                           atol=tol)
+        assert np.allclose(np.asarray(g, np.float32), want_g, atol=tol)
+
+
+@pytest.mark.parametrize("soft", [False, True], ids=["one_hot", "soft"])
+def test_softmax_ce_dense_targets_are_the_formulas_they_were(rng, soft):
+    """A distribution as targets keeps the log-probability form, bit for
+    bit: -sum(t * (z - lse)) and softmax(z) - t."""
+    import jax
+    import jax.numpy as jnp
+    z, t = _ce_case(rng, (2, 5, 11), "float32")
+    d = jax.nn.softmax(jnp.asarray(rng.randn(2, 5, 11).astype(np.float32))) \
+        if soft else jax.nn.one_hot(t, 11, dtype=jnp.float32)
+    assert not tensor.targets_are_indices(z, d)
+    assert tensor.targets_are_indices(z, t)
+    logp = z - jax.scipy.special.logsumexp(z, axis=-1, keepdims=True)
+    for kept in (None, tensor.softmax_lse(z)):
+        assert np.array_equal(tensor.softmax_cross_entropy_fwd(z, d, kept),
+                              -jnp.sum(d * logp, axis=-1))
+        assert np.array_equal(tensor.softmax_cross_entropy_bwd(z, d, kept),
+                              jax.nn.softmax(z, axis=-1) - d)
+
+
 def test_astype_l1_l2(dev):
     t = tensor.from_numpy(np.array([3.0, 4.0], np.float32), dev)
     h = t.as_type(tensor.float16)
