@@ -17,6 +17,7 @@ tracing unchanged — Model's graph mode simply traces one step (model.py).
 from __future__ import annotations
 
 import contextlib
+import math
 from collections import deque
 
 import numpy as np
@@ -1316,13 +1317,13 @@ class _FlashAttention(Operator):
     """Fused attention on the tape; forward is the Pallas flash kernel (or
     its reference fallback), backward is its custom_vjp (ops/attention.py)."""
 
-    def __init__(self, causal=False):
+    def __init__(self, causal=False, window=None):
         super().__init__()
-        self.causal = causal
+        self.causal, self.window = causal, window
 
     def forward(self, q, k, v):
         from .ops.attention import flash_attention
-        return flash_attention(q, k, v, self.causal)
+        return flash_attention(q, k, v, self.causal, window=self.window)
 
 
 class _RingAttention(Operator):
@@ -1605,22 +1606,56 @@ def swiglu(gate, up):
     return SwiGLU()(gate, up)
 
 
-def attention(q, k, v, causal=False, seq_axis=None):
+def attention(q, k, v, causal=False, seq_axis=None, window=None):
     """Fused attention (B,H,S,D); seq_axis names a mesh axis for ring
-    (sequence-parallel) execution."""
+    (sequence-parallel) execution. `window` (with `causal`; not on the
+    ring): a query sees its last `window` keys, itself among them."""
     if seq_axis is not None:
+        assert window is None, "ring attention takes no sliding window"
         return _RingAttention(seq_axis, causal)(q, k, v)
-    return _FlashAttention(causal)(q, k, v)
+    return _FlashAttention(causal, window)(q, k, v)
 
 
-def rope_tables(positions, dim, theta=10000.0):
+def yarn_frequencies(dim, theta, factor, original_max, beta_fast=32.0,
+                     beta_slow=1.0):
+    """(dim/2,) rotary frequencies under YaRN ("NTK by parts"): a pair that
+    turns more than `beta_fast` times over the `original_max` positions
+    keeps theta^(-2i/dim), one that turns less than `beta_slow` times takes
+    it divided by `factor`, the pairs between the two blend linearly in i."""
+    half = dim // 2
+    plain = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    # the pair index at which a frequency makes `turns` turns over the
+    # original context
+    at = lambda turns: dim * math.log(
+        original_max / (turns * 2 * math.pi)) / (2 * math.log(theta))
+    # (this module's own `max` and `min` are tape operators)
+    lo, hi = (int(np.clip(f(at(b)), 0, dim - 1)) for f, b in (
+        (math.floor, beta_fast), (math.ceil, beta_slow)))
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - lo)
+                    / (hi - lo or 1e-3), 0.0, 1.0)
+    return ramp * plain / factor + (1.0 - ramp) * plain
+
+
+def rope_tables(positions, dim, theta=10000.0, scaling=None):
     """(cos, sin) tables for NeoX-style rotary embeddings: positions (S,)
-    -> (S, dim) with the two half-blocks duplicated (cos = [c | c])."""
-    inv = theta ** (-jnp.arange(0, dim // 2, dtype=jnp.float32)
-                    / (dim // 2))
+    -> (S, dim) with the two half-blocks duplicated (cos = [c | c]).
+    `scaling`: None, or YaRN's {"factor", "original_max_position_embeddings",
+    "beta_fast", "beta_slow", "attention_factor"}: blended frequencies
+    (yarn_frequencies) and `attention_factor` on cos and sin."""
+    if scaling is None:
+        inv, scale = theta ** (-jnp.arange(0, dim // 2, dtype=jnp.float32)
+                               / (dim // 2)), None
+    else:
+        inv = yarn_frequencies(
+            dim, theta, scaling["factor"],
+            scaling["original_max_position_embeddings"],
+            scaling.get("beta_fast", 32.0), scaling.get("beta_slow", 1.0))
+        scale = scaling.get("attention_factor")
     ang = positions.astype(jnp.float32)[:, None] * inv[None, :]  # (S,D/2)
     cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], axis=-1)
     sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], axis=-1)
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
     return cos, sin
 
 
@@ -1640,10 +1675,11 @@ class Rope(Operator):
     `seq_axis` offsets positions by axis_index * S_local under sequence
     parallelism, the same pattern as _PosSlice for the learned table."""
 
-    def __init__(self, theta=10000.0, seq_axis=None):
+    def __init__(self, theta=10000.0, seq_axis=None, scaling=None):
         super().__init__("Rope")
         self.theta = float(theta)
         self.seq_axis = seq_axis
+        self.scaling = scaling      # rope_tables' `scaling` (YaRN)
 
     def forward(self, x):
         from jax import lax
@@ -1655,7 +1691,7 @@ class Rope(Operator):
             except NameError:
                 off = 0
         pos = jnp.arange(S) + off
-        cos, sin = rope_tables(pos, x.shape[-1], self.theta)
+        cos, sin = rope_tables(pos, x.shape[-1], self.theta, self.scaling)
         return apply_rope(x, cos, sin)
 
 

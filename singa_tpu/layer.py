@@ -669,13 +669,22 @@ class MultiHeadAttention(Layer):
     = 1 is MQA): Wk/Wv project to num_kv_heads*D and each KV head
     serves num_heads/num_kv_heads query heads. This shrinks the KV
     params AND — the real point — the serving KV cache, which is the
-    binding term of the decode roofline."""
+    binding term of the decode roofline.
+
+    `head_dim`: the width of a head where it is not `E / num_heads` (q is
+    then num_heads x head_dim wide, Wo maps that back to E). `window`
+    (with `causal`): a query sees its last `window` keys, itself among
+    them; the flash kernels skip the tiles left of it. `rope_scaling`:
+    YaRN's parameters for the rotary tables (autograd.rope_tables)."""
 
     def __init__(self, num_heads, causal=False, seq_axis=None, tp_axis=None,
                  bias=False, num_kv_heads=None, rope=False,
-                 rope_theta=10000.0, name=None):
+                 rope_theta=10000.0, head_dim=None, window=None,
+                 rope_scaling=None, name=None):
         super().__init__(name)
         self.num_heads = num_heads
+        self.head_dim, self.window = head_dim, window
+        self.rope_scaling = rope_scaling
         self.rope = bool(rope)          # rotary q/k (RoFormer/NeoX)
         self.rope_theta = rope_theta
         self.num_kv_heads = num_kv_heads or num_heads
@@ -689,9 +698,9 @@ class MultiHeadAttention(Layer):
 
     def initialize(self, x):
         e = x.shape[-1]
-        assert e % self.num_heads == 0
-        d = e // self.num_heads
-        kv_e = self.num_kv_heads * d
+        assert self.head_dim or e % self.num_heads == 0
+        d = self.head_dim or e // self.num_heads
+        q_e, kv_e = self.num_heads * d, self.num_kv_heads * d
         spec_col = spec_row = spec_colb = None
         if self.tp_axis is not None:
             from jax.sharding import PartitionSpec as P
@@ -699,13 +708,15 @@ class MultiHeadAttention(Layer):
             spec_row = P(self.tp_axis, None)
             spec_colb = P(self.tp_axis)
         for attr in ("Wq", "Wk", "Wv", "Wo"):
-            out_e = kv_e if attr in ("Wk", "Wv") else e
-            W = Tensor((e, out_e), device=x.device, dtype=x.dtype)
+            out_e = kv_e if attr in ("Wk", "Wv") else q_e
+            W = Tensor((q_e, e) if attr == "Wo" else (e, out_e),
+                       device=x.device, dtype=x.dtype)
             initializer.glorot_uniform(W)
             W.spec = spec_row if attr == "Wo" else spec_col
             self._register_param(attr, W)
             if self.use_bias:
-                b = Tensor((out_e,), device=x.device, dtype=x.dtype)
+                b = Tensor((e if attr == "Wo" else out_e,), device=x.device,
+                           dtype=x.dtype)
                 b.set_value(0.0)
                 # q/k/v biases shard with the heads (column); the output
                 # bias is added after the row-parallel psum: replicated
@@ -751,16 +762,17 @@ class MultiHeadAttention(Layer):
         if self.rope:
             # rotate q/k before the kv-head repeat (rotation is per-head
             # identical, so rotating the Hkv heads is cheaper)
-            rop = autograd.Rope(self.rope_theta, self.seq_axis)
-            q, k = rop(q), autograd.Rope(self.rope_theta,
-                                         self.seq_axis)(k)
+            rop = autograd.Rope(self.rope_theta, self.seq_axis,
+                                self.rope_scaling)
+            q, k = rop(q), autograd.Rope(self.rope_theta, self.seq_axis,
+                                         self.rope_scaling)(k)
         if grp > 1:
             # GQA: each kv head serves `grp` consecutive query heads
             # (repeat on the head axis; XLA folds the broadcast)
             k = autograd.UpSample([1, grp, 1, 1])(k)
             v = autograd.UpSample([1, grp, 1, 1])(v)
         o = autograd.attention(q, k, v, causal=self.causal,
-                               seq_axis=self.seq_axis)
+                               seq_axis=self.seq_axis, window=self.window)
         o = autograd.transpose(o, (0, 2, 1, 3))
         o = autograd.reshape(o, (B, S, -1))
         y = autograd.matmul(o, Wo)
@@ -784,14 +796,19 @@ class TransformerBlock(Layer):
     (`fc_gate`, `fc1`, `fc2`); `ffn_dim` sets the feed-forward's width where
     it is no multiple of the block's; `ffn_bias=False` drops its biases;
     `post_norm=True` adds a norm on each branch's output before it joins
-    the residual (`ln1_post`, `ln2_post`: "sandwich" norms)."""
+    the residual (`ln1_post`, `ln2_post`: "sandwich" norms). `head_dim`,
+    `window` and `rope_scaling` go to the attention as they are.
+    `moe_dropless=True` takes `DroplessMoE` for the expert layer (SiLU-gated
+    experts of width `ffn_dim`, no capacity, nothing dropped), of which this
+    device holds `moe_held` experts from `moe_offset` on (None: all)."""
 
     def __init__(self, num_heads, mlp_ratio=4, causal=True, seq_axis=None,
                  tp_axis=None, attn_bias=False, moe_experts=0, moe_k=1,
                  ep_axis=None, moe_capacity_factor=1.25, num_kv_heads=None,
                  rope=False, rope_theta=10000.0, norm="layer", norm_eps=None,
                  ffn="gelu", ffn_dim=None, ffn_bias=True, post_norm=False,
-                 name=None):
+                 head_dim=None, window=None, rope_scaling=None,
+                 moe_dropless=False, moe_held=None, moe_offset=0, name=None):
         super().__init__(name)
         assert norm in ("layer", "rms") and ffn in ("gelu", "swiglu"), \
             (norm, ffn)
@@ -804,7 +821,9 @@ class TransformerBlock(Layer):
                                        seq_axis=seq_axis, tp_axis=tp_axis,
                                        bias=attn_bias,
                                        num_kv_heads=num_kv_heads,
-                                       rope=rope, rope_theta=rope_theta)
+                                       rope=rope, rope_theta=rope_theta,
+                                       head_dim=head_dim, window=window,
+                                       rope_scaling=rope_scaling)
         self.ln2 = make_norm()
         self.post_norm = post_norm
         if post_norm:
@@ -813,7 +832,10 @@ class TransformerBlock(Layer):
         self.ffn, self.ffn_dim, self.ffn_bias = ffn, ffn_dim, ffn_bias
         self.tp_axis = tp_axis
         self.moe_experts = moe_experts
-        if moe_experts:
+        if moe_experts and moe_dropless:
+            self.moe = DroplessMoE(moe_experts, k=moe_k, held=moe_held,
+                                   offset=moe_offset)
+        elif moe_experts:
             self.moe = MoE(moe_experts, capacity_factor=moe_capacity_factor,
                            ep_axis=ep_axis, k=moe_k)
 
@@ -844,8 +866,79 @@ class TransformerBlock(Layer):
         return autograd.add(x, self.ln2_post(m) if self.post_norm else m)
 
 
+class DroplessMoE(Layer):
+    """Mixture-of-experts feed-forward that drops nothing: every token goes
+    to its top-`k` of `num_experts` experts (router and softmax in fp32
+    over all of them, the k gates renormalised to sum to 1), the (token,
+    choice) pairs are sorted by expert and the SiLU-gated experts
+    (silu(x Wg_e) * (x Wu_e)) Wd_e run as grouped matrix products over the
+    rows really routed (parallel/moe.py `dropless_moe`). No capacity, no
+    (tokens, experts, capacity) tensor, no auxiliary loss.
+
+    `held`, `offset`: this device holds experts offset .. offset + held - 1
+    of `num_experts` (None: all). The router still scores all of them; the
+    layer computes its own experts' part of the sum and hands that partial
+    sum on: summed over the devices that share the layer it is the whole
+    layer. Nothing here stands in for the other devices or the exchange.
+    After forward `self.rows` holds the rows routed to each held expert
+    (float32, off the tape)."""
+
+    def __init__(self, num_experts, hidden=None, k=1, held=None, offset=0,
+                 name=None):
+        super().__init__(name)
+        self.num_experts, self.hidden, self.k = num_experts, hidden, k
+        self.held = num_experts if held is None else held
+        self.offset = offset
+        assert 0 <= offset and offset + self.held <= num_experts, \
+            (num_experts, held, offset)
+        self.rows = None
+
+    def initialize(self, x):
+        d, h, H = x.shape[-1], self.hidden or 4 * x.shape[-1], self.held
+        Wr = Tensor((d, self.num_experts), device=x.device, dtype=x.dtype)
+        initializer.glorot_uniform(Wr)
+        self._register_param("Wr", Wr)
+        for attr, shape, fan in (("Wg", (H, d, h), d), ("Wu", (H, d, h), d),
+                                 ("Wd", (H, h, d), h)):
+            W = Tensor(shape, device=x.device, dtype=x.dtype)
+            W.gaussian(0.0, (2.0 / fan) ** 0.5)
+            self._register_param(attr, W)
+
+    def forward(self, x):
+        # the router reads x and its own weight as they are (fp32 under
+        # `amp`); the experts take the compute dtype
+        Wg, Wu, Wd = autograd.compute_cast(self.Wg, self.Wu, self.Wd)
+        y, rows = _DroplessMoEOp(self.k, self.offset)(
+            x, self.Wr, Wg, Wu, Wd)
+        # off the tape: a region that hands the rows out must not walk the
+        # layer's backward a second time for them
+        self.rows = Tensor(data=rows.data, device=x.device,
+                           requires_grad=False)
+        return y
+
+
+class _DroplessMoEOp(autograd.Operator):
+    def __init__(self, k, offset):
+        super().__init__("DroplessMoE")
+        self.k, self.offset = k, offset
+
+    def forward(self, x, Wr, Wg, Wu, Wd):
+        from .parallel.moe import dropless_moe
+        y, rows = dropless_moe(x.reshape(-1, x.shape[-1]), Wr, Wg, Wu, Wd,
+                               self.k, self.offset)
+        return y.reshape(x.shape).astype(x.dtype), rows
+
+
 class MoE(Layer):
     """Switch-style mixture-of-experts FFN over (..., D) activations.
+
+    THIS layer's routing has a capacity and DROPS on overflow: a (token,
+    choice) pair that finds its expert's queue full passes through with no
+    expert output, on one device as under `ep_axis` (`overflow` says how
+    many). `DroplessMoE` is the routing that drops nothing (sorted
+    dispatch, grouped products; one device's share of the experts, no
+    exchange across devices yet). The ONNX exporter refuses both
+    (sonnx/frontend.py: a model holding either layer has no ONNX form).
 
     `ep_axis` shards experts over that mesh axis (all_to_all dispatch,
     parallel/moe.py); out of mesh scope it falls back to the dense path.

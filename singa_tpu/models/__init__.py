@@ -9,7 +9,7 @@ dispatch in every model file; here it lives once in `base.Classifier`).
 
 from .base import Classifier  # noqa: F401
 from . import (mlp, cnn, alexnet, resnet, xceptionnet, transformer,  # noqa: F401
-               looplm)
+               looplm, mellum)
 
 _REGISTRY = {
     "mlp": mlp.create_model,
@@ -25,6 +25,7 @@ _REGISTRY = {
     "gpt": transformer.create_model,
     "gpt_pipe": transformer.create_pipelined,
     "looplm": looplm.create_model,
+    "mellum": mellum.create_model,
 }
 
 

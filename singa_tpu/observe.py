@@ -864,21 +864,27 @@ def record_attention_dispatch(site: str, path: str):
             "(kernel|interpret|reference)").inc(site=site, path=path)
 
 
-def record_flash_tiles(site: str, visited: int, masked: int, square: int):
+def record_flash_tiles(site: str, visited: int, masked: int, square: int,
+                       skipped: int = 0, window: int | None = None):
     """The tile schedule one flash call site was traced with
     (ops.attention.flash_plan), in sub-tiles a (batch x head) row: how
     many the kernel computes, how many of those add a mask, and the whole
     score square. Causal calls visit little over half and mask only the
-    sub-tiles the diagonal crosses; others visit all and mask none. A
-    gauge: it holds the site's latest trace."""
+    sub-tiles the diagonal crosses; others visit all and mask none. Under
+    a sliding window (`window`: its width, 0 for none) `skipped` counts
+    the sub-tiles of blocks under the diagonal that lie wholly left of
+    the window, neither computed nor fetched. A gauge: it holds the site's
+    latest trace."""
     assert site in ATTN_SITES, site
     if not _enabled:
         return
     g = gauge("singa_flash_tiles",
               "sub-tiles a (batch x head) row in the latest traced flash "
-              "call, by site and kind (visited|masked|square)")
+              "call, by site and kind (visited|masked|square|skipped: left "
+              "of a sliding window; window: its width, 0 for none)")
     for kind, n in (("visited", visited), ("masked", masked),
-                    ("square", square)):
+                    ("square", square), ("skipped", skipped),
+                    ("window", window or 0)):
         g.set(n, site=site, kind=kind)
 
 

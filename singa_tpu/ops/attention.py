@@ -33,23 +33,30 @@ _NEG_INF = -1e30
 
 
 def _causal_mask(sq, sk, q_off=0, k_off=0, dtype=jnp.float32,
-                 transposed=False):
-    """Additive mask, (sq, sk), or (sk, sq) for scores held keys x queries."""
+                 transposed=False, window=None):
+    """Additive mask, (sq, sk), or (sk, sq) for scores held keys x queries.
+    With `window` a query sees its last `window` keys, itself among them:
+    key positions at or under its own and within window - 1 of it."""
     shape, q_ax = ((sk, sq), 1) if transposed else ((sq, sk), 0)
     q_pos = q_off + lax.broadcasted_iota(jnp.int32, shape, q_ax)
     k_pos = k_off + lax.broadcasted_iota(jnp.int32, shape, 1 - q_ax)
-    return jnp.where(k_pos > q_pos, _NEG_INF, 0.0).astype(dtype)
+    hidden = k_pos > q_pos
+    if window is not None:
+        hidden = hidden | (q_pos - k_pos >= window)
+    return jnp.where(hidden, _NEG_INF, 0.0).astype(dtype)
 
 
 # ======================= 1. reference ====================================
 
-def attention_reference(q, k, v, causal=False, scale=None):
-    """q,k,v: (B, H, S, D). Returns (B, H, Sq, D)."""
+def attention_reference(q, k, v, causal=False, scale=None, window=None):
+    """q,k,v: (B, H, S, D). Returns (B, H, Sq, D). `window` (causal only):
+    a query sees its last `window` keys."""
     d = q.shape[-1]
     scale = scale if scale is not None else d ** -0.5
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
     if causal:
-        s = s + _causal_mask(q.shape[2], k.shape[2], dtype=s.dtype)
+        s = s + _causal_mask(q.shape[2], k.shape[2], dtype=s.dtype,
+                             window=window)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
@@ -92,6 +99,10 @@ def attention_reference(q, k, v, causal=False, scale=None):
 # None = blocks from flash_plan; an explicit block is honoured if it tiles.
 DEFAULT_BLOCK_Q = None
 DEFAULT_BLOCK_K = None
+
+# What a kernel's name ends in when it works under a sliding window: the
+# HLO instruction and its `op_name` tell a windowed call from a causal one.
+WINDOW_SUFFIX = "_win"
 
 # Largest tile a grid step holds. Bands are lane multiples (the backward
 # slices its per-row statistics along lanes); a block that no band divides
@@ -136,10 +147,15 @@ class FlashPlan(NamedTuple):
     """`fwd` None: no block tiles the call, it takes the reference path
     (`ok` False). `bwd` None: the backward takes the blockwise XLA path.
     `fused`: one backward kernel (dq accumulated in VMEM) instead of the
-    dq / dkv pair."""
+    dq / dkv pair. `window`: the sliding window the schedules were made
+    for (None: none, or one that reaches every key); `skipped` (forward,
+    backward): sub-tiles of the blocks at or under the diagonal that lie
+    wholly left of the window, which no grid step computes or fetches."""
     fwd: FlashTiles | None
     bwd: FlashTiles | None
     fused: bool
+    window: int | None = None
+    skipped: tuple = (0, 0)
 
     @property
     def ok(self):
@@ -161,30 +177,142 @@ def _tile_counts(sq, sk, uq, uk, causal):
     return visited, masked, rows * cols
 
 
+def _band(bq, bk, bands):
+    """The first of `bands` that divides both blocks; 0: none does."""
+    return next((t for t in bands if bq % t == 0 and bk % t == 0), 0)
+
+
 def _tiles(sq, sk, bq, bk, causal, bands):
     """The schedule at blocks (bq, bk), in the first of `bands` that
     divides both."""
-    band = next((t for t in bands if bq % t == 0 and bk % t == 0), 0)
+    band = _band(bq, bk, bands)
     return FlashTiles(bq, bk, band, *_tile_counts(
         sq, sk, band or bq, band or bk, causal))
 
 
-def flash_plan(sq, sk, d, causal, dtype, block_q=None, block_k=None):
+# ---- a sliding window under the causal mask ---------------------------------
+# A query sees its last `window` keys, itself among them. Of a q block's row
+# of (block_q, block_k) tiles only those the band of seen keys touches are
+# grid steps at all: the grid's streamed axis is as long as the most tiles a
+# block's band touches (`_window_steps`), it counts from the first such tile
+# (`_k_range` / `_q_range`), and the index maps clamp to the last, so a tile
+# wholly left of the window is neither computed nor fetched. Where blocks
+# are square and the window a multiple of them, the tile on the window's
+# left edge is the mirror of the one on the diagonal ("edge": bands stop at
+# it, one band x band mask); elsewhere a tile the window's edge or the
+# diagonal crosses goes whole under one mask built from the step's offsets.
+
+def _k_range(j, bq, bk, nk, window, mx=max, mn=min):
+    """(first, last) k block that some row of q block j sees; `mx`, `mn`:
+    jnp's where j is traced."""
+    return (mx(j * bq - window + 1, 0) // bk,
+            mn(((j + 1) * bq - 1) // bk, nk - 1))
+
+
+def _q_range(kb, bq, bk, nq, window, mx=max, mn=min):
+    """(first, last) q block some of whose rows see k block kb."""
+    return (mn((kb * bk) // bq, nq - 1),
+            mn(((kb + 1) * bk + window - 2) // bq, nq - 1))
+
+
+def _window_steps(sq, sk, bq, bk, window):
+    """(k tiles a q block's sweep holds at most, q tiles a k block's)."""
+    nq, nk = sq // bq, sk // bk
+    span = lambda rng, n, m: max(
+        hi - lo + 1 for lo, hi in (rng(i, bq, bk, m, window)
+                                   for i in range(n)))
+    return span(_k_range, nq, nk), span(_q_range, nk, nq)
+
+
+def _not(b):
+    return (not b) if isinstance(b, bool) else jnp.logical_not(b)
+
+
+def _window_kinds(j, kb, bq, bk, nq, nk, window):
+    """{kind: whether tile (q block j, k block kb) is worked as that kind}
+    under a causal window, for python numbers and for traced ones alike;
+    a tile of no kind is not visited. "full": no mask; "diag": the square
+    tile on the diagonal, bands stop at it; "edge": the square tile on the
+    window's left edge, its mirror; "crossed": one mask over the tile."""
+    live = (kb * bk <= j * bq + bq - 1) & ((kb + 1) * bk - 1 > j * bq - window) \
+        & (j <= nq - 1) & (kb <= nk - 1)
+    square = bq == bk
+    if square and window % bq == 0:
+        n = window // bq
+        return {"diag": live & (kb == j), "edge": live & (j - kb == n),
+                "full": live & (kb < j) & (j - kb < n)}
+    whole = ((kb + 1) * bk - 1 <= j * bq) & (j * bq + bq - 1 - kb * bk < window)
+    if square and bq <= window:
+        off = live & (kb != j)
+        return {"diag": live & (kb == j), "full": off & whole,
+                "crossed": off & _not(whole)}
+    return {"full": live & whole, "crossed": live & _not(whole)}
+
+
+def _window_counts(sq, sk, bq, bk, band, window):
+    """(visited, masked, square, skipped) in sub-tiles of band x band
+    (block_q x block_k when band is 0), as the kernels work the tiles:
+    a "diag" or an "edge" tile of n bands computes n (n + 1) / 2 and masks
+    n, a "crossed" one computes and masks all of its own."""
+    nq, nk = sq // bq, sk // bk
+    n = bq // band if band else 1
+    per = n * (bk // band if band else 1)
+    visited = masked = skipped = 0
+    for j in range(nq):
+        for kb in range(min(nk, ((j + 1) * bq - 1) // bk + 1)):
+            kinds = _window_kinds(j, kb, bq, bk, nq, nk, window)
+            kind = next((k for k, on in kinds.items() if on), None)
+            if kind is None:
+                skipped += per
+            elif kind == "full":
+                visited += per
+            elif kind == "crossed":
+                visited, masked = visited + per, masked + per
+            else:
+                visited, masked = visited + n * (n + 1) // 2, masked + n
+    return visited, masked, (sq // (band or bq)) * (sk // (band or bk)), \
+        skipped
+
+
+def flash_window(window, causal, sk):
+    """The window a call is scheduled for: None where there is none or it
+    reaches every key (the plain causal program, to the instruction)."""
+    if window is None:
+        return None
+    assert causal and window >= 1, \
+        f"a sliding window needs the causal mask and a width >= 1: {window}"
+    return None if window >= sk else int(window)
+
+
+def flash_plan(sq, sk, d, causal, dtype, block_q=None, block_k=None,
+               window=None):
     """The tile schedule of one flash_attention call, forward and backward,
     from what the call can see. Blocks: an explicit block is honoured when
     it tiles its sequence on 8-sublane alignment (else ok=False -> the
     reference path); None takes the largest evenly-tiling block at or
-    under 1024, so S <= 1024 is one grid step a (batch x head) row and
-    S = 384 or 896 still run the kernel."""
+    under 1024 (under a sliding window: at or under the window, so that
+    the work follows it), so S <= 1024 is one grid step a (batch x head)
+    row and S = 384 or 896 still run the kernel."""
+    window = flash_window(window, causal, sk)
+    target = _BLOCK_TARGET if window is None \
+        else min(_BLOCK_TARGET, max(128, window))
+
     def pick(s, explicit):
         if explicit is None:
-            return _fit_block(s, _BLOCK_TARGET)
+            # under a window first a block that divides it too: then the
+            # window's edge falls on tile boundaries ("edge" tiles)
+            return window and next(
+                (b for b in range(target - target % 8, 127, -8)
+                 if s % b == 0 and window % b == 0), None) \
+                or _fit_block(s, target)
         b = min(explicit, s)
         return b if s % b == 0 and b % 8 == 0 else None
 
     bq, bk = pick(sq, block_q), pick(sk, block_k)
     if bq is None or bk is None:
         return FlashPlan(None, None, False)
+    if window is not None:
+        return _window_plan(sq, sk, d, dtype, bq, bk, window)
     # band heights as measured (comment above): 256, but 128 on the
     # forward's diagonal tile once K streams over the grid
     bwd_bands = (256, 128)
@@ -199,9 +327,34 @@ def flash_plan(sq, sk, d, causal, dtype, block_q=None, block_k=None):
             else None
     # the fused backward keeps dq for a whole (batch x head) row in VMEM:
     # an f32 accumulator and the double-buffered output row
-    itemsize = jnp.dtype(dtype).itemsize
-    fused = sq * d * (4 + 2 * itemsize) <= _FUSED_DQ_BYTES_CAP
-    return FlashPlan(fwd, bwd, fused)
+    return FlashPlan(fwd, bwd, _fused_fits(sq, d, dtype))
+
+
+def _fused_fits(sq, d, dtype):
+    return sq * d * (4 + 2 * jnp.dtype(dtype).itemsize) \
+        <= _FUSED_DQ_BYTES_CAP
+
+
+def _window_plan(sq, sk, d, dtype, bq, bk, window):
+    """flash_plan's schedules under a window: the same bands; a backward
+    whose blocks no band divides and that are too large to go whole takes
+    the blockwise XLA path."""
+    bwd_bands = (256, 128)
+
+    def tiles(bands):
+        band = _band(bq, bk, bands)
+        *counts, skipped = _window_counts(sq, sk, bq, bk, band, window)
+        return FlashTiles(bq, bk, band, *counts), skipped
+
+    # the forward's bands as without a window (on the chip, PR 32, at
+    # (1, 32, 8192, 128), W = 1024: 128 2.25 ms, 256 2.68, 512 2.54;
+    # blocks of 512 3.00, of 256 6.06)
+    fwd, fwd_skipped = tiles(bwd_bands if bk == sk else (128,))
+    bwd, bwd_skipped = tiles(bwd_bands)
+    if not bwd.band and max(bq, bk) > _UNBANDED_BWD_CAP:
+        bwd, bwd_skipped = None, 0
+    return FlashPlan(fwd, bwd, _fused_fits(sq, d, dtype), window,
+                     (fwd_skipped, bwd_skipped))
 
 
 try:  # import here so CPU-only environments still import the module
@@ -232,14 +385,19 @@ def _dot_nt(a, b):
 
 
 def _visit_by_diagonal(causal, square, single, j, kb, block_q, block_k,
-                       visit):
+                       visit, window=None, nq=None, nk=None):
     """Run `visit(kind)` for this grid step's (block_q, block_k) tile:
     "full" (no mask), "diag" (square blocks, the tile on the diagonal:
     bands stop at it), "crossed" (blocks that are not square: one mask
     over the tile, built from the step's offsets), or nothing for a tile
     above the diagonal. The DMA for skipped tiles is elided too:
-    _causal_kv_map / _causal_q_map re-address the last needed block."""
-    if not causal:
+    _causal_kv_map / _causal_q_map re-address the last needed block.
+    Under a `window` the kinds are _window_kinds'."""
+    if window is not None:
+        for kind, on in _window_kinds(j, kb, block_q, block_k, nq, nk,
+                                      window).items():
+            pl.when(on)(functools.partial(visit, kind))
+    elif not causal:
         visit("full")
     elif square and single:
         visit("diag")
@@ -254,7 +412,8 @@ def _visit_by_diagonal(causal, square, single, j, kb, block_q, block_k,
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
-                      nq, nk, block_q, block_k, band, causal):
+                      nq, nk, block_q, block_k, band, causal, window,
+                      steps):
     """Grid: (batch*heads, q_blocks, k_blocks) — K/V blocks STREAM through
     VMEM one (block_k, D) tile at a time (no whole-row residency, so
     sequence length is bounded by HBM, not VMEM). Inside a step the query
@@ -262,15 +421,20 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
     see in this tile (scores of the unmasked columns and of the diagonal
     sub-tile share one row maximum, so a band pays the statistics once,
     not once a sub-tile). With nk > 1 the online-softmax state (acc, m, l)
-    lives in VMEM scratch, which persists across the k grid dimension."""
+    lives in VMEM scratch, which persists across the k grid dimension:
+    `steps` entries, nk of them, or under a `window` as many as a q
+    block's window reaches, counted from the first k block it reaches."""
     j = pl.program_id(1)
-    kb = pl.program_id(2)
+    step = kb = pl.program_id(2)
+    if window is not None:
+        kb = step + _k_range(j, block_q, block_k, nk, window,
+                             jnp.maximum, jnp.minimum)[0]
     square = block_q == block_k
     f32 = jnp.float32
-    if nk > 1:
+    if steps > 1:
         acc_ref, m_ref, l_ref = scratch
 
-        @pl.when(kb == 0)
+        @pl.when(step == 0)
         def _init():
             m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
             l_ref[...] = jnp.zeros_like(l_ref)
@@ -279,6 +443,10 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
     # one mask serves every sub-tile on the diagonal: square, aligned
     diag_mask = _causal_mask(band or block_q, band or block_q) \
         if causal and square else None
+    # the tile on the window's left edge: its mirror, as static
+    edge_mask = _causal_mask(band or block_q, band or block_q, q_off=window,
+                             window=window) \
+        if window is not None and square and window % block_q == 0 else None
 
     def finish(rows, m, l, acc):
         l = jnp.maximum(l, 1e-20)
@@ -289,19 +457,23 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
     def visit(kind):
         # with K streamed a tile under the diagonal goes whole: its bands
         # would each reload the key tile into the MXU for no column saved
-        rows_per = block_q if kind == "full" and nk > 1 else band or block_q
+        rows_per = block_q if kind == "full" and steps > 1 \
+            else band or block_q
         for r in range(block_q // rows_per):
             lo, hi = r * rows_per, (r + 1) * rows_per
             rows = slice(lo, hi)
             if kind == "diag":
                 cols = ([(0, lo, None)] if lo else []) \
                     + [(lo, hi, diag_mask)]
+            elif kind == "edge":
+                cols = [(lo, hi, edge_mask)] \
+                    + ([(hi, block_k, None)] if hi < block_k else [])
             elif kind == "full":
                 cols = [(0, block_k, None)]
             else:
                 cols = [(0, block_k, _causal_mask(
                     rows_per, block_k, q_off=j * block_q + lo,
-                    k_off=kb * block_k))]
+                    k_off=kb * block_k, window=window))]
             # dots run in the INPUT dtype (bf16 inputs → native MXU rate;
             # upcasting to f32 first would run the matmul at the ~4x-slower
             # fp32 rate) and accumulate f32 via preferred_element_type; the
@@ -315,7 +487,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
                 ss.append(s if mask is None else s + mask)
             m_new = functools.reduce(
                 jnp.maximum, (jnp.max(s, axis=-1, keepdims=True) for s in ss))
-            if nk > 1:
+            if steps > 1:
                 m_prev = m_ref[rows, :][:, :1]
                 m_new = jnp.maximum(m_prev, m_new)
             ps = [jnp.exp(s - m_new) for s in ss]
@@ -324,7 +496,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
                 jnp.dot(p.astype(v_ref.dtype), v_ref[0, a:b, :],
                         preferred_element_type=f32)
                 for p, (a, b, _) in zip(ps, cols))
-            if nk == 1:         # the band has seen every column it may
+            if steps == 1:      # the band has seen every column it may
                 finish(rows, m_new, l_new, pv)
                 continue
             corr = jnp.exp(m_prev - m_new)
@@ -335,21 +507,30 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
             m_ref[rows, :] = jnp.broadcast_to(m_new, (rows_per, _STAT_LANES))
 
     _visit_by_diagonal(causal, square, nq == 1 and nk == 1, j, kb, block_q,
-                       block_k, visit)
+                       block_k, visit, window, nq, nk)
 
-    if nk > 1:
-        @pl.when(kb == nk - 1)
+    if steps > 1:
+        @pl.when(step == steps - 1)
         def _finish():
             finish(slice(None), m_ref[...][:, :1], l_ref[...][:, :1],
                    acc_ref[...])
 
 
-def _causal_kv_map(causal, block_q, block_k, nk):
+def _causal_kv_map(causal, block_q, block_k, nk, window=None):
     """K/V BlockSpec index map for grids with kb innermost after the q
     block index. Causal: kb is CLAMPED to this q block's diagonal block,
     so every fully-masked step re-addresses the last needed block and
     Pallas skips the DMA (the copy only fires when the block index
-    changes) — masked K/V tiles are neither computed nor streamed."""
+    changes) — masked K/V tiles are neither computed nor streamed.
+    Under a `window` the innermost index counts from the first k block
+    the q block's window reaches."""
+    if window is not None:
+        def wmap(i, j, step):
+            first, last = _k_range(j, block_q, block_k, nk, window,
+                                   jnp.maximum, jnp.minimum)
+            return (i, jnp.minimum(first + step, last), 0)
+
+        return wmap
     if not causal:
         return lambda i, j, kb: (i, kb, 0)
 
@@ -360,10 +541,19 @@ def _causal_kv_map(causal, block_q, block_k, nk):
     return kmap
 
 
-def _causal_q_map(causal, block_q, block_k):
+def _causal_q_map(causal, block_q, block_k, nq=None, window=None):
     """Q-side BlockSpec index map for the dK/dV grid (bh, kb, j): causal
     clamps j UP to the first unmasked q block for kb, so the leading
-    masked steps address the same tile and their DMA is elided."""
+    masked steps address the same tile and their DMA is elided. Under a
+    `window` the innermost index counts from that block and is clamped to
+    the last one whose rows still see kb."""
+    if window is not None:
+        def wmap(i, kb, step):
+            first, last = _q_range(kb, block_q, block_k, nq, window,
+                                   jnp.maximum, jnp.minimum)
+            return (i, jnp.minimum(first + step, last), 0)
+
+        return wmap
     if not causal:
         return lambda i, kb, j: (i, j, 0)
 
@@ -374,7 +564,7 @@ def _causal_q_map(causal, block_q, block_k):
     return qmap
 
 
-def _flash_fwd_pallas(q, k, v, causal, scale, tiles, interpret):
+def _flash_fwd_pallas(q, k, v, causal, scale, tiles, interpret, window=None):
     b, h, sq, d = q.shape
     sk = k.shape[2]
     bh = b * h
@@ -386,14 +576,18 @@ def _flash_fwd_pallas(q, k, v, causal, scale, tiles, interpret):
     vf = v.reshape(bh, sk, d)
     nk = sk // block_k
     nq = sq // block_q
+    steps, name = nk, "singa_flash_fwd"
+    if window is not None:
+        steps = _window_steps(sq, sk, block_q, block_k, window)[0]
+        name += WINDOW_SUFFIX
     kernel = functools.partial(
         _flash_fwd_kernel, nq=nq, nk=nk, block_q=block_q, block_k=block_k,
-        band=band, causal=causal)
-    kvmap = _causal_kv_map(causal, block_q, block_k, nk)
+        band=band, causal=causal, window=window, steps=steps)
+    kvmap = _causal_kv_map(causal, block_q, block_k, nk, window)
     out, lse = pl.pallas_call(
         kernel,
-        name="singa_flash_fwd",
-        grid=(bh, nq, nk),
+        name=name,
+        grid=(bh, nq, steps),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j, kb: (i, j, 0)),
             pl.BlockSpec((1, block_k, d), kvmap),
@@ -412,7 +606,7 @@ def _flash_fwd_pallas(q, k, v, causal, scale, tiles, interpret):
             pltpu.VMEM((block_q, d), jnp.float32),
             pltpu.VMEM((block_q, _STAT_LANES), jnp.float32),
             pltpu.VMEM((block_q, _STAT_LANES), jnp.float32),
-        ] if nk > 1 else [],
+        ] if steps > 1 else [],
         interpret=interpret,
     )(qf, kf, vf)
     return out.reshape(b, h, sq, d), lse[:, :, 0].reshape(b, h, sq)
@@ -420,7 +614,7 @@ def _flash_fwd_pallas(q, k, v, causal, scale, tiles, interpret):
 
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       *refs, nq, nk, block_q, block_k, band, causal, scale,
-                      outs):
+                      outs, window, steps):
     """The backward of one (block_q, block_k) tile, TRANSPOSED: scores are
     held keys x queries, so p.T and ds.T — what dv = p.T @ do and
     dk = ds.T @ q consume — come out of the matmuls as they are, the
@@ -438,7 +632,11 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
       "dkv"  the same grid, dk/dv only.
       "dq"   grid (bh, q_blocks, k_blocks), dq only, one q block of scratch.
     Together "dq" + "dkv" recompute the two largest matmuls and the exp,
-    and stream every tile twice: the long-context path."""
+    and stream every tile twice: the long-context path.
+
+    The grid's last dimension has `steps` entries: every block of the
+    streamed side, or under a `window` as many as it reaches, counted from
+    the first block it reaches (_k_range, _q_range)."""
     refs = list(refs)
     want_dq, want_dkv = outs != "dkv", outs != "dq"
     dq_ref = refs.pop(0) if want_dq else None
@@ -447,12 +645,20 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dk_acc, dv_acc = refs if want_dkv else (None, None)
     if outs == "dq":
         j, kb = pl.program_id(1), pl.program_id(2)
-        dq_first, dq_last = kb == 0, kb == nk - 1
+        step = kb
+        if window is not None:
+            kb = step + _k_range(j, block_q, block_k, nk, window,
+                                 jnp.maximum, jnp.minimum)[0]
+        dq_first, dq_last = step == 0, step == steps - 1
         dq_base = 0
     else:
         kb, j = pl.program_id(1), pl.program_id(2)
-        dq_first = (kb == 0) & (j == 0)
-        dq_last = (kb == nk - 1) & (j == nq - 1)
+        step = j
+        if window is not None:
+            j = step + _q_range(kb, block_q, block_k, nq, window,
+                                jnp.maximum, jnp.minimum)[0]
+        dq_first = (kb == 0) & (step == 0)
+        dq_last = (kb == nk - 1) & (step == steps - 1)
         dq_base = j * block_q if nq > 1 else 0
     rows_per = band or block_k
     square = block_q == block_k
@@ -464,13 +670,16 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dq_acc[...] = jnp.zeros_like(dq_acc)
 
     if want_dkv:
-        @pl.when(j == 0)
+        @pl.when(step == 0)
         def _init_dkv():
             dk_acc[...] = jnp.zeros_like(dk_acc)
             dv_acc[...] = jnp.zeros_like(dv_acc)
 
     diag_mask = _causal_mask(rows_per, rows_per, transposed=True) \
         if causal and square else None
+    edge_mask = _causal_mask(rows_per, rows_per, q_off=window,
+                             transposed=True, window=window) \
+        if window is not None and square and window % block_q == 0 else None
 
     def visit(kind):
         for c in range(block_k // rows_per):
@@ -478,12 +687,16 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             if kind == "diag":
                 cols = [(lo, hi, diag_mask)] \
                     + ([(hi, block_q, None)] if hi < block_q else [])
+            elif kind == "edge":
+                cols = ([(0, lo, None)] if lo else []) \
+                    + [(lo, hi, edge_mask)]
             elif kind == "full":
                 cols = [(0, block_q, None)]
             else:
                 cols = [(0, block_q, _causal_mask(
                     block_q, rows_per, q_off=j * block_q,
-                    k_off=kb * block_k + lo, transposed=True))]
+                    k_off=kb * block_k + lo, transposed=True,
+                    window=window))]
             # native-dtype MXU dots (see fwd kernel); p and ds are rounded
             # to the input dtype for their matmuls, standard flash-2
             # practice. q arrives PRE-SCALED, so s matches the forward's
@@ -519,7 +732,7 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dv_acc[lo:hi, :] += dv
 
     _visit_by_diagonal(causal, square, nq == 1 and nk == 1, j, kb, block_q,
-                       block_k, visit)
+                       block_k, visit, window, nq, nk)
 
     if want_dq:
         @pl.when(dq_last)
@@ -527,7 +740,7 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
     if want_dkv:
-        @pl.when(j == nq - 1)
+        @pl.when(step == steps - 1)
         def _finish_dkv():
             dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
             dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
@@ -552,7 +765,7 @@ def _flash_bwd_stats(o, lse, do, block_q):
 
 
 def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, tiles, fused,
-                      interpret, stats=None):
+                      interpret, stats=None, window=None):
     """Pallas flash backward: the fused kernel, or the dq + dkv pair."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
@@ -570,6 +783,10 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, tiles, fused,
     shape_k = jax.ShapeDtypeStruct((bh, sk, d), k.dtype)
     shape_v = jax.ShapeDtypeStruct((bh, sk, d), v.dtype)
     acc_k = pltpu.VMEM((block_k, d), jnp.float32)
+    k_steps, q_steps, suffix = nk, nq, ""
+    if window is not None:
+        k_steps, q_steps = _window_steps(sq, sk, block_q, block_k, window)
+        suffix = WINDOW_SUFFIX
 
     def call(outs, name, grid, qmap, kvmap, out_specs, out_shape, scratch):
         q_spec = pl.BlockSpec((1, block_q, d), qmap)
@@ -580,8 +797,8 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, tiles, fused,
             functools.partial(
                 _flash_bwd_kernel, nq=nq, nk=nk, block_q=block_q,
                 block_k=block_k, band=band, causal=causal, scale=scale,
-                outs=outs),
-            name=name, grid=grid,
+                outs=outs, window=window, steps=grid[2]),
+            name=name + suffix, grid=grid,
             in_specs=[q_spec, kv_spec, kv_spec, q_spec, stat_spec,
                       stat_spec],
             out_specs=out_specs, out_shape=out_shape,
@@ -590,30 +807,31 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, tiles, fused,
 
     # grid (bh, k blocks, q blocks): q-side tiles stream, clamped to the
     # first block that sees this k block
-    qmap = _causal_q_map(causal, block_q, block_k)
+    qmap = _causal_q_map(causal, block_q, block_k, nq, window)
     kvmap_kq = lambda i, kb, j: (i, kb, 0)
     dkv_specs = [pl.BlockSpec((1, block_k, d), kvmap_kq)] * 2
     if fused:
         dq, dk, dv = call(
-            "all", "singa_flash_bwd", (bh, nk, nq), qmap, kvmap_kq,
+            "all", "singa_flash_bwd", (bh, nk, q_steps), qmap, kvmap_kq,
             [pl.BlockSpec((1, sq, d), lambda i, kb, j: (i, 0, 0))]
             + dkv_specs, [shape_q, shape_k, shape_v],
             [pltpu.VMEM((sq, d), jnp.float32), acc_k, acc_k])
     else:
         qmap_qk = lambda i, j, kb: (i, j, 0)
         dq = call(
-            "dq", "singa_flash_bwd_dq", (bh, nq, nk), qmap_qk,
-            _causal_kv_map(causal, block_q, block_k, nk),
+            "dq", "singa_flash_bwd_dq", (bh, nq, k_steps), qmap_qk,
+            _causal_kv_map(causal, block_q, block_k, nk, window),
             pl.BlockSpec((1, block_q, d), qmap_qk), shape_q,
             [pltpu.VMEM((block_q, d), jnp.float32)])
         dk, dv = call(
-            "dkv", "singa_flash_bwd_dkv", (bh, nk, nq), qmap, kvmap_kq,
+            "dkv", "singa_flash_bwd_dkv", (bh, nk, q_steps), qmap, kvmap_kq,
             dkv_specs, [shape_k, shape_v], [acc_k, acc_k])
     return (dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),
             dv.reshape(b, h, sk, d))
 
 
-def _flash_bwd_blockwise(q, k, v, o, lse, do, causal, scale, block_k):
+def _flash_bwd_blockwise(q, k, v, o, lse, do, causal, scale, block_k,
+                         window=None):
     """Recompute-based backward, scanned over K blocks (O(S) memory)."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
@@ -630,7 +848,8 @@ def _flash_bwd_blockwise(q, k, v, o, lse, do, causal, scale, block_k):
         v_blk = lax.dynamic_slice_in_dim(v, kb * block_k, block_k, axis=2)
         s = jnp.einsum("bhqd,bhkd->bhqk", qs, k_blk.astype(jnp.float32))
         if causal:
-            s = s + _causal_mask(sq, block_k, 0, kb * block_k)[None, None]
+            s = s + _causal_mask(sq, block_k, 0, kb * block_k,
+                                 window=window)[None, None]
         p = jnp.exp(s - lse[..., None])                    # (B,H,Sq,Bk)
         dv = jnp.einsum("bhqk,bhqd->bhkd", p, do_)
         dp = jnp.einsum("bhqd,bhkd->bhqk", do_, v_blk.astype(jnp.float32))
@@ -651,14 +870,17 @@ def _flash_bwd_blockwise(q, k, v, o, lse, do, causal, scale, block_k):
     return (dq * scale).astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention(q, k, v, causal=False, scale=None,
                     block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
-                    interpret=None):
+                    interpret=None, window=None):
     """Fused attention; q,k,v (B,H,S,D). Falls back to the reference path
-    when shapes don't tile (S % block != 0) or Pallas is unavailable."""
+    when shapes don't tile (S % block != 0) or Pallas is unavailable.
+    `window` (with `causal`): a query sees its last `window` keys, itself
+    among them; tiles wholly left of the window are not visited. None, or
+    a window that reaches every key, is the causal program itself."""
     out, _ = _flash_fwd(q, k, v, causal, scale, block_q, block_k,
-                        interpret)
+                        interpret, window)
     return out
 
 
@@ -673,33 +895,39 @@ def _kernel_path(interpret):
     return "interpret" if interpret else "kernel"
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+               window=None):
     d = q.shape[-1]
     scale, interpret = _resolve(scale, d, interpret)
     plan = flash_plan(q.shape[2], k.shape[2], d, causal, q.dtype, block_q,
-                      block_k)
+                      block_k, window)
     if not _HAS_PALLAS or not plan.ok:
         record_attention_dispatch("flash_fwd", "reference")
-        return attention_reference(q, k, v, causal, scale), None
+        return attention_reference(q, k, v, causal, scale, window), None
     record_attention_dispatch("flash_fwd", _kernel_path(interpret))
-    record_flash_tiles("flash_fwd", *plan.fwd[3:])
-    return _flash_fwd_pallas(q, k, v, causal, scale, plan.fwd, interpret)
+    record_flash_tiles("flash_fwd", *plan.fwd[3:], plan.skipped[0],
+                       plan.window)
+    return _flash_fwd_pallas(q, k, v, causal, scale, plan.fwd, interpret,
+                             plan.window)
 
 
-def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
+def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+                   window):
     out, lse = _flash_fwd(q, k, v, causal, scale, block_q, block_k,
-                          interpret)
+                          interpret, window)
     if lse is None:  # fallback path: vjp of the reference impl
         d = q.shape[-1]
         s, _ = _resolve(scale, d, interpret)
         _, ref_vjp = jax.vjp(
-            lambda q_, k_, v_: attention_reference(q_, k_, v_, causal, s),
+            lambda q_, k_, v_: attention_reference(q_, k_, v_, causal, s,
+                                                   window),
             q, k, v)
         return out, (None, ref_vjp)
     return out, ((q, k, v, out, lse), None)
 
 
-def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, res, g):
+def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, window, res,
+                   g):
     saved, ref_vjp = res
     if saved is None:
         record_attention_dispatch("flash_bwd", "reference")
@@ -708,15 +936,17 @@ def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, res, g):
     d = q.shape[-1]
     s, interp = _resolve(scale, d, interpret)
     sk = k.shape[2]
-    plan = flash_plan(q.shape[2], sk, d, causal, q.dtype, block_q, block_k)
+    plan = flash_plan(q.shape[2], sk, d, causal, q.dtype, block_q, block_k,
+                      window)
     if _HAS_PALLAS and plan.bwd:
         record_attention_dispatch("flash_bwd", _kernel_path(interp))
-        record_flash_tiles("flash_bwd", *plan.bwd[3:])
+        record_flash_tiles("flash_bwd", *plan.bwd[3:], plan.skipped[1],
+                           plan.window)
         return _flash_bwd_pallas(q, k, v, out, lse, g, causal, s, plan.bwd,
-                                 plan.fused, interp)
+                                 plan.fused, interp, window=plan.window)
     record_attention_dispatch("flash_bwd", "reference")
     return _flash_bwd_blockwise(q, k, v, out, lse, g, causal, s,
-                                _fit_block(sk, 512) or sk)
+                                _fit_block(sk, 512) or sk, plan.window)
 
 
 flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)

@@ -594,6 +594,9 @@ UNEXPORTABLE = {
     "_MoEOp": "expert routing is data-dependent top-k dispatch; ONNX has "
               "no MoE op and a Scatter decomposition would be quadratic "
               "— serve MoE through generate()/native checkpoints",
+    "_DroplessMoEOp": "the dropless expert layer: top-k routing, a sort by "
+                      "expert and grouped products over the rows routed; "
+                      "ONNX has no form of it either",
     "_ReversePadded": "internal helper of the bidirectional fused RNN; "
                       "the LSTM node's direction attr covers it on the "
                       "ONNX side",
@@ -610,6 +613,9 @@ UNEXPORTABLE = {
     "_LoopLoss": "training loss (see CrossEntropy)",
     "_TokenCrossEntropy": "training loss (see CrossEntropy)",
     "_Rows": "training step's sample of the logits, off the tape",
+    # the sparse model's training step (models/mellum.py)
+    "_SampleLogits": "training step's sample of the logits, off the tape",
+    "_Stack": "training step's rows routed a layer, off the tape",
     # shape/constant generators with no stable inference mapping
     "NonZero": "data-dependent output shape (host fallback op)",
     "Shape": "exported models carry static shapes",

@@ -104,6 +104,35 @@ def test_flash_plans_compile_for_v5e(one_chip, shape, causal, calls):
         == calls
 
 
+# a sliding window: the 8k cell's sliding layers (two tiles a q block, the
+# diagonal's and the window's edge, both in bands; the split backward), a
+# short row whose backward is fused, a window that is no multiple of the
+# block (tiles under one mask built from the step's offsets), and one
+# narrower than the smallest block
+@pytest.mark.parametrize("shape,window,calls", [
+    ((1, 4, 8192, 128), 1024, (1, 3)),
+    ((1, 4, 2048, 64), 512, (1, 2)),
+    ((1, 4, 2048, 128), 640, (1, 2)),
+    ((1, 4, 1024, 64), 96, (1, 2)),
+], ids=["s8k_w1024", "s2k_w512", "s2k_w640", "s1k_w96"])
+def test_windowed_flash_compiles_for_v5e(one_chip, shape, window, calls):
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def fwd(q, k, v):
+        return A.flash_attention(q, k, v, True, None, None, None, False,
+                                 window)
+
+    def grad(q, k, v):
+        return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    text = jax.jit(grad).lower(q, q, q).compile().as_text()
+    assert (_mosaic_calls(fwd, q, q, q), text.count(
+        'custom_call_target="tpu_custom_call"')) == calls
+    # the kernels' names say they work under a window
+    assert "singa_flash_fwd" + A.WINDOW_SUFFIX in text
+
+
 # what ServingEngine builds for GPT-2-small in chip_smoke.py: 8 slots,
 # P=2 heads packed per 128-lane row -> Hp=6, Q=P*G=2 query rows per token,
 # pages of 16 tokens, 64 pages per sequence (max_ctx 1024), 512 in the pool
