@@ -56,6 +56,7 @@ from __future__ import annotations
 import hashlib
 import json
 import os
+import re
 import time
 
 from . import config, observe
@@ -416,11 +417,88 @@ def _harvest_memory(compiled, args) -> dict:
     return mem
 
 
-def _write_hlo(compiled, key, fingerprint):
-    try:
-        text = compiled.as_text()
-    except Exception:
-        return None
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
+_HLO_ALL_REDUCE = re.compile(
+    r"^\s+(?:ROOT )?%?[\w.\-]+ = (.*?) all-reduce(-start)?\(")
+_HLO_FUSION = re.compile(
+    r"^\s+(?:ROOT )?%?([\w.\-]+) = .*? fusion\(.*\bcalls=%?([\w.\-]+)")
+# an array in an HLO type: its dtype's bits (none for pred) and its dims
+_HLO_ARRAY = re.compile(r"\b(?:pred|[a-z]+?(\d+)\w*)\[([\d,]*)\]")
+# libtpu's name for the fusion that opens an asynchronous collective
+# whose further steps ride inside the compute fusions after it
+ASYNC_COLLECTIVE_START = "async-collective-start"
+
+
+def _hlo_shape_bytes(shape: str) -> int:
+    """Bytes of an HLO result type's arrays: `f32[1024,50257]{...}`, or a
+    tuple of them."""
+    total = 0
+    for bits, dims in _HLO_ARRAY.findall(shape):
+        n = int(bits or 8)
+        for d in filter(None, dims.split(",")):
+            n *= int(d)
+        total += n // 8
+    return total
+
+
+def all_reduce_summary(hlo_text: str) -> dict:
+    """The all-reduces of one compiled (scheduled) module, each counted
+    once in the form the compiler left it in: {"collectives", "async",
+    "bytes", "async_bytes"}, bytes being the reduced results' sizes.
+
+    Blocking: an `all-reduce(` instruction of the entry or of a loop's
+    body; the core runs it and nothing else until it is done. One that
+    carries `frontend_attributes={async_collective_name=...}` is blocking
+    too: it WAS a start/done pair whose halves the scheduler put next to
+    each other, and XLA joined them again (convert_async_collectives_to_
+    sync keeps the start's name there).
+    Asynchronous: an `all-reduce-start(` (its `-done` comes later); or,
+    on a TPU, a fusion named `async-collective-start*` whose computation
+    holds the all-reduce's first step: the further steps run inside the
+    compute fusions scheduled between it and its `async-collective-done*`
+    (the all-reduce instructions in those fused computations are the same
+    reduction's pieces, and are not counted again)."""
+    held = {}      # computation -> [(bytes, is a -start)] of its all-reduces
+    fused = set()  # computations some fusion instruction calls
+    opened = []    # computations the async-collective-start fusions call
+    cur = None
+    for line in hlo_text.splitlines():
+        if not line.startswith(" "):
+            m = _HLO_COMPUTATION.match(line)
+            if m:
+                cur = m.group(1)
+            continue
+        if " all-reduce" in line:
+            m = _HLO_ALL_REDUCE.match(line)
+            if m:
+                held.setdefault(cur, []).append(
+                    (_hlo_shape_bytes(m.group(1)), bool(m.group(2))))
+                continue
+        if " fusion(" in line:
+            m = _HLO_FUSION.match(line)
+            if m:
+                fused.add(m.group(2))
+                if m.group(1).startswith(ASYNC_COLLECTIVE_START):
+                    opened.append(m.group(2))
+    plain = [r for comp, rs in held.items() if comp not in fused
+             for r in rs]
+    in_fusions = [b for comp in opened for b, _ in held.get(comp, ())]
+    paired = [b for b, start in plain if start]
+    return {"collectives": len(plain) + len(in_fusions),
+            "async": len(paired) + len(in_fusions),
+            "bytes": sum(b for b, _ in plain) + sum(in_fusions),
+            "async_bytes": sum(paired) + sum(in_fusions)}
+
+
+def all_reduces_of(variant) -> dict:
+    """`all_reduce_summary` of a built `AotVariant`: from its build's
+    record where the build had the text in hand (HLO capture on), else
+    from the executable's text, printed now."""
+    return (variant.record or {}).get("all_reduces") \
+        or all_reduce_summary(variant.run.as_text())
+
+
+def _write_hlo(text, key, fingerprint):
     try:
         os.makedirs(_hlo_dir, exist_ok=True)
         safe = key.replace(".", "_").replace("/", "_")
@@ -494,16 +572,19 @@ def _sig_fingerprint(key: str, sig: dict) -> str:
             sort_keys=True, default=str)).encode()).hexdigest()[:16]
 
 
-def _stage(fn, args):
+def _stage(fn, args, compiler_options=None):
     """Explicit trace -> lower -> compile of one jitted callable, with
     per-phase wall timing. Raises whatever the staging machinery
-    raises; callers decide the fallback."""
+    raises; callers decide the fallback. compiler_options: the
+    program's own XLA options ({name: value}), handed to the compile
+    whichever callable is staged: the warm store's deserialized module
+    is a jit of its own and carries none."""
     t0 = time.perf_counter()
     traced = fn.trace(*args)
     t1 = time.perf_counter()
     lowered = traced.lower()
     t2 = time.perf_counter()
-    compiled = lowered.compile()
+    compiled = lowered.compile(compiler_options=compiler_options)
     t3 = time.perf_counter()
     return compiled, {"trace": t1 - t0, "lower": t2 - t1,
                       "compile": t3 - t2}
@@ -664,9 +745,11 @@ def load_executable(key, fingerprint, *, count: bool = True):
     return warm_fn, result, seconds
 
 
-def build_compiled(fn, args, key, sig=None, device=None):
+def build_compiled(fn, args, key, sig=None, device=None,
+                   compiler_options=None):
     """Build `fn` (a jax.jit-wrapped callable) for `args` through the
-    explicit trace -> lower -> compile stages.
+    explicit trace -> lower -> compile stages, every compile under
+    `compiler_options` (see `_stage`).
 
     Times each phase into `singa_compile_phase_seconds`, harvests cost /
     memory analysis into the `singa_xla_*` / `singa_hbm_*` gauges,
@@ -706,7 +789,7 @@ def build_compiled(fn, args, key, sig=None, device=None):
     with observe.span("introspect.build", key=key):
         if warm_fn is not None:
             try:
-                compiled, phases = _stage(warm_fn, args)
+                compiled, phases = _stage(warm_fn, args, compiler_options)
             except Exception:
                 # deserialized but will not stage on this backend: the
                 # same trust verdict as a bad blob — drop the entry and
@@ -726,12 +809,12 @@ def build_compiled(fn, args, key, sig=None, device=None):
             rt = _deserialize_executable(blob) if blob else None
             if rt is not None:
                 try:
-                    compiled, phases = _stage(rt, args)
+                    compiled, phases = _stage(rt, args, compiler_options)
                 except Exception:
                     compiled = None
         if compiled is None:
             try:
-                compiled, phases = _stage(fn, args)
+                compiled, phases = _stage(fn, args, compiler_options)
             except Exception:
                 return None, None
     if warm_result is not None:
@@ -750,10 +833,20 @@ def build_compiled(fn, args, key, sig=None, device=None):
                       ).set(float(cost.get("bytes accessed", 0.0) or 0.0),
                             key=key)
         _set_hbm_gauges(mem, key)
-    hlo_path = _write_hlo(compiled, key, fingerprint) if _hlo_dir else None
+    hlo_path = all_reduces = None
+    if _hlo_dir:
+        try:
+            text = compiled.as_text()
+        except Exception:
+            text = None
+        if text:
+            hlo_path = _write_hlo(text, key, fingerprint)
+            # while the text is here: a step of 24 layers is 10-15 MB of
+            # it, a second or so to print again (all_reduces_of)
+            all_reduces = all_reduce_summary(text)
     rec = {"key": key, "fingerprint": fingerprint, "phases": phases,
            "cost": cost, "memory": mem, "hlo_path": hlo_path,
-           "warm": warm_result,
+           "all_reduces": all_reduces, "warm": warm_result,
            "ts": round(time.time(), 6)}
     _register_build(key, sig, rec, device=device)
     return compiled, rec
@@ -854,13 +947,19 @@ class AotExecutor:
     take them. cache_key(args) is the per-call cache key: by default every
     leaf's aval; a caller whose signature can only change through a few of
     its arguments (the training step: inputs, and how many optimizer
-    arrays) keys on those, so a cached call does O(inputs) host work."""
+    arrays) keys on those, so a cached call does O(inputs) host work.
+    compiler_options are the XLA options `fn` was jitted with (the
+    caller's `jax.jit(..., compiler_options=)`, so that the jit fall-back
+    compiles under them too): every build compiles under them, and they
+    are part of `static`, so the signature, its fingerprint and the warm
+    store never serve a build made under other options."""
 
     __slots__ = ("fn", "key", "names", "donated", "tag", "static",
-                 "device", "cache_key", "_execs")
+                 "device", "cache_key", "compiler_options", "_execs")
 
     def __init__(self, fn, key, names=None, donated=(), tag=None,
-                 static=None, device=None, cache_key=_leaf_avals):
+                 static=None, device=None, cache_key=_leaf_avals,
+                 compiler_options=None):
         self.fn = fn
         self.key = key
         self.names = names
@@ -870,6 +969,10 @@ class AotExecutor:
         # key a donated variant and an undonated one identically
         self.donated = tuple(donated)
         self.tag = tag
+        self.compiler_options = dict(compiler_options or {})
+        if self.compiler_options:
+            static = (f"{static or ''} compiler_options="
+                      f"{sorted(self.compiler_options.items())}")
         self.static = static
         self.device = device
         self.cache_key = cache_key
@@ -891,7 +994,8 @@ class AotExecutor:
                             static=self.static, donated=self.donated,
                             batch_hint=batch_hint)
             v = self._execs[k] = AotVariant(*build_compiled(
-                self.fn, args, self.key, sig, device=self.device))
+                self.fn, args, self.key, sig, device=self.device,
+                compiler_options=self.compiler_options))
         return v
 
     def give_to_jit(self, variant):

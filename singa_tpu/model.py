@@ -438,17 +438,24 @@ class Model(Layer, metaclass=ModelMeta):
                     out_specs=(state_in, opt_in, P(),
                                (P(), P(opt.axis)), P()),
                     check_vma=False)
+                # a step that reduces gradients over TPUs is compiled so
+                # that the reductions travel under the backward pass; {}
+                # on one device, off a TPU, and for every other step
+                options = opt.communicator.overlap_compile_options()
             else:
                 wrapped = step
+                options = {}
             # The cache key is O(#inputs): a step's signature changes only
             # with its inputs, or with len(opt_arrs) — the sparse DistOpt
             # strategies GROW their optimizer state (new residual slots)
             # between steps.
             return introspect.AotExecutor(
-                jax.jit(wrapped, donate_argnums=(0, 1)), "step",
+                jax.jit(wrapped, donate_argnums=(0, 1),
+                        compiler_options=options), "step",
                 names=("state", "opt", "rng", "arg"), donated=(0, 1),
                 tag=tag, static=static_repr, device=dev,
-                cache_key=lambda a: (_input_avals(a[3]), len(a[1])))
+                cache_key=lambda a: (_input_avals(a[3]), len(a[1])),
+                compiler_options=options)
 
         self._dist_shardings = None
         state_in = opt_in = None
@@ -618,6 +625,11 @@ class Model(Layer, metaclass=ModelMeta):
                     donated_bytes=sum(
                         int(getattr(a, "nbytes", 0))
                         for a in (*state_arrs, *opt_arrs)))
+                if self._dist_shardings is not None \
+                        and variant.run is not None:
+                    # what the compiler made of the step's reductions
+                    observe.record_grad_reduce(
+                        introspect.all_reduces_of(variant))
             t_obs = time.perf_counter()
         profiling = (dev.verbosity > 0 and
                      self._step_stats["steps"] >= dev.skip_iteration)

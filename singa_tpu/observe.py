@@ -904,6 +904,25 @@ def record_step_outputs(batch_sharded: int, mean_reduced: int):
     g.set(mean_reduced, kind="mean_reduced")
 
 
+def record_grad_reduce(summary: dict):
+    """What the compiler made of the all-reduces of the latest built
+    data-parallel step (Model under DistOpt on a mesh), as
+    `introspect.all_reduce_summary` reads the compiled text: how many
+    the step holds (`collectives`; the combiner packs the psums the tape
+    issued), the bytes they reduce, and of both how much is in an
+    asynchronous form, beside compute (`async`, `async_bytes`); the rest
+    blocks the core. 0 asynchronous on a TPU mesh means this compiler no
+    longer honours `Communicator.overlap_compile_options`. A gauge: it
+    holds the latest build."""
+    if not _enabled:
+        return
+    g = gauge("singa_grad_reduce",
+              "all-reduces of the latest built data-parallel step "
+              "(collectives|async|bytes|async_bytes)")
+    for kind, n in summary.items():
+        g.set(n, kind=kind)
+
+
 def record_cross_entropy(targets: str, lse: str):
     """The form of the latest traced softmax cross-entropy backward:
     `targets` "integer" (class indices: the loss is lse - logits[target],

@@ -308,8 +308,9 @@ def test_cache_key_and_signature_fields_reach_the_build(monkeypatch):
 
     real_build = introspect.build_compiled
 
-    def build(fn, args, key, s, device=None):
+    def build(fn, args, key, s, device=None, compiler_options=None):
         seen["build"] = (key, s, device)
+        assert not compiler_options    # none was given
         return real_build(fn, args, key, s, device=device)
 
     monkeypatch.setattr(introspect, "signature", sig)

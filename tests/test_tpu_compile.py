@@ -225,3 +225,86 @@ def test_cross_entropy_forms_nothing_of_the_logits_size_on_v5e(one_chip):
     assert len(wrote) == 1, [w[:2] for w in wrote]
     _name, opcode, line = wrote[0]
     assert opcode == "fusion" and "dot_general" in line, line
+
+
+# ---- the data-parallel step's reductions beside the backward pass ---------
+
+def _gpt_step(optimizer, batch, monkeypatch):
+    """(executor of the train step, its call as ShapeDtypeStructs less
+    their shardings) of a GPT wide enough that a weight's gradient is no
+    small array: (1024, 1024) fp32 is 4 MiB."""
+    import numpy as np
+    from singa_tpu import models, tensor
+    from singa_tpu.device import get_default_device
+    dev = get_default_device()
+    m = models.create_model("gpt", vocab_size=2048, max_seq=128, dim=1024,
+                            num_heads=16, num_layers=1)
+    m.set_optimizer(optimizer)
+    ids = np.zeros((batch, 128), np.int32)
+    m.compile([tensor.from_numpy(ids[:1], device=dev)], is_train=True,
+              use_graph=True, amp="bfloat16")
+    tx = tensor.from_numpy(ids, device=dev)
+    # the kernels' dispatch asks for the backend: the step is for a TPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    m._build_step(type(m).train_one_batch.__wrapped__, (tx, tx), {})
+    with m._tracers_kept_out(m._state_tensors) as (state, opt_arrs, rng):
+        call = (list(state), list(opt_arrs), rng, [tx.data, tx.data])
+    return m, call
+
+
+def _compiled_step_text(m, call, shardings):
+    """The step as `introspect.AotExecutor` stages it, for the described
+    devices `shardings` (state, batch) put the arguments on."""
+    from singa_tpu import introspect
+    ex = m._step_builder(0)
+    held, batch = shardings
+    sds = lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s)
+    state, opt_arrs, rng, inputs = call
+    with m._tracers_kept_out(m._state_tensors):
+        compiled, _ = introspect._stage(
+            ex.fn, ([sds(a, held) for a in state],
+                    [sds(a, held) for a in opt_arrs], sds(rng, held),
+                    [sds(a, batch) for a in inputs]), ex.compiler_options)
+    return ex, compiled.as_text()
+
+
+def test_dp_step_reduces_beside_the_backward_pass_on_v5e(topo, one_chip,
+                                                         monkeypatch):
+    """Four described chips: the step is compiled under the communicator's
+    options and its gradient reductions come out in the asynchronous form
+    (all but the small bucket of biases, norms and positions, and in a
+    model this shallow a matrix the scheduler finds no carrier for);
+    compiled without
+    them every reduction blocks; a one-chip step takes no option and holds
+    neither a reduction nor the form. A libtpu that stops honouring an
+    option fails here (an unknown option is a compile error; a silent one
+    shows as async == 0)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from singa_tpu import introspect, opt
+    from singa_tpu.parallel import communicator
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    on_mesh = (NamedSharding(mesh, P()), NamedSharding(mesh, P("data")))
+    m, call = _gpt_step(opt.DistOpt(opt.Adam(lr=1e-4), mesh=mesh), 8,
+                        monkeypatch)
+    ex, text = _compiled_step_text(m, call, on_mesh)
+    assert ex.compiler_options == communicator.OVERLAP_COMPILE_OPTIONS
+    got = introspect.all_reduce_summary(text)
+    assert got["async"] > 0 and got["collectives"] - got["async"] <= 2, got
+    assert got["async_bytes"] > 0.9 * got["bytes"], got
+    assert introspect.ASYNC_COLLECTIVE_START in text
+
+    monkeypatch.setattr(communicator, "OVERLAP_COMPILE_OPTIONS", {})
+    ex, text = _compiled_step_text(m, call, on_mesh)
+    plain = introspect.all_reduce_summary(text)
+    assert ex.compiler_options == {}
+    assert plain["collectives"] > 0 and plain["async"] == 0, plain
+    assert plain["bytes"] == got["bytes"]
+    monkeypatch.undo()
+
+    m, call = _gpt_step(opt.Adam(lr=1e-4), 2, monkeypatch)
+    ex, text = _compiled_step_text(m, call, (one_chip, one_chip))
+    assert ex.compiler_options == {} and "compiler_options" not in ex.static
+    assert introspect.all_reduce_summary(text)["collectives"] == 0
+    assert introspect.ASYNC_COLLECTIVE_START not in text \
+        and "async_collective_name" not in text
