@@ -930,28 +930,36 @@ class SoftMaxCrossEntropy(Operator):
     """Fused stable softmax-CE with a HAND backward (ref: C++ fused
     CrossEntropyFwd/Bwd tensor.h:625-637 for exactly this reason). Logits of
     any rank; the mean is over all leading axes. With class-index targets
-    the backward reads the forward's log-sum-exp."""
+    the backward reads the forward's log-sum-exp. A third input `w`, one
+    weight a row (the logits' leading axes), makes the loss
+    sum(w * CE) / rows: still over ALL rows, a row of weight 0 among them
+    (the objective of masked diffusion: 1 / rate on the masked positions,
+    0 elsewhere). It takes no gradient."""
 
     def __init__(self):
         super().__init__()
         self._cache = None
 
-    def forward(self, x, t):
+    def forward(self, x, t, w=None):
         self._in_dtype = x.dtype
         x = x.astype(jnp.float32)  # fp32 island under bf16 compute policy
         lse = tensor_module.softmax_lse(x)
-        self._cache = (x, t, lse)
+        self._cache = (x, t, lse, w)
         self._path = cross_entropy_path(x, t)
-        return jnp.mean(tensor_module.softmax_cross_entropy_fwd(x, t, lse))
+        ce = tensor_module.softmax_cross_entropy_fwd(x, t, lse)
+        return jnp.mean(ce if w is None else ce * w.astype(jnp.float32))
 
     def backward(self, dy):
-        x, t, lse = self._cache
+        x, t, lse, w = self._cache
         observe.record_cross_entropy(*self._path)
         # mean is over ALL leading dims (per-token for 3D logits), so the
         # scale is prod(x.shape[:-1]), not just the batch dim
         n = int(np.prod(x.shape[:-1])) if x.ndim > 1 else 1
         dx = tensor_module.softmax_cross_entropy_bwd(x, t, lse) * (dy / n)
-        return dx.astype(self._in_dtype), None  # no grad for targets
+        if w is None:
+            return dx.astype(self._in_dtype), None  # no grad for targets
+        dx = dx * w.astype(jnp.float32)[..., None]
+        return dx.astype(self._in_dtype), None, None
 
 
 # ---- NN ops (handle-backed in the reference, §2.6) -----------------------
@@ -1317,13 +1325,15 @@ class _FlashAttention(Operator):
     """Fused attention on the tape; forward is the Pallas flash kernel (or
     its reference fallback), backward is its custom_vjp (ops/attention.py)."""
 
-    def __init__(self, causal=False, window=None):
+    def __init__(self, causal=False, window=None, block_diffusion=None):
         super().__init__()
         self.causal, self.window = causal, window
+        self.block_diffusion = block_diffusion
 
     def forward(self, q, k, v):
         from .ops.attention import flash_attention
-        return flash_attention(q, k, v, self.causal, window=self.window)
+        return flash_attention(q, k, v, self.causal, window=self.window,
+                               block_diffusion=self.block_diffusion)
 
 
 class _RingAttention(Operator):
@@ -1530,8 +1540,12 @@ def ranking_loss(pos, neg, M=0.2):
     return RankingLoss(M)(pos, neg)
 
 
-def softmax_cross_entropy(x, t):
-    return SoftMaxCrossEntropy()(x, t)
+def softmax_cross_entropy(x, t, weight=None):
+    """Mean softmax cross-entropy over the rows; `weight` (one a row): the
+    mean of weight x cross-entropy over ALL rows."""
+    if weight is None:
+        return SoftMaxCrossEntropy()(x, t)
+    return SoftMaxCrossEntropy()(x, t, weight)
 
 
 def conv2d(handle, x, W, b=None):
@@ -1606,14 +1620,44 @@ def swiglu(gate, up):
     return SwiGLU()(gate, up)
 
 
-def attention(q, k, v, causal=False, seq_axis=None, window=None):
+def attention_mask(causal=False, window=None, block_diffusion=None):
+    """(kind, size) of the ONE mask an attention's arguments name: (None,
+    None) every key; ("causal", None) the keys at or before the query;
+    ("window", W) of those the last W, the query's own among them;
+    ("block_diffusion", b) the mask of block-diffusion training over a
+    doubled sequence [noised ; clean] in blocks of b
+    (ops.attention.block_diffusion_visible). A window goes with `causal`
+    and the block mask with neither: any other pair is refused here, not
+    computed."""
+    assert window is None or block_diffusion is None, \
+        "one mask an attention: a sliding window and the block-diffusion " \
+        f"mask exclude each other (window={window}, " \
+        f"block_diffusion={block_diffusion})"
+    if block_diffusion is not None:
+        assert not causal and int(block_diffusion) >= 1, \
+            "the block-diffusion mask is not causal over the doubled " \
+            f"sequence: causal={causal} block_diffusion={block_diffusion}"
+        return "block_diffusion", int(block_diffusion)
+    if window is not None:
+        assert causal and int(window) >= 1, \
+            f"a sliding window needs the causal mask: causal={causal} " \
+            f"window={window}"
+        return "window", int(window)
+    return ("causal" if causal else None), None
+
+
+def attention(q, k, v, causal=False, seq_axis=None, window=None,
+              block_diffusion=None):
     """Fused attention (B,H,S,D); seq_axis names a mesh axis for ring
-    (sequence-parallel) execution. `window` (with `causal`; not on the
-    ring): a query sees its last `window` keys, itself among them."""
+    (sequence-parallel) execution. The mask is `attention_mask`'s of
+    (`causal`, `window`, `block_diffusion`); the ring takes the causal
+    mask or none."""
+    kind, _ = attention_mask(causal, window, block_diffusion)
     if seq_axis is not None:
-        assert window is None, "ring attention takes no sliding window"
+        assert kind in (None, "causal"), \
+            f"ring attention takes the causal mask or none, not {kind}"
         return _RingAttention(seq_axis, causal)(q, k, v)
-    return _FlashAttention(causal, window)(q, k, v)
+    return _FlashAttention(causal, window, block_diffusion)(q, k, v)
 
 
 def yarn_frequencies(dim, theta, factor, original_max, beta_fast=32.0,
@@ -1675,11 +1719,15 @@ class Rope(Operator):
     `seq_axis` offsets positions by axis_index * S_local under sequence
     parallelism, the same pattern as _PosSlice for the learned table."""
 
-    def __init__(self, theta=10000.0, seq_axis=None, scaling=None):
+    def __init__(self, theta=10000.0, seq_axis=None, scaling=None,
+                 period=None):
         super().__init__("Rope")
         self.theta = float(theta)
         self.seq_axis = seq_axis
         self.scaling = scaling      # rope_tables' `scaling` (YaRN)
+        # position i is i mod `period`: the halves of a doubled sequence
+        # carry the same positions (None: positions run on)
+        self.period = period
 
     def forward(self, x):
         from jax import lax
@@ -1691,6 +1739,8 @@ class Rope(Operator):
             except NameError:
                 off = 0
         pos = jnp.arange(S) + off
+        if self.period is not None:
+            pos = pos % self.period
         cos, sin = rope_tables(pos, x.shape[-1], self.theta, self.scaling)
         return apply_rope(x, cos, sin)
 

@@ -322,3 +322,22 @@ class NumpyBatchIter:
             # producer mid-transform can't be interrupted — bounded
             # join, and the daemon thread finishes its batch on its own
             t.join(timeout=1.0)
+
+
+def block_diffusion_noise(rng, shape, block, rate_min=1e-3):
+    """The noising draw of block-diffusion training (models/sdar.py) for a
+    batch of `shape` = (batch, seq), seq a multiple of `block`: a rate t_k
+    uniform on [rate_min, 1] for each block of `block` positions, each
+    position masked with its block's rate. Returns (masked (batch, seq)
+    int32, 1 where the token is replaced by [MASK]; weight (batch, seq)
+    float32 = masked / t_k: the masked-diffusion objective's weight under
+    the linear schedule; rates (batch, seq / block) float32). `rng`: a
+    numpy Generator; a user's loop and the benchmark's driver draw it here
+    alike."""
+    batch, seq = shape
+    assert seq % block == 0, (seq, block)
+    rates = rng.uniform(rate_min, 1.0, (batch, seq // block))
+    t = np.repeat(rates, block, axis=1)
+    masked = rng.random((batch, seq)) < t
+    return (masked.astype(np.int32), (masked / t).astype(np.float32),
+            rates.astype(np.float32))

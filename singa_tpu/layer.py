@@ -599,8 +599,11 @@ class SoftMax(Layer):
 
 
 class SoftMaxCrossEntropy(Layer):
-    def forward(self, x, t):
-        return autograd.softmax_cross_entropy(x, t)
+    """Mean softmax cross-entropy; with `weight` (one a row) the mean of
+    weight x cross-entropy over all rows."""
+
+    def forward(self, x, t, weight=None):
+        return autograd.softmax_cross_entropy(x, t, weight)
 
 
 class MeanSquareError(Layer):
@@ -672,18 +675,35 @@ class MultiHeadAttention(Layer):
     binding term of the decode roofline.
 
     `head_dim`: the width of a head where it is not `E / num_heads` (q is
-    then num_heads x head_dim wide, Wo maps that back to E). `window`
-    (with `causal`): a query sees its last `window` keys, itself among
-    them; the flash kernels skip the tiles left of it. `rope_scaling`:
-    YaRN's parameters for the rotary tables (autograd.rope_tables)."""
+    then num_heads x head_dim wide, Wo maps that back to E).
+
+    The mask is ONE of `autograd.attention_mask`'s kinds, named by
+    (`causal`, `window`, `block_diffusion`) and checked here: `causal`
+    alone; `window` with `causal`: a query sees its last `window` keys,
+    itself among them; `block_diffusion` (without `causal` or a window):
+    the input is a doubled sequence [noised ; clean] in blocks of that
+    length under the mask of block-diffusion training, and both halves
+    carry the rotary positions 0..S/2-1. The flash kernels visit no tile
+    outside the mask. `rope_scaling`: YaRN's parameters for the rotary
+    tables (autograd.rope_tables). `qk_norm_eps` (None: no such norm): an
+    RMS norm with a learned gain and that eps over each head of q and of
+    k (`q_norm`, `k_norm`), before the rotary."""
 
     def __init__(self, num_heads, causal=False, seq_axis=None, tp_axis=None,
                  bias=False, num_kv_heads=None, rope=False,
                  rope_theta=10000.0, head_dim=None, window=None,
-                 rope_scaling=None, name=None):
+                 rope_scaling=None, block_diffusion=None, qk_norm_eps=None,
+                 name=None):
         super().__init__(name)
         self.num_heads = num_heads
         self.head_dim, self.window = head_dim, window
+        self.block_diffusion = block_diffusion
+        # refuses, here and not at the first forward, what is not ONE mask
+        autograd.attention_mask(causal, window, block_diffusion)
+        self.qk_norm = qk_norm_eps is not None
+        if self.qk_norm:
+            self.q_norm, self.k_norm = RMSNorm(qk_norm_eps), \
+                RMSNorm(qk_norm_eps)
         self.rope_scaling = rope_scaling
         self.rope = bool(rope)          # rotary q/k (RoFormer/NeoX)
         self.rope_theta = rope_theta
@@ -759,20 +779,22 @@ class MultiHeadAttention(Layer):
         q = self._split(proj(Wq, bq), B, S, heads)
         k = self._split(proj(Wk, bk), B, S, kv_heads)
         v = self._split(proj(Wv, bv), B, S, kv_heads)
+        if self.qk_norm:
+            q, k = self.q_norm(q), self.k_norm(k)
         if self.rope:
             # rotate q/k before the kv-head repeat (rotation is per-head
             # identical, so rotating the Hkv heads is cheaper)
-            rop = autograd.Rope(self.rope_theta, self.seq_axis,
-                                self.rope_scaling)
-            q, k = rop(q), autograd.Rope(self.rope_theta, self.seq_axis,
-                                         self.rope_scaling)(k)
+            rope_args = (self.rope_theta, self.seq_axis, self.rope_scaling,
+                         S // 2 if self.block_diffusion else None)
+            q, k = autograd.Rope(*rope_args)(q), autograd.Rope(*rope_args)(k)
         if grp > 1:
             # GQA: each kv head serves `grp` consecutive query heads
             # (repeat on the head axis; XLA folds the broadcast)
             k = autograd.UpSample([1, grp, 1, 1])(k)
             v = autograd.UpSample([1, grp, 1, 1])(v)
         o = autograd.attention(q, k, v, causal=self.causal,
-                               seq_axis=self.seq_axis, window=self.window)
+                               seq_axis=self.seq_axis, window=self.window,
+                               block_diffusion=self.block_diffusion)
         o = autograd.transpose(o, (0, 2, 1, 3))
         o = autograd.reshape(o, (B, S, -1))
         y = autograd.matmul(o, Wo)
@@ -797,7 +819,9 @@ class TransformerBlock(Layer):
     it is no multiple of the block's; `ffn_bias=False` drops its biases;
     `post_norm=True` adds a norm on each branch's output before it joins
     the residual (`ln1_post`, `ln2_post`: "sandwich" norms). `head_dim`,
-    `window` and `rope_scaling` go to the attention as they are.
+    `window`, `rope_scaling` and `block_diffusion` go to the attention as
+    they are; `qk_norm=True` gives it RMS norms on q and k with the
+    block's `norm_eps`.
     `moe_dropless=True` takes `DroplessMoE` for the expert layer (SiLU-gated
     experts of width `ffn_dim`, no capacity, nothing dropped), of which this
     device holds `moe_held` experts from `moe_offset` on (None: all)."""
@@ -808,7 +832,8 @@ class TransformerBlock(Layer):
                  rope=False, rope_theta=10000.0, norm="layer", norm_eps=None,
                  ffn="gelu", ffn_dim=None, ffn_bias=True, post_norm=False,
                  head_dim=None, window=None, rope_scaling=None,
-                 moe_dropless=False, moe_held=None, moe_offset=0, name=None):
+                 moe_dropless=False, moe_held=None, moe_offset=0,
+                 block_diffusion=None, qk_norm=False, name=None):
         super().__init__(name)
         assert norm in ("layer", "rms") and ffn in ("gelu", "swiglu"), \
             (norm, ffn)
@@ -823,7 +848,10 @@ class TransformerBlock(Layer):
                                        num_kv_heads=num_kv_heads,
                                        rope=rope, rope_theta=rope_theta,
                                        head_dim=head_dim, window=window,
-                                       rope_scaling=rope_scaling)
+                                       rope_scaling=rope_scaling,
+                                       block_diffusion=block_diffusion,
+                                       qk_norm_eps=(norm_eps or RMSNorm().eps)
+                                       if qk_norm else None)
         self.ln2 = make_norm()
         self.post_norm = post_norm
         if post_norm:

@@ -9,7 +9,7 @@ dispatch in every model file; here it lives once in `base.Classifier`).
 
 from .base import Classifier  # noqa: F401
 from . import (mlp, cnn, alexnet, resnet, xceptionnet, transformer,  # noqa: F401
-               looplm, mellum)
+               looplm, mellum, sdar)
 
 _REGISTRY = {
     "mlp": mlp.create_model,
@@ -26,6 +26,7 @@ _REGISTRY = {
     "gpt_pipe": transformer.create_pipelined,
     "looplm": looplm.create_model,
     "mellum": mellum.create_model,
+    "sdar": sdar.create_model,
 }
 
 

@@ -865,7 +865,8 @@ def record_attention_dispatch(site: str, path: str):
 
 
 def record_flash_tiles(site: str, visited: int, masked: int, square: int,
-                       skipped: int = 0, window: int | None = None):
+                       skipped: int = 0, window: int | None = None,
+                       block_diffusion: int | None = None):
     """The tile schedule one flash call site was traced with
     (ops.attention.flash_plan), in sub-tiles a (batch x head) row: how
     many the kernel computes, how many of those add a mask, and the whole
@@ -873,18 +874,23 @@ def record_flash_tiles(site: str, visited: int, masked: int, square: int,
     sub-tiles the diagonal crosses; others visit all and mask none. Under
     a sliding window (`window`: its width, 0 for none) `skipped` counts
     the sub-tiles of blocks under the diagonal that lie wholly left of
-    the window, neither computed nor fetched. A gauge: it holds the site's
-    latest trace."""
+    the window, neither computed nor fetched; under the block-diffusion
+    mask (`block_diffusion`: its block length, 0 for another mask) every
+    sub-tile that is not visited, the clean x noised quadrant's among them.
+    A gauge: it holds the site's latest trace."""
     assert site in ATTN_SITES, site
     if not _enabled:
         return
     g = gauge("singa_flash_tiles",
               "sub-tiles a (batch x head) row in the latest traced flash "
               "call, by site and kind (visited|masked|square|skipped: left "
-              "of a sliding window; window: its width, 0 for none)")
+              "of a sliding window, or outside the block-diffusion mask; "
+              "window: its width, 0 for none; block_diffusion: the mask's "
+              "block length, 0 for another mask)")
     for kind, n in (("visited", visited), ("masked", masked),
                     ("square", square), ("skipped", skipped),
-                    ("window", window or 0)):
+                    ("window", window or 0),
+                    ("block_diffusion", block_diffusion or 0)):
         g.set(n, site=site, kind=kind)
 
 

@@ -48,12 +48,35 @@ def _causal_mask(sq, sk, q_off=0, k_off=0, dtype=jnp.float32,
 
 # ======================= 1. reference ====================================
 
-def attention_reference(q, k, v, causal=False, scale=None, window=None):
+def block_diffusion_visible(seq, block):
+    """(seq, seq) booleans: whether query i sees key j under the mask of
+    block-diffusion training. The sequence is [noised ; clean], two halves
+    of S = seq / 2 positions in blocks of `block`; with blk(i) =
+    (i mod S) // block a noised query sees the noised keys of its own block
+    and the clean keys of the blocks before it, a clean query the clean
+    keys of its own block and of those before it, and no noised key."""
+    half = seq // 2
+    assert seq == 2 * half and half % block == 0, (seq, block)
+    pos = jnp.arange(seq)
+    noised, blk = pos < half, (pos % half) // block
+    qn, kn = noised[:, None], noised[None, :]
+    qb, kb = blk[:, None], blk[None, :]
+    return jnp.where(qn, jnp.where(kn, kb == qb, kb < qb),
+                     _not(kn) & (kb <= qb))
+
+
+def attention_reference(q, k, v, causal=False, scale=None, window=None,
+                        block_diffusion=None):
     """q,k,v: (B, H, S, D). Returns (B, H, Sq, D). `window` (causal only):
-    a query sees its last `window` keys."""
+    a query sees its last `window` keys. `block_diffusion` (no other
+    mask): the block length of `block_diffusion_visible`'s mask."""
     d = q.shape[-1]
     scale = scale if scale is not None else d ** -0.5
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+    if block_diffusion is not None:
+        assert not causal and window is None and q.shape[2] == k.shape[2]
+        s = jnp.where(block_diffusion_visible(q.shape[2], block_diffusion),
+                      s, jnp.asarray(_NEG_INF, s.dtype))
     if causal:
         s = s + _causal_mask(q.shape[2], k.shape[2], dtype=s.dtype,
                              window=window)
@@ -100,9 +123,11 @@ def attention_reference(q, k, v, causal=False, scale=None, window=None):
 DEFAULT_BLOCK_Q = None
 DEFAULT_BLOCK_K = None
 
-# What a kernel's name ends in when it works under a sliding window: the
-# HLO instruction and its `op_name` tell a windowed call from a causal one.
+# What a kernel's name ends in when it works under a sliding window, or
+# under the block-diffusion mask: the HLO instruction and its `op_name` tell
+# such a call from a causal one.
 WINDOW_SUFFIX = "_win"
+BLOCKDIFF_SUFFIX = "_bd"
 
 # Largest tile a grid step holds. Bands are lane multiples (the backward
 # slices its per-row statistics along lanes); a block that no band divides
@@ -150,12 +175,16 @@ class FlashPlan(NamedTuple):
     dq / dkv pair. `window`: the sliding window the schedules were made
     for (None: none, or one that reaches every key); `skipped` (forward,
     backward): sub-tiles of the blocks at or under the diagonal that lie
-    wholly left of the window, which no grid step computes or fetches."""
+    wholly left of the window, which no grid step computes or fetches.
+    `block_diffusion`: the block length of the block-diffusion mask the
+    schedules were made for (None: another mask); `skipped` then counts
+    every sub-tile of the score square that no grid step computes."""
     fwd: FlashTiles | None
     bwd: FlashTiles | None
     fused: bool
     window: int | None = None
     skipped: tuple = (0, 0)
+    block_diffusion: int | None = None
 
     @property
     def ok(self):
@@ -274,6 +303,125 @@ def _window_counts(sq, sk, bq, bk, band, window):
         skipped
 
 
+# ---- the block-diffusion mask -------------------------------------------------
+# The sequence is [noised ; clean], S positions each, in blocks of b
+# (`block_diffusion_visible`). In square tiles that tile S and that b
+# divides, with nh = S / tile, tile (q block j, k block kb) of the 2 nh x
+# 2 nh is one of: "nn" (noised x noised, kb == j: block-diagonal inside);
+# "nc" (noised x clean on the quadrant's diagonal, kb == j + nh: blocks
+# strictly before the query's); "cc" (clean x clean on the diagonal: blocks
+# at or before); "full" (under the diagonal of those two quadrants: no
+# mask); or nothing (above a diagonal, off it in noised x noised, all of
+# clean x noised): no grid step. A q block's sweep is its "nn" tile FIRST
+# (every row has met a key it sees before a tile that hides all from some
+# rows), then the clean tiles up to its diagonal: nh + 1 steps; a k block's
+# sweep is the noised q blocks from its diagonal on, then the clean ones:
+# 2 nh steps, one for a noised k block. Where b divides the band too, a
+# diagonal tile goes in bands that stop at the diagonal under one band x
+# band mask ("nn": the diagonal sub-tiles alone); else a band meets the
+# whole tile under one mask.
+
+def _pick(cond, a, b):
+    return a if cond else b
+
+
+def _bd_k_of(j, step, nh, where=_pick):
+    """The k block that step `step` of q block j's sweep works on (past
+    the sweep's end: one of no kind); `where`: jnp's where j is traced."""
+    return where(j < nh, where(step == 0, j, nh + step - 1), nh + step)
+
+
+def _bd_q_of(kb, step, nh, where=_pick):
+    """The q block that step `step` of k block kb's sweep works on (past
+    the sweep's end: 2 nh or more, no block)."""
+    return where(kb < nh, where(step == 0, kb, 2 * nh),
+                 where(step < 2 * nh - kb, kb - nh + step,
+                       step + 2 * (kb - nh)))
+
+
+def _bd_kinds(j, kb, nh):
+    """{kind: whether tile (q block j, k block kb) is worked as that kind}
+    under the block-diffusion mask, for python numbers and traced ones."""
+    qn, kn = j < nh, kb < nh
+    inside = (j < 2 * nh) & (kb < 2 * nh)
+    return {"nn": qn & (kb == j), "nc": qn & (kb == j + nh),
+            "cc": _not(qn) & (kb == j) & inside,
+            "full": _not(kn) & inside & (
+                (qn & (kb < j + nh)) | (_not(qn) & (kb < j)))}
+
+
+def _bd_tile_mask(kind, rows, cols, block, q0=0, k0=0, transposed=False):
+    """Additive mask of `rows` queries from q0 on against `cols` keys from
+    k0 on, both counted from the start of a tile of kind "nn", "nc" or
+    "cc" that `block` divides: (rows, cols), or (cols, rows) for scores
+    held keys x queries."""
+    shape, q_ax = ((cols, rows), 1) if transposed else ((rows, cols), 0)
+    pow2 = block & (block - 1) == 0
+
+    def blk(off, axis):
+        pos = off + lax.broadcasted_iota(jnp.int32, shape, axis)
+        # (the shift: what the TPU's vector unit surely has)
+        return lax.shift_right_logical(pos, block.bit_length() - 1) \
+            if pow2 else lax.div(pos, jnp.int32(block))
+    qb, kb = blk(q0, q_ax), blk(k0, 1 - q_ax)
+    seen = {"nn": kb == qb, "nc": kb < qb, "cc": kb <= qb}[kind]
+    return jnp.where(seen, 0.0, _NEG_INF).astype(jnp.float32)
+
+
+def _bd_counts(nh, n, banded):
+    """(visited, masked) sub-tiles of the 2 nh x 2 nh tiles of n x n
+    sub-tiles each, counted over the tiles as the kernels work them."""
+    visited = masked = 0
+    for j in range(2 * nh):
+        for kb in range(2 * nh):
+            kind = next((k for k, on in _bd_kinds(j, kb, nh).items() if on),
+                        None)
+            if kind == "full":
+                visited += n * n
+            elif kind and not banded:
+                visited, masked = visited + n * n, masked + n * n
+            elif kind:
+                visited += n if kind == "nn" else n * (n + 1) // 2
+                masked += n
+    return visited, masked
+
+
+def _bd_plan(seq, d, dtype, block_q, block_k, block):
+    """flash_plan's schedules under the block-diffusion mask: square tiles
+    that tile a half and that the block length divides, else no plan (the
+    reference path); the bands of a streamed K."""
+    half = seq // 2
+    assert seq == 2 * half and half % block == 0, (seq, block)
+    none = FlashPlan(None, None, False)
+    if block_q is None and block_k is None:
+        tile = next((t for t in range(min(_BLOCK_TARGET, half) // 8 * 8,
+                                      min(128, half) - 1, -8)
+                     if half % t == 0 and t % block == 0), None)
+    else:
+        tile = block_q or block_k
+        if (block_k or tile) != tile or tile % 8 or half % tile \
+                or tile % block:
+            tile = None
+    if tile is None:
+        return none
+    nh = half // tile
+
+    def tiles(bands):
+        band = _band(tile, tile, bands)
+        u = band or tile
+        visited, masked = _bd_counts(nh, tile // u, u % block == 0)
+        square = (seq // u) ** 2
+        return FlashTiles(tile, tile, band, visited, masked, square), \
+            square - visited
+
+    fwd, fwd_skipped = tiles((128,))
+    bwd, bwd_skipped = tiles((256, 128))
+    if not bwd.band and tile > _UNBANDED_BWD_CAP:
+        return none
+    return FlashPlan(fwd, bwd, _fused_fits(seq, d, dtype), None,
+                     (fwd_skipped, bwd_skipped), block)
+
+
 def flash_window(window, causal, sk):
     """The window a call is scheduled for: None where there is none or it
     reaches every key (the plain causal program, to the instruction)."""
@@ -285,14 +433,21 @@ def flash_window(window, causal, sk):
 
 
 def flash_plan(sq, sk, d, causal, dtype, block_q=None, block_k=None,
-               window=None):
+               window=None, block_diffusion=None):
     """The tile schedule of one flash_attention call, forward and backward,
     from what the call can see. Blocks: an explicit block is honoured when
     it tiles its sequence on 8-sublane alignment (else ok=False -> the
     reference path); None takes the largest evenly-tiling block at or
     under 1024 (under a sliding window: at or under the window, so that
     the work follows it), so S <= 1024 is one grid step a (batch x head)
-    row and S = 384 or 896 still run the kernel."""
+    row and S = 384 or 896 still run the kernel. `block_diffusion`: the
+    block length of the block-diffusion mask (`_bd_plan`), which goes with
+    no other mask."""
+    if block_diffusion is not None:
+        assert not causal and window is None and sq == sk, \
+            "the block-diffusion mask is the call's only mask, over one " \
+            f"doubled sequence: causal={causal} window={window} {sq}x{sk}"
+        return _bd_plan(sq, d, dtype, block_q, block_k, int(block_diffusion))
     window = flash_window(window, causal, sk)
     target = _BLOCK_TARGET if window is None \
         else min(_BLOCK_TARGET, max(128, window))
@@ -385,15 +540,19 @@ def _dot_nt(a, b):
 
 
 def _visit_by_diagonal(causal, square, single, j, kb, block_q, block_k,
-                       visit, window=None, nq=None, nk=None):
+                       visit, window=None, nq=None, nk=None, bd=None):
     """Run `visit(kind)` for this grid step's (block_q, block_k) tile:
     "full" (no mask), "diag" (square blocks, the tile on the diagonal:
     bands stop at it), "crossed" (blocks that are not square: one mask
     over the tile, built from the step's offsets), or nothing for a tile
     above the diagonal. The DMA for skipped tiles is elided too:
     _causal_kv_map / _causal_q_map re-address the last needed block.
-    Under a `window` the kinds are _window_kinds'."""
-    if window is not None:
+    Under a `window` the kinds are _window_kinds', under the
+    block-diffusion mask (`bd`) _bd_kinds'."""
+    if bd is not None:
+        for kind, on in _bd_kinds(j, kb, nq // 2).items():
+            pl.when(on)(functools.partial(visit, kind))
+    elif window is not None:
         for kind, on in _window_kinds(j, kb, block_q, block_k, nq, nk,
                                       window).items():
             pl.when(on)(functools.partial(visit, kind))
@@ -413,7 +572,7 @@ def _visit_by_diagonal(causal, square, single, j, kb, block_q, block_k,
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
                       nq, nk, block_q, block_k, band, causal, window,
-                      steps):
+                      steps, bd=None):
     """Grid: (batch*heads, q_blocks, k_blocks) — K/V blocks STREAM through
     VMEM one (block_k, D) tile at a time (no whole-row residency, so
     sequence length is bounded by HBM, not VMEM). Inside a step the query
@@ -423,10 +582,14 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
     not once a sub-tile). With nk > 1 the online-softmax state (acc, m, l)
     lives in VMEM scratch, which persists across the k grid dimension:
     `steps` entries, nk of them, or under a `window` as many as a q
-    block's window reaches, counted from the first k block it reaches."""
+    block's window reaches, counted from the first k block it reaches;
+    under the block-diffusion mask (`bd`: its block length) the steps of
+    _bd_k_of's sweep."""
     j = pl.program_id(1)
     step = kb = pl.program_id(2)
-    if window is not None:
+    if bd is not None:
+        kb = _bd_k_of(j, step, nq // 2, jnp.where)
+    elif window is not None:
         kb = step + _k_range(j, block_q, block_k, nk, window,
                              jnp.maximum, jnp.minimum)[0]
     square = block_q == block_k
@@ -459,6 +622,11 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
         # would each reload the key tile into the MXU for no column saved
         rows_per = block_q if kind == "full" and steps > 1 \
             else band or block_q
+        # a diagonal tile of the block-diffusion mask in bands that the
+        # block length divides: one band x band mask serves every band
+        bd_mask = _bd_tile_mask(kind, rows_per, rows_per, bd) \
+            if bd is not None and kind != "full" and rows_per % bd == 0 \
+            else None
         for r in range(block_q // rows_per):
             lo, hi = r * rows_per, (r + 1) * rows_per
             rows = slice(lo, hi)
@@ -470,6 +638,12 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
                     + ([(hi, block_k, None)] if hi < block_k else [])
             elif kind == "full":
                 cols = [(0, block_k, None)]
+            elif bd_mask is not None:
+                cols = ([(0, lo, None)] if lo and kind != "nn" else []) \
+                    + [(lo, hi, bd_mask)]
+            elif bd is not None:
+                cols = [(0, block_k, _bd_tile_mask(kind, rows_per, block_k,
+                                                   bd, q0=lo))]
             else:
                 cols = [(0, block_k, _causal_mask(
                     rows_per, block_k, q_off=j * block_q + lo,
@@ -507,7 +681,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
             m_ref[rows, :] = jnp.broadcast_to(m_new, (rows_per, _STAT_LANES))
 
     _visit_by_diagonal(causal, square, nq == 1 and nk == 1, j, kb, block_q,
-                       block_k, visit, window, nq, nk)
+                       block_k, visit, window, nq, nk, bd)
 
     if steps > 1:
         @pl.when(step == steps - 1)
@@ -516,14 +690,23 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
                    acc_ref[...])
 
 
-def _causal_kv_map(causal, block_q, block_k, nk, window=None):
+def _causal_kv_map(causal, block_q, block_k, nk, window=None, bd=None):
     """K/V BlockSpec index map for grids with kb innermost after the q
     block index. Causal: kb is CLAMPED to this q block's diagonal block,
     so every fully-masked step re-addresses the last needed block and
     Pallas skips the DMA (the copy only fires when the block index
     changes) — masked K/V tiles are neither computed nor streamed.
     Under a `window` the innermost index counts from the first k block
-    the q block's window reaches."""
+    the q block's window reaches; under the block-diffusion mask it follows
+    _bd_k_of's sweep, clamped to the q block's diagonal tile."""
+    if bd is not None:
+        nh = nk // 2
+
+        def bmap(i, j, step):
+            kb = _bd_k_of(j, step, nh, jnp.where)
+            return (i, jnp.minimum(kb, jnp.where(j < nh, j + nh, j)), 0)
+
+        return bmap
     if window is not None:
         def wmap(i, j, step):
             first, last = _k_range(j, block_q, block_k, nk, window,
@@ -541,12 +724,21 @@ def _causal_kv_map(causal, block_q, block_k, nk, window=None):
     return kmap
 
 
-def _causal_q_map(causal, block_q, block_k, nq=None, window=None):
+def _causal_q_map(causal, block_q, block_k, nq=None, window=None, bd=None):
     """Q-side BlockSpec index map for the dK/dV grid (bh, kb, j): causal
     clamps j UP to the first unmasked q block for kb, so the leading
     masked steps address the same tile and their DMA is elided. Under a
     `window` the innermost index counts from that block and is clamped to
-    the last one whose rows still see kb."""
+    the last one whose rows still see kb; under the block-diffusion mask it
+    follows _bd_q_of's sweep (a noised k block: its own q block alone)."""
+    if bd is not None:
+        nh = nq // 2
+
+        def bmap(i, kb, step):
+            j = _bd_q_of(kb, step, nh, jnp.where)
+            return (i, jnp.where(kb < nh, kb, jnp.minimum(j, nq - 1)), 0)
+
+        return bmap
     if window is not None:
         def wmap(i, kb, step):
             first, last = _q_range(kb, block_q, block_k, nq, window,
@@ -564,7 +756,8 @@ def _causal_q_map(causal, block_q, block_k, nq=None, window=None):
     return qmap
 
 
-def _flash_fwd_pallas(q, k, v, causal, scale, tiles, interpret, window=None):
+def _flash_fwd_pallas(q, k, v, causal, scale, tiles, interpret, window=None,
+                      bd=None):
     b, h, sq, d = q.shape
     sk = k.shape[2]
     bh = b * h
@@ -577,13 +770,16 @@ def _flash_fwd_pallas(q, k, v, causal, scale, tiles, interpret, window=None):
     nk = sk // block_k
     nq = sq // block_q
     steps, name = nk, "singa_flash_fwd"
-    if window is not None:
+    if bd is not None:
+        steps = nk // 2 + 1
+        name += BLOCKDIFF_SUFFIX
+    elif window is not None:
         steps = _window_steps(sq, sk, block_q, block_k, window)[0]
         name += WINDOW_SUFFIX
     kernel = functools.partial(
         _flash_fwd_kernel, nq=nq, nk=nk, block_q=block_q, block_k=block_k,
-        band=band, causal=causal, window=window, steps=steps)
-    kvmap = _causal_kv_map(causal, block_q, block_k, nk, window)
+        band=band, causal=causal, window=window, steps=steps, bd=bd)
+    kvmap = _causal_kv_map(causal, block_q, block_k, nk, window, bd)
     out, lse = pl.pallas_call(
         kernel,
         name=name,
@@ -614,7 +810,7 @@ def _flash_fwd_pallas(q, k, v, causal, scale, tiles, interpret, window=None):
 
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       *refs, nq, nk, block_q, block_k, band, causal, scale,
-                      outs, window, steps):
+                      outs, window, steps, bd=None):
     """The backward of one (block_q, block_k) tile, TRANSPOSED: scores are
     held keys x queries, so p.T and ds.T — what dv = p.T @ do and
     dk = ds.T @ q consume — come out of the matmuls as they are, the
@@ -636,7 +832,8 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     The grid's last dimension has `steps` entries: every block of the
     streamed side, or under a `window` as many as it reaches, counted from
-    the first block it reaches (_k_range, _q_range)."""
+    the first block it reaches (_k_range, _q_range), or under the
+    block-diffusion mask (`bd`) the steps of _bd_k_of's / _bd_q_of's sweep."""
     refs = list(refs)
     want_dq, want_dkv = outs != "dkv", outs != "dq"
     dq_ref = refs.pop(0) if want_dq else None
@@ -646,7 +843,9 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     if outs == "dq":
         j, kb = pl.program_id(1), pl.program_id(2)
         step = kb
-        if window is not None:
+        if bd is not None:
+            kb = _bd_k_of(j, step, nq // 2, jnp.where)
+        elif window is not None:
             kb = step + _k_range(j, block_q, block_k, nk, window,
                                  jnp.maximum, jnp.minimum)[0]
         dq_first, dq_last = step == 0, step == steps - 1
@@ -654,7 +853,9 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     else:
         kb, j = pl.program_id(1), pl.program_id(2)
         step = j
-        if window is not None:
+        if bd is not None:
+            j = _bd_q_of(kb, step, nq // 2, jnp.where)
+        elif window is not None:
             j = step + _q_range(kb, block_q, block_k, nq, window,
                                 jnp.maximum, jnp.minimum)[0]
         dq_first = (kb == 0) & (step == 0)
@@ -682,6 +883,10 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if window is not None and square and window % block_q == 0 else None
 
     def visit(kind):
+        bd_mask = _bd_tile_mask(kind, rows_per, rows_per, bd,
+                                transposed=True) \
+            if bd is not None and kind != "full" and rows_per % bd == 0 \
+            else None
         for c in range(block_k // rows_per):
             lo, hi = c * rows_per, (c + 1) * rows_per
             if kind == "diag":
@@ -692,6 +897,13 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     + [(lo, hi, edge_mask)]
             elif kind == "full":
                 cols = [(0, block_q, None)]
+            elif bd_mask is not None:
+                cols = [(lo, hi, bd_mask)] \
+                    + ([(hi, block_q, None)]
+                       if hi < block_q and kind != "nn" else [])
+            elif bd is not None:
+                cols = [(0, block_q, _bd_tile_mask(
+                    kind, block_q, rows_per, bd, k0=lo, transposed=True))]
             else:
                 cols = [(0, block_q, _causal_mask(
                     block_q, rows_per, q_off=j * block_q,
@@ -732,7 +944,7 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dv_acc[lo:hi, :] += dv
 
     _visit_by_diagonal(causal, square, nq == 1 and nk == 1, j, kb, block_q,
-                       block_k, visit, window, nq, nk)
+                       block_k, visit, window, nq, nk, bd)
 
     if want_dq:
         @pl.when(dq_last)
@@ -765,7 +977,7 @@ def _flash_bwd_stats(o, lse, do, block_q):
 
 
 def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, tiles, fused,
-                      interpret, stats=None, window=None):
+                      interpret, stats=None, window=None, bd=None):
     """Pallas flash backward: the fused kernel, or the dq + dkv pair."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
@@ -784,7 +996,9 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, tiles, fused,
     shape_v = jax.ShapeDtypeStruct((bh, sk, d), v.dtype)
     acc_k = pltpu.VMEM((block_k, d), jnp.float32)
     k_steps, q_steps, suffix = nk, nq, ""
-    if window is not None:
+    if bd is not None:
+        k_steps, q_steps, suffix = nk // 2 + 1, nq, BLOCKDIFF_SUFFIX
+    elif window is not None:
         k_steps, q_steps = _window_steps(sq, sk, block_q, block_k, window)
         suffix = WINDOW_SUFFIX
 
@@ -797,7 +1011,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, tiles, fused,
             functools.partial(
                 _flash_bwd_kernel, nq=nq, nk=nk, block_q=block_q,
                 block_k=block_k, band=band, causal=causal, scale=scale,
-                outs=outs, window=window, steps=grid[2]),
+                outs=outs, window=window, steps=grid[2], bd=bd),
             name=name + suffix, grid=grid,
             in_specs=[q_spec, kv_spec, kv_spec, q_spec, stat_spec,
                       stat_spec],
@@ -807,7 +1021,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, tiles, fused,
 
     # grid (bh, k blocks, q blocks): q-side tiles stream, clamped to the
     # first block that sees this k block
-    qmap = _causal_q_map(causal, block_q, block_k, nq, window)
+    qmap = _causal_q_map(causal, block_q, block_k, nq, window, bd)
     kvmap_kq = lambda i, kb, j: (i, kb, 0)
     dkv_specs = [pl.BlockSpec((1, block_k, d), kvmap_kq)] * 2
     if fused:
@@ -820,7 +1034,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, tiles, fused,
         qmap_qk = lambda i, j, kb: (i, j, 0)
         dq = call(
             "dq", "singa_flash_bwd_dq", (bh, nq, k_steps), qmap_qk,
-            _causal_kv_map(causal, block_q, block_k, nk, window),
+            _causal_kv_map(causal, block_q, block_k, nk, window, bd),
             pl.BlockSpec((1, block_q, d), qmap_qk), shape_q,
             [pltpu.VMEM((block_q, d), jnp.float32)])
         dk, dv = call(
@@ -870,17 +1084,20 @@ def _flash_bwd_blockwise(q, k, v, o, lse, do, causal, scale, block_k,
     return (dq * scale).astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def flash_attention(q, k, v, causal=False, scale=None,
                     block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
-                    interpret=None, window=None):
+                    interpret=None, window=None, block_diffusion=None):
     """Fused attention; q,k,v (B,H,S,D). Falls back to the reference path
     when shapes don't tile (S % block != 0) or Pallas is unavailable.
     `window` (with `causal`): a query sees its last `window` keys, itself
     among them; tiles wholly left of the window are not visited. None, or
-    a window that reaches every key, is the causal program itself."""
+    a window that reaches every key, is the causal program itself.
+    `block_diffusion` (no other mask): the sequence is [noised ; clean] in
+    blocks of that length under `block_diffusion_visible`'s mask; tiles
+    outside it are not visited, and the kernels' names end in `_bd`."""
     out, _ = _flash_fwd(q, k, v, causal, scale, block_q, block_k,
-                        interpret, window)
+                        interpret, window, block_diffusion)
     return out
 
 
@@ -896,38 +1113,39 @@ def _kernel_path(interpret):
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
-               window=None):
+               window=None, block_diffusion=None):
     d = q.shape[-1]
     scale, interpret = _resolve(scale, d, interpret)
     plan = flash_plan(q.shape[2], k.shape[2], d, causal, q.dtype, block_q,
-                      block_k, window)
+                      block_k, window, block_diffusion)
     if not _HAS_PALLAS or not plan.ok:
         record_attention_dispatch("flash_fwd", "reference")
-        return attention_reference(q, k, v, causal, scale, window), None
+        return attention_reference(q, k, v, causal, scale, window,
+                                   block_diffusion), None
     record_attention_dispatch("flash_fwd", _kernel_path(interpret))
     record_flash_tiles("flash_fwd", *plan.fwd[3:], plan.skipped[0],
-                       plan.window)
+                       plan.window, plan.block_diffusion)
     return _flash_fwd_pallas(q, k, v, causal, scale, plan.fwd, interpret,
-                             plan.window)
+                             plan.window, plan.block_diffusion)
 
 
 def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
-                   window):
+                   window, block_diffusion):
     out, lse = _flash_fwd(q, k, v, causal, scale, block_q, block_k,
-                          interpret, window)
+                          interpret, window, block_diffusion)
     if lse is None:  # fallback path: vjp of the reference impl
         d = q.shape[-1]
         s, _ = _resolve(scale, d, interpret)
         _, ref_vjp = jax.vjp(
             lambda q_, k_, v_: attention_reference(q_, k_, v_, causal, s,
-                                                   window),
+                                                   window, block_diffusion),
             q, k, v)
         return out, (None, ref_vjp)
     return out, ((q, k, v, out, lse), None)
 
 
-def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, window, res,
-                   g):
+def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, window,
+                   block_diffusion, res, g):
     saved, ref_vjp = res
     if saved is None:
         record_attention_dispatch("flash_bwd", "reference")
@@ -937,13 +1155,14 @@ def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, window, res,
     s, interp = _resolve(scale, d, interpret)
     sk = k.shape[2]
     plan = flash_plan(q.shape[2], sk, d, causal, q.dtype, block_q, block_k,
-                      window)
+                      window, block_diffusion)
     if _HAS_PALLAS and plan.bwd:
         record_attention_dispatch("flash_bwd", _kernel_path(interp))
         record_flash_tiles("flash_bwd", *plan.bwd[3:], plan.skipped[1],
-                           plan.window)
+                           plan.window, plan.block_diffusion)
         return _flash_bwd_pallas(q, k, v, out, lse, g, causal, s, plan.bwd,
-                                 plan.fused, interp, window=plan.window)
+                                 plan.fused, interp, window=plan.window,
+                                 bd=plan.block_diffusion)
     record_attention_dispatch("flash_bwd", "reference")
     return _flash_bwd_blockwise(q, k, v, out, lse, g, causal, s,
                                 _fit_block(sk, 512) or sk, plan.window)

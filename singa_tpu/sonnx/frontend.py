@@ -616,6 +616,8 @@ UNEXPORTABLE = {
     # the sparse model's training step (models/mellum.py)
     "_SampleLogits": "training step's sample of the logits, off the tape",
     "_Stack": "training step's rows routed a layer, off the tape",
+    "_Noise": "block-diffusion training step's noising and doubling of "
+              "the ids, off the tape",
     # shape/constant generators with no stable inference mapping
     "NonZero": "data-dependent output shape (host fallback op)",
     "Shape": "exported models carry static shapes",

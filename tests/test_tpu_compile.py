@@ -133,6 +133,33 @@ def test_windowed_flash_compiles_for_v5e(one_chip, shape, window, calls):
     assert "singa_flash_fwd" + A.WINDOW_SUFFIX in text
 
 
+# the block-diffusion mask: the SDAR cell's doubled sequence (2 x 4096 in
+# blocks of 4: tiles of 1024, the split backward), a short row whose
+# backward is fused, and a block length that no band divides (a diagonal
+# tile's bands each under a mask over the whole tile)
+@pytest.mark.parametrize("shape,block,calls", [
+    ((1, 4, 8192, 128), 4, (1, 3)),
+    ((1, 4, 2048, 64), 32, (1, 2)),
+    ((1, 4, 768, 128), 96, (1, 2)),
+], ids=["s8k_b4", "s2k_b32", "s768_b96"])
+def test_block_diffusion_flash_compiles_for_v5e(one_chip, shape, block,
+                                                calls):
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def fwd(q, k, v):
+        return A.flash_attention(q, k, v, False, None, None, None, False,
+                                 None, block)
+
+    def grad(q, k, v):
+        return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    text = jax.jit(grad).lower(q, q, q).compile().as_text()
+    assert (_mosaic_calls(fwd, q, q, q), text.count(
+        'custom_call_target="tpu_custom_call"')) == calls
+    assert "singa_flash_fwd" + A.BLOCKDIFF_SUFFIX in text
+
+
 # what ServingEngine builds for GPT-2-small in chip_smoke.py: 8 slots,
 # P=2 heads packed per 128-lane row -> Hp=6, Q=P*G=2 query rows per token,
 # pages of 16 tokens, 64 pages per sequence (max_ctx 1024), 512 in the pool
