@@ -901,7 +901,14 @@ class DroplessMoE(Layer):
     choice) pairs are sorted by expert and the SiLU-gated experts
     (silu(x Wg_e) * (x Wu_e)) Wd_e run as grouped matrix products over the
     rows really routed (parallel/moe.py `dropless_moe`). No capacity, no
-    (tokens, experts, capacity) tensor, no auxiliary loss.
+    (tokens, experts, capacity) tensor, no auxiliary loss. The sorted
+    buffer is sized for every pair at a held expert; the backward's passes
+    over its rows (the cotangent gathered row by row, the gate's backward,
+    the sum of the two input gradients) work on a rung, the least of 1/8,
+    1/4, 1/2 and the whole of it that holds the step's routed rows, chosen
+    on the device from the count the layer already has. The top rung is
+    the whole buffer, so no count overflows a rung and there is nothing to
+    configure.
 
     `held`, `offset`: this device holds experts offset .. offset + held - 1
     of `num_experts` (None: all). The router still scores all of them; the

@@ -28,6 +28,7 @@ import jax
 import numpy as np
 
 from .. import autograd, layer, model, observe
+from ..parallel.moe import rung_of, rungs
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 
@@ -127,33 +128,44 @@ class Mellum(model.Model):
         return loss, sampled, _Stack()(*rows)
 
 
-def _moe_plan(**kinds):
-    """What the latest traced step of a sparse model routes, readable with
-    no chip: `singa_moe_plan{kind}`."""
-    g = observe.gauge(
+def _plan_gauge():
+    return observe.gauge(
         "singa_moe_plan",
         "the latest traced step's expert layers, by kind: experts routed "
         "over, experts this device holds, choices a token (k), rows of the "
         "sorted buffer (tokens x min(k, held): the worst case; the grouped "
-        "products follow the rows really routed), blocks recomputed in the "
-        "backward pass")
-    for kind, v in kinds.items():
+        "products follow the rows really routed, the passes over the buffer "
+        "the rung that holds them), the least rung of the buffer's ladder, "
+        "blocks recomputed in the backward pass")
+
+
+def _moe_plan(**kinds):
+    """What the latest traced step of a sparse model routes, readable with
+    no chip: `singa_moe_plan{kind}`."""
+    g = _plan_gauge()
+    for kind, v in {**kinds,
+                    "rung_least": rungs(kinds["rows_worst"])[0]}.items():
         g.set(v, kind=kind)
 
 
 def record_rows(rows):
     """`rows` (L, held): a step's third output, fetched. Sets
-    `singa_moe_rows{layer, kind=routed|held_max|held_min}`: the rows routed
-    to this device's experts in each layer, and the largest and the least
-    load among them."""
+    `singa_moe_rows{layer, kind=routed|held_max|held_min|buffer}`: the rows
+    routed to this device's experts in each layer, the largest and the
+    least load among them, and the rung of the sorted buffer that the
+    layer's passes over its rows worked on (`parallel.moe.rung_of`, the
+    rule the step itself used, on the latest traced step's `rows_worst`)."""
     g = observe.gauge(
         "singa_moe_rows",
         "rows (token, choice pairs) routed to the experts this device "
         "holds in the latest fetched step, by layer: their sum, the largest "
-        "and the least expert's")
+        "and the least expert's, and the buffer length (a rung of the "
+        "ladder) the layer's row passes worked on")
+    worst = int(_plan_gauge().value(kind="rows_worst"))
     for i, r in enumerate(np.asarray(rows)):
         for kind, v in (("routed", r.sum()), ("held_max", r.max()),
-                        ("held_min", r.min())):
+                        ("held_min", r.min()),
+                        ("buffer", rung_of(r.sum(), worst))):
             g.set(float(v), layer=str(i), kind=kind)
 
 

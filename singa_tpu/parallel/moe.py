@@ -16,7 +16,12 @@ rides ICI and XLA overlaps it with the expert matmuls.
 (token, choice) pairs are sorted by expert and the experts run as grouped
 matrix products over the rows really routed; a device may hold a share of
 the experts and computes its part of the sum (no exchange across devices
-yet: the capacity path above is the one that runs under `ep_axis`).
+yet: the capacity path above is the one that runs under `ep_axis`). Its
+sorted buffer has a row for every pair that could be routed here; the
+backward's passes over the buffer's rows work on a RUNG of it, the least of
+an eighth, a quarter, a half and the whole that holds the rows really
+routed, picked on the device a layer and a step. The whole buffer is the
+top rung, so a rung cannot drop a row either.
 """
 
 from __future__ import annotations
@@ -25,6 +30,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 
@@ -176,6 +182,61 @@ def moe_ffn_ep(x, Wg, W1, b1, W2, b2, axis_name: str,
 # its outputs and in its input's gradient. Nothing zeroes them: every read
 # of a row goes through a SELECT on whether the pair has one (`held`), so
 # what lies there never meets a number.
+#
+# R is the worst case (every pair to a held expert), and an even share of
+# the pairs is an eighth or a quarter of it. What XLA makes around the
+# grouped products on the BACKWARD pass, row by row over the buffer, would
+# cost R rows whatever was routed: the cotangent's rows gathered in fp32,
+# the gate's backward, the sum of the two input gradients. So those passes
+# work on a RUNG: the least of R/8, R/4, R/2 and R that holds the rows
+# really routed, picked on the device from the count the layer has before
+# it gathers anything (`rung_of`), each pass under a `lax.switch` whose
+# branch does the same arithmetic on the rung's first rows and fills the
+# rest of its (R, .) result with zeros. The top rung is the whole buffer,
+# so a rung always holds every routed row: nothing is dropped by it and
+# there is nothing to set. The switches sit inside the backwards of
+# `custom_vjp`s: JAX never differentiates through one (it would keep every
+# branch's residuals). The grouped products, the router, the sorts and the
+# reads by pair (one a (token, choice) whatever was routed) are outside
+# them. So are the forward's two passes over the buffer (my chip run,
+# PR 35): the gather into it writes its 268 MB in 0.59 ms as it is (the
+# tokens sit in fast memory), which is what gathering an eighth and
+# filling the rest costs, and the gate's forward would gain 0.1 ms; and an
+# array that a conditional hands out counts TWICE in the memory the
+# compiler reserves for the step while it is live, a forward one from
+# there to the layer's backward.
+
+
+def rungs(R):
+    """The ladder of buffer lengths for a buffer of R rows: R/8, R/4, R/2
+    where they are whole, and R."""
+    return tuple(R // d for d in (8, 4, 2, 1) if R % d == 0)
+
+
+def rung_of(n, R):
+    """The least rung of `rungs(R)` that holds `n` rows. One rule for the
+    device (`n` traced: the count a layer routed) and the host (`n` a
+    number: `models.mellum.record_rows`)."""
+    xp = jnp if isinstance(n, jax.Array) else np
+    ladder = rungs(R)
+    return functools.reduce(lambda rung, b: xp.where(n <= b, b, rung),
+                            ladder[-2::-1], ladder[-1])
+
+
+def _on_rung(rung, R, rows_pass, *args):
+    """`rows_pass(B, *args)` for the one B of `rungs(R)` that `rung` is:
+    a branch a rung, and only the taken one runs."""
+    ladder = rungs(R)
+    return lax.switch(sum(rung > b for b in ladder[:-1]),
+                      [functools.partial(rows_pass, B) for B in ladder],
+                      *args)
+
+
+def _fill(a, R):
+    """`a` (B, ...) -> (R, ...), zeros past its rows."""
+    if a.shape[0] == R:
+        return a
+    return jnp.pad(a, ((0, R - a.shape[0]),) + ((0, 0),) * (a.ndim - 1))
 
 
 def route_topk(x, Wr, k):
@@ -189,56 +250,100 @@ def route_topk(x, Wr, k):
 
 
 def _rows_of_pairs(a, inv, held):
-    """a (R, D) -> (T, k, D) fp32: each pair's row, zeros where it has
-    none."""
+    """a (B, D), B a rung that holds every pair's row -> (T, k, D) fp32:
+    each pair's row, zeros where it has none."""
     rows = a[jnp.minimum(inv, a.shape[0] - 1)]
     return jnp.where(held[..., None], rows, 0).astype(jnp.float32)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _rows_of_tokens(x, order, inv, held, k):
-    """x (T, D) -> the buffer's rows (R, D): row r is the token of pair
-    order[r]. `inv` (T, k): the row of each pair; `held` (T, k): whether
-    it has one (its expert is held here and the row is in the buffer)."""
-    return x[order // k]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _rows_of_tokens(x, order, inv, held, rung, k):
+    """x (T, D) -> the buffer's rows (R, D), once for each of the two
+    products that read them (so that their two cotangents meet HERE, on the
+    rung, and not in a sum over R rows that JAX would write): row r is the
+    token of pair order[r]. `inv` (T, k): the row of each pair; `held`
+    (T, k): whether it has one (its expert is held here and the row is in
+    the buffer); `rung`: the buffer length the backward works on."""
+    return _rows_fwd(x, order, inv, held, rung, k)[0]
 
 
-def _rows_fwd(x, order, inv, held, k):
-    return x[order // k], (inv, held)
+def _rows_fwd(x, order, inv, held, rung, k):
+    xs = x[order // k]
+    return (xs, xs), (inv, held, rung)
 
 
-def _rows_bwd(k, res, g):
-    inv, held = res
-    return jnp.sum(_rows_of_pairs(g, inv, held), axis=1).astype(g.dtype), \
-        None, None, None
+def _rows_bwd(k, res, gs):
+    inv, held, rung = res
+
+    def tokens(B, g_gate, g_up, inv, held):
+        g = g_gate[:B] + g_up[:B]
+        return jnp.sum(_rows_of_pairs(g, inv, held), axis=1).astype(g.dtype)
+
+    return _on_rung(rung, gs[0].shape[0], tokens, *gs, inv, held), \
+        None, None, None, None
 
 
 _rows_of_tokens.defvjp(_rows_fwd, _rows_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _tokens_of_rows(o, gates, order, inv, held, k):
+def _silu_mul(hg, hu):
+    return jax.nn.silu(hg) * hu
+
+
+@jax.custom_vjp
+def _gate(hg, hu, rung):
+    """silu(hg) * hu of the two (R, F) products; its backward over the
+    rung's rows, zeros past them."""
+    return _silu_mul(hg, hu)
+
+
+def _gate_fwd(hg, hu, rung):
+    return _silu_mul(hg, hu), (hg, hu, rung)
+
+
+def _gate_bwd(res, dh):
+    hg, hu, rung = res
+    R = hg.shape[0]
+
+    def grads(B, hg, hu, dh):
+        dhg, dhu = jax.vjp(_silu_mul, hg[:B], hu[:B])[1](dh[:B])
+        return _fill(dhg, R), _fill(dhu, R)
+
+    return *_on_rung(rung, R, grads, hg, hu, dh), None
+
+
+_gate.defvjp(_gate_fwd, _gate_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _tokens_of_rows(o, gates, order, inv, held, rung, k):
     """y (T, D) fp32 = sum over a token's k pairs of gate x the pair's
     row of o (R, D); `gates` (T, k) is zero where the pair has no row."""
-    return _tokens_fwd(o, gates, order, inv, held, k)[0]
+    return _tokens_fwd(o, gates, order, inv, held, rung, k)[0]
 
 
-def _tokens_fwd(o, gates, order, inv, held, k):
+def _tokens_fwd(o, gates, order, inv, held, rung, k):
     return jnp.einsum("tk,tkd->td", gates, _rows_of_pairs(o, inv, held)), \
-        (o, gates, order, inv, held)
+        (o, gates, order, inv, held, rung)
 
 
 def _tokens_bwd(k, res, dy):
-    o, gates, order, inv, held = res
-    # row by row over the buffer, one pass: each row's token's cotangent
-    # times its gate is the row's, and their product summed is the gate's
-    # (read back by pair: numbers, not rows)
-    dy_rows = dy[order // k]                               # (R, D) fp32
-    do = gates.reshape(-1)[order][:, None] * dy_rows
-    dgate_rows = jnp.sum(o.astype(jnp.float32) * dy_rows, axis=1)
-    dgates = jnp.where(held, dgate_rows[jnp.minimum(inv, o.shape[0] - 1)],
-                       0.0)
-    return do.astype(o.dtype), dgates, None, None, None
+    o, gates, order, inv, held, rung = res
+    R = o.shape[0]
+
+    def rows(B, o, gates, order, dy):
+        # row by row over the rung, one pass: each row's token's cotangent
+        # times its gate is the row's, and their product summed is the
+        # gate's (read back by pair: numbers, not rows)
+        live = order[:B]
+        dy_rows = dy[live // k]                            # (B, D) fp32
+        do = gates.reshape(-1)[live][:, None] * dy_rows
+        dgate_rows = jnp.sum(o[:B].astype(jnp.float32) * dy_rows, axis=1)
+        return _fill(do.astype(o.dtype), R), _fill(dgate_rows, R)
+
+    do, dgate_rows = _on_rung(rung, R, rows, o, gates, order, dy)
+    dgates = jnp.where(held, dgate_rows[jnp.minimum(inv, R - 1)], 0.0)
+    return do, dgates, None, None, None, None
 
 
 _tokens_of_rows.defvjp(_tokens_fwd, _tokens_bwd)
@@ -298,12 +403,14 @@ def dropless_moe(x, Wr, Wg, Wu, Wd, k, offset=0):
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
         inv = jnp.argsort(order).astype(jnp.int32).reshape(T, k)
         order, held = order[:R], mine & (inv < R)
-        xs = _rows_of_tokens(x.astype(Wg.dtype), order, inv, held, k)
+        rung = rung_of(jnp.sum(sizes), R)
+        xs_gate, xs_up = _rows_of_tokens(x.astype(Wg.dtype), order, inv,
+                                         held, rung, k)
     with jax.named_scope("experts"):
-        h = jax.nn.silu(grouped_matmul(xs, Wg, sizes)) \
-            * grouped_matmul(xs, Wu, sizes)
+        h = _gate(grouped_matmul(xs_gate, Wg, sizes),
+                  grouped_matmul(xs_up, Wu, sizes), rung)
         o = grouped_matmul(h, Wd, sizes)
     with jax.named_scope("combine"):
         y = _tokens_of_rows(o, jnp.where(held, gates, 0.0), order, inv,
-                            held, k)
+                            held, rung, k)
     return y, lax.stop_gradient(sizes.astype(jnp.float32))

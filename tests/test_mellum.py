@@ -239,9 +239,11 @@ def test_moe_plan_and_rows_gauges():
                    tensor.from_numpy(y, device=dev))
     g = observe.get_registry().get("singa_moe_plan")
     plan = {k: int(g.value(kind=k)) for k in (
-        "experts", "held", "k", "rows_worst", "recomputed_blocks")}
+        "experts", "held", "k", "rows_worst", "rung_least",
+        "recomputed_blocks")}
     assert plan == {"experts": 8, "held": 4, "k": 4,
-                    "rows_worst": B * S * 4, "recomputed_blocks": 3}
+                    "rows_worst": B * S * 4, "rung_least": B * S * 4 // 8,
+                    "recomputed_blocks": 3}
     mellum.record_rows(rows.data)
     g = observe.get_registry().get("singa_moe_rows")
     r = np.asarray(rows.data)
@@ -249,6 +251,10 @@ def test_moe_plan_and_rows_gauges():
         assert g.value(layer=str(l), kind="routed") == r[l].sum()
         assert g.value(layer=str(l), kind="held_max") == r[l].max()
         assert g.value(layer=str(l), kind="held_min") == r[l].min()
+        # the buffer length the layer's row passes worked on: the least
+        # rung of the ladder that holds the rows routed
+        assert g.value(layer=str(l), kind="buffer") == min(
+            b for b in moe.rungs(B * S * 4) if b >= r[l].sum())
     # every pair whose expert is held is counted, none twice
     assert 0 < r.sum(1).max() <= B * S * 4
 
@@ -307,6 +313,82 @@ def _experts(T=64, D=32, F=48, E=8, seed=0):
             mk(.2, E, F, D))
 
 
+RUNGS = ("R_8", "R_4", "R_2", "R")
+
+
+def _wider_by_an_unread_input(Wg, Wu, Wd, sl):
+    """The weights of experts `sl` for an input one coordinate wider: they
+    do not read it (the router may)."""
+    held = Wg[sl].shape[0]
+    pad = lambda W: jnp.concatenate([W[sl], jnp.zeros((held, 1, 48))], 1)
+    return pad(Wg), pad(Wu), jnp.concatenate(
+        [Wd[sl], jnp.zeros((held, 48, 1))], 2)
+
+
+def _routed(rung, held, offset, k=4):
+    """(`_experts()` with one more input coordinate that only the held
+    experts' router logits read, R): the coordinate's weight is the first
+    of a scan at which the rows routed to the held experts need rung
+    `rung` of the buffer's ladder (0: R/8 .. 3: R) and no lower one."""
+    x, Wr, Wg, Wu, Wd = _experts()
+    T, E = x.shape[0], Wr.shape[1]
+    R = T * min(k, held)
+    least, most = ((0,) + moe.rungs(R))[rung:rung + 2]
+    here = (np.arange(E) >= offset) & (np.arange(E) < offset + held)
+    logits = np.asarray(x @ Wr)
+    for bias in np.arange(-30.0, 30.0, 0.1):
+        chosen = np.argsort(-(logits + bias * here), 1)[:, :k]
+        if least < here[chosen].sum() <= most:
+            break
+    else:
+        raise AssertionError((rung, held, offset))
+    return (jnp.concatenate([x, jnp.ones((T, 1))], 1),
+            jnp.concatenate([Wr, jnp.asarray(bias * here, Wr.dtype)[None]]),
+            *_wider_by_an_unread_input(
+                Wg, Wu, Wd, slice(offset, offset + held))), R
+
+
+@pytest.mark.parametrize("n,R,want", [
+    (0, 64, 8), (1, 64, 8), (8, 64, 8), (9, 64, 16), (33, 64, 64),
+    (64, 64, 64),
+    # an R that 8 does not divide: the rungs that are whole
+    (3, 12, 3), (4, 12, 6), (7, 12, 12), (5, 7, 7)])
+def test_rung_of(n, R, want):
+    """The least of R/8, R/4, R/2 and R that holds n rows: one rule for a
+    number on the host (`record_rows` hands it a float32) and for a count
+    traced on the device."""
+    assert int(moe.rung_of(n, R)) == want
+    assert int(moe.rung_of(np.float32(n), R)) == want
+    assert int(jax.jit(lambda n: moe.rung_of(n, R))(jnp.int32(n))) == want
+    assert moe.rungs(R)[-1] == R and want in moe.rungs(R)
+
+
+@pytest.mark.parametrize("held,offset", [(2, 0), (3, 4)])
+@pytest.mark.parametrize("rung", range(4), ids=RUNGS)
+def test_row_passes_equal_the_whole_buffer(rung, held, offset, monkeypatch):
+    """In every rung of the ladder the layer's value, rows and five
+    gradients are BIT-equal to the same call made on the whole buffer
+    (`rung_of` patched to the top rung: the program before the ladder)."""
+    args, R = _routed(rung, held, offset)
+
+    def step():     # a new function a call: `jax.jit` traces it again
+        def run(*a):
+            layer = lambda *b: moe.dropless_moe(*b, 4, offset)
+            return layer(*a), jax.grad(
+                lambda *b: jnp.sum(jnp.sin(layer(*b)[0])),
+                (0, 1, 2, 3, 4))(*a)
+        return jax.jit(run)
+
+    (y, rows), grads = step()(*args)
+    assert int(moe.rung_of(rows.sum(), R)) == moe.rungs(R)[rung]
+    assert 0 < int(rows.sum())
+    monkeypatch.setattr(moe, "rung_of", lambda n, R: R)
+    (y_whole, rows_whole), grads_whole = step()(*args)
+    for a, b in zip((y, rows, *grads), (y_whole, rows_whole, *grads_whole)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    assert all(bool(jnp.any(g != 0)) for g in grads)
+
+
 @pytest.mark.parametrize("held,offset", [(8, 0), (2, 0), (2, 6), (3, 4)])
 def test_expert_layer_matches_a_dense_loop(held, offset):
     """Values, rows and all five gradients, for the whole layer and for a
@@ -327,11 +409,13 @@ def test_expert_layer_matches_a_dense_loop(held, offset):
     assert max(_rel(a, b) for a, b in zip(got, want)) < 2e-5
 
 
+@pytest.mark.parametrize("held,offset", [(8, 0), (1, 5)])
 @pytest.mark.parametrize("k", [1, 4])
-def test_every_token_sent_to_one_expert_and_none_dropped(k):
+def test_every_token_sent_to_one_expert_and_none_dropped(k, held, offset):
     """A router that sends every token to expert 5 first: its group is all
     T rows (a capacity would have dropped most), values and gradients as
-    the dense loop's."""
+    the dense loop's. With every pair at a held expert (all 8 held, or
+    expert 5 alone) the buffer is full: the top rung of its ladder."""
     x, Wr, Wg, Wu, Wd = _experts()
     Wr = Wr.at[:, 5].set(0.0)
     x = x.at[:, 0].set(0.0)
@@ -339,17 +423,18 @@ def test_every_token_sent_to_one_expert_and_none_dropped(k):
     # one more coordinate that only expert 5's logit reads
     x = jnp.concatenate([x, jnp.full((64, 1), 50.0)], 1)
     Wr = jnp.concatenate([Wr, jnp.zeros((1, 8)).at[0, 5].set(1.0)])
-    pad = lambda W: jnp.concatenate([W, jnp.zeros((8, 1, 48))], 1)
-    args = (x, Wr, pad(Wg), pad(Wu),
-            jnp.concatenate([Wd, jnp.zeros((8, 48, 1))], 2))
+    args = (x, Wr, *_wider_by_an_unread_input(
+        Wg, Wu, Wd, slice(offset, offset + held)))
+    R = 64 * min(k, held)
     with jax.default_matmul_precision("highest"):
-        y, rows = moe.dropless_moe(*args, k)
-        assert int(rows[5]) == 64 and int(rows.sum()) == 64 * k
-        assert _rel(y, _dense(*args, k)) < 1e-5
+        y, rows = moe.dropless_moe(*args, k, offset)
+        assert int(rows[5 - offset]) == 64 and int(rows.sum()) == R
+        assert int(moe.rung_of(rows.sum(), R)) == R
+        assert _rel(y, _dense(*args, k, offset)) < 1e-5
         grad = lambda fn: jax.grad(
             lambda *a: jnp.sum(jnp.sin(fn(*a))), (0, 2, 3, 4))(*args)
-        got = grad(lambda *a: moe.dropless_moe(*a, k)[0])
-        want = grad(lambda *a: _dense(*a, k))
+        got = grad(lambda *a: moe.dropless_moe(*a, k, offset)[0])
+        want = grad(lambda *a: _dense(*a, k, offset))
     assert max(_rel(a, b) for a, b in zip(got, want)) < 2e-5
 
 
@@ -420,11 +505,14 @@ def test_onnx_export_refuses_both_expert_layers():
     assert "_DroplessMoEOp" in table
 
 
-def test_rows_past_the_groups_never_meet_a_number(monkeypatch):
+@pytest.mark.parametrize("rung", range(4), ids=RUNGS)
+def test_rows_past_the_groups_never_meet_a_number(rung, monkeypatch):
     """The TPU's grouped kernel leaves the buffer's rows past the last
     group uninitialised, in its output and in its input's gradient. With
     NaNs planted there, values and every gradient are still the dense
-    loop's: each read of a row selects on whether the pair has one."""
+    loop's: each read of a row selects on whether the pair has one. In
+    every rung of the buffer's ladder: the row passes work on the rung's
+    rows, those between the last group and the rung's end among them."""
     plain = moe.grouped_matmul
 
     @jax.custom_vjp
@@ -448,11 +536,11 @@ def test_rows_past_the_groups_never_meet_a_number(monkeypatch):
 
     poisoned.defvjp(fwd, bwd)
     monkeypatch.setattr(moe, "grouped_matmul", poisoned)
-    x, Wr, Wg, Wu, Wd = _experts()
-    args = (x, Wr, Wg[2:5], Wu[2:5], Wd[2:5])
+    args, R = _routed(rung, 3, 2)
     with jax.default_matmul_precision("highest"):
         y, rows = moe.dropless_moe(*args, 4, 2)
-        assert int(rows.sum()) < 64 * 3          # rows are left over
+        assert int(rows.sum()) < R               # rows are left over
+        assert int(moe.rung_of(rows.sum(), R)) == moe.rungs(R)[rung]
         assert _rel(y, _dense(*args, 4, 2)) < 1e-5
         grad = lambda fn: jax.grad(
             lambda *a: jnp.sum(jnp.sin(fn(*a))), (0, 1, 2, 3, 4))(*args)

@@ -26,6 +26,7 @@ from singa_tpu import autograd, data, device, layer, models, observe, opt, \
     tensor
 from singa_tpu.models import sdar
 from singa_tpu.ops import attention as att
+from singa_tpu.parallel import moe
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -252,7 +253,7 @@ def test_scopes_name_the_noising_and_the_block_diffusion_kernels():
 
 def test_plan_gauges():
     m, dev = _build("bfloat16", True)
-    m(*_tensors(dev, _batch()))
+    _, _, rows = m(*_tensors(dev, _batch()))
     reg = observe.get_registry()
     g = reg.get("singa_blockdiff_plan")
     plan = {k: int(g.value(kind=k)) for k in (
@@ -263,7 +264,14 @@ def test_plan_gauges():
                     "pairs_square": B * 4 * S * S, "recomputed_blocks": 2}
     g = reg.get("singa_moe_plan")
     assert int(g.value(kind="rows_worst")) == 2 * B * S * 4
+    assert int(g.value(kind="rung_least")) == 2 * B * S * 4 // 8
     assert int(g.value(kind="experts")) == 16 and int(g.value(kind="held")) == 4
+    # the buffer length each layer's row passes worked on in that step
+    sdar.record_rows(rows.data)
+    g = reg.get("singa_moe_rows")
+    for l, r in enumerate(np.asarray(rows.data)):
+        assert g.value(layer=str(l), kind="buffer") == min(
+            b for b in moe.rungs(2 * B * S * 4) if b >= r.sum())
     # the loss took integer targets and read the forward's log-sum-exp
     g = reg.get("singa_cross_entropy")
     assert g.value(targets="integer", lse="kept") == 1

@@ -335,3 +335,54 @@ def test_dp_step_reduces_beside_the_backward_pass_on_v5e(topo, one_chip,
     assert introspect.all_reduce_summary(text)["collectives"] == 0
     assert introspect.ASYNC_COLLECTIVE_START not in text \
         and "async_collective_name" not in text
+
+
+# ---- the expert layer's row passes on the rung its rows need -------------
+
+def test_expert_layer_switches_its_row_passes_on_the_rung_on_v5e(
+        one_chip, monkeypatch):
+    """The dropless expert layer, forward and backward, in bf16 at a size
+    the grouped kernel tiles: each of the backward's three passes over the
+    sorted buffer's rows is still a `conditional` of four branches under
+    `moe` in the compiled text (one turned into a select would run every
+    branch), the least branch of the combine's backward gathers R/8 rows of
+    the cotangent, and the grouped products are the nine Mosaic calls of
+    the layer before the ladder, all in the entry computation
+    (`expert_matmul_roofline.train` counts each call under `experts` as
+    one product)."""
+    import re
+    from singa_tpu.parallel import moe
+    T, D, F, E, H, k = 1024, 256, 256, 8, 4, 4
+    R = T * min(k, H)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def step(*args):
+        with jax.named_scope("moe"):
+            return jax.value_and_grad(
+                lambda *a: jnp.sum(moe.dropless_moe(*a, k)[0]),
+                (0, 1, 2, 3, 4))(*args)
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    text = jax.jit(step).lower(
+        sds((T, D), jnp.float32), sds((D, E), jnp.float32),
+        sds((H, D, F), jnp.bfloat16), sds((H, D, F), jnp.bfloat16),
+        sds((H, F, D), jnp.bfloat16)).compile().as_text()
+    entry = text[text.index("\nENTRY "):]
+    mosaic = 'custom_call_target="tpu_custom_call"'
+    assert text.count(mosaic) == entry.count(mosaic) == 9
+    switches = [(m.group(1).split(", "), m.group(2)) for m in re.finditer(
+        r" conditional\([^\n]*branch_computations=\{([^}]*)\}[^\n]*"
+        r'op_name="([^"]*)"', entry)]
+    assert sorted(part for _b, op in switches
+                  for part in ("dispatch", "experts", "combine")
+                  if f"({part})" in op) == ["combine", "dispatch", "experts"]
+    assert all(len(branches) == 4 and "moe/" in op and "transpose(" in op
+               for branches, op in switches), switches
+    # the combine's backward, least branch: R/8 rows of the cotangent
+    # gathered in fp32, R rows of the products' dtype written
+    least = next(branches[0].lstrip("%") for branches, op in switches
+                 if "(combine)" in op)
+    body = text[text.index(f"\n%{least} "):]
+    body = body[:body.index("\n}")]
+    assert re.search(rf"f32\[{R // 8},{D}\][^\n]* fusion\(", body), body
+    assert re.search(rf"bf16\[{R},{D}\]", body), body
