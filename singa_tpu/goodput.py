@@ -94,6 +94,14 @@ SPAN_BUCKETS = {
     # exactly what the cold-vs-warm goodput A/B asserts
     "introspect.warm_load": BUCKET_COMPILE,
     "model.jit_fallback": BUCKET_COMPILE,
+    # the rest of set-up: the constructor, the eager init pass (one small
+    # program an operator, each compiled), the optimizer's state, and
+    # the first call of a fresh executable (nested in its model.step /
+    # model.eval, so netted out of it)
+    "model.create": BUCKET_COMPILE,
+    "model.init": BUCKET_COMPILE,
+    "opt.setup": BUCKET_COMPILE,
+    "introspect.first_dispatch": BUCKET_COMPILE,
     "data.wait": BUCKET_DATA_WAIT,
     "snapshot.flush": BUCKET_CHECKPOINT,
     "snapshot.load": BUCKET_CHECKPOINT,

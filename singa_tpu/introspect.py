@@ -57,6 +57,7 @@ import hashlib
 import json
 import os
 import re
+import threading
 import time
 
 from . import config, observe
@@ -93,6 +94,33 @@ COMPILE_PHASES = ("trace", "lower", "compile")
 PHASE_TRACE = "trace"
 PHASE_LOWER = "lower"
 PHASE_COMPILE = "compile"
+
+#: Where a compile (or a read of the persistent cache) happened, for
+#: `singa_xla_compile_seconds{where=...}`: the leaf of the span open on the
+#: compiling thread when it is one of these; `other` under any other span,
+#: `none` outside every span (a caller's own programs: the benchmark's
+#: reference and checks). `compile` is the phase span of a staged build.
+XLA_COMPILE_WHERE = ("model.init", "opt.setup", "model.create",
+                     "model.build", "compile", "introspect.first_dispatch",
+                     "model.jit_fallback", "model.step", "model.eval",
+                     "data.wait", "tensor.fetch", "serving.engine_step",
+                     "serving.engine_prefill", "other", "none")
+WHERE_OTHER = "other"
+WHERE_NONE = "none"
+#: `source=` of the same histogram: the backend compiled the program, or
+#: the persistent cache held it.
+XLA_COMPILE_SOURCES = ("backend", "cache")
+SOURCE_BACKEND = "backend"
+SOURCE_CACHE = "cache"
+
+#: Span leaves `setup_report` sums, each net of the others nested in it:
+#: the spans set-up's work happens under, and the spans a compile can be
+#: booked to (so that a build under `model.eval` is not counted twice).
+SETUP_SPANS = ("model.create", "model.init", "opt.setup", "model.build",
+               "introspect.warm_load", "introspect.build", "trace", "lower",
+               "compile", "introspect.first_dispatch", "model.jit_fallback",
+               "model.step", "model.eval", "data.wait", "tensor.fetch",
+               "serving.engine_step", "serving.engine_prefill")
 
 #: Executable keys (the `key=` label on the gauges/histograms above).
 EXEC_KEYS = ("step", "eval", "serving.prefill", "serving.decode_scan",
@@ -324,6 +352,101 @@ def compile_phase_totals() -> dict:
         if ph in out:
             out[ph] += float(row.get("sum") or 0.0)
     return out
+
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_compiling = threading.local()
+
+
+def _on_jax_duration(event, seconds, **_kw):
+    """jax.monitoring listener: one observation of
+    `singa_xla_compile_seconds` a program jax asked its backend for. jax
+    times every such request under `_COMPILE_EVENT`, a program found in
+    the persistent cache too; it reports the cache's read first, from
+    inside the request and on the same thread, which is how the two are
+    told apart. The seconds are the request's, either way."""
+    if event == _CACHE_EVENT:
+        _compiling.from_cache = True
+        return
+    if event != _COMPILE_EVENT:
+        return
+    source = SOURCE_CACHE if getattr(_compiling, "from_cache", False) \
+        else SOURCE_BACKEND
+    _compiling.from_cache = False
+    if not observe.is_enabled():
+        return
+    path = observe.current_span()
+    where = path.rsplit("/", 1)[-1] if path else WHERE_NONE
+    if where not in XLA_COMPILE_WHERE:
+        where = WHERE_OTHER
+    observe.histogram(
+        "singa_xla_compile_seconds",
+        "wall seconds of each program jax had compiled or read from the "
+        "persistent cache, by source and by the span it fell under"
+    ).observe(seconds, source=source, where=where)
+
+
+if __name__ != "__main__":
+    # once a process, in the module that stages every program (run as a
+    # script, the canonical module that `__main__` hands over to listens)
+    from jax import monitoring as _monitoring
+    _monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
+def setup_report() -> dict:
+    """Where set-up's seconds went, read from the registry and the build
+    records as they stand (nothing is kept here):
+
+    spans     {leaf: {"seconds", "count"}} over SETUP_SPANS, each span net
+              of the SETUP_SPANS nested in it (goodput's rule: a child's
+              whole time comes off its nearest listed ancestor), so the
+              rows add up to wall time and not past it
+    paths     the same by whole span path, for a reader that has to tell
+              an `opt.setup` under `model.build` from one that is not
+    builds    {key: {"builds", "trace", "lower", "compile"}}: the staged
+              builds' phases, summed a key
+    compiles  {where: {source: {"seconds", "count"}}} of
+              singa_xla_compile_seconds
+    """
+    reg = observe.get_registry()
+    rows = {}
+    h = reg.get("singa_span_seconds")
+    for row in (h.snapshot() if h is not None else ()):
+        path = row["labels"].get("span", "")
+        if path.rsplit("/", 1)[-1] in SETUP_SPANS:
+            rows[path] = [float(row["sum"]), int(row["count"])]
+    gross = {p: s for p, (s, _n) in rows.items()}
+    for path, seconds in gross.items():
+        parts = path.split("/")
+        for i in range(len(parts) - 1, 0, -1):
+            if parts[i - 1] in SETUP_SPANS:
+                anc = "/".join(parts[:i])
+                if anc in rows:     # else still open: nothing to net yet
+                    rows[anc][0] -= seconds
+                break
+    spans = {}
+    for path, (s, n) in rows.items():
+        leaf = spans.setdefault(path.rsplit("/", 1)[-1],
+                                {"seconds": 0.0, "count": 0})
+        leaf["seconds"] += s
+        leaf["count"] += n
+    builds = {}
+    for key, recs in _builds.items():
+        b = builds[key] = {"builds": len(recs)}
+        for ph in COMPILE_PHASES:
+            b[ph] = sum(float(r["phases"].get(ph, 0.0)) for r in recs)
+    compiles = {}
+    h = reg.get("singa_xla_compile_seconds")
+    for row in (h.snapshot() if h is not None else ()):
+        lab = row["labels"]
+        compiles.setdefault(lab.get("where", WHERE_NONE), {})[
+            lab.get("source", SOURCE_BACKEND)] = {
+                "seconds": float(row["sum"]), "count": int(row["count"])}
+    return {"spans": spans,
+            "paths": {p: {"seconds": s, "count": n}
+                      for p, (s, n) in sorted(rows.items())},
+            "builds": builds, "compiles": compiles}
 
 
 def _set_hbm_gauges(mem, key):
@@ -575,16 +698,21 @@ def _sig_fingerprint(key: str, sig: dict) -> str:
 def _stage(fn, args, compiler_options=None):
     """Explicit trace -> lower -> compile of one jitted callable, with
     per-phase wall timing. Raises whatever the staging machinery
-    raises; callers decide the fallback. compiler_options: the
+    raises; callers decide the fallback. Each phase is a span too (a
+    child of the caller's `introspect.build`), so a profiler trace holds
+    the three as intervals. compiler_options: the
     program's own XLA options ({name: value}), handed to the compile
     whichever callable is staged: the warm store's deserialized module
     is a jit of its own and carries none."""
     t0 = time.perf_counter()
-    traced = fn.trace(*args)
+    with observe.span(PHASE_TRACE):
+        traced = fn.trace(*args)
     t1 = time.perf_counter()
-    lowered = traced.lower()
+    with observe.span(PHASE_LOWER):
+        lowered = traced.lower()
     t2 = time.perf_counter()
-    compiled = lowered.compile(compiler_options=compiler_options)
+    with observe.span(PHASE_COMPILE):
+        compiled = lowered.compile(compiler_options=compiler_options)
     t3 = time.perf_counter()
     return compiled, {"trace": t1 - t0, "lower": t2 - t1,
                       "compile": t3 - t2}
@@ -1018,8 +1146,13 @@ class AotExecutor:
         return True
 
     def dispatch(self, variant, args):
-        """Run `args` through the variant `prepare` resolved them to."""
-        variant.fresh = False
+        """Run `args` through the variant `prepare` resolved them to. The
+        first call of a new executable runs under a span of its own: a
+        load of the program that the build put off shows there."""
+        if variant.fresh:
+            variant.fresh = False
+            with observe.span("introspect.first_dispatch", key=self.key):
+                return self.dispatch(variant, args)
         if variant.run is not None:
             try:
                 return variant.run(*args)
@@ -1096,6 +1229,7 @@ def explain(model=None, device=None, xplane=None, top=10) -> dict:
              "total_ms": round(r["total_ms"], 3),
              "pct": round(r["pct"], 1)}
             for r in xprof.top_ops(xplane, top)]
+    rep["setup"] = setup_report()
     # the dynamic half of the memory model (singa_tpu.memory): live
     # region breakdown when a ledger is installed, and the pre-flight
     # fit estimate combining this module's static analysis with the
@@ -1115,6 +1249,24 @@ def explain(model=None, device=None, xplane=None, top=10) -> dict:
 
 def _mb(b):
     return f"{(b or 0) / 1e6:.2f} MB"
+
+
+def _format_setup(setup: dict) -> list:
+    """The "set-up" block of the explain page: a `setup_report`'s spans in
+    SETUP_SPANS' order, then its compiles by where they fell."""
+    spans, compiles = setup.get("spans") or {}, setup.get("compiles") or {}
+    if not spans and not compiles:
+        return []
+    lines = ["set-up (seconds net of nested spans):"]
+    for leaf in SETUP_SPANS:
+        if leaf in spans:
+            lines.append(f"  {leaf:<26} {spans[leaf]['seconds']:>9.3f}s "
+                         f"x{spans[leaf]['count']}")
+    for where in XLA_COMPILE_WHERE:
+        for source, v in sorted((compiles.get(where) or {}).items()):
+            lines.append(f"  xla {source:<7} under {where:<26} "
+                         f"{v['seconds']:>9.3f}s x{v['count']}")
+    return lines
 
 
 def format_explain(rep: dict) -> str:
@@ -1163,6 +1315,7 @@ def format_explain(rep: dict) -> str:
             + (f" vs limit {_mb(lim)} -> "
                f"{'fits' if fit['fits'] else 'DOES NOT FIT'}"
                if lim else " (device limit unknown)"))
+    lines.extend(_format_setup(rep.get("setup") or {}))
     blames = rep.get("recompiles", [])
     lines.append(f"recompile history ({len(blames)}):")
     for b in blames:
@@ -1280,7 +1433,8 @@ __all__ = [
     "note_step_flops",
     "capture_hlo", "executable_manifest", "latest_fingerprint",
     "last_build", "blame_history",
-    "compile_phase_totals",
+    "compile_phase_totals", "setup_report",
+    "XLA_COMPILE_WHERE", "XLA_COMPILE_SOURCES", "SETUP_SPANS",
     "explain", "format_explain", "reset", "main",
 ]
 

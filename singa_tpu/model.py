@@ -219,12 +219,17 @@ class Model(Layer, metaclass=ModelMeta):
         prev = autograd.training
         autograd.training = False  # init pass builds no tape
         try:
-            self.forward(*inputs)
+            # every parameter made and initialised, eagerly: one small
+            # program an operator, each compiled (or read from the cache)
+            # under this span (introspect's singa_xla_compile_seconds)
+            with observe.span("model.init"):
+                self.forward(*inputs)
         finally:
             autograd.training = prev
         self.train(is_train)
         if self._optimizer is not None:
-            self._optimizer.setup(self.get_params().values())
+            with observe.span("opt.setup"):
+                self._optimizer.setup(self.get_params().values())
 
     def train(self, mode: bool = True):
         self.training = mode
@@ -270,7 +275,8 @@ class Model(Layer, metaclass=ModelMeta):
         t0 = time.perf_counter()
         opt = self._optimizer
         if opt is not None:
-            opt.setup(self.get_params().values())
+            with observe.span("opt.setup"):
+                opt.setup(self.get_params().values())
         # memory-ledger birth-site hook: params (re-read per snapshot —
         # donation replaces the buffers every step) and the retained
         # step inputs the flight recorder would snapshot
@@ -517,7 +523,6 @@ class Model(Layer, metaclass=ModelMeta):
         # that tag's jitted step, one variant an abstract signature
         self._compiled_step = {}
         self._step_stats["compile_s"] = time.perf_counter() - t0
-        observe.record_step_build(self._step_stats["compile_s"])
 
     def _static_mismatch(self, args):
         """Rebuild the full dict comparison only to phrase the error —

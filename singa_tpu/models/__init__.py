@@ -7,6 +7,7 @@ to the DistOpt strategy named by `dist_option` (the reference repeats this
 dispatch in every model file; here it lives once in `base.Classifier`).
 """
 
+from .. import observe
 from .base import Classifier  # noqa: F401
 from . import (mlp, cnn, alexnet, resnet, xceptionnet, transformer,  # noqa: F401
                looplm, mellum, sdar)
@@ -36,4 +37,5 @@ def create_model(name: str, **kwargs):
         fn = _REGISTRY[name]
     except KeyError:
         raise ValueError(f"unknown model {name!r}; have {sorted(_REGISTRY)}")
-    return fn(**kwargs)
+    with observe.span("model.create"):
+        return fn(**kwargs)

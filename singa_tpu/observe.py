@@ -646,6 +646,9 @@ class span:
 
     __slots__ = ("name", "attrs", "path", "_t0", "_ann", "_off")
 
+    #: a row in `singa_span_seconds` at exit (`trace_span` keeps none)
+    timed = True
+
     def __init__(self, name: str, **attrs):
         self.name = name
         self.attrs = attrs
@@ -690,7 +693,7 @@ class span:
         stack = getattr(_tls, "span_stack", None)
         if stack and stack[-1] == self.path:
             stack.pop()
-        if _enabled:
+        if _enabled and self.timed:
             _default.histogram(
                 "singa_span_seconds",
                 "wall seconds per span() region (label: slash-joined "
@@ -704,18 +707,24 @@ class span:
         return False
 
 
+class trace_span(span):
+    """A span that names its interval and is not timed into the registry:
+    the nesting path, the `TraceAnnotation`, the ring and the listeners as
+    `span`, no row in `singa_span_seconds`. For a site between a fence's
+    return and the next dispatch, where the device waits for the host:
+    with the one histogram write of a timed span in `Tensor.numpy()` a
+    training loop read 0.15-0.5 % slower on the chip in every run, with
+    the rest of the span and no write in none (PERF.md section 6, PR 36:
+    microseconds there decide whether the prefetcher's next batch runs
+    beside the dispatch or before it)."""
+
+    __slots__ = ()
+    timed = False
+
+
 # ---- framework instrumentation hooks ---------------------------------------
 # Called from the hot paths (model/opt/serving/communicator/bench). Each is
 # a no-op when disabled; none of them may raise into the training loop.
-
-def record_step_build(seconds: float):
-    """Step-builder wall time (Model._build_step: trace prep, not the XLA
-    compile itself — that lands in the first step's latency)."""
-    if not _enabled:
-        return
-    histogram("singa_step_build_seconds",
-              "Model._build_step wall seconds").observe(seconds)
-
 
 def record_compile(batch_class, recompile: bool = False,
                    donated_bytes: int | None = None):
@@ -1076,9 +1085,10 @@ __all__ = [
     "set_step_callback", "add_span_listener", "remove_span_listener",
     "add_step_listener", "remove_step_listener",
     "start_diag_server",
+    "trace_span",
     "enable_span_records", "disable_span_records", "span_records",
     "span_records_enabled", "note_span",
-    "record_step", "record_step_build", "record_step_fenced",
+    "record_step", "record_step_fenced",
     "record_compile", "record_hbm", "record_opt_update", "record_comm",
     "record_comm_host",
     "record_decode", "record_scaler_decision",

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from . import device as device_module
+from . import observe
 from .device import Device, get_default_device
 
 # ---- dtypes (parity with core.proto:26-34 + singa tensor.py) -------------
@@ -125,14 +126,21 @@ class Tensor:
     # ---- conversions ----------------------------------------------------
     def numpy(self) -> np.ndarray:
         a = self.data
-        if not getattr(a, "is_fully_addressable", True):
-            # a global array whose rows live on other processes' devices
-            # (a data-parallel step's batch output under jax.distributed):
-            # the read gathers, every process gets the whole value
-            from jax.experimental import multihost_utils
-            return np.asarray(
-                multihost_utils.process_allgather(a, tiled=True))
-        return np.asarray(a)
+        # the read that fences: it returns when the device has run every
+        # program the value depends on, so a training loop's wait for its
+        # steps shows under this span, which names the device's idle gaps
+        # beside it in a profiler trace. Not timed into the registry: the
+        # device is idle until the loop's next dispatch (trace_span)
+        with observe.trace_span("tensor.fetch"):
+            if not getattr(a, "is_fully_addressable", True):
+                # a global array whose rows live on other processes'
+                # devices (a data-parallel step's batch output under
+                # jax.distributed): the read gathers, every process gets
+                # the whole value
+                from jax.experimental import multihost_utils
+                return np.asarray(
+                    multihost_utils.process_allgather(a, tiled=True))
+            return np.asarray(a)
 
     def item(self):
         return self.numpy().item()

@@ -277,3 +277,237 @@ def test_cli_smoke(tmp_path):
     assert "GFLOP/step" in out.stdout
     assert "recompile history" in out.stdout
     assert "hlo:" in out.stdout  # capture wired through the CLI
+
+
+# ---- set-up measures itself: spans, the compile counter, setup_report ------
+
+class _Enters:
+    """The span paths opened while it is installed, in order."""
+
+    def __enter__(self):
+        self.paths = []
+        self._cb = observe.add_span_listener(
+            lambda *_a: None, on_enter=self.paths.append)
+        return self
+
+    def __exit__(self, *_exc):
+        observe.remove_span_listener(self._cb)
+
+    def leaves(self):
+        return [p.rsplit("/", 1)[-1] for p in self.paths]
+
+
+def _zoo_mlp(dev, rng, batch=8):
+    from singa_tpu import models
+    m = models.create_model("mlp", data_size=10, num_classes=4)
+    m.set_optimizer(opt.SGD(lr=0.1))
+    tx, ty = _batch(dev, rng, batch)
+    m.compile([tx], is_train=True, use_graph=True)
+    return m, tx, ty
+
+
+def test_setup_spans_open_in_order_and_once(dev, rng):
+    with _Enters() as seen:
+        m, tx, ty = _zoo_mlp(dev, rng)
+        m(tx, ty)
+    setup = [p for p in seen.paths if p.rsplit("/", 1)[-1]
+             in introspect.SETUP_SPANS]
+    assert setup == [
+        "model.create", "model.init", "opt.setup", "model.build",
+        "model.build/opt.setup", "introspect.build",
+        "introspect.build/trace", "introspect.build/lower",
+        "introspect.build/compile", "model.step",
+        "model.step/introspect.first_dispatch"]
+    with _Enters() as again:
+        m(tx, ty)
+    assert again.paths == ["model.step"]
+
+
+def test_first_dispatch_span_once_a_build(dev, rng):
+    m, tx, ty = _compiled_mlp(dev, rng, 8)
+    h = lambda: observe.get_registry().get("singa_span_seconds").count(
+        span="model.step/introspect.first_dispatch")
+    for _ in range(3):
+        m(tx, ty)
+    assert h() == 1
+    m(*_batch(dev, rng, 6))        # a new signature: a build, a first call
+    m(*_batch(dev, rng, 6))
+    assert h() == 2
+
+
+def test_phase_totals_equal_the_phase_spans(dev, rng):
+    m, tx, ty = _compiled_mlp(dev, rng, 8)
+    m(tx, ty)
+    m.eval()
+    m(tx)                          # a second staged build, under model.eval
+    spans = observe.get_registry().get("singa_span_seconds")
+    totals = introspect.compile_phase_totals()
+    for ph in introspect.COMPILE_PHASES:
+        spanned = sum(r["sum"] for r in spans.snapshot()
+                      if r["labels"]["span"].endswith(
+                          "introspect.build/" + ph))
+        assert totals[ph] > 0
+        assert abs(totals[ph] - spanned) < 1e-3, (ph, totals[ph], spanned)
+    rep = introspect.setup_report()
+    assert rep["builds"]["step"]["builds"] == 1
+    assert rep["builds"]["eval"]["builds"] >= 1
+    assert abs(sum(rep["builds"][k]["compile"] for k in ("step", "eval"))
+               - totals["compile"]) < 1e-9
+
+
+@pytest.mark.parametrize("span_name, where", [
+    ("model.init", "model.init"), (None, "none"),
+    ("a.span.nobody.declared", "other"),
+    ("model.step/introspect.first_dispatch", "introspect.first_dispatch")])
+def test_compile_is_booked_to_the_open_span(span_name, where):
+    import contextlib
+    import jax
+    x = np.arange(5, dtype=np.float32)   # made without a program
+    salt = float(len(where))             # a program no other case compiled
+    with contextlib.ExitStack() as stack:
+        for name in (span_name or "").split("/"):
+            if name:
+                stack.enter_context(observe.span(name))
+        jax.jit(lambda x: x * 3 + salt)(x).block_until_ready()
+    h = observe.get_registry().get("singa_xla_compile_seconds")
+    assert h.count(source="backend", where=where) == 1
+    assert h.sum(source="backend", where=where) > 0
+    assert sum(r["count"] for r in h.snapshot()) == 1
+
+
+@pytest.mark.parametrize("events, source", [
+    (("/jax/core/compile/backend_compile_duration",), "backend"),
+    (("/jax/compilation_cache/cache_retrieval_time_sec",
+      "/jax/core/compile/backend_compile_duration"), "cache"),
+    (("/jax/compilation_cache/compile_time_saved_sec",), None)])
+def test_compile_listener_tells_cache_from_backend(events, source):
+    """jax times a cache hit under the compile event too, after reporting
+    the read from inside it: one observation a request, by its source."""
+    for ev in events:
+        introspect._on_jax_duration(ev, 0.25, fun_name="f")
+    h = observe.get_registry().get("singa_xla_compile_seconds")
+    if source is None:
+        assert h is None
+        return
+    assert h.count(source=source, where="none") == 1
+    assert h.sum(source=source, where="none") == 0.25
+    # the mark does not outlive its request
+    introspect._on_jax_duration(events[-1], 0.5)
+    assert h.count(source="backend", where="none") \
+        == (2 if source == "backend" else 1)
+
+
+def test_warm_cache_dir_books_cache_and_no_backend_compile(tmp_path):
+    import jax
+    import jax.numpy as jnp
+    from singa_tpu import warmstart
+    h = lambda **lab: (observe.get_registry().get(
+        "singa_xla_compile_seconds") or observe.histogram(
+            "singa_xla_compile_seconds")).count(where="model.init", **lab)
+    f = lambda x: jnp.tanh(x) * 11 + 5
+    x = np.arange(6, dtype=np.float32)
+    try:
+        warmstart.configure_xla_cache(str(tmp_path / "xla"))
+        with observe.span("model.init"):
+            jax.jit(f)(x).block_until_ready()
+        assert (h(source="backend"), h(source="cache")) == (1, 0)
+        # "a second run": nothing compiled is left in the process
+        jax.clear_caches()
+        observe.get_registry().reset()
+        with observe.span("model.init"):
+            jax.jit(f)(x).block_until_ready()
+        assert (h(source="backend"), h(source="cache")) == (0, 1)
+    finally:
+        warmstart._unconfigure_xla_cache()
+
+
+def test_setup_report_nets_a_build_under_eval(dev, rng):
+    m, tx, ty = _compiled_mlp(dev, rng, 8)
+    m(tx, ty)
+    m.eval()
+    m(tx)
+    spans = observe.get_registry().get("singa_span_seconds")
+    rep = introspect.setup_report()
+    gross = spans.sum(span="model.eval")
+    build = spans.sum(span="model.eval/introspect.build")
+    first = spans.sum(span="model.eval/introspect.first_dispatch")
+    assert build > 0 and first > 0
+    assert abs(rep["spans"]["model.eval"]["seconds"]
+               - (gross - build - first)) < 1e-9
+    assert rep["paths"]["model.eval"]["count"] == 1
+    # and the build itself is net of its three phases
+    phases = sum(spans.sum(span="model.eval/introspect.build/" + ph)
+                 for ph in introspect.COMPILE_PHASES)
+    assert abs(rep["paths"]["model.eval/introspect.build"]["seconds"]
+               - (build - phases)) < 1e-9
+    # the rows add up to the wall time of the outermost listed spans
+    top = sum(r["sum"] for r in spans.snapshot()
+              if r["labels"]["span"] in rep["paths"]
+              and not any(a in introspect.SETUP_SPANS for a in
+                          r["labels"]["span"].split("/")[:-1]))
+    assert abs(sum(v["seconds"] for v in rep["spans"].values())
+               - top) < 1e-6
+    # every span a compile can be booked to is a span the report nets
+    assert set(introspect.XLA_COMPILE_WHERE[:-2]) <= set(
+        introspect.SETUP_SPANS)
+
+
+def test_setup_report_of_an_empty_registry_and_explain_block(dev, rng):
+    assert introspect.setup_report() == {
+        "spans": {}, "paths": {}, "builds": {}, "compiles": {}}
+    assert "set-up" not in introspect.format_explain(introspect.explain())
+    m, tx, ty = _compiled_mlp(dev, rng, 8)
+    m(tx, ty)
+    text = introspect.format_explain(introspect.explain(model=m))
+    block = text[text.index("set-up (seconds net of nested spans):"):]
+    for leaf in ("model.init", "opt.setup", "model.build", "trace",
+                 "lower", "compile", "introspect.first_dispatch"):
+        assert f"\n  {leaf} " in block, leaf
+    assert "xla backend under compile" in block
+
+
+@pytest.mark.parametrize("suppressed", [False, True])
+def test_tensor_numpy_opens_tensor_fetch(dev, rng, suppressed):
+    import contextlib
+    tx, _ty = _batch(dev, rng, 4)
+    quiet = observe.suppress_spans() if suppressed \
+        else contextlib.nullcontext()
+    with _Enters() as seen, quiet:
+        got = tx.numpy()
+    assert got.shape == (4, 10)
+    assert seen.paths == ([] if suppressed else ["tensor.fetch"])
+    # a trace_span: named (above), on the stack while open, never a row
+    # (the write sat between a fence's return and the next dispatch)
+    assert observe.get_registry().get("singa_span_seconds") is None
+    with observe.trace_span("tensor.fetch"):
+        assert observe.current_span() == "tensor.fetch"
+        with observe.span("inner"):
+            pass
+    h = observe.get_registry().get("singa_span_seconds")
+    assert h.count(span="tensor.fetch/inner") == 1
+    assert h.count(span="tensor.fetch") == 0
+
+
+def test_observation_off_leaves_no_row_and_the_same_step():
+    from singa_tpu.device import get_default_device
+    dev = get_default_device()
+
+    def run():
+        dev.SetRandSeed(3)
+        m, tx, ty = _compiled_mlp(dev, np.random.RandomState(0), 8)
+        outs = [m(tx, ty) for _ in range(2)]
+        return [np.asarray(t.data) for pair in outs for t in pair]
+
+    on = run()
+    assert observe.get_registry().get("singa_xla_compile_seconds") \
+        is not None
+    observe.get_registry().reset()
+    introspect.reset()
+    observe.enable(False)
+    try:
+        off = run()
+        assert observe.get_registry().names() == []
+        assert introspect.setup_report()["spans"] == {}
+    finally:
+        observe.enable(True)
+    assert all(np.array_equal(a, b) for a, b in zip(on, off))

@@ -308,11 +308,11 @@ def test_train_step_telemetry(dev, rng, reg, tmp_path):
     assert reg.get("singa_steps_total").value() == 3
     assert reg.get("singa_step_donated_bytes").value() > 0
     # optimizer instrumentation fired at trace time: 4 params, once —
-    # nested under the AOT staging span since the goodput layer (the
-    # trace runs inside introspect.build_compiled)
+    # nested under the AOT staging span's trace phase (the trace runs
+    # inside introspect.build_compiled, each phase a span of its own)
     assert reg.get("singa_opt_updates_total").value(strategy="local") == 4
     assert reg.get("singa_span_seconds").count(
-        span="introspect.build/opt.apply_updates") == 1
+        span="introspect.build/trace/opt.apply_updates") == 1
     # and the per-step dispatch span fired once per step
     assert reg.get("singa_span_seconds").count(span="model.step") == 3
 

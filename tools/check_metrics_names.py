@@ -20,7 +20,7 @@ literal, then fails if
   5. a `reason=` / `phase=` / `bucket=` / `region=` / `op=` /
      `outcome=` / `objective=` / `kv_dtype=` / `verdict=` /
      `replica=` / `attr=` / `decision=` / `leg=` / `cause=` /
-     `result=` label value on a metric record call
+     `result=` / `source=` / `where=` label value on a metric record call
      (.inc/.set/.observe/.dec) does not come from a declared enum: these
      labels are CONTRACTUALLY low-cardinality (introspect.py's
      RECOMPILE_REASONS / COMPILE_PHASES, goodput.py's GOODPUT_BUCKETS,
@@ -46,7 +46,10 @@ literal, then fails if
      observatory's `cause=` values are exactly compile /
      workload_shift / contention / host / unknown — and warmstart.py's
      CACHE_RESULTS — the warm-store lookup counter's `result=` values
-     are exactly hit / miss / stale / corrupt),
+     are exactly hit / miss / stale / corrupt — and introspect.py's
+     XLA_COMPILE_SOURCES / XLA_COMPILE_WHERE — the compile histogram's
+     `source=` values are exactly backend / cache and its `where=`
+     values the declared span leaves plus other / none),
      so a string literal must be a
      member of a module-level ALL-CAPS tuple of string literals, a NAME
      must be a module-level constant whose value is a member, and a
@@ -148,11 +151,13 @@ def registrations_in(path, tree=None):
 # audit.py's AUDIT_VERDICTS; cause: regress.py's REGRESS_CAUSES — the
 # regression observatory's attributed-cause enum; result: warmstart.py's
 # CACHE_RESULTS — the warm-store lookup classification
-# hit|miss|stale|corrupt).
+# hit|miss|stale|corrupt; source/where: introspect.py's
+# XLA_COMPILE_SOURCES / XLA_COMPILE_WHERE — what jax's compile events
+# are booked under).
 ENUM_LABEL_KWARGS = ("reason", "phase", "bucket", "region", "op",
                      "outcome", "objective", "kv_dtype", "verdict",
                      "replica", "attr", "decision", "leg", "cause",
-                     "result")
+                     "result", "source", "where")
 RECORD_FUNCS = {"inc", "set", "observe", "dec"}
 
 # Rule 6: `host=` label values must originate in the cluster topology.
