@@ -875,7 +875,8 @@ def record_attention_dispatch(site: str, path: str):
 
 def record_flash_tiles(site: str, visited: int, masked: int, square: int,
                        skipped: int = 0, window: int | None = None,
-                       block_diffusion: int | None = None):
+                       block_diffusion: int | None = None,
+                       steps: tuple = (0, 0)):
     """The tile schedule one flash call site was traced with
     (ops.attention.flash_plan), in sub-tiles a (batch x head) row: how
     many the kernel computes, how many of those add a mask, and the whole
@@ -886,7 +887,10 @@ def record_flash_tiles(site: str, visited: int, masked: int, square: int,
     the window, neither computed nor fetched; under the block-diffusion
     mask (`block_diffusion`: its block length, 0 for another mask) every
     sub-tile that is not visited, the clean x noised quadrant's among them.
-    A gauge: it holds the site's latest trace."""
+    `steps`: (grid steps a (batch x head) row, summed over the site's
+    Mosaic calls; how many of them work no tile: a step costs its
+    overhead whether or not it works one). A gauge: it holds the site's
+    latest trace."""
     assert site in ATTN_SITES, site
     if not _enabled:
         return
@@ -895,11 +899,13 @@ def record_flash_tiles(site: str, visited: int, masked: int, square: int,
               "call, by site and kind (visited|masked|square|skipped: left "
               "of a sliding window, or outside the block-diffusion mask; "
               "window: its width, 0 for none; block_diffusion: the mask's "
-              "block length, 0 for another mask)")
+              "block length, 0 for another mask; steps|idle_steps: grid "
+              "steps a row, and those of them that work no tile)")
     for kind, n in (("visited", visited), ("masked", masked),
                     ("square", square), ("skipped", skipped),
                     ("window", window or 0),
-                    ("block_diffusion", block_diffusion or 0)):
+                    ("block_diffusion", block_diffusion or 0),
+                    ("steps", steps[0]), ("idle_steps", steps[1])):
         g.set(n, site=site, kind=kind)
 
 

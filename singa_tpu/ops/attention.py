@@ -20,11 +20,13 @@ Three tiers, same math:
 from __future__ import annotations
 
 import functools
+import itertools
 import operator
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ..observe import record_attention_dispatch, record_flash_tiles
@@ -178,13 +180,16 @@ class FlashPlan(NamedTuple):
     wholly left of the window, which no grid step computes or fetches.
     `block_diffusion`: the block length of the block-diffusion mask the
     schedules were made for (None: another mask); `skipped` then counts
-    every sub-tile of the score square that no grid step computes."""
+    every sub-tile of the score square that no grid step computes.
+    `steps` (forward, backward): (grid steps a (batch x head) row, how many
+    of them work no tile), summed over the backward's calls."""
     fwd: FlashTiles | None
     bwd: FlashTiles | None
     fused: bool
     window: int | None = None
     skipped: tuple = (0, 0)
     block_diffusion: int | None = None
+    steps: tuple = ((0, 0), (0, 0))
 
     @property
     def ok(self):
@@ -204,6 +209,19 @@ def _tile_counts(sq, sk, uq, uk, causal):
         visited += seen
         masked += seen - min(cols, (i * uq + 1) // uk)
     return visited, masked, rows * cols
+
+
+def _grid_steps(fwd, bwd, fused, count):
+    """FlashPlan.steps. `count(t)` at the blocks of schedule t: (the grid
+    steps a row of a pass that sweeps by q block: forward, dq; of one that
+    sweeps by k block: dkv, fused; the tiles a pass works)."""
+    by_q, _, worked = count(fwd)
+    forward = by_q, by_q - worked
+    if bwd is None:
+        return forward, (0, 0)
+    by_q, by_k, worked = count(bwd)
+    steps = by_k + (0 if fused else by_q)
+    return forward, (steps, steps - (1 if fused else 2) * worked)
 
 
 def _band(bq, bk, bands):
@@ -312,31 +330,100 @@ def _window_counts(sq, sk, bq, bk, band, window):
 # strictly before the query's); "cc" (clean x clean on the diagonal: blocks
 # at or before); "full" (under the diagonal of those two quadrants: no
 # mask); or nothing (above a diagonal, off it in noised x noised, all of
-# clean x noised): no grid step. A q block's sweep is its "nn" tile FIRST
-# (every row has met a key it sees before a tile that hides all from some
-# rows), then the clean tiles up to its diagonal: nh + 1 steps; a k block's
-# sweep is the noised q blocks from its diagonal on, then the clean ones:
-# 2 nh steps, one for a noised k block. Where b divides the band too, a
+# clean x noised): no grid step. The grid of a pass is a TABLE of the tiles
+# it works (`_bd_sweeps`), one grid step each and none empty, that the
+# index maps and the kernel read from scalar memory: (batch x head, step).
+# A noised q block's "nn" tile is no step of its own: it rides in the step
+# of the block's "nc" tile, whose bands take the block's own noised keys
+# into the same softmax (forward) or work both tiles' key bands against the
+# one q block (backward). A q block's sweep is the whole tiles left of its
+# diagonal, then its diagonal tile: every row of a sweep's first tile sees
+# a key. A k block's sweep (dk, dv) is over a PAIR, clean k block nh + c
+# and noised k block c, both resident: the noised q blocks from c on ("nc"
+# with "nn" first), then the clean ones. Where b divides the band too, a
 # diagonal tile goes in bands that stop at the diagonal under one band x
 # band mask ("nn": the diagonal sub-tiles alone); else a band meets the
-# whole tile under one mask.
+# whole tile under one mask. A diagonal tile is the last of its q block's
+# sweep, so the forward's bands write the output rows themselves (no store
+# of the running state that the next band's load would wait behind), and
+# its bands are traced a stage apart (`_staggered`; the backward's abreast).
+#
+# Measured on one TPU v5e (PR 37; `tools/flash_bench.py --shape 1,32,8192,128
+# --block-diffusion 4`, the SDAR cell's call: tiles of 1024, nh = 4, bf16;
+# ms a call, median of 30 in a profiler trace):
+#   forward   grid (bh, 8, 5), 16 of 40 steps a head empty, "nn" a step
+#             of its own (before)                                      3.160
+#             the table, 20 steps, "nn" riding, bands in turn          2.908
+#             + the bands write the output                             2.653
+#             + stages abreast 2.505; staggered 2.448 (by two stages 2.656)
+#             bands of 256 / 512 in place of 128       2.606 / 2.871 (worse)
+#             the columns left of the diagonal as one product a key chunk
+#             of 128 (all the rows under it), not one a band   2.806 (worse)
+#   backward  dq (bh, 8, 5) + dkv (bh, 8, 8), 16 + 40 steps empty (before)
+#                                                               7.905 (both)
+#             the tables, 20 + 20 steps, k blocks in pairs   3.242 + 3.472
+#             + bands abreast on the diagonal tiles          2.891 + 3.472
+#             (staggered 2.987 + 3.472; bands of 128 2.961 + 3.300: half the
+#             arithmetic on "nn", twice the instructions to trace and lower)
+# A whole tile's dk / dv runs at the MXU's peak (4 x 1024 x 1024 x 128
+# multiply-adds in 5.4 us), so only fewer sub-tiles would move that pass; the
+# forward's diagonal tiles are at 3.8 us against a whole tile's 3.9 with 40
+# of its 64 sub-tiles: still the place furthest from its arithmetic.
 
-def _pick(cond, a, b):
-    return a if cond else b
+class BdStep(NamedTuple):
+    """One grid step of a block-diffusion pass: tile (q block `q`, clean k
+    block `k`) worked as `kind` ("full", "cc", or "nc": the last with the
+    "nn" tile (q, q) riding along); `first` / `last` of its sweep, where
+    the accumulators in scratch start and are written out."""
+    q: int
+    k: int
+    kind: str
+    first: bool
+    last: bool
+
+    def tiles(self):
+        """The (q block, k block, kind) tiles the step works."""
+        own = (self.q, self.k, self.kind)
+        return (own, (self.q, self.q, "nn")) if self.kind == "nc" else (own,)
 
 
-def _bd_k_of(j, step, nh, where=_pick):
-    """The k block that step `step` of q block j's sweep works on (past
-    the sweep's end: one of no kind); `where`: jnp's where j is traced."""
-    return where(j < nh, where(step == 0, j, nh + step - 1), nh + step)
+# what a step's kind reads in the kernels' table, and the table's rows
+_BD_STEP_KINDS = ("full", "cc", "nc")
+_BD_Q, _BD_K, _BD_KIND, _BD_FIRST, _BD_LAST = range(5)
 
 
-def _bd_q_of(kb, step, nh, where=_pick):
-    """The q block that step `step` of k block kb's sweep works on (past
-    the sweep's end: 2 nh or more, no block)."""
-    return where(kb < nh, where(step == 0, kb, 2 * nh),
-                 where(step < 2 * nh - kb, kb - nh + step,
-                       step + 2 * (kb - nh)))
+def _bd_kind(j, kb, nh):
+    """The one kind of tile (q block j, k block kb), or None."""
+    return next((k for k, on in _bd_kinds(j, kb, nh).items() if on), None)
+
+
+def _bd_sweeps(nh):
+    """(the q blocks' sweeps, the k pairs' sweeps) as lists of BdStep, in
+    grid order: every tile of some kind once a list, a sweep's steps
+    consecutive. By q block the k blocks ascend, so the diagonal tile comes
+    last; by k pair the q blocks ascend, so "nc" comes first."""
+    tiles = [(j, kb, _bd_kind(j, kb, nh))
+             for j in range(2 * nh) for kb in range(nh, 2 * nh)]
+    tiles = [t for t in tiles if t[2] is not None]
+
+    def sweeps(key):
+        order = sorted(tiles, key=key)
+        own = [key(t)[0] for t in order]
+        return [BdStep(j, kb, kind, i == 0 or own[i - 1] != own[i],
+                       i == len(own) - 1 or own[i + 1] != own[i])
+                for i, (j, kb, kind) in enumerate(order)]
+
+    return sweeps(lambda t: (t[0], t[1])), sweeps(lambda t: (t[1], t[0]))
+
+
+def _bd_table(sweep):
+    """A sweep as the kernels read it: int32 (5, steps), rows _BD_Q .. ., a
+    constant of the traced program (numpy: nothing goes to the device while
+    the call is traced)."""
+    return np.array(
+        [[s.q for s in sweep], [s.k for s in sweep],
+         [_BD_STEP_KINDS.index(s.kind) for s in sweep],
+         [s.first for s in sweep], [s.last for s in sweep]], np.int32)
 
 
 def _bd_kinds(j, kb, nh):
@@ -374,8 +461,7 @@ def _bd_counts(nh, n, banded):
     visited = masked = 0
     for j in range(2 * nh):
         for kb in range(2 * nh):
-            kind = next((k for k, on in _bd_kinds(j, kb, nh).items() if on),
-                        None)
+            kind = _bd_kind(j, kb, nh)
             if kind == "full":
                 visited += n * n
             elif kind and not banded:
@@ -418,8 +504,11 @@ def _bd_plan(seq, d, dtype, block_q, block_k, block):
     bwd, bwd_skipped = tiles((256, 128))
     if not bwd.band and tile > _UNBANDED_BWD_CAP:
         return none
-    return FlashPlan(fwd, bwd, _fused_fits(seq, d, dtype), None,
-                     (fwd_skipped, bwd_skipped), block)
+    fused = _fused_fits(seq, d, dtype)
+    by_q, by_k = map(len, _bd_sweeps(nh))   # the same tiles in two orders
+    return FlashPlan(
+        fwd, bwd, fused, None, (fwd_skipped, bwd_skipped), block,
+        _grid_steps(fwd, bwd, fused, lambda t: (by_q, by_k, by_q)))
 
 
 def flash_window(window, causal, sk):
@@ -482,7 +571,11 @@ def flash_plan(sq, sk, d, causal, dtype, block_q=None, block_k=None,
             else None
     # the fused backward keeps dq for a whole (batch x head) row in VMEM:
     # an f32 accumulator and the double-buffered output row
-    return FlashPlan(fwd, bwd, _fused_fits(sq, d, dtype))
+    fused = _fused_fits(sq, d, dtype)
+    grid = lambda t: (sq // t.block_q) * (sk // t.block_k)
+    return FlashPlan(fwd, bwd, fused, steps=_grid_steps(
+        fwd, bwd, fused, lambda t: (grid(t), grid(t), _tile_counts(
+            sq, sk, t.block_q, t.block_k, causal)[0])))
 
 
 def _fused_fits(sq, d, dtype):
@@ -508,8 +601,14 @@ def _window_plan(sq, sk, d, dtype, bq, bk, window):
     bwd, bwd_skipped = tiles(bwd_bands)
     if not bwd.band and max(bq, bk) > _UNBANDED_BWD_CAP:
         bwd, bwd_skipped = None, 0
-    return FlashPlan(fwd, bwd, _fused_fits(sq, d, dtype), window,
-                     (fwd_skipped, bwd_skipped))
+    fused = _fused_fits(sq, d, dtype)
+    nq, nk = sq // bq, sk // bk
+    k_steps, q_steps = _window_steps(sq, sk, bq, bk, window)
+    worked = sum(any(_window_kinds(j, kb, bq, bk, nq, nk, window).values())
+                 for j in range(nq) for kb in range(nk))
+    return FlashPlan(fwd, bwd, fused, window, (fwd_skipped, bwd_skipped),
+                     steps=_grid_steps(fwd, bwd, fused, lambda t: (
+                         nq * k_steps, nk * q_steps, worked)))
 
 
 try:  # import here so CPU-only environments still import the module
@@ -539,20 +638,44 @@ def _dot_nt(a, b):
                            preferred_element_type=jnp.float32)
 
 
+# A tile's bands are generators that yield between their stages (a band's
+# score products; its statistics and exponentials; its products with V or
+# with q / do / k; its write), and the order their stages are traced in is
+# the order the compiler keeps (PR 26: statement order moves a kernel's
+# time by 3 to 8 %). `_in_turn` is a band after another, every kernel's
+# order but for the diagonal tiles of the block-diffusion mask.
+
+def _in_turn(bands):
+    for band in bands:
+        for _ in band:
+            pass
+
+
+def _abreast(bands):
+    """Every band's first stage, then every band's second, and so on."""
+    for _ in itertools.zip_longest(*bands):
+        pass
+
+
+def _staggered(bands):
+    """A band starts a stage behind the band before it."""
+    waiting, running = list(bands), []
+    while waiting or running:
+        if waiting:
+            running.append(waiting.pop(0))
+        running = [b for b in running if next(b, running) is not running]
+
+
 def _visit_by_diagonal(causal, square, single, j, kb, block_q, block_k,
-                       visit, window=None, nq=None, nk=None, bd=None):
+                       visit, window=None, nq=None, nk=None):
     """Run `visit(kind)` for this grid step's (block_q, block_k) tile:
     "full" (no mask), "diag" (square blocks, the tile on the diagonal:
     bands stop at it), "crossed" (blocks that are not square: one mask
     over the tile, built from the step's offsets), or nothing for a tile
     above the diagonal. The DMA for skipped tiles is elided too:
     _causal_kv_map / _causal_q_map re-address the last needed block.
-    Under a `window` the kinds are _window_kinds', under the
-    block-diffusion mask (`bd`) _bd_kinds'."""
-    if bd is not None:
-        for kind, on in _bd_kinds(j, kb, nq // 2).items():
-            pl.when(on)(functools.partial(visit, kind))
-    elif window is not None:
+    Under a `window` the kinds are _window_kinds'."""
+    if window is not None:
         for kind, on in _window_kinds(j, kb, block_q, block_k, nq, nk,
                                       window).items():
             pl.when(on)(functools.partial(visit, kind))
@@ -570,9 +693,24 @@ def _visit_by_diagonal(causal, square, single, j, kb, block_q, block_k,
         pl.when(needed & jnp.logical_not(under))(lambda: visit("crossed"))
 
 
+def _visit_bd_step(tab_ref, t, visit):
+    """Run `visit(kind)` for the kind the table gives step t."""
+    for n, kind in enumerate(_BD_STEP_KINDS):
+        pl.when(tab_ref[_BD_KIND, t] == n)(functools.partial(visit, kind))
+
+
+def _flash_fwd_bd_kernel(tab_ref, q_ref, k_ref, v_ref, kn_ref, vn_ref, *rest,
+                         **kw):
+    """_flash_fwd_kernel on grid (batch*heads, steps of _bd_sweeps' q
+    sweeps): `tab_ref` the table in scalar memory, k_ref / v_ref the
+    step's clean k block, kn_ref / vn_ref the q block's own noised one."""
+    _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, **kw,
+                      sweep=(tab_ref, kn_ref, vn_ref))
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
                       nq, nk, block_q, block_k, band, causal, window,
-                      steps, bd=None):
+                      steps, bd=None, sweep=None):
     """Grid: (batch*heads, q_blocks, k_blocks) — K/V blocks STREAM through
     VMEM one (block_k, D) tile at a time (no whole-row residency, so
     sequence length is bounded by HBM, not VMEM). Inside a step the query
@@ -582,22 +720,34 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
     not once a sub-tile). With nk > 1 the online-softmax state (acc, m, l)
     lives in VMEM scratch, which persists across the k grid dimension:
     `steps` entries, nk of them, or under a `window` as many as a q
-    block's window reaches, counted from the first k block it reaches;
-    under the block-diffusion mask (`bd`: its block length) the steps of
-    _bd_k_of's sweep."""
-    j = pl.program_id(1)
-    step = kb = pl.program_id(2)
+    block's window reaches, counted from the first k block it reaches.
+    Under the block-diffusion mask (`bd`: its block length) the grid is
+    (batch*heads, steps) and `sweep` holds what tells a step its tile
+    (_flash_fwd_bd_kernel): the state starts where the table says a sweep
+    does; a band of an "nc" tile takes three pieces into its one softmax
+    (the clean columns left of the diagonal, the diagonal sub-tile, and the
+    diagonal sub-tile of the q block's noised keys, "nn"); and a diagonal
+    tile, the last of its sweep, writes the output rows band by band from
+    the state it read, so no step finishes a sweep apart."""
+    here = (k_ref, v_ref)
     if bd is not None:
-        kb = _bd_k_of(j, step, nq // 2, jnp.where)
-    elif window is not None:
-        kb = step + _k_range(j, block_q, block_k, nk, window,
-                             jnp.maximum, jnp.minimum)[0]
+        tab_ref, *noised = sweep
+        t = pl.program_id(1)
+        j = kb = None
+        first = lambda: tab_ref[_BD_FIRST, t] == 1
+    else:
+        j = pl.program_id(1)
+        step = kb = pl.program_id(2)
+        if window is not None:
+            kb = step + _k_range(j, block_q, block_k, nk, window,
+                                 jnp.maximum, jnp.minimum)[0]
+        first, last = lambda: step == 0, lambda: step == steps - 1
     square = block_q == block_k
     f32 = jnp.float32
     if steps > 1:
         acc_ref, m_ref, l_ref = scratch
 
-        @pl.when(step == 0)
+        @pl.when(first())
         def _init():
             m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
             l_ref[...] = jnp.zeros_like(l_ref)
@@ -617,96 +767,114 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
         lse_ref[0, rows, :] = jnp.broadcast_to(
             m + jnp.log(l), (acc.shape[0], _STAT_LANES))
 
+    def bd_cols(kind, kv, rows_per, lo, hi, mask):
+        """A band's pieces of a diagonal tile of the block-diffusion mask:
+        in bands that the block length divides `mask`, band x band, serves
+        every band; else the band meets the whole tile under one mask."""
+        if mask is None:
+            return [(kv, 0, block_k, _bd_tile_mask(kind, rows_per, block_k,
+                                                   bd, q0=lo))]
+        return ([(kv, 0, lo, None)] if lo and kind != "nn" else []) \
+            + [(kv, lo, hi, mask)]
+
     def visit(kind):
         # with K streamed a tile under the diagonal goes whole: its bands
         # would each reload the key tile into the MXU for no column saved
         rows_per = block_q if kind == "full" and steps > 1 \
             else band or block_q
-        # a diagonal tile of the block-diffusion mask in bands that the
-        # block length divides: one band x band mask serves every band
-        bd_mask = _bd_tile_mask(kind, rows_per, rows_per, bd) \
-            if bd is not None and kind != "full" and rows_per % bd == 0 \
-            else None
-        for r in range(block_q // rows_per):
-            lo, hi = r * rows_per, (r + 1) * rows_per
-            rows = slice(lo, hi)
-            if kind == "diag":
-                cols = ([(0, lo, None)] if lo else []) \
-                    + [(lo, hi, diag_mask)]
-            elif kind == "edge":
-                cols = [(lo, hi, edge_mask)] \
-                    + ([(hi, block_k, None)] if hi < block_k else [])
-            elif kind == "full":
-                cols = [(0, block_k, None)]
-            elif bd_mask is not None:
-                cols = ([(0, lo, None)] if lo and kind != "nn" else []) \
-                    + [(lo, hi, bd_mask)]
-            elif bd is not None:
-                cols = [(0, block_k, _bd_tile_mask(kind, rows_per, block_k,
-                                                   bd, q0=lo))]
-            else:
-                cols = [(0, block_k, _causal_mask(
-                    rows_per, block_k, q_off=j * block_q + lo,
-                    k_off=kb * block_k, window=window))]
-            # dots run in the INPUT dtype (bf16 inputs → native MXU rate;
-            # upcasting to f32 first would run the matmul at the ~4x-slower
-            # fp32 rate) and accumulate f32 via preferred_element_type; the
-            # softmax/stats stay in f32. q arrives PRE-SCALED (the wrapper
-            # folds the softmax scale into q, where XLA fuses it for free —
-            # an in-kernel multiply would cost a VPU pass over the scores).
-            q = q_ref[0, rows, :]                      # (band, D), scaled
-            ss = []
-            for a, b, mask in cols:
-                s = _dot_nt(q, k_ref[0, a:b, :])
-                ss.append(s if mask is None else s + mask)
-            m_new = functools.reduce(
-                jnp.maximum, (jnp.max(s, axis=-1, keepdims=True) for s in ss))
-            if steps > 1:
-                m_prev = m_ref[rows, :][:, :1]
-                m_new = jnp.maximum(m_prev, m_new)
-            ps = [jnp.exp(s - m_new) for s in ss]
-            l_new = _total(jnp.sum(p, axis=-1, keepdims=True) for p in ps)
-            pv = _total(
-                jnp.dot(p.astype(v_ref.dtype), v_ref[0, a:b, :],
-                        preferred_element_type=f32)
-                for p, (a, b, _) in zip(ps, cols))
-            if steps == 1:      # the band has seen every column it may
-                finish(rows, m_new, l_new, pv)
-                continue
-            corr = jnp.exp(m_prev - m_new)
-            acc_ref[rows, :] = acc_ref[rows, :] * corr + pv
-            l_ref[rows, :] = jnp.broadcast_to(
-                l_ref[rows, :][:, :1] * corr + l_new,
-                (rows_per, _STAT_LANES))
-            m_ref[rows, :] = jnp.broadcast_to(m_new, (rows_per, _STAT_LANES))
+        bd_diag = bd is not None and kind != "full"
+        bd_masks = {k: _bd_tile_mask(k, rows_per, rows_per, bd)
+                    for k in ((kind, "nn") if kind == "nc" else (kind,))} \
+            if bd_diag and rows_per % bd == 0 else {}
+        bands = [work(kind, r, rows_per, bd_masks)
+                 for r in range(block_q // rows_per)]
+        # a diagonal tile of the block-diffusion mask: a band a stage behind
+        # the one before it (measured with the mask's schedule, above)
+        (_staggered if bd_diag else _in_turn)(bands)
 
-    _visit_by_diagonal(causal, square, nq == 1 and nk == 1, j, kb, block_q,
-                       block_k, visit, window, nq, nk, bd)
+    def work(kind, r, rows_per, bd_masks):
+        # under the block-diffusion mask a diagonal tile is the last of
+        # its sweep: its bands write the output themselves
+        ends = bd is not None and kind != "full"
+        lo, hi = r * rows_per, (r + 1) * rows_per
+        rows = slice(lo, hi)
+        if kind == "diag":
+            cols = ([(here, 0, lo, None)] if lo else []) \
+                + [(here, lo, hi, diag_mask)]
+        elif kind == "edge":
+            cols = [(here, lo, hi, edge_mask)] \
+                + ([(here, hi, block_k, None)] if hi < block_k else [])
+        elif kind == "full":
+            cols = [(here, 0, block_k, None)]
+        elif bd is not None:
+            cols = bd_cols(kind, here, rows_per, lo, hi, bd_masks.get(kind))
+            if kind == "nc":
+                cols += bd_cols("nn", noised, rows_per, lo, hi,
+                                bd_masks.get("nn"))
+        else:
+            cols = [(here, 0, block_k, _causal_mask(
+                rows_per, block_k, q_off=j * block_q + lo,
+                k_off=kb * block_k, window=window))]
+        # dots run in the INPUT dtype (bf16 inputs → native MXU rate;
+        # upcasting to f32 first would run the matmul at the ~4x-slower
+        # fp32 rate) and accumulate f32 via preferred_element_type; the
+        # softmax/stats stay in f32. q arrives PRE-SCALED (the wrapper
+        # folds the softmax scale into q, where XLA fuses it for free —
+        # an in-kernel multiply would cost a VPU pass over the scores).
+        q = q_ref[0, rows, :]                      # (band, D), scaled
+        ss = []
+        for (keys, _), a, b, mask in cols:
+            s = _dot_nt(q, keys[0, a:b, :])
+            ss.append(s if mask is None else s + mask)
+        yield
+        m_new = functools.reduce(
+            jnp.maximum, (jnp.max(s, axis=-1, keepdims=True) for s in ss))
+        if steps > 1:
+            m_prev = m_ref[rows, :][:, :1]
+            m_new = jnp.maximum(m_prev, m_new)
+        ps = [jnp.exp(s - m_new) for s in ss]
+        l_new = _total(jnp.sum(p, axis=-1, keepdims=True) for p in ps)
+        yield
+        pv = _total(
+            jnp.dot(p.astype(values.dtype), values[0, a:b, :],
+                    preferred_element_type=f32)
+            for p, ((_, values), a, b, _) in zip(ps, cols))
+        yield
+        if steps == 1:      # the band has seen every column it may
+            finish(rows, m_new, l_new, pv)
+            return
+        corr = jnp.exp(m_prev - m_new)
+        if ends:
+            finish(rows, m_new, l_ref[rows, :][:, :1] * corr + l_new,
+                   acc_ref[rows, :] * corr + pv)
+            return
+        acc_ref[rows, :] = acc_ref[rows, :] * corr + pv
+        l_ref[rows, :] = jnp.broadcast_to(
+            l_ref[rows, :][:, :1] * corr + l_new,
+            (rows_per, _STAT_LANES))
+        m_ref[rows, :] = jnp.broadcast_to(m_new, (rows_per, _STAT_LANES))
 
-    if steps > 1:
-        @pl.when(step == steps - 1)
+    if bd is not None:
+        _visit_bd_step(tab_ref, t, visit)
+    else:
+        _visit_by_diagonal(causal, square, nq == 1 and nk == 1, j, kb,
+                           block_q, block_k, visit, window, nq, nk)
+
+    if steps > 1 and bd is None:
+        @pl.when(last())
         def _finish():
             finish(slice(None), m_ref[...][:, :1], l_ref[...][:, :1],
                    acc_ref[...])
 
 
-def _causal_kv_map(causal, block_q, block_k, nk, window=None, bd=None):
+def _causal_kv_map(causal, block_q, block_k, nk, window=None):
     """K/V BlockSpec index map for grids with kb innermost after the q
     block index. Causal: kb is CLAMPED to this q block's diagonal block,
     so every fully-masked step re-addresses the last needed block and
     Pallas skips the DMA (the copy only fires when the block index
     changes) — masked K/V tiles are neither computed nor streamed.
     Under a `window` the innermost index counts from the first k block
-    the q block's window reaches; under the block-diffusion mask it follows
-    _bd_k_of's sweep, clamped to the q block's diagonal tile."""
-    if bd is not None:
-        nh = nk // 2
-
-        def bmap(i, j, step):
-            kb = _bd_k_of(j, step, nh, jnp.where)
-            return (i, jnp.minimum(kb, jnp.where(j < nh, j + nh, j)), 0)
-
-        return bmap
+    the q block's window reaches."""
     if window is not None:
         def wmap(i, j, step):
             first, last = _k_range(j, block_q, block_k, nk, window,
@@ -724,21 +892,12 @@ def _causal_kv_map(causal, block_q, block_k, nk, window=None, bd=None):
     return kmap
 
 
-def _causal_q_map(causal, block_q, block_k, nq=None, window=None, bd=None):
+def _causal_q_map(causal, block_q, block_k, nq=None, window=None):
     """Q-side BlockSpec index map for the dK/dV grid (bh, kb, j): causal
     clamps j UP to the first unmasked q block for kb, so the leading
     masked steps address the same tile and their DMA is elided. Under a
     `window` the innermost index counts from that block and is clamped to
-    the last one whose rows still see kb; under the block-diffusion mask it
-    follows _bd_q_of's sweep (a noised k block: its own q block alone)."""
-    if bd is not None:
-        nh = nq // 2
-
-        def bmap(i, kb, step):
-            j = _bd_q_of(kb, step, nh, jnp.where)
-            return (i, jnp.where(kb < nh, kb, jnp.minimum(j, nq - 1)), 0)
-
-        return bmap
+    the last one whose rows still see kb."""
     if window is not None:
         def wmap(i, kb, step):
             first, last = _q_range(kb, block_q, block_k, nq, window,
@@ -756,6 +915,16 @@ def _causal_q_map(causal, block_q, block_k, nq=None, window=None, bd=None):
     return qmap
 
 
+def _bd_maps(nh):
+    """Index maps on grid (batch*heads, step) under a sweep's table: the
+    step's q block, its clean k block, and the noised k block of the same
+    number as the q block (a clean q block: the last noised one, which the
+    sweep before it left in place, so nothing is fetched)."""
+    return (lambda i, t, tab: (i, tab[_BD_Q, t], 0),
+            lambda i, t, tab: (i, tab[_BD_K, t], 0),
+            lambda i, t, tab: (i, jnp.minimum(tab[_BD_Q, t], nh - 1), 0))
+
+
 def _flash_fwd_pallas(q, k, v, causal, scale, tiles, interpret, window=None,
                       bd=None):
     b, h, sq, d = q.shape
@@ -771,15 +940,43 @@ def _flash_fwd_pallas(q, k, v, causal, scale, tiles, interpret, window=None,
     nq = sq // block_q
     steps, name = nk, "singa_flash_fwd"
     if bd is not None:
-        steps = nk // 2 + 1
+        sweep = _bd_sweeps(nk // 2)[0]
+        steps = len(sweep)
         name += BLOCKDIFF_SUFFIX
     elif window is not None:
         steps = _window_steps(sq, sk, block_q, block_k, window)[0]
         name += WINDOW_SUFFIX
-    kernel = functools.partial(
-        _flash_fwd_kernel, nq=nq, nk=nk, block_q=block_q, block_k=block_k,
-        band=band, causal=causal, window=window, steps=steps, bd=bd)
-    kvmap = _causal_kv_map(causal, block_q, block_k, nk, window, bd)
+    params = dict(nq=nq, nk=nk, block_q=block_q, block_k=block_k, band=band,
+                  causal=causal, window=window, steps=steps, bd=bd)
+    out_shape = [
+        jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+        jax.ShapeDtypeStruct((bh, sq, _STAT_LANES), jnp.float32),
+    ]
+    scratch = [
+        pltpu.VMEM((block_q, d), jnp.float32),
+        pltpu.VMEM((block_q, _STAT_LANES), jnp.float32),
+        pltpu.VMEM((block_q, _STAT_LANES), jnp.float32),
+    ] if steps > 1 else []
+    if bd is not None:
+        qmap, kmap, knmap = _bd_maps(nk // 2)
+        kv_specs = [pl.BlockSpec((1, block_k, d), m)
+                    for m in (kmap, kmap, knmap, knmap)]
+        out, lse = pl.pallas_call(
+            functools.partial(_flash_fwd_bd_kernel, **params),
+            name=name,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(bh, steps),
+                in_specs=[pl.BlockSpec((1, block_q, d), qmap)] + kv_specs,
+                out_specs=[pl.BlockSpec((1, block_q, d), qmap),
+                           pl.BlockSpec((1, block_q, _STAT_LANES), qmap)],
+                scratch_shapes=scratch),
+            out_shape=out_shape,
+            interpret=interpret,
+            compiler_params=_bd_compiler_params(),
+        )(_bd_table(sweep), qf, kf, vf, kf, vf)
+        return out.reshape(b, h, sq, d), lse[:, :, 0].reshape(b, h, sq)
+    kernel = functools.partial(_flash_fwd_kernel, **params)
+    kvmap = _causal_kv_map(causal, block_q, block_k, nk, window)
     out, lse = pl.pallas_call(
         kernel,
         name=name,
@@ -794,23 +991,27 @@ def _flash_fwd_pallas(q, k, v, causal, scale, tiles, interpret, window=None,
             pl.BlockSpec((1, block_q, _STAT_LANES),
                          lambda i, j, kb: (i, j, 0)),
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, sq, _STAT_LANES), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, _STAT_LANES), jnp.float32),
-            pltpu.VMEM((block_q, _STAT_LANES), jnp.float32),
-        ] if steps > 1 else [],
+        out_shape=out_shape,
+        scratch_shapes=scratch,
         interpret=interpret,
     )(qf, kf, vf)
     return out.reshape(b, h, sq, d), lse[:, :, 0].reshape(b, h, sq)
 
 
+def _flash_bwd_bd_kernel(tab_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                         delta_ref, *refs, outs, **kw):
+    """_flash_bwd_kernel on grid (batch*heads, steps of one of _bd_sweeps'
+    lists): "dq" sweeps by q block and takes the q block's noised k block
+    as two more inputs; "dkv" and "all" sweep by k pair, k_ref / v_ref and
+    the dk / dv blocks holding (noised, clean) of the pair."""
+    noised, refs = (refs[:2], refs[2:]) if outs == "dq" else ((), refs)
+    _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
+                      outs=outs, **kw, sweep=(tab_ref,) + tuple(noised))
+
+
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       *refs, nq, nk, block_q, block_k, band, causal, scale,
-                      outs, window, steps, bd=None):
+                      outs, window, steps, bd=None, sweep=None):
     """The backward of one (block_q, block_k) tile, TRANSPOSED: scores are
     held keys x queries, so p.T and ds.T — what dv = p.T @ do and
     dk = ds.T @ q consume — come out of the matmuls as they are, the
@@ -832,20 +1033,37 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     The grid's last dimension has `steps` entries: every block of the
     streamed side, or under a `window` as many as it reaches, counted from
-    the first block it reaches (_k_range, _q_range), or under the
-    block-diffusion mask (`bd`) the steps of _bd_k_of's / _bd_q_of's sweep."""
+    the first block it reaches (_k_range, _q_range). Under the
+    block-diffusion mask (`bd`) the grid is (bh, steps) and `sweep` holds
+    what tells a step its tile (_flash_bwd_bd_kernel); a step of kind "nc"
+    works the "nc" tile's key bands and then the "nn" tile's, against the
+    one q block."""
     refs = list(refs)
     want_dq, want_dkv = outs != "dkv", outs != "dq"
     dq_ref = refs.pop(0) if want_dq else None
     dk_ref, dv_ref = (refs.pop(0), refs.pop(0)) if want_dkv else (None, None)
     dq_acc = refs.pop(0) if want_dq else None
     dk_acc, dv_acc = refs if want_dkv else (None, None)
-    if outs == "dq":
+    # where a tile's keys lie: (k ref, v ref, their block's leading index,
+    # the accumulators' leading index)
+    here = noised = (k_ref, v_ref, (0,), ())
+    if bd is not None:
+        tab_ref, *kvn = sweep
+        t = pl.program_id(1)
+        j, kb = tab_ref[_BD_Q, t], None
+        first = lambda: tab_ref[_BD_FIRST, t] == 1
+        last = lambda: tab_ref[_BD_LAST, t] == 1
+        if outs == "dq":
+            noised = (*kvn, (0,), ())
+            dq_first, dq_last, dq_base = first(), last(), 0
+        else:
+            here, noised = ((k_ref, v_ref, (0, half), (half,))
+                            for half in (1, 0))
+            dq_first, dq_last, dq_base = t == 0, t == steps - 1, j * block_q
+    elif outs == "dq":
         j, kb = pl.program_id(1), pl.program_id(2)
         step = kb
-        if bd is not None:
-            kb = _bd_k_of(j, step, nq // 2, jnp.where)
-        elif window is not None:
+        if window is not None:
             kb = step + _k_range(j, block_q, block_k, nk, window,
                                  jnp.maximum, jnp.minimum)[0]
         dq_first, dq_last = step == 0, step == steps - 1
@@ -853,14 +1071,14 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     else:
         kb, j = pl.program_id(1), pl.program_id(2)
         step = j
-        if bd is not None:
-            j = _bd_q_of(kb, step, nq // 2, jnp.where)
-        elif window is not None:
+        if window is not None:
             j = step + _q_range(kb, block_q, block_k, nq, window,
                                 jnp.maximum, jnp.minimum)[0]
         dq_first = (kb == 0) & (step == 0)
         dq_last = (kb == nk - 1) & (step == steps - 1)
         dq_base = j * block_q if nq > 1 else 0
+    if bd is None:
+        first, last = lambda: step == 0, lambda: step == steps - 1
     rows_per = band or block_k
     square = block_q == block_k
     f32 = jnp.float32
@@ -871,7 +1089,7 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dq_acc[...] = jnp.zeros_like(dq_acc)
 
     if want_dkv:
-        @pl.when(step == 0)
+        @pl.when(first())
         def _init_dkv():
             dk_acc[...] = jnp.zeros_like(dk_acc)
             dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -883,68 +1101,89 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if window is not None and square and window % block_q == 0 else None
 
     def visit(kind):
+        if bd is not None and kind == "nc":
+            tile("nc", here)
+            tile("nn", noised)
+        else:
+            tile(kind, here)
+
+    def tile(kind, where):
         bd_mask = _bd_tile_mask(kind, rows_per, rows_per, bd,
                                 transposed=True) \
             if bd is not None and kind != "full" and rows_per % bd == 0 \
             else None
-        for c in range(block_k // rows_per):
-            lo, hi = c * rows_per, (c + 1) * rows_per
-            if kind == "diag":
-                cols = [(lo, hi, diag_mask)] \
-                    + ([(hi, block_q, None)] if hi < block_q else [])
-            elif kind == "edge":
-                cols = ([(0, lo, None)] if lo else []) \
-                    + [(lo, hi, edge_mask)]
-            elif kind == "full":
-                cols = [(0, block_q, None)]
-            elif bd_mask is not None:
-                cols = [(lo, hi, bd_mask)] \
-                    + ([(hi, block_q, None)]
-                       if hi < block_q and kind != "nn" else [])
-            elif bd is not None:
-                cols = [(0, block_q, _bd_tile_mask(
-                    kind, block_q, rows_per, bd, k0=lo, transposed=True))]
-            else:
-                cols = [(0, block_q, _causal_mask(
-                    block_q, rows_per, q_off=j * block_q,
-                    k_off=kb * block_k + lo, transposed=True,
-                    window=window))]
-            # native-dtype MXU dots (see fwd kernel); p and ds are rounded
-            # to the input dtype for their matmuls, standard flash-2
-            # practice. q arrives PRE-SCALED, so s matches the forward's
-            # lse directly and dk = ds.T @ q_scaled IS the true
-            # scale * ds.T @ q; dq gets its factor when it is written.
-            k_blk = k_ref[0, lo:hi, :]                     # (band, D)
-            v_blk = v_ref[0, lo:hi, :]
-            dk = dv = None
-            for a, b, mask in cols:
-                q = q_ref[0, a:b, :]
-                do = do_ref[0, a:b, :]
-                s = _dot_nt(k_blk, q)                      # (band, b - a)
-                if mask is not None:
-                    s = s + mask
-                p = jnp.exp(s - lse_ref[0, 0, :, a:b])
-                dp = _dot_nt(v_blk, do)
-                ds = (p * (dp - delta_ref[0, 0, :, a:b])).astype(q.dtype)
-                if want_dkv:
-                    # summed as they come: collecting a band's products
-                    # and adding them after the loop read 3 to 8 % slower
-                    # on the chip (the compiler follows the order given)
-                    part = jnp.dot(p.astype(do.dtype), do,
-                                   preferred_element_type=f32)
-                    dv = part if dv is None else dv + part
-                    part = jnp.dot(ds, q, preferred_element_type=f32)
-                    dk = part if dk is None else dk + part
-                if want_dq:
-                    dq_acc[pl.ds(dq_base + a, b - a), :] += lax.dot_general(
-                        ds, k_blk, (((0,), (0,)), ((), ())),
-                        preferred_element_type=f32)
-            if want_dkv:
-                dk_acc[lo:hi, :] += dk
-                dv_acc[lo:hi, :] += dv
+        bands = [work(kind, where, c, bd_mask)
+                 for c in range(block_k // rows_per)]
+        # a diagonal tile of the block-diffusion mask: the bands abreast
+        # (dq 2.89 ms a call against 3.24 in turn; dk / dv the same)
+        (_abreast if bd is not None and kind != "full" else _in_turn)(bands)
 
-    _visit_by_diagonal(causal, square, nq == 1 and nk == 1, j, kb, block_q,
-                       block_k, visit, window, nq, nk, bd)
+    def work(kind, where, c, bd_mask):
+        keys, values, at, acc_at = where
+        lo, hi = c * rows_per, (c + 1) * rows_per
+        if kind == "diag":
+            cols = [(lo, hi, diag_mask)] \
+                + ([(hi, block_q, None)] if hi < block_q else [])
+        elif kind == "edge":
+            cols = ([(0, lo, None)] if lo else []) \
+                + [(lo, hi, edge_mask)]
+        elif kind == "full":
+            cols = [(0, block_q, None)]
+        elif bd_mask is not None:
+            cols = [(lo, hi, bd_mask)] \
+                + ([(hi, block_q, None)]
+                   if hi < block_q and kind != "nn" else [])
+        elif bd is not None:
+            cols = [(0, block_q, _bd_tile_mask(
+                kind, block_q, rows_per, bd, k0=lo, transposed=True))]
+        else:
+            cols = [(0, block_q, _causal_mask(
+                block_q, rows_per, q_off=j * block_q,
+                k_off=kb * block_k + lo, transposed=True,
+                window=window))]
+        # native-dtype MXU dots (see fwd kernel); p and ds are rounded
+        # to the input dtype for their matmuls, standard flash-2
+        # practice. q arrives PRE-SCALED, so s matches the forward's
+        # lse directly and dk = ds.T @ q_scaled IS the true
+        # scale * ds.T @ q; dq gets its factor when it is written.
+        band_rows = (slice(lo, hi), slice(None))
+        k_blk = keys[at + band_rows]                   # (band, D)
+        v_blk = values[at + band_rows]
+        dk = dv = None
+        for a, b, mask in cols:
+            q = q_ref[0, a:b, :]
+            do = do_ref[0, a:b, :]
+            s = _dot_nt(k_blk, q)                      # (band, b - a)
+            if mask is not None:
+                s = s + mask
+            yield
+            p = jnp.exp(s - lse_ref[0, 0, :, a:b])
+            dp = _dot_nt(v_blk, do)
+            ds = (p * (dp - delta_ref[0, 0, :, a:b])).astype(q.dtype)
+            yield
+            if want_dkv:
+                # summed as they come: collecting a band's products
+                # and adding them after the loop read 3 to 8 % slower
+                # on the chip (the compiler follows the order given)
+                part = jnp.dot(p.astype(do.dtype), do,
+                               preferred_element_type=f32)
+                dv = part if dv is None else dv + part
+                part = jnp.dot(ds, q, preferred_element_type=f32)
+                dk = part if dk is None else dk + part
+            if want_dq:
+                dq_acc[pl.ds(dq_base + a, b - a), :] += lax.dot_general(
+                    ds, k_blk, (((0,), (0,)), ((), ())),
+                    preferred_element_type=f32)
+            yield
+        if want_dkv:
+            dk_acc[acc_at + band_rows] += dk
+            dv_acc[acc_at + band_rows] += dv
+
+    if bd is not None:
+        _visit_bd_step(tab_ref, t, visit)
+    else:
+        _visit_by_diagonal(causal, square, nq == 1 and nk == 1, j, kb,
+                           block_q, block_k, visit, window, nq, nk)
 
     if want_dq:
         @pl.when(dq_last)
@@ -952,7 +1191,7 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
     if want_dkv:
-        @pl.when(step == steps - 1)
+        @pl.when(last())
         def _finish_dkv():
             dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
             dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
@@ -963,6 +1202,17 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 # streamed tiles (~16 MB scoped in all) — 6 MB covers S=8192 at D=64 in
 # bf16; longer rows take the split kernels.
 _FUSED_DQ_BYTES_CAP = 6 * 1024 * 1024
+
+
+def _bd_compiler_params():
+    """What every `_bd` call is compiled under. Blocks and FlashPlan.fused
+    are sized so that a call on ONE k block a step fits a Mosaic call's
+    default 16 MB of scoped VMEM; a `_bd` step holds a second one (the q
+    block's noised K / V beside the clean; a pair's K, V, dk, dv and
+    accumulators), which is less than what the call held before: twice the
+    default bounds it (fp32 at D = 256, and the fused fp32 backward from
+    S = 2048 on, pass 16 MB by 0.5 to 11)."""
+    return pltpu.CompilerParams(vmem_limit_bytes=32 * 1024 * 1024)
 
 
 def _flash_bwd_stats(o, lse, do, block_q):
@@ -991,14 +1241,16 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, tiles, fused,
     dof = do.reshape(bh, sq, d)
     lsef, delta = stats if stats is not None else _flash_bwd_stats(
         o, lse, do, block_q)
+    if bd is not None:
+        return tuple(g.reshape(b, h, sq, d) for g in _flash_bwd_bd_calls(
+            qf, kf, vf, dof, lsef, delta, scale, tiles, fused, interpret,
+            bd))
     shape_q = jax.ShapeDtypeStruct((bh, sq, d), q.dtype)
     shape_k = jax.ShapeDtypeStruct((bh, sk, d), k.dtype)
     shape_v = jax.ShapeDtypeStruct((bh, sk, d), v.dtype)
     acc_k = pltpu.VMEM((block_k, d), jnp.float32)
     k_steps, q_steps, suffix = nk, nq, ""
-    if bd is not None:
-        k_steps, q_steps, suffix = nk // 2 + 1, nq, BLOCKDIFF_SUFFIX
-    elif window is not None:
+    if window is not None:
         k_steps, q_steps = _window_steps(sq, sk, block_q, block_k, window)
         suffix = WINDOW_SUFFIX
 
@@ -1011,7 +1263,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, tiles, fused,
             functools.partial(
                 _flash_bwd_kernel, nq=nq, nk=nk, block_q=block_q,
                 block_k=block_k, band=band, causal=causal, scale=scale,
-                outs=outs, window=window, steps=grid[2], bd=bd),
+                outs=outs, window=window, steps=grid[2]),
             name=name + suffix, grid=grid,
             in_specs=[q_spec, kv_spec, kv_spec, q_spec, stat_spec,
                       stat_spec],
@@ -1021,7 +1273,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, tiles, fused,
 
     # grid (bh, k blocks, q blocks): q-side tiles stream, clamped to the
     # first block that sees this k block
-    qmap = _causal_q_map(causal, block_q, block_k, nq, window, bd)
+    qmap = _causal_q_map(causal, block_q, block_k, nq, window)
     kvmap_kq = lambda i, kb, j: (i, kb, 0)
     dkv_specs = [pl.BlockSpec((1, block_k, d), kvmap_kq)] * 2
     if fused:
@@ -1034,7 +1286,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, tiles, fused,
         qmap_qk = lambda i, j, kb: (i, j, 0)
         dq = call(
             "dq", "singa_flash_bwd_dq", (bh, nq, k_steps), qmap_qk,
-            _causal_kv_map(causal, block_q, block_k, nk, window, bd),
+            _causal_kv_map(causal, block_q, block_k, nk, window),
             pl.BlockSpec((1, block_q, d), qmap_qk), shape_q,
             [pltpu.VMEM((block_q, d), jnp.float32)])
         dk, dv = call(
@@ -1042,6 +1294,61 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, tiles, fused,
             dkv_specs, [shape_k, shape_v], [acc_k, acc_k])
     return (dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),
             dv.reshape(b, h, sk, d))
+
+
+def _flash_bwd_bd_calls(qf, kf, vf, dof, lsef, delta, scale, tiles, fused,
+                        interpret, bd):
+    """The backward under the block-diffusion mask on (bh, seq, d) operands:
+    (dq, dk, dv), dk and dv as (bh, 2, seq / 2, d). The passes that sweep
+    by k block ("dkv", "all") see K, V, dk and dv as (noised, clean) halves
+    and hold a PAIR of blocks a sweep, clean k block nh + c over noised c."""
+    bh, seq, d = qf.shape
+    tile, _, band = tiles[:3]
+    nh = seq // tile // 2
+    by_q, by_k = _bd_sweeps(nh)
+    qmap, kmap, knmap = _bd_maps(nh)
+    q_spec = pl.BlockSpec((1, tile, d), qmap)
+    stat_spec = pl.BlockSpec(
+        (1, 1, 1, tile), lambda i, t, tab: (i, tab[_BD_Q, t], 0, 0))
+    pair = (bh, 2, seq // 2, d)
+    pair_spec = pl.BlockSpec(
+        (1, 2, tile, d), lambda i, t, tab: (i, 0, tab[_BD_K, t] - nh, 0))
+    pair_acc = pltpu.VMEM((2, tile, d), jnp.float32)
+
+    def call(outs, name, sweep, keys, out_specs, out_shape, scratch):
+        specs, arrays = zip(*keys)
+        return pl.pallas_call(
+            functools.partial(
+                _flash_bwd_bd_kernel, nq=2 * nh, nk=2 * nh, block_q=tile,
+                block_k=tile, band=band, causal=False, scale=scale,
+                outs=outs, window=None, steps=len(sweep), bd=bd),
+            name=name + BLOCKDIFF_SUFFIX,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(bh, len(sweep)),
+                in_specs=[q_spec, *specs[:2], q_spec, stat_spec, stat_spec,
+                          *specs[2:]],
+                out_specs=out_specs, scratch_shapes=scratch),
+            out_shape=out_shape, interpret=interpret,
+            compiler_params=_bd_compiler_params(),
+        )(_bd_table(sweep), qf, *arrays[:2], dof, lsef, delta, *arrays[2:])
+
+    pairs = [(pair_spec, a.reshape(pair)) for a in (kf, vf)]
+    shape_q = jax.ShapeDtypeStruct(qf.shape, qf.dtype)
+    dkv_shapes = [jax.ShapeDtypeStruct(pair, a.dtype) for a in (kf, vf)]
+    if fused:
+        return call(
+            "all", "singa_flash_bwd", by_k, pairs,
+            [pl.BlockSpec((1, seq, d), lambda i, t, tab: (i, 0, 0)),
+             pair_spec, pair_spec], [shape_q] + dkv_shapes,
+            [pltpu.VMEM((seq, d), jnp.float32), pair_acc, pair_acc])
+    dq = call(
+        "dq", "singa_flash_bwd_dq", by_q,
+        [(pl.BlockSpec((1, tile, d), m), a)
+         for m, a in ((kmap, kf), (kmap, vf), (knmap, kf), (knmap, vf))],
+        q_spec, shape_q, [pltpu.VMEM((tile, d), jnp.float32)])
+    dk, dv = call("dkv", "singa_flash_bwd_dkv", by_k, pairs,
+                  [pair_spec, pair_spec], dkv_shapes, [pair_acc, pair_acc])
+    return dq, dk, dv
 
 
 def _flash_bwd_blockwise(q, k, v, o, lse, do, causal, scale, block_k,
@@ -1124,7 +1431,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
                                    block_diffusion), None
     record_attention_dispatch("flash_fwd", _kernel_path(interpret))
     record_flash_tiles("flash_fwd", *plan.fwd[3:], plan.skipped[0],
-                       plan.window, plan.block_diffusion)
+                       plan.window, plan.block_diffusion, plan.steps[0])
     return _flash_fwd_pallas(q, k, v, causal, scale, plan.fwd, interpret,
                              plan.window, plan.block_diffusion)
 
@@ -1159,7 +1466,7 @@ def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, window,
     if _HAS_PALLAS and plan.bwd:
         record_attention_dispatch("flash_bwd", _kernel_path(interp))
         record_flash_tiles("flash_bwd", *plan.bwd[3:], plan.skipped[1],
-                           plan.window, plan.block_diffusion)
+                           plan.window, plan.block_diffusion, plan.steps[1])
         return _flash_bwd_pallas(q, k, v, out, lse, g, causal, s, plan.bwd,
                                  plan.fused, interp, window=plan.window,
                                  bd=plan.block_diffusion)

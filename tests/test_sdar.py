@@ -279,10 +279,15 @@ def test_plan_gauges():
     # clean x noised quadrant
     g = reg.get("singa_flash_tiles")
     t = {k: int(g.value(site="flash_fwd", kind=k)) for k in (
-        "visited", "masked", "square", "skipped", "block_diffusion")}
+        "visited", "masked", "square", "skipped", "block_diffusion",
+        "steps", "idle_steps")}
     assert t["block_diffusion"] == 4
     assert t["visited"] + t["skipped"] == t["square"] == 4
     assert t["visited"] == 3
+    # a grid step a worked tile, in the forward and in the backward
+    assert (t["steps"], t["idle_steps"]) == (2, 0)
+    assert [int(g.value(site="flash_bwd", kind=k))
+            for k in ("steps", "idle_steps")] == [2, 0]
 
 
 # ---- the eight shares ----------------------------------------------------------
@@ -400,24 +405,31 @@ def test_what_no_tile_fits_takes_the_reference_path(shape, block, bq, bk):
         assert max(_errors(shape, block, bq, bk)) < 2e-4
 
 
-@pytest.mark.parametrize("args,fwd,bwd,skipped", [
+@pytest.mark.parametrize("args,fwd,bwd,skipped,steps", [
     # the cell's call: tiles of 1024, bands of 128 forward and 256 backward.
     # Forward: 4 "nn" tiles of 8 diagonal bands, 4 + 4 diagonal tiles of 36
     # sub-tiles, 6 + 6 whole tiles of 64: 1088 of the square's 4096 (a
-    # causal call over 8192 visits 2080)
+    # causal call over 8192 visits 2080). 20 grid steps a pass (the "nn"
+    # tiles ride with "nc"), the split backward's two passes 40
     ((8192, 128, "bfloat16", 4), (1024, 1024, 128, 1088, 96, 4096),
-     (1024, 1024, 256, 288, 48, 1024), (3008, 736)),
+     (1024, 1024, 256, 288, 48, 1024), (3008, 736), (20, 40)),
     # S one tile
     ((256, 32, "float32", 4), (128, 128, 128, 3, 3, 4),
-     (128, 128, 128, 3, 3, 4), (1, 1)),
+     (128, 128, 128, 3, 3, 4), (1, 1), (2, 2)),
     # a block length no band divides: every diagonal tile whole under masks
     ((768, 32, "float32", 96), (384, 384, 128, 27, 27, 36),
-     (384, 384, 128, 27, 27, 36), (9, 9)),
+     (384, 384, 128, 27, 27, 36), (9, 9), (2, 2)),
     # the plan picks a tile the block length divides (512, not 768)
     ((3072, 32, "float32", 512), (512, 512, 128, 240, 144, 576),
-     (512, 512, 256, 60, 36, 144), (336, 84)),
-], ids=["cell", "one-tile", "b96", "b512"])
-def test_flash_plan_block_diffusion_table(args, fwd, bwd, skipped):
+     (512, 512, 256, 60, 36, 144), (336, 84), (12, 12)),
+    # S one tile of 1024 (nh = 1): a noised and a clean q block, a step each
+    ((2048, 128, "bfloat16", 4), (1024, 1024, 128, 80, 24, 256),
+     (1024, 1024, 256, 24, 12, 64), (176, 40), (2, 2)),
+    # S two tiles (nh = 2): 2 "nn" x 8, 2 + 2 diagonal tiles x 36, 2 whole
+    ((4096, 128, "bfloat16", 4), (1024, 1024, 128, 288, 48, 1024),
+     (1024, 1024, 256, 80, 24, 256), (736, 176), (6, 6)),
+], ids=["cell", "one-tile", "b96", "b512", "nh1", "nh2"])
+def test_flash_plan_block_diffusion_table(args, fwd, bwd, skipped, steps):
     seq, d, dtype, block = args
     plan = att.flash_plan(seq, seq, d, False, jnp.dtype(dtype), None, None,
                           None, block)
@@ -427,21 +439,66 @@ def test_flash_plan_block_diffusion_table(args, fwd, bwd, skipped):
     for t, s in zip((plan.fwd, plan.bwd), plan.skipped):
         assert t.visited + s == t.square          # visited + skipped = all
     # no tile of the clean x noised quadrant is of any kind, nor one above
-    # a diagonal; a q block's sweep meets exactly the tiles of some kind
+    # a diagonal
     nh = seq // 2 // plan.fwd.block_q
-    kinds = lambda j, kb: [k for k, on in att._bd_kinds(j, kb, nh).items()
-                           if on]
-    for j in range(2 * nh):
-        swept = {att._bd_k_of(j, s, nh) for s in range(nh + 1)}
-        for kb in range(2 * nh):
-            kind = kinds(j, kb)
-            assert len(kind) <= 1
-            if j >= nh and kb < nh:
-                assert not kind
-            assert bool(kind) <= (kb in swept)
-    for kb in range(2 * nh):
-        swept = {att._bd_q_of(kb, s, nh) for s in range(2 * nh)}
-        assert {j for j in range(2 * nh) if kinds(j, kb)} <= swept
+    of_a_kind = {(j, kb, att._bd_kind(j, kb, nh))
+                 for j in range(2 * nh) for kb in range(2 * nh)
+                 if att._bd_kind(j, kb, nh)}
+    assert not any(j >= nh > kb for j, kb, _ in of_a_kind)
+    assert all(sum(att._bd_kinds(j, kb, nh).values()) <= 1
+               for j in range(2 * nh) for kb in range(2 * nh))
+    # a pass's grid is the sweeps' steps: every tile of some kind is met
+    # exactly once a pass, a q block's (a k pair's) steps are consecutive
+    # with `first` and `last` at their ends, no step is empty
+    for sweep, own in zip(att._bd_sweeps(nh), (lambda s: s.q, lambda s: s.k)):
+        met = [t for s in sweep for t in s.tiles()]
+        assert len(met) == len(set(met)) and set(met) == of_a_kind
+        assert all(s.tiles() for s in sweep)
+        keys = [own(s) for s in sweep]
+        runs = [k for i, k in enumerate(keys) if i == 0 or keys[i - 1] != k]
+        assert len(runs) == len(set(runs))              # consecutive
+        assert [s.first for s in sweep] == [
+            i == 0 or keys[i - 1] != k for i, k in enumerate(keys)]
+        assert [s.last for s in sweep] == [
+            i == len(keys) - 1 or keys[i + 1] != k
+            for i, k in enumerate(keys)]
+    # the first step of a q block's sweep has met a key for every row: a
+    # whole tile, or the block's own diagonal (a noised block's "nc" step
+    # brings its "nn" tile), and the diagonal tile is the sweep's last
+    by_q, by_k = att._bd_sweeps(nh)
+    for s in by_q:
+        if s.first:
+            assert s.kind == "full" or s.k == (s.q if s.q >= nh
+                                               else s.q + nh)
+        assert s.last == (s.kind != "full")
+    # what the gauge gives: grid steps a row and those that work no tile,
+    # summed over the backward's calls
+    fused = 1 if plan.fused else 2
+    assert plan.steps == ((steps[0], 0), (steps[1], 0))
+    assert (len(by_q), len(by_q) * (fused - 1) + len(by_k)) == steps
+
+
+@pytest.mark.parametrize("mask,fwd,bwd", [
+    # the cell's call: 20 steps a pass, the split backward's two passes 40
+    ("block_diffusion", (20, 0), (40, 0)),
+    # the causal call of the same shape, for scale: 28 of a pass's 64 steps
+    # lie above the diagonal
+    ("causal", (64, 28), (128, 56)),
+])
+def test_flash_tiles_gauge_counts_grid_steps_and_the_idle_ones(mask, fwd, bwd):
+    """singa_flash_tiles{kind=steps|idle_steps} after one traced call at
+    (1, 1, 8192, 128) bf16 (traced, not run): the block-diffusion passes
+    leave no grid step without a tile."""
+    q = jax.ShapeDtypeStruct((1, 1, 8192, 128), jnp.bfloat16)
+    causal, block = mask == "causal", 4 if mask == "block_diffusion" else None
+    jax.eval_shape(jax.grad(lambda q, k, v: att.flash_attention(
+        q, k, v, causal, None, None, None, True, None, block).astype(
+            jnp.float32).sum(), (0, 1, 2)), q, q, q)
+    g = observe.get_registry().get("singa_flash_tiles")
+    got = [tuple(int(g.value(site=site, kind=kind))
+                 for kind in ("steps", "idle_steps"))
+           for site in ("flash_fwd", "flash_bwd")]
+    assert got == [fwd, bwd]
 
 
 def test_the_mask_is_one_argument_s_doing():

@@ -160,6 +160,30 @@ def test_block_diffusion_flash_compiles_for_v5e(one_chip, shape, block,
     assert "singa_flash_fwd" + A.BLOCKDIFF_SUFFIX in text
 
 
+# a `_bd` grid step holds a second k block (the q block's noised K / V in
+# the forward and dq; a (noised, clean) pair of K, V, dk, dv in dk / dv and
+# the fused backward): past a Mosaic call's default scoped VMEM for the
+# fused backward in fp32 and for the pair at D = 256, where the calls on one
+# block compiled; the `_bd` calls ask for twice the default
+@pytest.mark.parametrize("shape,dtype,fused", [
+    ((1, 2, 4096, 64), "float32", True),
+    ((1, 2, 4096, 256), "bfloat16", False),
+], ids=["s4k_d64_fp32_fused", "s4k_d256_split"])
+def test_block_diffusion_calls_hold_a_second_k_block_on_v5e(one_chip, shape,
+                                                            dtype, fused):
+    q = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
+    assert A.flash_plan(shape[2], shape[2], shape[3], False, q.dtype,
+                        block_diffusion=4).fused == fused
+    text = jax.jit(jax.grad(
+        lambda *a: A.flash_attention(*a, False, None, None, None, False,
+                                     None, 4).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))).lower(q, q, q).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == (2 if fused else 3)
+    assert "singa_flash_bwd" + A.BLOCKDIFF_SUFFIX in text \
+        or "singa_flash_bwd_dkv" + A.BLOCKDIFF_SUFFIX in text
+
+
 # what ServingEngine builds for GPT-2-small in chip_smoke.py: 8 slots,
 # P=2 heads packed per 128-lane row -> Hp=6, Q=P*G=2 query rows per token,
 # pages of 16 tokens, 64 pages per sequence (max_ctx 1024), 512 in the pool

@@ -85,6 +85,32 @@ def test_flash_train_cell_shape_default_plan():
         _assert_close_quantile(a, b, tol=2e-2, max_tol=1e-1)
 
 
+def test_flash_block_diffusion_cell_shape():
+    """The block-diffusion cell's call: (1, 32, 8192, 128) bf16 under the
+    mask of blocks of 4 (tiles of 1024, the table's 20 grid steps a pass,
+    "nn" riding with "nc", the split backward over k pairs), forward and
+    the three gradients against the reference under the dense mask, on the
+    first two heads (the reference's scores are 8192 x 8192 a head)."""
+    rng = np.random.RandomState(5)
+    q, k, v = _rand_qkv(rng, 1, 32, 8192, 128, jnp.bfloat16)
+    w = jnp.asarray(rng.standard_normal(q.shape), jnp.bfloat16)
+    heads = lambda a: a[:, :2].astype(jnp.float32)
+
+    def run(fn, w, *a):
+        out, vjp = jax.vjp(lambda *x: fn(*x, False, None, None, 4), *a)
+        return (out,) + vjp(w.astype(out.dtype))
+
+    got = jax.jit(lambda *a: run(
+        lambda q, k, v, causal, scale, window, b: flash_attention(
+            q, k, v, causal, scale, None, None, False, window, b), *a))(
+        w, q, k, v)
+    want = jax.jit(lambda *a: run(attention_reference, *a))(
+        *(heads(a) for a in (w, q, k, v)))
+    _assert_close_quantile(heads(got[0]), want[0], tol=8e-3, max_tol=5e-2)
+    for a, b in zip(got[1:], want[1:]):
+        _assert_close_quantile(heads(a), b, tol=2e-2, max_tol=1e-1)
+
+
 def test_flash_long_sequence_compiled():
     """S=16k head: whole-row VMEM residency would blow VMEM (16k*128*4B*2
     = 16 MB just for K/V of one head); streamed blocks must handle it."""
