@@ -805,6 +805,49 @@ class MultiHeadAttention(Layer):
         return y
 
 
+class ShortConv(Layer):
+    """The gated short convolution over (B, S, E) (ops/shortconv.py): the
+    input projected to three streams (B, C, u) = split3(x W_in), z = B * u,
+    a depthwise causal convolution of `taps` taps over z along the
+    sequence (zeros before its start), the result gated by C and projected
+    by W_out. No bias. ONE tape operator, its device time under
+    `in_proj`, `mix` and `out_proj`. The state a decoder would carry for
+    it is the last `taps - 1` rows of z a sequence; nothing here serves it
+    (serving.py and engine.py hold keys and values only), packs documents
+    through it (the taps cross a boundary) or exports it (sonnx refuses
+    it by name)."""
+
+    def __init__(self, taps=3, name=None):
+        super().__init__(name)
+        self.taps = taps
+
+    def initialize(self, x):
+        e = x.shape[-1]
+        for attr, shape in (("W_in", (e, 3 * e)), ("W_out", (e, e))):
+            W = Tensor(shape, device=x.device, dtype=x.dtype)
+            initializer.glorot_uniform(W)
+            self._register_param(attr, W)
+        # a depthwise filter's fan-in is its taps
+        w = Tensor((e, self.taps), device=x.device, dtype=x.dtype)
+        w.uniform(-self.taps ** -0.5, self.taps ** -0.5)
+        self._register_param("w", w)
+
+    def forward(self, x):
+        # the taps stay as they are (fp32 under `amp`): the chain between
+        # the products computes in fp32
+        x, W_in, W_out = autograd.compute_cast(x, self.W_in, self.W_out)
+        return _ShortConvOp()(x, W_in, self.w, W_out)
+
+
+class _ShortConvOp(autograd.Operator):
+    def __init__(self):
+        super().__init__("ShortConv")
+
+    def forward(self, x, W_in, w, W_out):
+        from .ops.shortconv import short_conv
+        return short_conv(x, W_in, w, W_out)
+
+
 class TransformerBlock(Layer):
     """Pre-norm block: x + MHA(N(x)); x + MLP(N(x)). `tp_axis` makes the
     attention head-parallel and the MLP column→row parallel (two psums per
@@ -824,7 +867,10 @@ class TransformerBlock(Layer):
     block's `norm_eps`.
     `moe_dropless=True` takes `DroplessMoE` for the expert layer (SiLU-gated
     experts of width `ffn_dim`, no capacity, nothing dropped), of which this
-    device holds `moe_held` experts from `moe_offset` on (None: all)."""
+    device holds `moe_held` experts from `moe_offset` on (None: all);
+    `moe_router`: that layer's routing arguments (`score`, `bias`, `scale`,
+    `gate_eps`). `mixer="conv"` puts a `ShortConv` of `conv_taps` taps
+    (`conv`) in the attention's place: such a block has no `attn`."""
 
     def __init__(self, num_heads, mlp_ratio=4, causal=True, seq_axis=None,
                  tp_axis=None, attn_bias=False, moe_experts=0, moe_k=1,
@@ -833,25 +879,26 @@ class TransformerBlock(Layer):
                  ffn="gelu", ffn_dim=None, ffn_bias=True, post_norm=False,
                  head_dim=None, window=None, rope_scaling=None,
                  moe_dropless=False, moe_held=None, moe_offset=0,
-                 block_diffusion=None, qk_norm=False, name=None):
+                 block_diffusion=None, qk_norm=False, mixer="attention",
+                 conv_taps=3, moe_router=None, name=None):
         super().__init__(name)
-        assert norm in ("layer", "rms") and ffn in ("gelu", "swiglu"), \
-            (norm, ffn)
+        assert norm in ("layer", "rms") and ffn in ("gelu", "swiglu") \
+            and mixer in ("attention", "conv"), (norm, ffn, mixer)
         norm_cls = LayerNorm if norm == "layer" else RMSNorm
 
         def make_norm():    # each class has its own default eps
             return norm_cls() if norm_eps is None else norm_cls(norm_eps)
         self.ln1 = make_norm()
-        self.attn = MultiHeadAttention(num_heads, causal=causal,
-                                       seq_axis=seq_axis, tp_axis=tp_axis,
-                                       bias=attn_bias,
-                                       num_kv_heads=num_kv_heads,
-                                       rope=rope, rope_theta=rope_theta,
-                                       head_dim=head_dim, window=window,
-                                       rope_scaling=rope_scaling,
-                                       block_diffusion=block_diffusion,
-                                       qk_norm_eps=(norm_eps or RMSNorm().eps)
-                                       if qk_norm else None)
+        self.mixer = mixer
+        if mixer == "conv":
+            self.conv = ShortConv(conv_taps)
+        else:
+            self.attn = MultiHeadAttention(
+                num_heads, causal=causal, seq_axis=seq_axis, tp_axis=tp_axis,
+                bias=attn_bias, num_kv_heads=num_kv_heads, rope=rope,
+                rope_theta=rope_theta, head_dim=head_dim, window=window,
+                rope_scaling=rope_scaling, block_diffusion=block_diffusion,
+                qk_norm_eps=(norm_eps or RMSNorm().eps) if qk_norm else None)
         self.ln2 = make_norm()
         self.post_norm = post_norm
         if post_norm:
@@ -862,7 +909,7 @@ class TransformerBlock(Layer):
         self.moe_experts = moe_experts
         if moe_experts and moe_dropless:
             self.moe = DroplessMoE(moe_experts, k=moe_k, held=moe_held,
-                                   offset=moe_offset)
+                                   offset=moe_offset, **(moe_router or {}))
         elif moe_experts:
             self.moe = MoE(moe_experts, capacity_factor=moe_capacity_factor,
                            ep_axis=ep_axis, k=moe_k)
@@ -882,7 +929,7 @@ class TransformerBlock(Layer):
                           tp_mode="row")
 
     def forward(self, x):
-        a = self.attn(self.ln1(x))
+        a = (self.conv if self.mixer == "conv" else self.attn)(self.ln1(x))
         x = autograd.add(x, self.ln1_post(a) if self.post_norm else a)
         h = self.ln2(x)
         if self.moe_experts:
@@ -916,9 +963,21 @@ class DroplessMoE(Layer):
     sum on: summed over the devices that share the layer it is the whole
     layer. Nothing here stands in for the other devices or the exchange.
     After forward `self.rows` holds the rows routed to each held expert
-    (float32, off the tape)."""
+    (float32, off the tape).
+
+    The routing (parallel/moe.py `route_topk`): `score` "softmax" over all
+    experts or "sigmoid" of each; the k gates divided by their sum +
+    `gate_eps`, times `scale`. `bias=True` adds a selection bias `b`
+    (num_experts,): added to the scores for the top-k alone, never in the
+    gates. It is a STATE, not a parameter (`_register_state`, as
+    BatchNorm2d's running statistics: saved, restored and carried by the
+    graph step, with no gradient and in no optimizer's state), zero on a
+    fresh layer; `update_bias(rate)` moves it by the load of the latest
+    forward, `self.load` (num_experts,): the pairs sent to each of ALL the
+    experts, held or not (float32, off the tape; None without a bias)."""
 
     def __init__(self, num_experts, hidden=None, k=1, held=None, offset=0,
+                 score="softmax", bias=False, scale=1.0, gate_eps=0.0,
                  name=None):
         super().__init__(name)
         self.num_experts, self.hidden, self.k = num_experts, hidden, k
@@ -926,7 +985,9 @@ class DroplessMoE(Layer):
         self.offset = offset
         assert 0 <= offset and offset + self.held <= num_experts, \
             (num_experts, held, offset)
-        self.rows = None
+        self.use_bias = bias
+        self.route = dict(score=score, scale=scale, eps=gate_eps)
+        self.rows = self.load = None
 
     def initialize(self, x):
         d, h, H = x.shape[-1], self.hidden or 4 * x.shape[-1], self.held
@@ -938,30 +999,47 @@ class DroplessMoE(Layer):
             W = Tensor(shape, device=x.device, dtype=x.dtype)
             W.gaussian(0.0, (2.0 / fan) ** 0.5)
             self._register_param(attr, W)
+        if self.use_bias:
+            b = Tensor((self.num_experts,), device=x.device, dtype=x.dtype)
+            b.set_value(0.0)
+            self._register_state("b", b)
 
     def forward(self, x):
         # the router reads x and its own weight as they are (fp32 under
         # `amp`); the experts take the compute dtype
         Wg, Wu, Wd = autograd.compute_cast(self.Wg, self.Wu, self.Wd)
-        y, rows = _DroplessMoEOp(self.k, self.offset)(
-            x, self.Wr, Wg, Wu, Wd)
+        y, *counts = _DroplessMoEOp(self.k, self.offset, **self.route)(
+            x, self.Wr, Wg, Wu, Wd, *([self.b] if self.use_bias else []))
         # off the tape: a region that hands the rows out must not walk the
         # layer's backward a second time for them
-        self.rows = Tensor(data=rows.data, device=x.device,
-                           requires_grad=False)
+        off = lambda c: Tensor(data=c.data, device=x.device,
+                               requires_grad=False)
+        self.rows = off(counts[0])
+        self.load = off(counts[1]) if self.use_bias else None
         return y
+
+    def update_bias(self, rate, load=None):
+        """b_e += rate x sign(mean(load) - load_e) over ALL the experts:
+        one that took more than its share is chosen less often from the
+        next step on, at the same gates (auxiliary-loss-free balancing,
+        arXiv:2408.15664). `load`: a forward's `self.load` (the latest
+        one's when None). Call it after the optimizer's step."""
+        import jax.numpy as jnp
+        load = (self.load if load is None else load).data
+        self.b.data = self.b.data + rate * jnp.sign(
+            jnp.mean(load) - load).astype(self.b.data.dtype)
 
 
 class _DroplessMoEOp(autograd.Operator):
-    def __init__(self, k, offset):
+    def __init__(self, k, offset, **route):
         super().__init__("DroplessMoE")
-        self.k, self.offset = k, offset
+        self.k, self.offset, self.route = k, offset, route
 
-    def forward(self, x, Wr, Wg, Wu, Wd):
+    def forward(self, x, Wr, Wg, Wu, Wd, bias=None):
         from .parallel.moe import dropless_moe
-        y, rows = dropless_moe(x.reshape(-1, x.shape[-1]), Wr, Wg, Wu, Wd,
-                               self.k, self.offset)
-        return y.reshape(x.shape).astype(x.dtype), rows
+        y, *counts = dropless_moe(x.reshape(-1, x.shape[-1]), Wr, Wg, Wu, Wd,
+                                  self.k, self.offset, bias, **self.route)
+        return (y.reshape(x.shape).astype(x.dtype), *counts)
 
 
 class MoE(Layer):

@@ -10,7 +10,7 @@ dispatch in every model file; here it lives once in `base.Classifier`).
 from .. import observe
 from .base import Classifier  # noqa: F401
 from . import (mlp, cnn, alexnet, resnet, xceptionnet, transformer,  # noqa: F401
-               looplm, mellum, sdar)
+               looplm, mellum, sdar, lfm2)
 
 _REGISTRY = {
     "mlp": mlp.create_model,
@@ -28,6 +28,7 @@ _REGISTRY = {
     "looplm": looplm.create_model,
     "mellum": mellum.create_model,
     "sdar": sdar.create_model,
+    "lfm2": lfm2.create_model,
 }
 
 

@@ -136,7 +136,10 @@ def _plan_gauge():
         "sorted buffer (tokens x min(k, held): the worst case; the grouped "
         "products follow the rows really routed, the passes over the buffer "
         "the rung that holds them), the least rung of the buffer's ladder, "
-        "blocks recomputed in the backward pass")
+        "blocks recomputed in the backward pass; where the model says so, "
+        "dense_layers (leading layers without experts), sigmoid (1: the "
+        "router scores by a sigmoid, not a softmax), bias (1: a selection "
+        "bias that the step moves)")
 
 
 def _moe_plan(**kinds):
@@ -148,8 +151,10 @@ def _moe_plan(**kinds):
         g.set(v, kind=kind)
 
 
-def record_rows(rows):
-    """`rows` (L, held): a step's third output, fetched. Sets
+def record_rows(rows, first=0):
+    """`rows` (L, held): a step's third output, fetched (`first`: the
+    index of its first layer in the model, where leading layers hold no
+    experts). Sets
     `singa_moe_rows{layer, kind=routed|held_max|held_min|buffer}`: the rows
     routed to this device's experts in each layer, the largest and the
     least load among them, and the rung of the sorted buffer that the
@@ -162,7 +167,7 @@ def record_rows(rows):
         "and the least expert's, and the buffer length (a rung of the "
         "ladder) the layer's row passes worked on")
     worst = int(_plan_gauge().value(kind="rows_worst"))
-    for i, r in enumerate(np.asarray(rows)):
+    for i, r in enumerate(np.asarray(rows), first):
         for kind, v in (("routed", r.sum()), ("held_max", r.max()),
                         ("held_min", r.min()),
                         ("buffer", rung_of(r.sum(), worst))):

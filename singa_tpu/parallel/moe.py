@@ -239,14 +239,32 @@ def _fill(a, R):
     return jnp.pad(a, ((0, R - a.shape[0]),) + ((0, 0),) * (a.ndim - 1))
 
 
-def route_topk(x, Wr, k):
+def route_topk(x, Wr, k, score="softmax", bias=None, scale=1.0, eps=0.0):
     """(gates (T, k) fp32, renormalised over the k chosen; experts (T, k)
-    int32): softmax over ALL experts in fp32, the matmul at full
-    precision (a tie in the top-k moves a whole row), then the top k."""
+    int32): the scores of ALL experts in fp32 (`score`: "softmax" over
+    them, or "sigmoid" of each), the matmul at full precision (a tie in
+    the top-k moves a whole row), then the top k. `bias` (E,): added to
+    the scores for the SELECTION alone (the gates are the chosen experts'
+    plain scores, and nothing differentiates through it: a buffer that
+    balances the load, arXiv:2408.15664). The gates are divided by their
+    sum + `eps` and multiplied by `scale`."""
+    assert score in ("softmax", "sigmoid"), score
     logits = jnp.dot(x.astype(jnp.float32), Wr.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
-    topv, topi = lax.top_k(jax.nn.softmax(logits, axis=-1), k)
-    return topv / jnp.sum(topv, axis=-1, keepdims=True), topi
+    scores = jax.nn.softmax(logits, axis=-1) if score == "softmax" \
+        else jax.nn.sigmoid(logits)
+    if bias is None:
+        topv, topi = lax.top_k(scores, k)
+    else:
+        topi = lax.top_k(scores + lax.stop_gradient(
+            bias.astype(jnp.float32)), k)[1]
+        # read by comparison: a gather's transpose is a scatter-add of
+        # T x k numbers, which the TPU serialises
+        chosen = topi[..., None] == jnp.arange(scores.shape[-1])
+        topv = jnp.sum(jnp.where(chosen, scores[:, None, :], 0.0), axis=-1)
+    total = jnp.sum(topv, axis=-1, keepdims=True)
+    gates = topv / (total + eps if eps else total)
+    return (gates * scale if scale != 1.0 else gates), topi
 
 
 def _rows_of_pairs(a, inv, held):
@@ -381,17 +399,24 @@ def grouped_matmul(lhs, rhs, sizes):
     return lax.ragged_dot(lhs, rhs.astype(lhs.dtype), sizes)
 
 
-def dropless_moe(x, Wr, Wg, Wu, Wd, k, offset=0):
+def dropless_moe(x, Wr, Wg, Wu, Wd, k, offset=0, bias=None, **route):
     """x (T, D); Wr (D, E) the router over ALL experts; Wg, Wu (H, D, F),
     Wd (H, F, D): the H experts offset .. offset + H - 1 this device holds.
     Returns (y (T, D) fp32: sum over a token's chosen AND held experts of
     gate x (silu(x Wg_e) * (x Wu_e)) Wd_e; rows (H,) float32: the rows
     routed to each held expert, off the gradient). The experts compute in
-    the dtype of their weights."""
+    the dtype of their weights. `route`: `route_topk`'s `score`, `scale`
+    and `eps`. With a selection `bias` (E,) a third result: load (E,)
+    float32, the pairs sent to each of ALL the experts, held or not, off
+    the gradient (what the bias is moved by)."""
     T, H = x.shape[0], Wg.shape[0]
     R = T * min(k, H)
     with jax.named_scope("router"):
-        gates, experts = route_topk(x, Wr, k)
+        gates, experts = route_topk(x, Wr, k, bias=bias, **route)
+        if bias is not None:
+            load = jnp.sum(experts.reshape(-1)[:, None]
+                           == jnp.arange(Wr.shape[1])[None, :], axis=0,
+                           dtype=jnp.int32)
     with jax.named_scope("dispatch"):
         local = experts - offset
         mine = (local >= 0) & (local < H)
@@ -413,4 +438,7 @@ def dropless_moe(x, Wr, Wg, Wu, Wd, k, offset=0):
     with jax.named_scope("combine"):
         y = _tokens_of_rows(o, jnp.where(held, gates, 0.0), order, inv,
                             held, rung, k)
-    return y, lax.stop_gradient(sizes.astype(jnp.float32))
+    out = (y, lax.stop_gradient(sizes.astype(jnp.float32)))
+    if bias is not None:
+        out += (lax.stop_gradient(load.astype(jnp.float32)),)
+    return out

@@ -597,6 +597,10 @@ UNEXPORTABLE = {
     "_DroplessMoEOp": "the dropless expert layer: top-k routing, a sort by "
                       "expert and grouped products over the rows routed; "
                       "ONNX has no form of it either",
+    "_ShortConvOp": "the gated short convolution (layer.ShortConv): a "
+                    "model holding one has no ONNX form here (its chain "
+                    "is not decomposed, and no exported graph carries "
+                    "the state a decoder would keep for it)",
     "_ReversePadded": "internal helper of the bidirectional fused RNN; "
                       "the LSTM node's direction attr covers it on the "
                       "ONNX side",
@@ -616,6 +620,8 @@ UNEXPORTABLE = {
     # the sparse model's training step (models/mellum.py)
     "_SampleLogits": "training step's sample of the logits, off the tape",
     "_Stack": "training step's rows routed a layer, off the tape",
+    "_Zeros": "training step's counts of a layer without experts, off "
+              "the tape",
     "_Noise": "block-diffusion training step's noising and doubling of "
               "the ids, off the tape",
     # shape/constant generators with no stable inference mapping

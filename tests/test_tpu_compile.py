@@ -410,3 +410,27 @@ def test_expert_layer_switches_its_row_passes_on_the_rung_on_v5e(
     body = body[:body.index("\n}")]
     assert re.search(rf"f32\[{R // 8},{D}\][^\n]* fusion\(", body), body
     assert re.search(rf"bf16\[{R},{D}\]", body), body
+
+
+def test_short_conv_keeps_no_fp32_copy_of_its_streams_on_v5e(one_chip):
+    """The gated short convolution at the cell's shape (1 x 16,384 x 2,048,
+    bf16 products, fp32 taps), forward and all four gradients in one
+    program: XLA's fusions implement the chain (no Mosaic call), and what
+    the program reserves stays under a gigabyte (806 MB: the (.., 3D)
+    product, its gradient and one fp32 stream between two fusions; four
+    fp32 copies of the streams kept for the backward would add half as
+    much again)."""
+    from singa_tpu.ops import shortconv
+    S, D = 16384, 2048
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                 sharding=one_chip)
+    args = (sds((1, S, D), jnp.bfloat16), sds((D, 3 * D), jnp.bfloat16),
+            sds((D, 3), jnp.float32), sds((D, D), jnp.bfloat16))
+
+    def grad(*a):
+        return jax.grad(lambda *b: shortconv.short_conv(*b).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2, 3))(*a)
+
+    compiled = jax.jit(grad).lower(*args).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
