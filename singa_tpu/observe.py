@@ -53,6 +53,10 @@ COMM_OPS = ("all_reduce", "all_reduce_half", "all_gather", "broadcast",
 ATTN_SITES = ("flash_fwd", "flash_bwd", "paged", "flash_decode")
 ATTN_PATHS = ("kernel", "interpret", "reference")
 
+#: the paths a gated short convolution's chain can take (ops.shortconv):
+#: its Pallas kernels compiled, the same in interpret mode, or the jnp chain
+SHORTCONV_PATHS = ("kernel", "interpret", "xla")
+
 #: what a softmax cross-entropy's targets are, and where its backward's
 #: log-sum-exp comes from (record_cross_entropy)
 CE_TARGETS = ("integer", "dense")
@@ -871,6 +875,17 @@ def record_attention_dispatch(site: str, path: str):
     counter("singa_attention_dispatch_total",
             "attention call sites traced, by site and the path taken "
             "(kernel|interpret|reference)").inc(site=site, path=path)
+
+
+def record_shortconv_dispatch(path: str):
+    """The implementation one short convolution's chain took, counted at
+    trace time like record_attention_dispatch."""
+    assert path in SHORTCONV_PATHS, path
+    if not _enabled:
+        return
+    counter("singa_shortconv_dispatch_total",
+            "short-convolution chains traced, by the path taken "
+            "(kernel|interpret|xla)").inc(path=path)
 
 
 def record_flash_tiles(site: str, visited: int, masked: int, square: int,
