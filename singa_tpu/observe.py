@@ -57,6 +57,10 @@ ATTN_PATHS = ("kernel", "interpret", "reference")
 #: its Pallas kernels compiled, the same in interpret mode, or the jnp chain
 SHORTCONV_PATHS = ("kernel", "interpret", "xla")
 
+#: the paths a chunked gated delta rule can take (ops.gated_delta): the
+#: same three
+DELTA_RULE_PATHS = SHORTCONV_PATHS
+
 #: what a softmax cross-entropy's targets are, and where its backward's
 #: log-sum-exp comes from (record_cross_entropy)
 CE_TARGETS = ("integer", "dense")
@@ -885,6 +889,17 @@ def record_shortconv_dispatch(path: str):
         return
     counter("singa_shortconv_dispatch_total",
             "short-convolution chains traced, by the path taken "
+            "(kernel|interpret|xla)").inc(path=path)
+
+
+def record_delta_rule_dispatch(path: str):
+    """The implementation one chunked delta rule took, counted at trace
+    time like record_shortconv_dispatch."""
+    assert path in DELTA_RULE_PATHS, path
+    if not _enabled:
+        return
+    counter("singa_delta_rule_dispatch_total",
+            "chunked delta rules traced, by the path taken "
             "(kernel|interpret|xla)").inc(path=path)
 
 

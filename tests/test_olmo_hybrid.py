@@ -88,10 +88,11 @@ def _rel(a, b):
 
 
 @functools.lru_cache(maxsize=None)
-def _stepped(amp, recompute):
+def _stepped(amp, recompute, chunk=CFG["chunk"]):
     """(initial parameters, loss, sample, {name: gradient}) of one eager
-    training step on batch 0."""
-    m, dev = _build(amp, recompute)
+    training step on batch 0 (chunks of 8: the delta rule's kernels, in
+    interpret mode; of 4: its XLA form)."""
+    m, dev = _build(amp, recompute, chunk=chunk)
     p0 = _params(m)
     x, y = _batch()
     out = m(tensor.from_numpy(x, device=dev), tensor.from_numpy(y, device=dev))
@@ -166,12 +167,39 @@ def test_recompute_equals_the_ordinary_tape():
     assert max(_rel(again[3][k], plain[3][k]) for k in plain[3]) <= 1e-5
 
 
+def _rule_paths():
+    c = observe.get_registry().get("singa_delta_rule_dispatch_total")
+    return {p: int(c.value(path=p)) if c is not None else 0
+            for p in observe.DELTA_RULE_PATHS}
+
+
+def test_the_kernels_step_matches_reference_and_rebuilds_as_the_tape():
+    """With chunks of 8 the rule runs in its Pallas kernels (interpret mode
+    here; the step's custom vjp): the fp32 step's loss, logits and every
+    gradient against the reference as the XLA form's are held, and a
+    rebuilt block (its kernels' second forward and backward) against the
+    ordinary tape as above."""
+    before = _rule_paths()
+    plain, again = (_stepped(None, rc, 8) for rc in (False, True))
+    moved = {p: n - before[p] for p, n in _rule_paths().items()}
+    assert moved["interpret"] > 0 and moved["xla"] == 0, moved
+    want, g_ref = _reference()
+    tol = TOL[None]
+    assert abs(plain[1] - want["loss"]) / want["loss"] <= tol["loss"]
+    assert _rel(plain[2], want["sample"]) <= tol["logits"]
+    for k in g_ref:
+        assert _rel(plain[3][k], g_ref[k]) <= tol["grad"], k
+    for a, b in zip(plain[1:3], again[1:3]):
+        assert np.array_equal(a, b)
+    assert max(_rel(again[3][k], plain[3][k]) for k in plain[3]) <= 1e-5
+
+
 @functools.lru_cache(maxsize=None)
-def _graph_run(recompute):
+def _graph_run(recompute, chunk=CFG["chunk"]):
     """(what two graph-mode Adam steps hand back, the parameters after the
     first, the step's lowered text)."""
     m, dev = _build("bfloat16", recompute, graph=True,
-                    optimizer=opt.Adam(lr=1e-3))
+                    optimizer=opt.Adam(lr=1e-3), chunk=chunk)
     x, y = _batch()
     tx, ty = (tensor.from_numpy(a, device=dev) for a in (x, y))
     outs = [np.asarray(o.data) for o in m(tx, ty)]
@@ -219,6 +247,20 @@ def test_scopes_name_the_linear_attention_s_parts():
         and not has("TransformerBlock_3/ln1/") \
         and not has("TransformerBlock_0/attn/")
     assert has("head/") and has("sce/")
+    # with chunks of 8 the rule is its kernels' calls (interpret mode here,
+    # named as the compiled calls are): under `delta` in every phase, the
+    # backward's under its forward's scope after the custom vjp
+    names = set(re.findall(r'"jit\(step\)/([^"]*)"', _graph_run(2, 8)[2]))
+    has = lambda *parts: any(all(p in n for p in parts) for n in names)
+    assert has("TransformerBlock_0/linear_attn/delta/singa_delta_fwd/")
+    assert has("TransformerBlock_2/linear_attn/jvp(delta)/singa_delta_fwd/")
+    assert has("recompute/TransformerBlock_1/linear_attn/jvp(delta)/"
+               "singa_delta_fwd/")
+    for blk in (0, 1, 2):
+        assert has(f"bwd/TransformerBlock_{blk}/linear_attn/",
+                   "linear_attn/jvp(delta)/singa_delta_bwd/"), blk
+    assert not any("singa_delta" in n and "/delta/" not in n
+                   and "(delta)/" not in n for n in names)
 
 
 @pytest.mark.parametrize("recompute,rebuilt", [(False, 0), (2, 2), (True, 3)])

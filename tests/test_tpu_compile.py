@@ -442,13 +442,15 @@ def test_short_conv_keeps_no_fp32_copy_of_its_streams_on_v5e(one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes <= 806e6
 
 
-def test_gated_delta_layer_compiles_at_the_cell_s_widths_on_v5e(one_chip):
+def test_gated_delta_layer_compiles_at_the_cell_s_widths_on_v5e(one_chip,
+                                                              monkeypatch):
     """The gated delta-rule layer at the Olmo Hybrid cell's widths (hidden
     3,840, 30 heads of 96 / 192, 4 taps, chunks of 64; 1,024 tokens here,
     the cell's 4,096 in ~11 s), forward and every gradient in one program:
-    no Pallas kernel (the rule is XLA's: ROADMAP A15), the pass from chunk
-    to chunk one loop each way, the triangular solve expanded by the
-    compiler into products."""
+    the rule is the Pallas kernels' two calls, forward and backward, and
+    nothing of the XLA form is left: no triangular solve, no loop over the
+    chunks."""
+    import re
     from singa_tpu.ops import gated_delta
     E, H, dk, dv, S = 3840, 30, 96, 192, 1024
     bf, f32 = jnp.bfloat16, jnp.float32
@@ -463,6 +465,40 @@ def test_gated_delta_layer_compiles_at_the_cell_s_widths_on_v5e(one_chip):
         return jax.grad(lambda *b: gated_delta.gated_delta_net(
             *b, heads=H).astype(f32).sum(), argnums=tuple(range(14)))(*a)
 
+    # the rule's dispatch asks for the backend: the program is for a TPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     text = jax.jit(grad).lower(*args).compile().as_text()
-    assert "tpu_custom_call" not in text and "triangular-solve" not in text
-    assert text.count(" while(") == 2
+    calls = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*?'
+                       r'op_name="[^"]*/(singa_delta_\w+)/', text)
+    assert sorted(calls) == ["singa_delta_bwd", "singa_delta_fwd"]
+    assert "triangular-solve" not in text and " while(" not in text
+
+
+def test_delta_rule_kernels_reserve_less_than_the_xla_form_on_v5e(
+        one_chip, monkeypatch):
+    """The chunked rule alone at the cell's shape (1 x 4,096 tokens, 30
+    heads of 96 / 192, chunks of 64, fp32), forward and every gradient: the
+    kernels compile inside their scoped VMEM, and the program reserves at
+    most 60 % of what the XLA form's does (727 against 1,705 MB: the
+    kernels keep q, k, v, the gates and the state entering each chunk, where
+    autodiff kept the solve's results, the scan's states and the scores)."""
+    from singa_tpu.ops import gated_delta
+    B, S, H, dk, dv = 1, 4096, 30, 96, 192
+    sds = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                              sharding=one_chip)
+    args = (sds(B, S, H, dk), sds(B, S, H, dk), sds(B, S, H, dv),
+            sds(B, S, H), sds(B, S, H))
+
+    def temps():
+        grad = jax.grad(lambda *b: gated_delta.chunked_delta_rule(*b).sum(),
+                        argnums=tuple(range(5)))
+        c = jax.jit(grad).lower(*args).compile()
+        return c.memory_analysis().temp_size_in_bytes, c.as_text().count(
+            'custom_call_target="tpu_custom_call"')
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    kernels, n = temps()
+    assert n == 2
+    monkeypatch.setattr(gated_delta, "delta_rule_plan", lambda *_: None)
+    xla, n = temps()
+    assert n == 0 and kernels <= 0.6 * xla, (kernels, xla)
