@@ -927,7 +927,7 @@ def record_linear_attn_plan(**kinds):
 def record_flash_tiles(site: str, visited: int, masked: int, square: int,
                        skipped: int = 0, window: int | None = None,
                        block_diffusion: int | None = None,
-                       steps: tuple = (0, 0)):
+                       steps: tuple = (0, 0), dq_rows: tuple | None = None):
     """The tile schedule one flash call site was traced with
     (ops.attention.flash_plan), in sub-tiles a (batch x head) row: how
     many the kernel computes, how many of those add a mask, and the whole
@@ -940,7 +940,9 @@ def record_flash_tiles(site: str, visited: int, masked: int, square: int,
     sub-tile that is not visited, the clean x noised quadrant's among them.
     `steps`: (grid steps a (batch x head) row, summed over the site's
     Mosaic calls; how many of them work no tile: a step costs its
-    overhead whether or not it works one). A gauge: it holds the site's
+    overhead whether or not it works one). `dq_rows` (a backward's): (the
+    query rows whose dq its sweep by k block holds, the rows before them,
+    which a `_dq` call of their own makes). A gauge: it holds the site's
     latest trace."""
     assert site in ATTN_SITES, site
     if not _enabled:
@@ -951,13 +953,18 @@ def record_flash_tiles(site: str, visited: int, masked: int, square: int,
               "of a sliding window, or outside the block-diffusion mask; "
               "window: its width, 0 for none; block_diffusion: the mask's "
               "block length, 0 for another mask; steps|idle_steps: grid "
-              "steps a row, and those of them that work no tile)")
+              "steps a row, and those of them that work no tile; "
+              "dq_rows_held|dq_rows_rest: a backward's query rows whose dq "
+              "its sweep holds, and those a _dq call makes)")
     for kind, n in (("visited", visited), ("masked", masked),
                     ("square", square), ("skipped", skipped),
                     ("window", window or 0),
                     ("block_diffusion", block_diffusion or 0),
                     ("steps", steps[0]), ("idle_steps", steps[1])):
         g.set(n, site=site, kind=kind)
+    if dq_rows is not None:
+        g.set(dq_rows[0], site=site, kind="dq_rows_held")
+        g.set(dq_rows[1], site=site, kind="dq_rows_rest")
 
 
 def record_step_outputs(batch_sharded: int, mean_reduced: int):

@@ -173,16 +173,21 @@ class FlashTiles(NamedTuple):
 class FlashPlan(NamedTuple):
     """`fwd` None: no block tiles the call, it takes the reference path
     (`ok` False). `bwd` None: the backward takes the blockwise XLA path.
-    `fused`: one backward kernel (dq accumulated in VMEM) instead of the
-    dq / dkv pair. `window`: the sliding window the schedules were made
-    for (None: none, or one that reaches every key); `skipped` (forward,
-    backward): sub-tiles of the blocks at or under the diagonal that lie
-    wholly left of the window, which no grid step computes or fetches.
+    `fused`: `held` is every row, so the backward is one kernel (dq
+    accumulated in VMEM) and no `_dq` call. `window`: the sliding window
+    the schedules were made for (None: none, or one that reaches every
+    key); `skipped` (forward, backward): sub-tiles of the blocks at or
+    under the diagonal that lie wholly left of the window, which no grid
+    step computes or fetches.
     `block_diffusion`: the block length of the block-diffusion mask the
     schedules were made for (None: another mask); `skipped` then counts
     every sub-tile of the score square that no grid step computes.
     `steps` (forward, backward): (grid steps a (batch x head) row, how many
-    of them work no tile), summed over the backward's calls."""
+    of them work no tile), summed over the backward's calls. `held`: the
+    query rows, the last of a row, whose dq the backward's sweep over k
+    blocks accumulates in VMEM beside dk and dv (`_held_rows`; under the
+    causal mask the last rows meet the most tiles); a `_dq` call makes dq
+    for the rows before them."""
     fwd: FlashTiles | None
     bwd: FlashTiles | None
     fused: bool
@@ -190,6 +195,7 @@ class FlashPlan(NamedTuple):
     skipped: tuple = (0, 0)
     block_diffusion: int | None = None
     steps: tuple = ((0, 0), (0, 0))
+    held: int = 0
 
     @property
     def ok(self):
@@ -211,17 +217,21 @@ def _tile_counts(sq, sk, uq, uk, causal):
     return visited, masked, rows * cols
 
 
-def _grid_steps(fwd, bwd, fused, count):
-    """FlashPlan.steps. `count(t)` at the blocks of schedule t: (the grid
-    steps a row of a pass that sweeps by q block: forward, dq; of one that
-    sweeps by k block: dkv, fused; the tiles a pass works)."""
-    by_q, _, worked = count(fwd)
+def _grid_steps(fwd, bwd, sq, held, count):
+    """FlashPlan.steps. `count(t, rows)` at the blocks of schedule t: (the
+    grid steps a row of a pass that sweeps the first `rows` query rows by q
+    block: forward, dq; of one that sweeps by k block: dkv, the backward's
+    sweep; the tiles worked in the first `rows` rows). The backward sweeps
+    every tile by k block and has a `_dq` call sweep the rows before the
+    `held` ones by q block."""
+    by_q, _, worked = count(fwd, sq)
     forward = by_q, by_q - worked
     if bwd is None:
         return forward, (0, 0)
-    by_q, by_k, worked = count(bwd)
-    steps = by_k + (0 if fused else by_q)
-    return forward, (steps, steps - (1 if fused else 2) * worked)
+    _, by_k, worked = count(bwd, sq)
+    by_q, _, rest = count(bwd, sq - held)
+    steps = by_k + by_q
+    return forward, (steps, steps - worked - rest)
 
 
 def _band(bq, bk, bands):
@@ -504,11 +514,13 @@ def _bd_plan(seq, d, dtype, block_q, block_k, block):
     bwd, bwd_skipped = tiles((256, 128))
     if not bwd.band and tile > _UNBANDED_BWD_CAP:
         return none
-    fused = _fused_fits(seq, d, dtype)
+    # the `_bd` calls hold every row's dq, or none
+    held = seq if _held_rows(seq, d, dtype, tile) == seq else 0
     by_q, by_k = map(len, _bd_sweeps(nh))   # the same tiles in two orders
     return FlashPlan(
-        fwd, bwd, fused, None, (fwd_skipped, bwd_skipped), block,
-        _grid_steps(fwd, bwd, fused, lambda t: (by_q, by_k, by_q)))
+        fwd, bwd, held == seq, None, (fwd_skipped, bwd_skipped), block,
+        _grid_steps(fwd, bwd, seq, held, lambda t, rows: (
+            (by_q, by_k, by_q) if rows else (0, by_k, 0))), held)
 
 
 def flash_window(window, causal, sk):
@@ -569,18 +581,23 @@ def flash_plan(sq, sk, d, causal, dtype, block_q=None, block_k=None,
         bk = _fit_block(sk, min(bk, _UNBANDED_BWD_CAP))
         bwd = _tiles(sq, sk, bq, bk, causal, bwd_bands) if bq and bk \
             else None
-    # the fused backward keeps dq for a whole (batch x head) row in VMEM:
-    # an f32 accumulator and the double-buffered output row
-    fused = _fused_fits(sq, d, dtype)
-    grid = lambda t: (sq // t.block_q) * (sk // t.block_k)
-    return FlashPlan(fwd, bwd, fused, steps=_grid_steps(
-        fwd, bwd, fused, lambda t: (grid(t), grid(t), _tile_counts(
-            sq, sk, t.block_q, t.block_k, causal)[0])))
+    held = _held_rows(sq, d, dtype, (bwd or fwd).block_q)
+    nk = lambda t: sk // t.block_k
+    return FlashPlan(fwd, bwd, held == sq, steps=_grid_steps(
+        fwd, bwd, sq, held, lambda t, rows: (
+            rows // t.block_q * nk(t), sq // t.block_q * nk(t),
+            _tile_counts(rows, sk, t.block_q, t.block_k, causal)[0])),
+        held=held)
 
 
-def _fused_fits(sq, d, dtype):
-    return sq * d * (4 + 2 * jnp.dtype(dtype).itemsize) \
-        <= _FUSED_DQ_BYTES_CAP
+def _held_rows(sq, d, dtype, block_q):
+    """The query rows, a multiple of `block_q` and at most `sq`, whose dq the
+    backward keeps in VMEM over a sweep by k block: an f32 accumulator and
+    the double-buffered output row, within _FUSED_DQ_BYTES_CAP as it counts
+    them (D lanes a row; _held_compiler_params gives the call the lanes a
+    row of D < 128 takes beyond that)."""
+    row = d * (4 + 2 * jnp.dtype(dtype).itemsize)
+    return min(sq, _FUSED_DQ_BYTES_CAP // row // block_q * block_q)
 
 
 def _window_plan(sq, sk, d, dtype, bq, bk, window):
@@ -601,14 +618,15 @@ def _window_plan(sq, sk, d, dtype, bq, bk, window):
     bwd, bwd_skipped = tiles(bwd_bands)
     if not bwd.band and max(bq, bk) > _UNBANDED_BWD_CAP:
         bwd, bwd_skipped = None, 0
-    fused = _fused_fits(sq, d, dtype)
+    held = _held_rows(sq, d, dtype, bq)
     nq, nk = sq // bq, sk // bk
     k_steps, q_steps = _window_steps(sq, sk, bq, bk, window)
-    worked = sum(any(_window_kinds(j, kb, bq, bk, nq, nk, window).values())
-                 for j in range(nq) for kb in range(nk))
-    return FlashPlan(fwd, bwd, fused, window, (fwd_skipped, bwd_skipped),
-                     steps=_grid_steps(fwd, bwd, fused, lambda t: (
-                         nq * k_steps, nk * q_steps, worked)))
+    worked = [sum(any(_window_kinds(j, kb, bq, bk, nq, nk, window).values())
+                  for kb in range(nk)) for j in range(nq)]
+    return FlashPlan(fwd, bwd, held == sq, window, (fwd_skipped, bwd_skipped),
+                     steps=_grid_steps(fwd, bwd, sq, held, lambda t, rows: (
+                         rows // bq * k_steps, nk * q_steps,
+                         sum(worked[:rows // bq]))), held=held)
 
 
 try:  # import here so CPU-only environments still import the module
@@ -1011,7 +1029,7 @@ def _flash_bwd_bd_kernel(tab_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       *refs, nq, nk, block_q, block_k, band, causal, scale,
-                      outs, window, steps, bd=None, sweep=None):
+                      outs, window, steps, bd=None, sweep=None, dq_from=0):
     """The backward of one (block_q, block_k) tile, TRANSPOSED: scores are
     held keys x queries, so p.T and ds.T — what dv = p.T @ do and
     dk = ds.T @ q consume — come out of the matmuls as they are, the
@@ -1025,11 +1043,18 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
              scratch over the whole sweep of one bh row (TPU grid iteration
              is sequential, so the scratch survives it) and is written once,
              when the row's last tile is in; callers gate this on the
-             scratch fitting VMEM (FlashPlan.fused).
+             scratch fitting VMEM (FlashPlan.held). With `dq_from` > 0 the
+             scratch holds the q blocks from dq_from on; the tiles of the
+             blocks before compute their dq product too and a select drops
+             it (with a `pl.when` around the product and its add the LFM2
+             cell's sweep read 38.1 ms a call, with the select 31.6, on one
+             TPU v5e); the first of `refs` is then the array of the
+             "dq" call that made those blocks, which the output dq aliases
+             (its rows are left as that call wrote them).
       "dkv"  the same grid, dk/dv only.
       "dq"   grid (bh, q_blocks, k_blocks), dq only, one q block of scratch.
-    Together "dq" + "dkv" recompute the two largest matmuls and the exp,
-    and stream every tile twice: the long-context path.
+    "dq" + "dkv" recompute the two largest matmuls and the exp, and
+    stream every tile twice: the backward of rows too long for any held.
 
     The grid's last dimension has `steps` entries: every block of the
     streamed side, or under a `window` as many as it reaches, counted from
@@ -1039,6 +1064,8 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     works the "nc" tile's key bands and then the "nn" tile's, against the
     one q block."""
     refs = list(refs)
+    if dq_from:
+        refs.pop(0)
     want_dq, want_dkv = outs != "dkv", outs != "dq"
     dq_ref = refs.pop(0) if want_dq else None
     dk_ref, dv_ref = (refs.pop(0), refs.pop(0)) if want_dkv else (None, None)
@@ -1076,7 +1103,8 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                                 jnp.maximum, jnp.minimum)[0]
         dq_first = (kb == 0) & (step == 0)
         dq_last = (kb == nk - 1) & (step == steps - 1)
-        dq_base = j * block_q if nq > 1 else 0
+        dq_base = (jnp.maximum(j - dq_from, 0) if dq_from else j) \
+            * block_q if nq > 1 else 0
     if bd is None:
         first, last = lambda: step == 0, lambda: step == steps - 1
     rows_per = band or block_k
@@ -1171,9 +1199,12 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 part = jnp.dot(ds, q, preferred_element_type=f32)
                 dk = part if dk is None else dk + part
             if want_dq:
-                dq_acc[pl.ds(dq_base + a, b - a), :] += lax.dot_general(
-                    ds, k_blk, (((0,), (0,)), ((), ())),
-                    preferred_element_type=f32)
+                part = lax.dot_general(ds, k_blk, (((0,), (0,)), ((), ())),
+                                       preferred_element_type=f32)
+                if dq_from:
+                    # the product of a q block that is not held is dropped
+                    part = jnp.where(j >= dq_from, part, 0.0)
+                dq_acc[pl.ds(dq_base + a, b - a), :] += part
             yield
         if want_dkv:
             dk_acc[acc_at + band_rows] += dk
@@ -1197,11 +1228,28 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-# VMEM the fused backward may spend on dq for one (batch x head) row: the
-# (Sq, D) f32 accumulator and the double-buffered output row beside the
-# streamed tiles (~16 MB scoped in all) — 6 MB covers S=8192 at D=64 in
-# bf16; longer rows take the split kernels.
+# VMEM the backward may spend on dq for the rows it holds over its sweep by
+# k block (FlashPlan.held): the f32 accumulator and the double-buffered
+# output rows beside the streamed tiles, counted at D lanes a row — 6 MB
+# covers S=8192 at D=64 in bf16; of longer rows the last that fit are held,
+# a `_dq` call makes the rest. Blocks are sized so that a call holding this
+# much fits a Mosaic call's default scoped VMEM (_SCOPED_VMEM_BYTES).
 _FUSED_DQ_BYTES_CAP = 6 * 1024 * 1024
+_SCOPED_VMEM_BYTES = 16 * 1024 * 1024
+
+
+def _held_compiler_params(held, d, dtype):
+    """What the sweep that holds `held` rows' dq is compiled under. In VMEM
+    a row takes at least 128 lanes, so where D < 128 the rows held take
+    more than _FUSED_DQ_BYTES_CAP counts: the call's scoped VMEM grows by
+    what they take beyond it (a D = 64 row twice what the cap counts: at
+    32 heads x 16,384 x 64, 12,288 rows held, 22 MiB where a described v5e
+    reserves 18.3 MB). None where they fit: the default."""
+    held_bytes = held * max(d, 128) * (4 + 2 * jnp.dtype(dtype).itemsize)
+    if held_bytes <= _FUSED_DQ_BYTES_CAP:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=_SCOPED_VMEM_BYTES
+                                + held_bytes - _FUSED_DQ_BYTES_CAP)
 
 
 def _bd_compiler_params():
@@ -1226,9 +1274,12 @@ def _flash_bwd_stats(o, lse, do, block_q):
     return lse.reshape(stat), delta.reshape(stat)
 
 
-def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, tiles, fused,
+def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, tiles, held,
                       interpret, stats=None, window=None, bd=None):
-    """Pallas flash backward: the fused kernel, or the dq + dkv pair."""
+    """Pallas flash backward: one sweep by k block that makes dk, dv and
+    the dq of the last `held` query rows (FlashPlan.held: every row, the
+    fused backward), and a `_dq` call for the rows before them, whose
+    array the sweep's dq aliases; none held: the `_dq` + `_dkv` pair."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
     bh = b * h
@@ -1243,8 +1294,9 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, tiles, fused,
         o, lse, do, block_q)
     if bd is not None:
         return tuple(g.reshape(b, h, sq, d) for g in _flash_bwd_bd_calls(
-            qf, kf, vf, dof, lsef, delta, scale, tiles, fused, interpret,
-            bd))
+            qf, kf, vf, dof, lsef, delta, scale, tiles, held == sq,
+            interpret, bd))
+    rest = (sq - held) // block_q       # the q blocks the "dq" call makes
     shape_q = jax.ShapeDtypeStruct((bh, sq, d), q.dtype)
     shape_k = jax.ShapeDtypeStruct((bh, sk, d), k.dtype)
     shape_v = jax.ShapeDtypeStruct((bh, sk, d), v.dtype)
@@ -1254,7 +1306,8 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, tiles, fused,
         k_steps, q_steps = _window_steps(sq, sk, block_q, block_k, window)
         suffix = WINDOW_SUFFIX
 
-    def call(outs, name, grid, qmap, kvmap, out_specs, out_shape, scratch):
+    def call(outs, name, grid, qmap, kvmap, out_specs, out_shape, scratch,
+             *dq_rest):
         q_spec = pl.BlockSpec((1, block_q, d), qmap)
         kv_spec = pl.BlockSpec((1, block_k, d), kvmap)
         stat_spec = pl.BlockSpec(
@@ -1263,35 +1316,48 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, tiles, fused,
             functools.partial(
                 _flash_bwd_kernel, nq=nq, nk=nk, block_q=block_q,
                 block_k=block_k, band=band, causal=causal, scale=scale,
-                outs=outs, window=window, steps=grid[2]),
+                outs=outs, window=window, steps=grid[2],
+                dq_from=rest if dq_rest else 0),
             name=name + suffix, grid=grid,
             in_specs=[q_spec, kv_spec, kv_spec, q_spec, stat_spec,
-                      stat_spec],
+                      stat_spec] + [pl.BlockSpec(memory_space=pl.ANY)]
+            * len(dq_rest),
             out_specs=out_specs, out_shape=out_shape,
             scratch_shapes=scratch, interpret=interpret,
-        )(qf, kf, vf, dof, lsef, delta)
+            input_output_aliases={6: 0} if dq_rest else {},
+            compiler_params=_held_compiler_params(held, d, q.dtype)
+            if outs == "all" else None,
+        )(qf, kf, vf, dof, lsef, delta, *dq_rest)
 
     # grid (bh, k blocks, q blocks): q-side tiles stream, clamped to the
     # first block that sees this k block
     qmap = _causal_q_map(causal, block_q, block_k, nq, window)
     kvmap_kq = lambda i, kb, j: (i, kb, 0)
     dkv_specs = [pl.BlockSpec((1, block_k, d), kvmap_kq)] * 2
-    if fused:
-        dq, dk, dv = call(
-            "all", "singa_flash_bwd", (bh, nk, q_steps), qmap, kvmap_kq,
-            [pl.BlockSpec((1, sq, d), lambda i, kb, j: (i, 0, 0))]
-            + dkv_specs, [shape_q, shape_k, shape_v],
-            [pltpu.VMEM((sq, d), jnp.float32), acc_k, acc_k])
-    else:
+    dq_rest = ()
+    if rest:
         qmap_qk = lambda i, j, kb: (i, j, 0)
-        dq = call(
-            "dq", "singa_flash_bwd_dq", (bh, nq, k_steps), qmap_qk,
+        dq_rest = call(
+            "dq", "singa_flash_bwd_dq", (bh, rest, k_steps), qmap_qk,
             _causal_kv_map(causal, block_q, block_k, nk, window),
             pl.BlockSpec((1, block_q, d), qmap_qk), shape_q,
-            [pltpu.VMEM((block_q, d), jnp.float32)])
+            [pltpu.VMEM((block_q, d), jnp.float32)]),
+    if not held:
         dk, dv = call(
             "dkv", "singa_flash_bwd_dkv", (bh, nk, q_steps), qmap, kvmap_kq,
             dkv_specs, [shape_k, shape_v], [acc_k, acc_k])
+        dq = dq_rest[0]
+    else:
+        # the held rows' dq, written once a (batch x head) row as the last
+        # tile is in: the whole row, or the rows from the first held one on
+        dq_spec = pl.BlockSpec(
+            (pl.Element(1), pl.Element(held), pl.Element(d)),
+            lambda i, kb, j: (i, sq - held, 0)) if rest else \
+            pl.BlockSpec((1, sq, d), lambda i, kb, j: (i, 0, 0))
+        dq, dk, dv = call(
+            "all", "singa_flash_bwd", (bh, nk, q_steps), qmap, kvmap_kq,
+            [dq_spec] + dkv_specs, [shape_q, shape_k, shape_v],
+            [pltpu.VMEM((held, d), jnp.float32), acc_k, acc_k], *dq_rest)
     return (dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),
             dv.reshape(b, h, sk, d))
 
@@ -1466,9 +1532,10 @@ def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, window,
     if _HAS_PALLAS and plan.bwd:
         record_attention_dispatch("flash_bwd", _kernel_path(interp))
         record_flash_tiles("flash_bwd", *plan.bwd[3:], plan.skipped[1],
-                           plan.window, plan.block_diffusion, plan.steps[1])
+                           plan.window, plan.block_diffusion, plan.steps[1],
+                           (plan.held, q.shape[2] - plan.held))
         return _flash_bwd_pallas(q, k, v, out, lse, g, causal, s, plan.bwd,
-                                 plan.fused, interp, window=plan.window,
+                                 plan.held, interp, window=plan.window,
                                  bd=plan.block_diffusion)
     record_attention_dispatch("flash_bwd", "reference")
     return _flash_bwd_blockwise(q, k, v, out, lse, g, causal, s,
@@ -1569,7 +1636,7 @@ def _ring_flash_vjp_bwd(axis_name, causal, scale, plan, interp, res, g):
             def f(_):
                 dq, dk, dv = _flash_bwd_pallas(
                     q, k_cur, v_cur, out, lse, g, causal_flag, scale,
-                    plan.bwd, plan.fused, interp, stats=stats)
+                    plan.bwd, plan.held, interp, stats=stats)
                 return dq.astype(f32), dk.astype(f32), dv.astype(f32)
             return f
 

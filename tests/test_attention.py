@@ -93,6 +93,43 @@ def test_ring_attention_grads_match():
                                    rtol=2e-3, atol=2e-3)
 
 
+@pytest.mark.parametrize("causal", [True, False])
+def test_ring_attention_grads_with_held_rows(causal):
+    """Ring attention's hops run the backward whose sweep holds the last
+    rows' dq, a `_dq` call making the rest (half of a shard's 1,536 rows
+    under a small _FUSED_DQ_BYTES_CAP): the hop on the diagonal under the
+    causal mask, the others with none. The gradients of full attention."""
+    from jax.sharding import PartitionSpec as P
+    from singa_tpu.parallel import make_mesh
+    mesh = make_mesh({"sp": 2})
+    rng = np.random.default_rng(5)
+    q, k, v = _qkv(rng, b=1, h=1, s=2 * 1536, d=16)
+    spec = P(None, None, "sp", None)
+
+    @functools.partial(jax.shard_map, mesh=mesh,
+                       in_specs=(spec, spec, spec), out_specs=P(),
+                       check_vma=False)
+    def ring_loss(q, k, v):
+        o = att.ring_attention(q, k, v, "sp", causal=causal)
+        return jax.lax.psum(jnp.sum(o ** 2), "sp")
+
+    def full_loss(q, k, v):
+        return jnp.sum(att.attention_reference(q, k, v, causal) ** 2)
+
+    cap = att._FUSED_DQ_BYTES_CAP
+    try:
+        att._FUSED_DQ_BYTES_CAP = 768 * 16 * (4 + 2 * 4)
+        assert att.flash_plan(1536, 1536, 16, causal, jnp.float32).held \
+            == 768
+        g_ring = jax.grad(ring_loss, argnums=(0, 1, 2))(q, k, v)
+    finally:
+        att._FUSED_DQ_BYTES_CAP = cap
+    g_full = jax.grad(full_loss, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g_ring, g_full):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-3, atol=2e-3)
+
+
 def test_gpt_trains(dev):
     from singa_tpu import models, opt, tensor
     rng = np.random.RandomState(0)
@@ -469,11 +506,114 @@ def test_flash_plan_refuses_blocks_that_do_not_tile(args):
 def test_flash_plan_fused_backward_budget():
     """dq for a whole row in VMEM (f32 accumulator + double-buffered
     output) within 6 MB: S = 8192 at D = 64 fuses in bf16 (4 MB) and fp32
-    (6 MB); D = 128, or S = 16384, takes the dq + dkv pair."""
+    (6 MB); D = 128, or S = 16384, holds the last rows that fit and has a
+    `_dq` call make the rest: 12,288 of 16,384 at D = 64, 6,144 of 8,192
+    at D = 128, under a sliding window as without."""
     assert att.flash_plan(8192, 8192, 64, True, jnp.bfloat16).fused
     assert att.flash_plan(8192, 8192, 64, True, jnp.float32).fused
+    assert att.flash_plan(8192, 8192, 64, True, jnp.bfloat16).held == 8192
     assert not att.flash_plan(8192, 8192, 128, True, jnp.bfloat16).fused
     assert not att.flash_plan(16384, 16384, 64, True, jnp.bfloat16).fused
+    assert att.flash_plan(16384, 16384, 64, True, jnp.bfloat16).held \
+        == 12288
+    assert att.flash_plan(8192, 8192, 128, True, jnp.bfloat16).held == 6144
+    assert att.flash_plan(8192, 8192, 128, True, jnp.bfloat16,
+                          window=1024).held == 6144
+
+
+def _vjp(fn, q, k, v, w):
+    out, vjp = jax.vjp(fn, q, k, v)
+    return vjp(w.astype(out.dtype))
+
+
+# the rows held: all but one q block, half, one; under the causal mask, a
+# sliding window and no mask (ring attention's hops off the diagonal);
+# blocks of 128 over 512 rows: four q blocks a row
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("held_blocks", [3, 2, 1],
+                         ids=["all_but_one", "half", "one"])
+@pytest.mark.parametrize("causal,window", [(True, None), (True, 128),
+                                           (False, None)],
+                         ids=["causal", "window", "no_mask"])
+def test_flash_bwd_held_rows_match_the_pair_and_reference(causal, window,
+                                                          held_blocks, d,
+                                                          dtype):
+    """The backward whose sweep by k block holds the last rows' dq beside
+    dk and dv, a `_dq` call making the rows before them: the gradients of
+    the `_dq` + `_dkv` pair (no row held) and of attention_reference."""
+    dtype = jnp.dtype(dtype)
+    rng = np.random.default_rng(11)
+    q, k, v, w = (jnp.asarray(rng.standard_normal((1, 2, 512, d)), dtype)
+                  for _ in range(4))
+    flash = lambda *x: att.flash_attention(*x, causal, None, 128, 128, True,
+                                           window)
+    cap = att._FUSED_DQ_BYTES_CAP
+    try:
+        att._FUSED_DQ_BYTES_CAP = held_blocks * 128 * d \
+            * (4 + 2 * dtype.itemsize)
+        plan = att.flash_plan(512, 512, d, causal, dtype, 128, 128, window)
+        held = _vjp(flash, q, k, v, w)
+        att._FUSED_DQ_BYTES_CAP = 0
+        pair = _vjp(flash, q, k, v, w)
+    finally:
+        att._FUSED_DQ_BYTES_CAP = cap
+    assert plan.held == held_blocks * 128 and not plan.fused
+    want = _vjp(lambda *x: att.attention_reference(*x, causal, None, window),
+                *(a.astype(jnp.float32) for a in (q, k, v, w)))
+    # bf16: p and ds are rounded to 8 bits of mantissa for their matmuls,
+    # as in test_flash_default_plan_matches_reference
+    tol = 2e-4 if dtype == jnp.float32 else 6e-2
+    for name, h, p, r in zip(("dq", "dk", "dv"), held, pair, want):
+        h, p = h.astype(jnp.float32), p.astype(jnp.float32)
+        # the same products summed in the same order as the pair's
+        assert float(jnp.max(jnp.abs(h - p))) <= 1e-6, name
+        assert float(jnp.max(jnp.abs(h - r))) < tol, name
+
+
+@pytest.mark.parametrize("shape,rows", [
+    ((1, 2, 1024, 64), (1024, 0)),
+    ((1, 2, 16384, 64), (12288, 4096)),
+    ((1, 2, 8192, 128), (6144, 2048)),
+], ids=["fused", "lfm2", "mellum"])
+def test_flash_tiles_gauge_reads_the_held_rows(shape, rows):
+    """A traced backward leaves singa_flash_tiles{site="flash_bwd",
+    kind=dq_rows_held|dq_rows_rest}: the rows whose dq its sweep holds
+    and those a `_dq` call makes."""
+    from singa_tpu import observe
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    jax.eval_shape(jax.grad(lambda q, k, v: att.flash_attention(
+        q, k, v, True).astype(jnp.float32).sum(), (0, 1, 2)), q, q, q)
+    g = observe.get_registry().get("singa_flash_tiles")
+    assert (int(g.value(site="flash_bwd", kind="dq_rows_held")),
+            int(g.value(site="flash_bwd", kind="dq_rows_rest"))) == rows
+
+
+@pytest.mark.parametrize("seq,d,limit", [
+    (16384, 64, 22 << 20),      # the LFM2 cell's: 12,288 rows held
+    (12288, 64, 22 << 20),      # fused: every row held
+    (8192, 128, None),          # Mellum's: 6,144 rows held at D = 128
+    (1024, 64, None),           # the GPT cells' fused row
+], ids=["lfm2_s16k", "s12k_fused", "mellum_s8k", "gpt_s1k"])
+def test_held_rows_sweep_reserves_the_vmem_its_rows_take(seq, d, limit):
+    """A row of D < 128 takes 128 lanes in VMEM, more than the held rows'
+    budget counts: the sweep that holds them asks for the default scoped
+    VMEM plus what they take beyond the budget (12 MiB at 128 lanes for
+    12,288 rows: 16 + 6 MiB), and a sweep whose rows fit asks for nothing
+    (the default). No other flash call asks."""
+    import re
+    q = jax.ShapeDtypeStruct((1, 2, seq, d), jnp.bfloat16)
+    text = jax.jit(jax.grad(lambda q: att.flash_attention(
+        q, q, q, True, None, None, None, False).astype(jnp.float32).sum())
+    ).trace(q).lower(lowering_platforms=("tpu",)).as_text()
+    asked = {}
+    for op in re.findall(r"@tpu_custom_call\(.*", text):
+        size = re.search(r"scoped_memory_configs.*?size\\22: (\d+)", op)
+        asked[re.search(r'kernel_name = "(\w+)"', op).group(1)] = \
+            int(size.group(1)) if size else None
+    assert asked.pop("singa_flash_bwd") == limit
+    assert set(asked) <= {"singa_flash_fwd", "singa_flash_bwd_dq"}
+    assert all(v is None for v in asked.values()), asked
 
 
 @pytest.mark.parametrize("causal", [True, False])

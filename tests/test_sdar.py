@@ -482,8 +482,10 @@ def test_flash_plan_block_diffusion_table(args, fwd, bwd, skipped, steps):
     # the cell's call: 20 steps a pass, the split backward's two passes 40
     ("block_diffusion", (20, 0), (40, 0)),
     # the causal call of the same shape, for scale: 28 of a pass's 64 steps
-    # lie above the diagonal
-    ("causal", (64, 28), (128, 56)),
+    # lie above the diagonal; the backward's sweep holds dq for the last
+    # 6,144 rows, its `_dq` call takes the first two q blocks (16 steps, 3
+    # tiles worked)
+    ("causal", (64, 28), (80, 41)),
 ])
 def test_flash_tiles_gauge_counts_grid_steps_and_the_idle_ones(mask, fwd, bwd):
     """singa_flash_tiles{kind=steps|idle_steps} after one traced call at

@@ -88,7 +88,11 @@ def test_flash_attention_compiles_for_v5e(one_chip, shape, direction):
 # the other plans flash_plan hands out: the engine's prefill buckets (one
 # block, 896 = 7 bands of 128), an encoder (no mask), K streamed over the
 # grid with dq for a whole row in VMEM (fused, at its 6 MB budget), and
-# the dq + dkv pair of long rows. VMEM overflow shows here, not on the chip.
+# long rows, whose backward's sweep holds the last rows' dq beside a `_dq`
+# call for the rest (the LFM2 cell's layer: 32 heads, 12,288 of 16,384
+# rows held at D = 64). A row of D = 64 takes 128 lanes in VMEM, twice what
+# the held rows' budget counts: the fused call at 32 heads x 12,288 needs
+# 16.8 MB. VMEM overflow shows here, not on the chip.
 @pytest.mark.parametrize("shape,causal,calls", [
     ((1, 20, 128, 64), True, (1, 2)),
     ((1, 20, 384, 64), True, (1, 2)),
@@ -96,7 +100,10 @@ def test_flash_attention_compiles_for_v5e(one_chip, shape, direction):
     ((4, 16, 1024, 64), False, (1, 2)),
     ((1, 2, 12288, 64), True, (1, 2)),
     ((1, 2, 16384, 128), True, (1, 3)),
-], ids=["b128", "b384", "b896", "encoder", "s12k_fused", "s16k_split"])
+    ((1, 32, 12288, 64), True, (1, 2)),
+    ((1, 32, 16384, 64), True, (1, 3)),
+], ids=["b128", "b384", "b896", "encoder", "s12k_fused", "s16k_split",
+        "s12k_fused_h32", "lfm2_s16k"])
 def test_flash_plans_compile_for_v5e(one_chip, shape, causal, calls):
     q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
     fwd, grad = _flash_fwd_and_grad(causal)
@@ -105,16 +112,19 @@ def test_flash_plans_compile_for_v5e(one_chip, shape, causal, calls):
 
 
 # a sliding window: the 8k cell's sliding layers (two tiles a q block, the
-# diagonal's and the window's edge, both in bands; the split backward), a
+# diagonal's and the window's edge, both in bands; the backward's sweep
+# holding the last 6,144 rows' dq), the same at the cell's 32 heads, a
 # short row whose backward is fused, a window that is no multiple of the
 # block (tiles under one mask built from the step's offsets), and one
 # narrower than the smallest block
 @pytest.mark.parametrize("shape,window,calls", [
     ((1, 4, 8192, 128), 1024, (1, 3)),
+    ((1, 32, 8192, 128), 1024, (1, 3)),
     ((1, 4, 2048, 64), 512, (1, 2)),
     ((1, 4, 2048, 128), 640, (1, 2)),
     ((1, 4, 1024, 64), 96, (1, 2)),
-], ids=["s8k_w1024", "s2k_w512", "s2k_w640", "s1k_w96"])
+], ids=["s8k_w1024", "mellum_s8k_w1024", "s2k_w512", "s2k_w640",
+        "s1k_w96"])
 def test_windowed_flash_compiles_for_v5e(one_chip, shape, window, calls):
     q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
 
@@ -131,6 +141,35 @@ def test_windowed_flash_compiles_for_v5e(one_chip, shape, window, calls):
         'custom_call_target="tpu_custom_call"')) == calls
     # the kernels' names say they work under a window
     assert "singa_flash_fwd" + A.WINDOW_SUFFIX in text
+
+
+# three call sites of a backward past the fused budget: every Mosaic call
+# of the compiled step keeps the scope of the site it stands for, which the
+# benchmark's scope table reads
+def test_split_backward_keeps_each_site_s_scope_on_v5e(one_chip):
+    import re
+    q = jax.ShapeDtypeStruct((1, 2, 8192, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def grad(q, k, v):
+        def loss(q):
+            parts = []
+            for i in range(3):
+                with jax.named_scope(f"layer{i}"):
+                    # inputs of its own: equal calls would be one to XLA
+                    parts.append(A.flash_attention(q * (i + 1), k, v, True,
+                                                   None, None, None, False))
+            return sum(p.astype(jnp.float32).sum() for p in parts)
+        return jax.grad(loss)(q)
+
+    text = jax.jit(grad).lower(q, q, q).compile().as_text()
+    sites = sorted(
+        (re.search(r"layer(\d)", op).group(1), name)
+        for op, name in re.findall(
+            r'custom_call_target="tpu_custom_call".*?op_name="([^"]*/'
+            r'(singa_flash_bwd\w*)/[^"]*)"', text))
+    assert sites == [(str(i), n) for i in range(3)
+                     for n in ("singa_flash_bwd", "singa_flash_bwd_dq")]
 
 
 # the block-diffusion mask: the SDAR cell's doubled sequence (2 x 4096 in

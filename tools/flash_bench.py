@@ -86,7 +86,7 @@ def block_diffusion_table(A, shape, dtype, block, heads=2, calls=30):
         rows.append((mask, "forward", ms, *steps[0], plan.fwd.visited,
                      err([o], [ref])))
         ms, g = kernel_ms(lambda *x: A._flash_bwd_pallas(
-            *x, causal, scale, plan.bwd, False, False, None, None, bd),
+            *x, causal, scale, plan.bwd, 0, False, None, None, bd),
             (q, k, v, o, lse, do), calls)
         rows.append((mask, "dq + dkv", ms, *steps[1], plan.bwd.visited,
                      err(g, grads)))
@@ -148,7 +148,7 @@ def main():
               f"max |diff| {err([o], [ref]):.4f}{mark}")
         try:
             ms, g = kernel_ms(lambda *x: A._flash_bwd_pallas(
-                *x, causal, scale, t, True, False), (q, k, v, o, lse, do))
+                *x, causal, scale, t, s, False), (q, k, v, o, lse, do))
         except Exception as e:      # a whole 1024-block overflows VMEM
             print(f"backward band {band:4d}: does not compile "
                   f"({str(e).splitlines()[0][:80]})")
