@@ -13,6 +13,7 @@ XLA executable, so there is no per-layer kernel dispatch cost to hide.
 
 from __future__ import annotations
 
+import dataclasses
 import math
 from collections import OrderedDict
 
@@ -936,111 +937,190 @@ class _GatedDeltaOp(autograd.Operator):
         return gated_delta_net(*xs, **self.kw)
 
 
+class Mixer:
+    """A block's token mixer, described: `build(tp_axis, norm_eps)` makes
+    its layer (under the block's `tp_axis` and its norms' eps), which the
+    block holds under the attribute `attr`."""
+
+    attr = None
+
+    def build(self, tp_axis, norm_eps):
+        raise NotImplementedError
+
+
+@dataclasses.dataclass(frozen=True)
+class Attention(Mixer):
+    """Softmax attention (`MultiHeadAttention`, `attn`): its arguments, but
+    for the block's `tp_axis` and its norms' eps, which the q and k norms
+    take. A window beside `block_diffusion`, or any other pair that is not
+    ONE mask, is refused when the block is made."""
+
+    num_heads: int
+    causal: bool = True
+    num_kv_heads: int | None = None
+    head_dim: int | None = None
+    window: int | None = None
+    block_diffusion: int | None = None
+    rope: bool = False
+    rope_theta: float = 10000.0
+    rope_scaling: dict | None = None
+    qk_norm: str | None = None
+    bias: bool = False
+    seq_axis: str | None = None
+
+    attr = "attn"
+
+    def build(self, tp_axis, norm_eps):
+        return MultiHeadAttention(tp_axis=tp_axis, qk_norm_eps=norm_eps,
+                                  **vars(self))
+
+
+@dataclasses.dataclass(frozen=True)
+class Convolution(Mixer):
+    """The gated short convolution of `taps` taps (`ShortConv`, `conv`)."""
+
+    taps: int = 3
+
+    attr = "conv"
+
+    def build(self, tp_axis, norm_eps):
+        return ShortConv(self.taps)
+
+
+@dataclasses.dataclass(frozen=True)
+class DeltaRule(Mixer):
+    """Gated delta-rule linear attention (`GatedDeltaNet`, `linear_attn`):
+    its arguments."""
+
+    heads: int
+    key_dim: int
+    value_dim: int
+    conv_taps: int = 4
+    neg_eigval: bool = True
+    chunk: int = 64
+    norm_eps: float = 1e-6
+
+    attr = "linear_attn"
+
+    def build(self, tp_axis, norm_eps):
+        return GatedDeltaNet(**vars(self))
+
+
+@dataclasses.dataclass(frozen=True)
+class FeedForward:
+    """A block's dense feed-forward: "gelu", gelu(x W1) W2 (`fc1`, `fc2`),
+    or "swiglu", (silu(x W_gate) * (x W_up)) W_down (`fc_gate`, `fc1`,
+    `fc2`); `dim` wide (None: `ratio` x the block's width)."""
+
+    kind: str = "gelu"
+    dim: int | None = None
+    ratio: int = 4
+    bias: bool = True
+
+    def __post_init__(self):
+        assert self.kind in ("gelu", "swiglu"), self.kind
+
+
+@dataclasses.dataclass(frozen=True)
+class Experts:
+    """A block's mixture-of-experts feed-forward (`moe`): `num` experts of
+    width `dim` (None: `ratio` x the block's), top-`k`. `dropless`: a
+    `DroplessMoE`, of which this device holds `held` experts from
+    `offset` on (None: all), routed by `router` (its `score`, `bias`,
+    `scale`, `gate_eps`); else a capacity-bound `MoE` (`capacity_factor`,
+    expert-parallel over `ep_axis`), whose router losses surface on
+    `moe.{aux_loss,z_loss}` after forward."""
+
+    num: int
+    k: int = 1
+    dim: int | None = None
+    ratio: int = 4
+    dropless: bool = False
+    held: int | None = None
+    offset: int = 0
+    router: dict | None = None
+    capacity_factor: float = 1.25
+    ep_axis: str | None = None
+
+    def build(self):
+        if self.dropless:
+            return DroplessMoE(self.num, k=self.k, held=self.held,
+                               offset=self.offset, **(self.router or {}))
+        return MoE(self.num, capacity_factor=self.capacity_factor,
+                   ep_axis=self.ep_axis, k=self.k)
+
+
+@dataclasses.dataclass(frozen=True)
+class Norms:
+    """A block's norms: `kind` "layer" or "rms" with `eps` (None: the
+    class's own); `pre`: before each branch (`ln1`, `ln2`); `post`: on
+    each branch's output before it joins the residual (`ln1_post`,
+    `ln2_post`)."""
+
+    kind: str = "layer"
+    eps: float | None = None
+    pre: bool = True
+    post: bool = False
+
+    def __post_init__(self):
+        assert self.kind in ("layer", "rms"), self.kind
+
+    def make(self):
+        cls = LayerNorm if self.kind == "layer" else RMSNorm
+        return cls() if self.eps is None else cls(self.eps)
+
+
 class TransformerBlock(Layer):
-    """Pre-norm block: x + MHA(N(x)); x + MLP(N(x)). `tp_axis` makes the
-    attention head-parallel and the MLP column→row parallel (two psums per
-    block total, the Megatron layout). `moe_experts > 0` replaces the dense
-    MLP with a top-`moe_k` MoE FFN (expert-parallel over `ep_axis`); the
-    router losses surface on `self.moe.{aux_loss,z_loss}` after forward.
+    """x + Mix(N1(x)); x + FFN(N2(x)), from three descriptions: the mixer
+    (`Attention`, `Convolution`, `DeltaRule`), the feed-forward
+    (`FeedForward`, `Experts`) and the norms (`Norms`; with `pre=False,
+    post=True` OLMo 2's block, x + N(Mix(x)); x + N(FFN(x))). The defaults
+    are the GPT-2 block's: LayerNorms before each branch, a biased GELU
+    feed-forward 4x wide. `tp_axis` makes the attention head-parallel and
+    the dense feed-forward column -> row parallel (two psums a block, the
+    Megatron layout)."""
 
-    The defaults are the GPT-2 block (LayerNorm, biased GELU MLP of
-    `mlp_ratio` x the width). `norm="rms"` takes RMS norms with `norm_eps`;
-    `ffn="swiglu"` the gated feed-forward (silu(x W_gate) * (x W_up)) W_down
-    (`fc_gate`, `fc1`, `fc2`); `ffn_dim` sets the feed-forward's width where
-    it is no multiple of the block's; `ffn_bias=False` drops its biases;
-    `post_norm=True` adds a norm on each branch's output before it joins
-    the residual (`ln1_post`, `ln2_post`: "sandwich" norms). `head_dim`,
-    `window`, `rope_scaling` and `block_diffusion` go to the attention as
-    they are, and so does `qk_norm` ("head", "projection": RMS norms on q
-    and k over each head or over the whole projection) with the block's
-    `norm_eps`.
-    `moe_dropless=True` takes `DroplessMoE` for the expert layer (SiLU-gated
-    experts of width `ffn_dim`, no capacity, nothing dropped), of which this
-    device holds `moe_held` experts from `moe_offset` on (None: all);
-    `moe_router`: that layer's routing arguments (`score`, `bias`, `scale`,
-    `gate_eps`). `mixer="conv"` puts a `ShortConv` of `conv_taps` taps
-    (`conv`) in the attention's place: such a block has no `attn`;
-    `mixer="linear_attn"` a `GatedDeltaNet` (`linear_attn`) of the
-    arguments in `linear` (`heads`, `key_dim`, `value_dim`, ...).
-    `pre_norm=False` drops the norms before the branches: with
-    `post_norm=True` that is OLMo 2's block, x + N(Op(x)); x + N(MLP(x))."""
-
-    def __init__(self, num_heads, mlp_ratio=4, causal=True, seq_axis=None,
-                 tp_axis=None, attn_bias=False, moe_experts=0, moe_k=1,
-                 ep_axis=None, moe_capacity_factor=1.25, num_kv_heads=None,
-                 rope=False, rope_theta=10000.0, norm="layer", norm_eps=None,
-                 ffn="gelu", ffn_dim=None, ffn_bias=True, post_norm=False,
-                 head_dim=None, window=None, rope_scaling=None,
-                 moe_dropless=False, moe_held=None, moe_offset=0,
-                 block_diffusion=None, qk_norm=None, mixer="attention",
-                 conv_taps=3, moe_router=None, linear=None, pre_norm=True,
-                 name=None):
+    def __init__(self, mixer, ffn=FeedForward(), norms=Norms(),
+                 tp_axis=None, name=None):
         super().__init__(name)
-        assert norm in ("layer", "rms") and ffn in ("gelu", "swiglu") \
-            and mixer in ("attention", "conv", "linear_attn"), \
-            (norm, ffn, mixer)
-        norm_cls = LayerNorm if norm == "layer" else RMSNorm
-
-        def make_norm():    # each class has its own default eps
-            return norm_cls() if norm_eps is None else norm_cls(norm_eps)
-        self.pre_norm = pre_norm
-        if pre_norm:
-            self.ln1 = make_norm()
-        self.mixer = mixer
-        if mixer == "conv":
-            self.conv = ShortConv(conv_taps)
-        elif mixer == "linear_attn":
-            self.linear_attn = GatedDeltaNet(**linear)
-        else:
-            self.attn = MultiHeadAttention(
-                num_heads, causal=causal, seq_axis=seq_axis, tp_axis=tp_axis,
-                bias=attn_bias, num_kv_heads=num_kv_heads, rope=rope,
-                rope_theta=rope_theta, head_dim=head_dim, window=window,
-                rope_scaling=rope_scaling, block_diffusion=block_diffusion,
-                qk_norm=qk_norm, qk_norm_eps=norm_eps)
-        if pre_norm:
-            self.ln2 = make_norm()
-        self.post_norm = post_norm
-        if post_norm:
-            self.ln1_post, self.ln2_post = make_norm(), make_norm()
-        self.mlp_ratio = mlp_ratio
-        self.ffn, self.ffn_dim, self.ffn_bias = ffn, ffn_dim, ffn_bias
+        assert isinstance(mixer, Mixer), mixer
+        self.mixer, self.ffn, self.norms = mixer, ffn, norms
         self.tp_axis = tp_axis
-        self.moe_experts = moe_experts
-        if moe_experts and moe_dropless:
-            self.moe = DroplessMoE(moe_experts, k=moe_k, held=moe_held,
-                                   offset=moe_offset, **(moe_router or {}))
-        elif moe_experts:
-            self.moe = MoE(moe_experts, capacity_factor=moe_capacity_factor,
-                           ep_axis=ep_axis, k=moe_k)
+        if norms.pre:
+            self.ln1 = norms.make()
+        setattr(self, mixer.attr, mixer.build(tp_axis, norms.eps))
+        if norms.pre:
+            self.ln2 = norms.make()
+        if norms.post:
+            self.ln1_post, self.ln2_post = norms.make(), norms.make()
+        if isinstance(ffn, Experts):
+            self.moe = ffn.build()
 
     def initialize(self, x):
-        e = x.shape[-1]
-        width = self.ffn_dim or e * self.mlp_ratio
-        if self.moe_experts:
+        e, ffn = x.shape[-1], self.ffn
+        width = ffn.dim or e * ffn.ratio
+        if isinstance(ffn, Experts):
             self.moe.hidden = width
             return
-        wide = dict(bias=self.ffn_bias, tp_axis=self.tp_axis,
-                    tp_mode="column")
-        if self.ffn == "swiglu":
+        wide = dict(bias=ffn.bias, tp_axis=self.tp_axis, tp_mode="column")
+        if ffn.kind == "swiglu":
             self.fc_gate = Linear(width, **wide)
         self.fc1 = Linear(width, **wide)
-        self.fc2 = Linear(e, bias=self.ffn_bias, tp_axis=self.tp_axis,
+        self.fc2 = Linear(e, bias=ffn.bias, tp_axis=self.tp_axis,
                           tp_mode="row")
 
     def forward(self, x):
-        op = self.attn if self.mixer == "attention" \
-            else getattr(self, self.mixer)
-        a = op(self.ln1(x) if self.pre_norm else x)
-        x = autograd.add(x, self.ln1_post(a) if self.post_norm else a)
-        h = self.ln2(x) if self.pre_norm else x
-        if self.moe_experts:
+        pre, post = self.norms.pre, self.norms.post
+        a = getattr(self, self.mixer.attr)(self.ln1(x) if pre else x)
+        x = autograd.add(x, self.ln1_post(a) if post else a)
+        h = self.ln2(x) if pre else x
+        if isinstance(self.ffn, Experts):
             m = self.moe(h)
-        elif self.ffn == "swiglu":
+        elif self.ffn.kind == "swiglu":
             m = self.fc2(autograd.swiglu(self.fc_gate(h), self.fc1(h)))
         else:
             m = self.fc2(autograd.gelu(self.fc1(h)))
-        return autograd.add(x, self.ln2_post(m) if self.post_norm else m)
+        return autograd.add(x, self.ln2_post(m) if post else m)
 
 
 class DroplessMoE(Layer):

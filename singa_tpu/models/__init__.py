@@ -1,10 +1,12 @@
 """Model zoo — TPU-native counterparts of the reference's example models
 (examples/cnn/model/{cnn,alexnet,resnet,xceptionnet}.py, examples/mlp).
 
-Each module exposes `create_model(**kwargs)`; every model is a
-`model.Model` whose `train_one_batch(x, y, dist_option, spars)` dispatches
-to the DistOpt strategy named by `dist_option` (the reference repeats this
-dispatch in every model file; here it lives once in `base.Classifier`).
+The registry names each model's constructor: a module's
+`create_model(**kwargs)`, or a language model's class. Every classifier is
+a `model.Model` whose `train_one_batch(x, y, dist_option, spars)`
+dispatches to the DistOpt strategy named by `dist_option` (the reference
+repeats this dispatch in every model file; here it lives once in
+`base.Classifier`).
 """
 
 from .. import observe
@@ -25,11 +27,11 @@ _REGISTRY = {
     "xceptionnet": xceptionnet.create_model,
     "gpt": transformer.create_model,
     "gpt_pipe": transformer.create_pipelined,
-    "looplm": looplm.create_model,
-    "mellum": mellum.create_model,
-    "sdar": sdar.create_model,
-    "lfm2": lfm2.create_model,
-    "olmo_hybrid": olmo_hybrid.create_model,
+    "looplm": looplm.LoopLM,
+    "mellum": mellum.Mellum,
+    "sdar": sdar.SDAR,
+    "lfm2": lfm2.LFM2,
+    "olmo_hybrid": olmo_hybrid.OlmoHybrid,
 }
 
 

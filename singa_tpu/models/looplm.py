@@ -13,13 +13,12 @@ Every N, Attn, MLP, W_head and the gate has ONE set of weights, used at
 every pass: a parameter has T consumers on the tape, and its gradient is
 whole only when the backward pass reaches the first pass's use of it.
 
-The block is `layer.TransformerBlock` by arguments. With `recompute=True`
-each block application and each pass's head-and-loss is an
-`autograd.Region`: the step keeps the regions' inputs (T x L block inputs,
-T hidden states) and rebuilds everything else on the way back; without it
-they are recorded on the ordinary tape. Under `amp` the shared weights are
-cast once a step (`autograd.cast_once`), not once a use, and a weight's T
-gradients are summed in fp32.
+Embedding, blocks, final norm and head are models/lm.py's, and so is the
+`recompute` rule: the blocks it names, in every pass, and each pass's
+head-and-loss (where it names any) are `autograd.Region`s, kept as their
+inputs (T x L block inputs, T hidden states) and rebuilt on the way back.
+Under `amp` the shared weights are cast once a step (`autograd.cast_once`),
+not once a use, and a weight's T gradients are summed in fp32.
 """
 
 from __future__ import annotations
@@ -29,9 +28,10 @@ import jax.numpy as jnp
 from jax import lax
 import numpy as np
 
-from .. import autograd, layer, model, observe
+from .. import autograd, layer, observe
 from .. import tensor as tensor_module
 from ..tensor import Tensor
+from . import lm
 
 
 class _TokenCrossEntropy(autograd.Operator):
@@ -120,49 +120,31 @@ class ExitGate(layer.Layer):
         return _Gate()(h, self.w, self.b)
 
 
-class LoopLM(model.Model):
+class LoopLM(lm.LM):
     """`forward(ids)` -> (the T passes' logits (T, B, S, V), the exit
     distribution (T, B, S)); `train_one_batch(ids, targets)` -> (loss, the
     T passes' mean cross-entropies, the mean exit distribution, the last
-    pass's logits at `sample` fixed positions): never the (tokens,
-    vocabulary) logits, which a loop that runs ahead would pile up."""
+    pass's logits at `sample` fixed positions)."""
 
-    def __init__(self, vocab_size, dim=256, num_heads=8, num_layers=4,
+    def __init__(self, vocab_size=256, dim=256, num_heads=8, num_layers=4,
                  ffn_dim=None, ut_steps=4, rope_theta=1e6, norm_eps=1e-6,
                  beta=0.1, sample=128, recompute=False, name=None):
-        super().__init__(name)
-        self.vocab_size, self.dim = vocab_size, dim
-        self.ut_steps, self.beta, self.sample = ut_steps, beta, sample
-        self.recompute = recompute
-        # the residual stream stays fp32 under `amp`
-        self.tok_embed = layer.Embedding(vocab_size, dim, out_dtype="float32")
-        self.blocks = [layer.TransformerBlock(
-            num_heads, causal=True, rope=True, rope_theta=rope_theta,
-            norm="rms", norm_eps=norm_eps, ffn="swiglu", ffn_dim=ffn_dim,
-            ffn_bias=False, post_norm=True) for _ in range(num_layers)]
-        self.register_layers(*self.blocks)
-        self.ln_f = layer.RMSNorm(norm_eps)
-        self.head = layer.Linear(vocab_size, bias=False, out_dtype="float32")
+        blocks = [layer.TransformerBlock(
+            layer.Attention(num_heads, rope=True, rope_theta=rope_theta),
+            layer.FeedForward("swiglu", ffn_dim, bias=False),
+            layer.Norms("rms", norm_eps, post=True))
+            for _ in range(num_layers)]
+        super().__init__(vocab_size, dim, blocks, norm_eps, sample, recompute,
+                         name)
+        self.ut_steps, self.beta = ut_steps, beta
         self.exit_gate = ExitGate()
-
-    # -- the regions a step may recompute ----------------------------------
-    def _region(self, fn, *xs, reads):
-        """A block application or a pass's head-and-loss: what a training
-        step with `recompute` keeps as its inputs alone and rebuilds on the
-        way back."""
-        if not (autograd.training and self.recompute):
-            return fn(*xs)
-        self._regions += 1
-        return autograd.region(fn, *xs, reads=reads)
 
     def _passes(self, ids):
         """The hidden state after each pass, h_1..h_T."""
         h = self.tok_embed(ids)
         for t in range(1, self.ut_steps + 1):
             with jax.named_scope(f"ut{t}"):
-                for b in self.blocks:
-                    h = self._region(b, h, reads=b.get_params().values())
-                h = self.ln_f(h)
+                h = self.ln_f(self.trunk(h)[0])
             yield h
 
     def _shared_weights(self):
@@ -193,11 +175,8 @@ class LoopLM(model.Model):
         return fn
 
     def train_one_batch(self, ids, targets):
-        T = self.ut_steps
-        n = int(np.prod(ids.shape))
-        # the sampled positions: fixed, evenly spread over the batch
-        rows = np.linspace(0, n - 1, min(self.sample, n)).astype(np.int32)
-        self._regions = 0
+        T, rebuilt = self.ut_steps, self.rebuilt()
+        rows = self.sample_rows(int(np.prod(ids.shape)))
         with jax.named_scope("loop_loss"):
             tflat = autograd.reshape(targets, (-1,))
         # the backward pass too: its rebuilt regions find the same copies
@@ -205,33 +184,19 @@ class LoopLM(model.Model):
             lams, ces, sampled = [], [], None
             for t, h in enumerate(self._passes(ids), 1):
                 lams.append(self.exit_gate(h))
-                out = self._region(
+                out = self.region(
                     self._head_loss(rows if t == T else None), h, tflat,
+                    rebuild=rebuilt > 0,
                     reads=self.head.get_params().values())
                 if t == T:
                     out, sampled = out
                 ces.append(out)
             with jax.named_scope("loop_loss"):
                 loss, ce_pass, p_mean = _LoopLoss(self.beta)(*lams, *ces)
-            _loop_plan(passes=T, blocks=len(self.blocks),
-                       applications=T * len(self.blocks),
-                       recomputed_regions=self._regions,
-                       weight_casts=1 if autograd.compute_dtype else 0)
+            observe.record_plan(
+                "singa_loop_plan", passes=T, blocks=len(self.blocks),
+                applications=T * len(self.blocks),
+                recomputed_regions=T * (rebuilt + (rebuilt > 0)),
+                weight_casts=1 if autograd.compute_dtype else 0)
             self.optimizer(loss)
         return loss, ce_pass, p_mean, sampled
-
-
-def _loop_plan(**kinds):
-    """What the latest traced step of a looped model does, readable with
-    no chip: `singa_loop_plan{kind}`."""
-    g = observe.gauge(
-        "singa_loop_plan",
-        "the latest traced step of a looped model, by kind: passes, blocks, "
-        "block applications, regions recomputed in the backward pass, casts "
-        "of one shared weight to the compute dtype (0: no amp)")
-    for kind, v in kinds.items():
-        g.set(v, kind=kind)
-
-
-def create_model(vocab_size=256, **kwargs):
-    return LoopLM(vocab_size, **kwargs)

@@ -4,7 +4,7 @@ fourth (Olmo Hybrid, `model_type: "olmo_hybrid"`), with OLMo 2's norms
 after each branch.
 
     layer:  h = x + N1(Op(x));  y = h + N2(FFN(h))       RMS norms, no norm
-            before a branch; the residual stream fp32 under `amp`
+            before a branch
     Op:     by `layer_types`: "linear_attention": `layer.GatedDeltaNet`
             (ops/gated_delta.py: q, k, v through 4 causal taps and SiLU, q
             and k L2-normalised, beta = 2 sigmoid(x W_b), alpha =
@@ -14,92 +14,50 @@ after each branch.
             with RMS norms over the whole q and k projections, no rotary
             (the config's `rope_theta` is null), causal
     FFN:    (silu(n W_gate) * (n W_up)) W_down
-    loss:   mean cross-entropy of the untied head's logits over the
-            vocabulary held
 
-The layers are `layer.TransformerBlock` by arguments (`mixer`, `linear`,
-`pre_norm=False`, `post_norm=True`, `qk_norm="projection"`). `recompute`:
-the first n blocks (True: all) are `autograd.Region`s, rebuilt in the
-backward pass (models/mellum.py's rule).
+On models/lm.py's skeleton: blocks of `layer.DeltaRule` or
+`layer.Attention`, and `layer.Norms(pre=False, post=True)`.
 """
 
 from __future__ import annotations
 
 import math
 
-import jax
-import numpy as np
-
-from .. import autograd, layer, model, observe
-from .mellum import _SampleLogits
+from .. import layer, observe
+from . import lm
 
 LINEAR, FULL = "linear_attention", "full_attention"
 
 
-class OlmoHybrid(model.Model):
+class OlmoHybrid(lm.LM):
     """`forward(ids)` -> logits (B, S, V); `train_one_batch(ids, targets)`
     -> (loss, the logits at `sample` fixed positions)."""
 
-    def __init__(self, vocab_size, dim=256, num_heads=8, head_dim=None,
+    def __init__(self, vocab_size=256, dim=256, num_heads=8, head_dim=None,
                  layer_types=(LINEAR, LINEAR, LINEAR, FULL), ffn_dim=512,
                  linear_heads=8, linear_key_dim=32, linear_value_dim=64,
                  linear_conv_taps=4, linear_neg_eigval=True, chunk=64,
                  norm_eps=1e-6, sample=128, recompute=False, name=None):
-        super().__init__(name)
         assert all(t in (LINEAR, FULL) for t in layer_types), layer_types
-        self.vocab_size, self.dim, self.sample = vocab_size, dim, sample
-        self.layer_types = tuple(layer_types)
-        self.recompute = len(layer_types) if recompute is True \
-            else int(recompute)
-        self.linear = dict(heads=linear_heads, key_dim=linear_key_dim,
-                           value_dim=linear_value_dim,
-                           conv_taps=linear_conv_taps,
-                           neg_eigval=linear_neg_eigval, chunk=chunk,
-                           norm_eps=norm_eps)
-        # the residual stream stays fp32 under `amp`
-        self.tok_embed = layer.Embedding(vocab_size, dim, out_dtype="float32")
-        self.blocks = [layer.TransformerBlock(
-            num_heads, mixer="linear_attn" if kind == LINEAR else "attention",
-            linear=self.linear, causal=True, head_dim=head_dim, rope=False,
-            qk_norm="projection", norm="rms", norm_eps=norm_eps,
-            ffn="swiglu", ffn_dim=ffn_dim, ffn_bias=False, pre_norm=False,
-            post_norm=True)
-            for kind in self.layer_types]
-        self.register_layers(*self.blocks)
-        self.ln_f = layer.RMSNorm(norm_eps)
-        self.head = layer.Linear(vocab_size, bias=False, out_dtype="float32")
-        self.sce = layer.SoftMaxCrossEntropy()
+        linear = layer.DeltaRule(
+            linear_heads, linear_key_dim, linear_value_dim,
+            conv_taps=linear_conv_taps, neg_eigval=linear_neg_eigval,
+            chunk=chunk, norm_eps=norm_eps)
+        attn = layer.Attention(num_heads, head_dim=head_dim,
+                               qk_norm="projection")
+        blocks = [layer.TransformerBlock(
+            linear if kind == LINEAR else attn,
+            layer.FeedForward("swiglu", ffn_dim, bias=False),
+            layer.Norms("rms", norm_eps, pre=False, post=True))
+            for kind in layer_types]
+        super().__init__(vocab_size, dim, blocks, norm_eps, sample, recompute,
+                         name)
+        self.layer_types, self.linear = tuple(layer_types), linear
 
-    def _trunk(self, ids):
-        h = self.tok_embed(ids)
-        for i, b in enumerate(self.blocks):
-            if autograd.training and i < self.recompute:
-                h = autograd.region(b, h, reads=b.get_params().values())
-            else:
-                h = b(h)
-        return self.ln_f(h)
-
-    def forward(self, ids):
-        return self.head(self._trunk(ids))
-
-    def train_one_batch(self, ids, targets):
-        n = int(np.prod(ids.shape))
-        at = np.linspace(0, n - 1, min(self.sample, n)).astype(np.int32)
-        h = self._trunk(ids)
-        with jax.named_scope("head"):
-            sampled = _SampleLogits(at)(h, self.head.W)
-        loss = self.sce(self.head(h), targets)
-        rebuilt = min(self.recompute, len(self.blocks)) \
-            if autograd.training else 0
-        observe.record_linear_attn_plan(
-            layers=self.layer_types.count(LINEAR),
-            heads=self.linear["heads"], key_dim=self.linear["key_dim"],
-            value_dim=self.linear["value_dim"], chunk=self.linear["chunk"],
-            chunks=math.ceil(ids.shape[-1] / self.linear["chunk"]),
+    def plan(self, shape):
+        d, rebuilt = self.linear, self.rebuilt()
+        observe.record_plan(
+            "singa_linear_attn_plan", layers=self.layer_types.count(LINEAR),
+            heads=d.heads, key_dim=d.key_dim, value_dim=d.value_dim,
+            chunk=d.chunk, chunks=math.ceil(shape[-1] / d.chunk),
             recomputed_blocks=self.layer_types[:rebuilt].count(LINEAR))
-        self.optimizer(loss)
-        return loss, sampled
-
-
-def create_model(vocab_size=256, **kwargs):
-    return OlmoHybrid(vocab_size, **kwargs)

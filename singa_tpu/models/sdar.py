@@ -4,8 +4,7 @@ experts routed top-k with nothing dropped, attention is grouped-query with
 RMS norms on q and k, and a training step feeds the sequence TWICE, noised
 and clean, under a mask that is not causal.
 
-    layer:  h = x + Attn(N1(x));  y = h + MoE(N2(h))      RMS norms, the
-            residual stream fp32 under `amp`
+    layer:  h = x + Attn(N1(x));  y = h + MoE(N2(h))      RMS norms
     Attn:   q = Nq(x Wq), k = Nk(x Wk) per head over `head_dim` (learned
             gain), then rotary (rotate-half, `rope_theta`, no scaling) at
             position p(i); v = x Wv; `num_heads` query heads on
@@ -32,21 +31,18 @@ and clean, under a mask that is not causal.
 `data.block_diffusion_noise` draws `masked` and the weight `masked / t`; the
 step makes `xt` and the doubled input on the device (`noise`). MASK is the
 last row of the vocabulary held (`vocab_size - 1`): data never draws it.
-The layers are `layer.TransformerBlock` by arguments (`block_diffusion`,
-`qk_norm`, `head_dim`, `moe_dropless`); the flash kernels schedule the
-mask (ops/attention.py: no grid step outside it). `experts_held` /
-`expert_offset` and `recompute` as in models/mellum.py; `sample`: the
-step hands back the logits at that many positions of the noised half
-(`sample_positions`).
+On models/lm.py's skeleton; the flash kernels schedule the mask
+(ops/attention.py: no grid step outside it). `experts_held` /
+`expert_offset` as in models/mellum.py.
 """
 
 from __future__ import annotations
 
-import jax
 import numpy as np
 
-from .. import autograd, layer, model, observe
-from .mellum import _SampleLogits, _Stack, _moe_plan, record_rows  # noqa: F401
+from .. import autograd, layer, observe
+from . import lm
+from .lm import record_rows  # noqa: F401  (the benchmark's drivers call it)
 
 
 class _Noise(autograd.Operator):
@@ -79,92 +75,49 @@ def sample_positions(batch, seq, sample):
     return np.concatenate([np.arange(first), rest]).astype(np.int32)
 
 
-class SDAR(model.Model):
+class SDAR(lm.SparseLM):
     """`forward(doubled)` -> the noised half's logits (B, S, V) of a
     doubled input (B, 2S); `train_one_batch(ids, masked, weight)` -> (loss,
     the logits at `sample` fixed positions of the noised half, the rows
     routed to each held expert of each layer (L, held))."""
 
-    def __init__(self, vocab_size, dim=256, num_heads=8, num_kv_heads=2,
+    def __init__(self, vocab_size=256, dim=256, num_heads=8, num_kv_heads=2,
                  head_dim=64, num_layers=2, ffn_dim=128, num_experts=8,
                  experts_per_token=2, experts_held=None, expert_offset=0,
                  rope_theta=1e6, norm_eps=1e-6, block_length=4, sample=128,
                  recompute=False, name=None):
-        super().__init__(name)
-        self.vocab_size, self.dim, self.sample = vocab_size, dim, sample
+        attn = layer.Attention(
+            num_heads, causal=False, num_kv_heads=num_kv_heads,
+            head_dim=head_dim, block_diffusion=int(block_length), rope=True,
+            rope_theta=rope_theta, qk_norm="head")
+        experts = layer.Experts(num_experts, experts_per_token, dim=ffn_dim,
+                                dropless=True, held=experts_held,
+                                offset=expert_offset)
+        blocks = [layer.TransformerBlock(attn, experts,
+                                         layer.Norms("rms", norm_eps))
+                  for _ in range(num_layers)]
+        super().__init__(vocab_size, dim, blocks, norm_eps, sample, recompute,
+                         name)
         self.mask_id, self.block_length = vocab_size - 1, int(block_length)
-        self.recompute = num_layers if recompute is True else int(recompute)
-        self.num_experts, self.k = num_experts, experts_per_token
-        # the residual stream stays fp32 under `amp`
-        self.tok_embed = layer.Embedding(vocab_size, dim, out_dtype="float32")
-        self.blocks = [layer.TransformerBlock(
-            num_heads, causal=False, block_diffusion=self.block_length,
-            qk_norm="head", num_kv_heads=num_kv_heads, head_dim=head_dim,
-            rope=True, rope_theta=rope_theta, norm="rms", norm_eps=norm_eps,
-            ffn_dim=ffn_dim, moe_experts=num_experts,
-            moe_k=experts_per_token, moe_dropless=True,
-            moe_held=experts_held, moe_offset=expert_offset)
-            for _ in range(num_layers)]
-        self.register_layers(*self.blocks)
-        self.ln_f = layer.RMSNorm(norm_eps)
-        self.head = layer.Linear(vocab_size, bias=False, out_dtype="float32")
-        self.sce = layer.SoftMaxCrossEntropy()
 
-    def _trunk(self, doubled):
-        """(the final norm's output on the noised half (B, S, d), [rows
-        routed a held expert] a layer) of a doubled input (B, 2S)."""
+    def hidden(self, doubled):
+        """Of a doubled input (B, 2S): the noised half, sliced, normed."""
         S = doubled.shape[1] // 2
         assert doubled.shape[1] == 2 * S and S % self.block_length == 0, \
             (doubled.shape, self.block_length)
-        h, rows = self.tok_embed(doubled), []
-        for i, b in enumerate(self.blocks):
-            fn = lambda x, b=b: (b(x), b.moe.rows)
-            if autograd.training and i < self.recompute:
-                h, r = autograd.region(fn, h, reads=b.get_params().values())
-            else:
-                h, r = fn(h)
-            rows.append(r)
-        return self.ln_f(autograd.slice(h, [0], [S], [1])), rows
-
-    def forward(self, doubled):
-        return self.head(self._trunk(doubled)[0])
+        h, counts = self.trunk(self.tok_embed(doubled))
+        return self.ln_f(autograd.slice(h, [0], [S], [1])), counts
 
     def train_one_batch(self, ids, masked, weight):
         B, S = ids.shape
-        n = B * S
-        at = sample_positions(B, S, self.sample)
-        h, rows = self._trunk(_Noise(self.mask_id)(ids, masked))
-        with jax.named_scope("head"):
-            sampled = _SampleLogits(at)(h, self.head.W)
+        n, b = B * S, self.block_length
+        h, counts = self.hidden(_Noise(self.mask_id)(ids, masked))
+        sampled = self.sampled_logits(h, sample_positions(B, S, self.sample))
         loss = self.sce(self.head(h), ids, weight)
-        held, b = self.blocks[0].moe.held, self.block_length
-        recomputed = min(self.recompute, len(self.blocks)) \
-            if autograd.training else 0
-        _moe_plan(experts=self.num_experts, held=held, k=self.k,
-                  rows_worst=2 * n * min(self.k, held),
-                  recomputed_blocks=recomputed)
-        _blockdiff_plan(block=b, rows=2 * n, loss_rows=n,
-                        pairs_inside=B * (S * S + S * b),
-                        pairs_square=B * 4 * S * S,
-                        recomputed_blocks=recomputed)
+        self.moe_plan(2 * n)
+        observe.record_plan(
+            "singa_blockdiff_plan", block=b, rows=2 * n, loss_rows=n,
+            pairs_inside=B * (S * S + S * b), pairs_square=B * 4 * S * S,
+            recomputed_blocks=self.rebuilt())
         self.optimizer(loss)
-        return loss, sampled, _Stack()(*rows)
-
-
-def _blockdiff_plan(**kinds):
-    """What the latest traced block-diffusion step feeds its layers,
-    readable with no chip: `singa_blockdiff_plan{kind}`."""
-    g = observe.gauge(
-        "singa_blockdiff_plan",
-        "the latest traced block-diffusion training step, by kind: block "
-        "(positions a block), rows (of the doubled input, through every "
-        "layer), loss_rows (the noised half: the head's and the loss's), "
-        "pairs_inside (query, key pairs a head inside the mask: S^2 + S b "
-        "a sequence), pairs_square (the doubled sequence's square), "
-        "recomputed_blocks (rebuilt in the backward pass)")
-    for kind, v in kinds.items():
-        g.set(v, kind=kind)
-
-
-def create_model(vocab_size=256, **kwargs):
-    return SDAR(vocab_size, **kwargs)
+        return (loss, sampled, *self.outputs(counts))

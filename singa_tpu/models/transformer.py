@@ -146,13 +146,15 @@ class GPT(_VocabTPMixin, model.Model):
         self.moe_experts = moe_experts
         self.moe_aux_weight = moe_aux_weight
         self.moe_z_weight = moe_z_weight
-        blocks = [layer.TransformerBlock(
-            num_heads, mlp_ratio, causal=True, seq_axis=seq_axis,
-            tp_axis=tp_axis, attn_bias=attn_bias, moe_experts=moe_experts,
-            moe_k=moe_k, ep_axis=ep_axis,
-            moe_capacity_factor=moe_capacity_factor,
-            num_kv_heads=num_kv_heads,
-            rope=(pos_encoding == "rope"), rope_theta=rope_theta)
+        attn = layer.Attention(num_heads, num_kv_heads=num_kv_heads,
+                               rope=(pos_encoding == "rope"),
+                               rope_theta=rope_theta, bias=attn_bias,
+                               seq_axis=seq_axis)
+        ffn = layer.Experts(moe_experts, moe_k, ratio=mlp_ratio,
+                            capacity_factor=moe_capacity_factor,
+                            ep_axis=ep_axis) if moe_experts \
+            else layer.FeedForward(ratio=mlp_ratio)
+        blocks = [layer.TransformerBlock(attn, ffn, tp_axis=tp_axis)
                   for _ in range(num_layers)]
         self.blocks = blocks
         self.register_layers(*blocks)

@@ -903,23 +903,50 @@ def record_delta_rule_dispatch(path: str):
             "(kernel|interpret|xla)").inc(path=path)
 
 
+# the plan gauges: what the latest traced step of a model does, one number
+# by `kind` (each model names its kinds), set at trace time by
+# `record_plan` and readable with no chip
+PLANS = {
+    "singa_moe_plan":
+        "the latest traced step's expert layers, by kind: experts routed "
+        "over, experts this device holds, choices a token (k), rows of the "
+        "sorted buffer (tokens x min(k, held): the worst case; the grouped "
+        "products follow the rows really routed, the passes over the buffer "
+        "the rung that holds them), the least rung of the buffer's ladder, "
+        "blocks recomputed in the backward pass; where the model says so, "
+        "dense_layers (leading layers without experts), sigmoid (1: the "
+        "router scores by a sigmoid, not a softmax), bias (1: a selection "
+        "bias that the step moves)",
+    "singa_blockdiff_plan":
+        "the latest traced block-diffusion training step, by kind: block "
+        "(positions a block), rows (of the doubled input, through every "
+        "layer), loss_rows (the noised half: the head's and the loss's), "
+        "pairs_inside (query, key pairs a head inside the mask: S^2 + S b "
+        "a sequence), pairs_square (the doubled sequence's square), "
+        "recomputed_blocks (rebuilt in the backward pass)",
+    "singa_conv_plan":
+        "the latest traced step's gated short convolutions, by kind: "
+        "layers that run one, channels (the stream's width: each has its "
+        "own filter), taps a filter, and how many of those layers sit in "
+        "blocks rebuilt in the backward pass",
+    "singa_loop_plan":
+        "the latest traced step of a looped model, by kind: passes, blocks, "
+        "block applications, regions recomputed in the backward pass, casts "
+        "of one shared weight to the compute dtype (0: no amp)",
+    "singa_linear_attn_plan":
+        "the latest traced step's gated delta-rule layers, by kind "
+        "(layers|heads|key_dim|value_dim|chunk|chunks|recomputed_blocks)",
+}
+# the kinds of `singa_linear_attn_plan` (models/olmo_hybrid.py)
 LINEAR_ATTN_KINDS = ("layers", "heads", "key_dim", "value_dim", "chunk",
                      "chunks", "recomputed_blocks")
 
 
-def record_linear_attn_plan(**kinds):
-    """What the latest traced step's linear-attention layers work on, set
-    at trace time and readable with no chip: layers, heads a layer, the
-    key and value widths of a head, tokens a chunk of the delta rule,
-    chunks a sequence, and how many of those layers sit in blocks rebuilt
-    in the backward pass."""
-    assert set(kinds) == set(LINEAR_ATTN_KINDS), kinds
+def record_plan(name, **kinds):
+    """Set the plan gauge `name` (one of `PLANS`) by kind."""
     if not _enabled:
         return
-    g = gauge("singa_linear_attn_plan",
-              "the latest traced step's gated delta-rule layers, by kind "
-              "(layers|heads|key_dim|value_dim|chunk|chunks|"
-              "recomputed_blocks)")
+    g = gauge(name, PLANS[name])
     for kind, v in kinds.items():
         g.set(v, kind=kind)
 
@@ -1157,5 +1184,5 @@ __all__ = [
     "record_comm_host",
     "record_decode", "record_scaler_decision",
     "record_regress_verdict", "record_checkpoint_bytes",
-    "record_prefetch", "record_ckpt_async",
+    "record_prefetch", "record_ckpt_async", "record_plan",
 ]

@@ -826,7 +826,7 @@ def _decode_core(m, S0, max_new, moe_capacity_factor=None, kv_dtype=None):
     moe_ks = [(b.moe.k, float(moe_capacity_factor
                               if moe_capacity_factor is not None
                               else b.moe.capacity_factor))
-              if b.moe_experts else None for b in m.blocks]
+              if hasattr(b, "moe") else None for b in m.blocks]
     return _DecodeCore(H, m.dim, S0, T, (m.dim // H) ** -0.5, moe_ks,
                        kv_heads=kv,
                        rope=(getattr(m, "pos_encoding", "learned")
@@ -857,7 +857,7 @@ def decode_raw(m):
         if b.attn.use_bias:
             arrs += [b.attn.bq.data, b.attn.bk.data, b.attn.bv.data,
                      b.attn.bo.data]
-        if b.moe_experts:
+        if hasattr(b, "moe"):
             arrs += [b.moe.Wg.data, b.moe.W1.data, b.moe.b1.data,
                      b.moe.W2.data, b.moe.b2.data]
         else:
@@ -931,7 +931,7 @@ def decode_params(m):
             "bo": b.attn.bo.data if ab else zeros,
             "g2": b.ln2.gamma.data, "b2": b.ln2.beta.data,
         }
-        if b.moe_experts:
+        if hasattr(b, "moe"):
             # routing degree/capacity stay STATIC on _DecodeCore
             # (moe_ks), not in the traced param tree
             bp.update({

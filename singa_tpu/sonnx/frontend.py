@@ -621,7 +621,7 @@ UNEXPORTABLE = {
     "_LoopLoss": "training loss (see CrossEntropy)",
     "_TokenCrossEntropy": "training loss (see CrossEntropy)",
     "_Rows": "training step's sample of the logits, off the tape",
-    # the sparse model's training step (models/mellum.py)
+    # the language models' training steps (models/lm.py, models/sdar.py)
     "_SampleLogits": "training step's sample of the logits, off the tape",
     "_Stack": "training step's rows routed a layer, off the tape",
     "_Zeros": "training step's counts of a layer without experts, off "

@@ -354,8 +354,9 @@ def test_short_conv_starts_from_a_zero_history():
 def test_short_conv_layer_holds_three_parameters_and_takes_amp():
     dev = device.get_default_device()
     dev.SetRandSeed(1)
-    blk = layer.TransformerBlock(4, mixer="conv", conv_taps=3, norm="rms",
-                                 ffn="swiglu", ffn_dim=96, ffn_bias=False)
+    blk = layer.TransformerBlock(layer.Convolution(taps=3),
+                                 layer.FeedForward("swiglu", 96, bias=False),
+                                 layer.Norms("rms"))
     x = tensor.from_numpy(np.random.default_rng(0).standard_normal(
         (2, 32, 64)).astype(np.float32), device=dev)
     y = blk(x)
@@ -366,7 +367,7 @@ def test_short_conv_layer_holds_three_parameters_and_takes_amp():
     assert not any(k.startswith("attn.") for k in shapes)
     assert float(jnp.abs(blk.conv.w.data).max()) <= 3 ** -0.5
     with pytest.raises(AssertionError):
-        layer.TransformerBlock(4, mixer="ssm")
+        layer.TransformerBlock("ssm")
 
 
 def test_onnx_export_refuses_the_short_convolution_by_name():

@@ -402,16 +402,17 @@ def test_recomputed_region_keeps_no_residual():
 
 class _OldBlock(layer.Layer):
     """layer.TransformerBlock as it stood before it took norm / ffn
-    arguments (PR 26), dense path."""
+    arguments, dense path; what it took as arguments it now reads from the
+    descriptions the block takes."""
 
-    def __init__(self, num_heads, mlp_ratio=4, causal=True, attn_bias=False,
-                 **_):
+    def __init__(self, mixer, ffn, **_):
         super().__init__(None)
         self.ln1 = layer.LayerNorm()
-        self.attn = layer.MultiHeadAttention(num_heads, causal=causal,
-                                             bias=attn_bias)
+        self.attn = layer.MultiHeadAttention(mixer.num_heads,
+                                             causal=mixer.causal,
+                                             bias=mixer.bias)
         self.ln2 = layer.LayerNorm()
-        self.mlp_ratio = mlp_ratio
+        self.mlp_ratio = ffn.ratio
 
     def initialize(self, x):
         e = x.shape[-1]

@@ -4,8 +4,9 @@ layers (models/olmo_hybrid.py) against its plain reference
 chunked delta rule against the token recurrence (a sequence that is not a
 multiple of the chunk, beta in (1, 2), the state left as it is where
 alpha = 1 and beta = 0), the layer's causality, the whole-projection QK
-norm, OLMo's norms after each branch, the loss over the vocabulary slice,
-the gauge, and the other models' steps lowered as before.
+norm, OLMo's norms after each branch, the loss over the vocabulary slice
+and the gauge. (The other models' steps, lowered as before, are pinned in
+tests/test_lowered_programs.py.)
 
 Small size on the CPU: a period of four layers (linear, linear, linear,
 full), hidden 64, 4 attention heads of 16, 2 linear heads of 8 / 16, 4
@@ -14,7 +15,6 @@ kernels run in interpret mode).
 """
 
 import functools
-import hashlib
 import importlib.util
 import os
 import re
@@ -430,9 +430,9 @@ def test_whole_projection_qk_norm_differs_from_the_per_head_one():
 def test_norms_sit_after_each_branch_only():
     """OLMo 2's block: x + N1(Op(x)), then h + N2(FFN(h)); no norm before
     a branch, and the sandwich block still has both."""
-    olmo = layer.TransformerBlock(4, norm="rms", ffn="swiglu", ffn_dim=48,
-                                  ffn_bias=False, pre_norm=False,
-                                  post_norm=True)
+    olmo = layer.TransformerBlock(
+        layer.Attention(4), layer.FeedForward("swiglu", 48, bias=False),
+        layer.Norms("rms", pre=False, post=True))
     x = tensor.from_numpy(np.random.default_rng(0).standard_normal(
         (1, 8, 32)).astype(np.float32) * 3)
     y = np.asarray(olmo(x).data)
@@ -447,48 +447,8 @@ def test_norms_sit_after_each_branch_only():
     m = (jax.nn.silu(h @ p["fc_gate.W"]) * (h @ p["fc1.W"])) @ p["fc2.W"]
     np.testing.assert_allclose(y, h + rms(np.asarray(m), p["ln2_post.gamma"]),
                                rtol=1e-4, atol=1e-4)
-    sandwich = layer.TransformerBlock(4, norm="rms", post_norm=True)
+    sandwich = layer.TransformerBlock(layer.Attention(4),
+                                      norms=layer.Norms("rms", post=True))
     sandwich(x)
     assert {"ln1", "ln2", "ln1_post", "ln2_post"} <= set(sandwich.sublayers())
 
-
-# ---- the other models' steps, as they were --------------------------------
-
-# sha256 of `m.lower_step().as_text()` (no locations) of one graph step
-# under bf16 amp and Adam, at the parent commit of ISSUE 40 and here: the
-# changes to TransformerBlock and MultiHeadAttention take new arguments
-# whose defaults leave these programs as they were. A PR that changes one
-# of these programs on purpose writes its new digest here and says so.
-LOWERED = {
-    "gpt": ("9aefea607561e1c33c23f57ed036ff1527b0d11a30d8b37bf5739202990f1a5a",
-            dict(vocab_size=96, max_seq=64, dim=64, num_heads=4,
-                 num_layers=2)),
-    "lfm2": ("1fa0ff567f4647b97cb4c8c0d5cb323dee9fbf74ec46af6409cd065dfa02d96a",
-             dict(vocab_size=96, dim=64, num_heads=4, num_kv_heads=2,
-                  head_dim=16, layer_types=["conv", "full_attention", "conv"],
-                  conv_taps=3, num_dense_layers=1, dense_ffn_dim=160,
-                  ffn_dim=48, num_experts=8, experts_per_token=4,
-                  experts_held=4, expert_offset=2, sample=16, recompute=2)),
-    "mellum": ("8b9b9a3fa202bcf2688d4421f2fb70ef3c838d7c9f51717f62950072c64b454f",
-               dict(vocab_size=96, dim=64, num_heads=4, num_kv_heads=2,
-                    head_dim=32,
-                    layer_types=["sliding_attention", "full_attention"],
-                    window=32, ffn_dim=48, num_experts=8, experts_per_token=4,
-                    experts_held=4, expert_offset=2, sample=16, recompute=1)),
-}
-
-
-@pytest.mark.parametrize("name", sorted(LOWERED))
-def test_other_models_lower_to_the_text_they_lowered_to(name):
-    digest, kw = LOWERED[name]
-    dev = device.get_default_device()
-    dev.SetRandSeed(3)
-    m = models.create_model(name, **kw)
-    m.set_optimizer(opt.Adam(lr=1e-3))
-    ids = np.random.default_rng(0).integers(0, 96, (2, 65)).astype(np.int32)
-    x, y = (tensor.from_numpy(a, device=dev)
-            for a in (ids[:, :-1].copy(), ids[:, 1:].copy()))
-    m.compile([x], is_train=True, use_graph=True, amp="bfloat16")
-    m(x, y)
-    text = m.lower_step().as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == digest

@@ -320,10 +320,11 @@ def test_the_eight_shares_add_up_to_the_uncut_reference_s_layer():
     total, rows, mid = 0, [], None
     for o in range(0, E, 2):
         blk = layer.TransformerBlock(
-            CFG["num_heads"], causal=False, block_diffusion=4, qk_norm="head",
-            num_kv_heads=2, head_dim=32, rope=True, rope_theta=1e6,
-            norm="rms", norm_eps=1e-6, ffn_dim=f, moe_experts=E, moe_k=4,
-            moe_dropless=True, moe_held=2, moe_offset=o)
+            layer.Attention(CFG["num_heads"], causal=False, num_kv_heads=2,
+                            head_dim=32, block_diffusion=4, rope=True,
+                            rope_theta=1e6, qk_norm="head"),
+            layer.Experts(E, 4, dim=f, dropless=True, held=2, offset=o),
+            layer.Norms("rms", 1e-6))
         blk(tx)                 # the first call makes its weights
         blk.set_params({k: np.asarray(v[o:o + 2]) if k in (
             "moe.Wg", "moe.Wu", "moe.Wd") else np.asarray(v)
@@ -518,7 +519,8 @@ def test_the_mask_is_one_argument_s_doing():
             layer.MultiHeadAttention(2, bad[0], window=bad[1],
                                      block_diffusion=(bad + (None,))[2])
     with pytest.raises(AssertionError):
-        layer.TransformerBlock(2, causal=True, window=8, block_diffusion=4)
+        layer.TransformerBlock(layer.Attention(2, causal=True, window=8,
+                                               block_diffusion=4))
     with pytest.raises(AssertionError):
         att.flash_plan(512, 512, 32, True, jnp.float32, block_diffusion=4)
     q = tensor.from_numpy(np.zeros((1, 1, 8, 4), np.float32))
